@@ -472,16 +472,16 @@ def main(argv=None):
     assert len(req_pids) == 2, \
         "one global rid must span prefill AND decode pids"
     roof = cl.stats()["roofline"]
-    assert roof["step_mfu"] > 0 and roof["step_hbm_bw_util"] > 0
+    on_chip = roof["device"] != "cpu"   # utilizations need a chip peak
+    assert (roof["step_mfu"] is not None) == on_chip
     with tempfile.TemporaryDirectory() as d13:
         cl.export_trace(os.path.join(d13, "fleet.json"))
     cl.shutdown()
     print(f"flight recorder: merged trace spans {len(procs)} pids, "
           f"{len(flows_s)} handoff flow links resolved, req{g} "
-          f"end-to-end across 2 replicas; roofline step_mfu "
-          f"{roof['step_mfu']:.4f}, hbm_bw_util "
-          f"{roof['step_hbm_bw_util']:.4f} "
-          f"(cpu_proxy={roof['cpu_proxy']})")
+          f"end-to-end across 2 replicas; roofline on "
+          f"{roof['device']}: step_mfu {roof['step_mfu']}, "
+          f"hbm_bw_util {roof['step_hbm_bw_util']}")
 
     # ---- 14. TREE speculation at the same verify node budget. Two
     # claims. Safety rail first: a chain-topology tree

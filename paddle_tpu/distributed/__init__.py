@@ -49,12 +49,28 @@ sharding = sharding_mod
 
 
 def spawn(func, args=(), nprocs=-1, join=True, daemon=False, **options):
-    """``paddle.distributed.spawn`` — multiprocess launch over local
-    devices (used by collective tests; each proc sees the emulated mesh)."""
+    """``paddle.distributed.spawn`` — multiprocess launch for CPU
+    emulation (each proc sees the same CPU device view). Refused where
+    the children would share an accelerator: a chip belongs to one
+    process, and one process drives every local chip through the
+    mesh."""
     import multiprocessing as mp
     import os
+    import jax
+    from jax._src import xla_bridge
+    from .launch import children_share_chip
     if nprocs == -1:
         nprocs = 1
+    parent_holds_chip = xla_bridge.backends_are_initialized() \
+        and jax.default_backend() != "cpu"
+    if parent_holds_chip or children_share_chip(nprocs):
+        raise RuntimeError(
+            f"distributed.spawn(nprocs={nprocs}): the children would "
+            "open an accelerator that "
+            + ("this process already holds" if parent_holds_chip
+               else "they all share")
+            + ", and a chip belongs to one process; set "
+            "JAX_PLATFORMS=cpu for multi-process CPU emulation")
     procs = []
     for rank in range(nprocs):
         env = dict(os.environ)

@@ -27,11 +27,19 @@ class ParallelEnv:
         self._init_from_env()
 
     def _init_from_env(self):
-        self.rank = int(os.environ.get("PADDLE_TRAINER_ID",
-                                       jax.process_index()))
+        # a launcher's environment answers without touching JAX: the
+        # process index / count queries initialise the backend, which
+        # must not happen before ``jax.distributed.initialize``
+        rank = os.environ.get("PADDLE_TRAINER_ID")
+        self.rank = int(rank) if rank is not None \
+            else jax.process_index()
         eps = os.environ.get("PADDLE_TRAINER_ENDPOINTS", "")
-        n_env = len(eps.split(",")) if eps else jax.process_count()
-        self.world_size = int(os.environ.get("PADDLE_TRAINERS_NUM", n_env))
+        world = os.environ.get("PADDLE_TRAINERS_NUM")
+        if world is not None:
+            self.world_size = int(world)
+        else:
+            self.world_size = len(eps.split(",")) if eps \
+                else jax.process_count()
         self.device_id = int(os.environ.get("FLAGS_selected_gpus",
                                             "0").split(",")[0])
         self.current_endpoint = os.environ.get("PADDLE_CURRENT_ENDPOINT",
@@ -73,15 +81,20 @@ def init_parallel_env(strategy=None):
     env = _env()
     coord = os.environ.get("PADDLE_MASTER") or \
         os.environ.get("MASTER_ADDR")
-    if coord and env.world_size > 1 and jax.process_count() == 1:
+    # One process per host drives all its chips, so the rendezvous is
+    # for jobs that run ONE process on each of several hosts. A
+    # launcher that starts several processes on this host
+    # (PADDLE_LOCAL_SIZE > 1) is the CPU emulation layout: its ranks
+    # share one device view and talk through the TCPStore collectives,
+    # never through the coordination service.
+    local_size = int(os.environ.get("PADDLE_LOCAL_SIZE", "1"))
+    if coord and env.world_size > 1 and local_size == 1 \
+            and not jax.distributed.is_initialized():
         port = os.environ.get("MASTER_PORT", "8476")
-        try:
-            jax.distributed.initialize(
-                coordinator_address=f"{coord}:{port}"
-                if ":" not in coord else coord,
-                num_processes=env.world_size, process_id=env.rank)
-        except Exception:
-            pass  # already initialized or single-host emulation
+        jax.distributed.initialize(
+            coordinator_address=f"{coord}:{port}"
+            if ":" not in coord else coord,
+            num_processes=env.world_size, process_id=env.rank)
     log_dir = os.environ.get("PADDLE_LOG_DIR")
     if log_dir:
         from ..framework.log import init_per_rank_logging

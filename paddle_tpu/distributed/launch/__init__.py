@@ -2,17 +2,36 @@
 distributed/launch/`` parity).
 
 The reference spawns one process per GPU with PADDLE_TRAINER_* env and an
-HTTP/etcd master. Single-controller jax on TPU usually wants ONE process
-per host seeing all local chips, so the default is nprocs=1 with the env
-set for rank bookkeeping; ``--nproc_per_node`` > 1 spawns the reference's
-multi-process layout for emulation/tests (each proc gets the same device
-view; collectives still run via the mesh).
+HTTP/etcd master. Single-controller jax on TPU wants ONE process per host
+seeing all local chips (a chip belongs to one process: a second one that
+reaches for it fails or hangs), so the default is nprocs=1 with the env
+set for rank bookkeeping. ``--nproc_per_node`` > 1 spawns the reference's
+multi-process layout for CPU emulation/tests only (each proc gets the
+same CPU device view; collectives run through the TCPStore) and is
+refused where the children would share an accelerator. The launcher
+itself never touches JAX — it must not hold the chip its child needs.
 """
 from __future__ import annotations
 
+import glob
 import os
 import subprocess
 import sys
+
+
+def children_share_chip(nprocs: int, environ=None) -> bool:
+    """Would ``nprocs`` children started from this process contend for
+    one accelerator? Answered WITHOUT touching JAX (asking JAX would
+    take the chip): children held to the CPU by ``JAX_PLATFORMS`` never
+    do; otherwise they do whenever the host has TPU device nodes, since
+    every child sees the same chips."""
+    if nprocs <= 1:
+        return False
+    environ = os.environ if environ is None else environ
+    platforms = environ.get("JAX_PLATFORMS", "")
+    if platforms:
+        return platforms.split(",")[0].strip().lower() != "cpu"
+    return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
 
 
 def parse_args(argv):
@@ -94,6 +113,7 @@ def _spawn_pod(args, nprocs, attempt, elastic_port=None):
                 f"{hosts[node_rank]}:{6170 + rank}",
             "PADDLE_MASTER": master,
             "PADDLE_NODE_RANK": str(node_rank),
+            "PADDLE_LOCAL_SIZE": str(nprocs),
             "PADDLE_RESTART_ATTEMPT": str(attempt),
             "PADDLE_LOG_DIR": args.log_dir,
             "FLAGS_selected_gpus": str(rank),
@@ -170,6 +190,13 @@ def _watch_pod(procs, poll_s=0.2, watcher=None, register_deadline=120.0):
 def launch(argv=None):
     args = parse_args(argv if argv is not None else sys.argv[1:])
     nprocs = args.nproc_per_node or 1
+    if children_share_chip(nprocs):
+        raise SystemExit(
+            f"launch: --nproc_per_node {nprocs} would start {nprocs} "
+            "processes that all open this host's accelerator, and a "
+            "chip belongs to one process. Run one process per host (it "
+            "drives every local chip through the mesh), or set "
+            "JAX_PLATFORMS=cpu for the multi-process CPU emulation.")
     os.makedirs(args.log_dir, exist_ok=True)
     watcher = None
     elastic_port = None

@@ -467,7 +467,7 @@ _GMM_TILING_BWD = (512, 512, 512)
 
 @_functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _gmm32(lhs, rhs, group_sizes, tiling):
-    """megablox gmm with every Pallas trace under disable_x64.
+    """megablox gmm with every Pallas trace under ``kernel_scope``.
 
     The stock ``megablox.ops.gmm`` custom VJP traces its backward
     kernels when jax.grad runs — outside any caller context manager —
@@ -480,8 +480,8 @@ def _gmm32(lhs, rhs, group_sizes, tiling):
     # the module of the same name; importlib reaches the module
     _mb = importlib.import_module(
         "jax.experimental.pallas.ops.tpu.megablox.gmm")
-    from ..ops.pallas.flash_attention_kernel import disable_x64
-    with disable_x64():
+    from ..ops.pallas.flash_attention_kernel import kernel_scope
+    with kernel_scope("megablox_gmm"):
         return _mb.gmm(lhs, rhs, group_sizes,
                        preferred_element_type=lhs.dtype, tiling=tiling)
 
@@ -495,8 +495,8 @@ def _mb_bwd_dlhs(g, rhs, group_sizes):
     import importlib
     _mb = importlib.import_module(
         "jax.experimental.pallas.ops.tpu.megablox.gmm")
-    from ..ops.pallas.flash_attention_kernel import disable_x64
-    with disable_x64():
+    from ..ops.pallas.flash_attention_kernel import kernel_scope
+    with kernel_scope("megablox_gmm"):
         return _mb.gmm(g, rhs, group_sizes,
                        preferred_element_type=g.dtype,
                        tiling=_GMM_TILING_BWD, transpose_rhs=True)
@@ -507,8 +507,8 @@ def _mb_bwd_drhs(lhs, g, group_sizes, num_groups):
     import importlib
     _mb = importlib.import_module(
         "jax.experimental.pallas.ops.tpu.megablox.gmm")
-    from ..ops.pallas.flash_attention_kernel import disable_x64
-    with disable_x64():
+    from ..ops.pallas.flash_attention_kernel import kernel_scope
+    with kernel_scope("megablox_tgmm"):
         return _mb.tgmm(lhs.swapaxes(0, 1), g, group_sizes,
                         preferred_element_type=g.dtype,
                         tiling=_GMM_TILING_BWD,
@@ -535,15 +535,11 @@ def _use_megablox(n_rows, d_in, d_out):
     under the fixed (512, 1024, 512) tiling). Since r6 this predicate
     also gates the PER-SHARD shapes inside the EP shard_map fast path —
     per-shard buffer shapes are static there, so the kernel is legal
-    under expert sharding. CPU test meshes, tiny shapes, and any shape
-    the kernel rejects at trace time take the ragged_dot path (see the
-    fallback in the callers)."""
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-    except Exception:
-        return False
-    return (n_rows >= 1024 and d_in % 8 == 0 and d_out % 8 == 0)
+    under expert sharding. CPU test meshes and tiny shapes take the
+    ragged_dot path; a shape this gate admits that the kernel then
+    refuses to trace or compile is an error, not a fallback."""
+    return (jax.default_backend() == "tpu"
+            and n_rows >= 1024 and d_in % 8 == 0 and d_out % 8 == 0)
 
 
 def _grouped_mm(lhs, rhs, group_sizes, tiling=None,
@@ -562,16 +558,8 @@ def _grouped_mm(lhs, rhs, group_sizes, tiling=None,
     MOE_STATS["grouped_mm_calls"] += 1
     if allow_pallas and _use_megablox(lhs.shape[0], lhs.shape[1],
                                       rhs.shape[-1]):
-        try:
-            out = _gmm32(lhs, rhs, group_sizes, tiling or _GMM_TILING)
-            MOE_STATS["grouped_mm_kernel"] = "megablox"
-            return out
-        except Exception as exc:
-            import warnings
-            warnings.warn(
-                "moe: megablox gmm unavailable for shape "
-                f"{lhs.shape} x {rhs.shape} ({exc!r}); using "
-                "lax.ragged_dot")
+        MOE_STATS["grouped_mm_kernel"] = "megablox"
+        return _gmm32(lhs, rhs, group_sizes, tiling or _GMM_TILING)
     MOE_STATS["grouped_mm_kernel"] = "ragged_dot"
     return jax.lax.ragged_dot(lhs, rhs, group_sizes)
 
@@ -581,14 +569,8 @@ def _grouped_mm_dlhs(g, rhs, group_sizes):
     transpose-rhs grouped matmul with the backward tiling."""
     MOE_STATS["grouped_mm_calls"] += 1
     if _use_megablox(g.shape[0], g.shape[1], rhs.shape[1]):
-        try:
-            out = _mb_bwd_dlhs(g, rhs, group_sizes)
-            MOE_STATS["grouped_mm_kernel"] = "megablox"
-            return out
-        except Exception as exc:
-            import warnings
-            warnings.warn(f"moe: megablox bwd gmm unavailable "
-                          f"({exc!r}); using lax.ragged_dot")
+        MOE_STATS["grouped_mm_kernel"] = "megablox"
+        return _mb_bwd_dlhs(g, rhs, group_sizes)
     MOE_STATS["grouped_mm_kernel"] = "ragged_dot"
     return jax.lax.ragged_dot(g, rhs.swapaxes(1, 2), group_sizes)
 
@@ -599,14 +581,8 @@ def _grouped_mm_drhs(lhs, g, group_sizes, num_groups):
     of ragged_dot elsewhere."""
     MOE_STATS["grouped_mm_calls"] += 1
     if _use_megablox(lhs.shape[0], lhs.shape[1], g.shape[-1]):
-        try:
-            out = _mb_bwd_drhs(lhs, g, group_sizes, num_groups)
-            MOE_STATS["grouped_mm_kernel"] = "megablox"
-            return out
-        except Exception as exc:
-            import warnings
-            warnings.warn(f"moe: megablox tgmm unavailable "
-                          f"({exc!r}); using ragged_dot transpose")
+        MOE_STATS["grouped_mm_kernel"] = "megablox"
+        return _mb_bwd_drhs(lhs, g, group_sizes, num_groups)
     MOE_STATS["grouped_mm_kernel"] = "ragged_dot"
     shape = jax.ShapeDtypeStruct(
         (num_groups, lhs.shape[1], g.shape[-1]), g.dtype)
@@ -647,26 +623,25 @@ def moe_fused_enabled() -> bool:
 
 def _use_fused_gmm(n_rows, d_model, d_ffn, fused=None):
     """Eligibility of the fused-dispatch kernels for this shape.
-    Returns ``False`` (sorted path), ``"tpu"`` (compiled kernels) or
-    ``"interpret"`` (Pallas interpreter — CPU tests set
-    ``PADDLE_TPU_MOE_FUSED_GMM=interpret`` to exercise the fused
-    graph end-to-end off-TPU). ``fused``: the per-call/config override
-    (``None`` = env default). Production gating mirrors
-    ``_use_megablox``: real TPU backend, MXU-scale row count, and
-    128-aligned dims so ``pick_tiling`` finds lane-aligned tiles."""
+    Returns ``False`` (sorted path) or ``"interpret"`` (Pallas
+    interpreter — CPU tests set ``PADDLE_TPU_MOE_FUSED_GMM=interpret``
+    to exercise the fused graph end-to-end). ``fused``: the
+    per-call/config override (``None`` = env default).
+
+    NEVER the compiled kernels: Mosaic (jax 0.9.0 / libtpu 0.0.34, one
+    v5e, ``chip_smoke.py`` PR 21) refuses both the gather-on-read and
+    the scatter-on-write — their per-row async copies slice ONE row
+    out of a tiled array ("Slice shape along dimension 0 must be
+    aligned to tiling (8), but is 1", for the HBM activations and the
+    VMEM store tile alike). On a TPU backend every shape therefore
+    takes the sorted megablox path; what a row gather Mosaic accepts
+    looks like is ROADMAP S1."""
     env = os.environ.get("PADDLE_TPU_MOE_FUSED_GMM", "1")
-    if env == "0" or fused is False:
+    if env != "interpret" or fused is False:
         return False
     aligned = (d_model % 128 == 0 and d_ffn % 128 == 0
                and n_rows % 128 == 0)
-    if env == "interpret":
-        return "interpret" if aligned else False
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-    except Exception:
-        return False
-    return "tpu" if (n_rows >= 1024 and aligned) else False
+    return "interpret" if aligned else False
 
 
 @_functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -1165,13 +1140,12 @@ def _dropless_ep(x, gates, topk_idx, gate_up, down, axis, ep,
 
     core.defvjp(core_fwd, core_bwd)
 
-    from .shard_utils import shard_map_compat
     from jax.sharding import PartitionSpec as P
-    f = shard_map_compat(
-        core, mesh,
+    f = jax.shard_map(
+        core, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(axis, None),
                   P(axis, None, None), P(axis, None, None)),
-        out_specs=(P(axis, None), P()))
+        out_specs=(P(axis, None), P()), check_vma=False)
     return f(x, gates, topk_idx, gate_up, down)
 
 

@@ -178,13 +178,13 @@ def pipeline_apply(stage_fn: PipelineStageFn, stacked_params,
         None, batch_spec if last_feeds.shape[1] == microbatches.shape[1]
         else None, *([None] * (last_feeds.ndim - 2)))
 
-    from .shard_utils import manual_region, shard_map_compat
-    mapped = shard_map_compat(
-        per_device, mesh,
-        (in_spec_params, mb_spec, rep(first_params), rep(last_params),
-         lf_spec,
-         *[P(*([None] * jnp.ndim(e))) for e in extra]),
-        out_spec)
+    from .shard_utils import manual_region
+    mapped = jax.shard_map(
+        per_device, mesh=mesh,
+        in_specs=(in_spec_params, mb_spec, rep(first_params),
+                  rep(last_params), lf_spec,
+                  *[P(*([None] * jnp.ndim(e))) for e in extra]),
+        out_specs=out_spec, check_vma=False)
     with manual_region():
         return mapped(stacked_params, microbatches, first_params,
                       last_params, last_feeds, *extra)

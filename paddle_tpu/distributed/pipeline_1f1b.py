@@ -312,13 +312,15 @@ def pipeline_1f1b_grads(stage_fn: Callable, stacked_params, feeds,
         return _pipe_outputs(axis, _axes, nm, n_dp, loss_acc,
                              gm_acc, gf_acc, gl_acc)
 
-    from .shard_utils import manual_region, shard_map_compat
-    mapped = shard_map_compat(
-        per_device, mesh,
-        (in_spec_params, feed_spec, rep(first_params), rep(last_params),
-         lf_spec, P()),
-        (P(), jax.tree_util.tree_map(lambda _: P(axis), stacked_params),
-         rep(first_params), rep(last_params)))
+    from .shard_utils import manual_region
+    mapped = jax.shard_map(
+        per_device, mesh=mesh,
+        in_specs=(in_spec_params, feed_spec, rep(first_params),
+                  rep(last_params), lf_spec, P()),
+        out_specs=(P(), jax.tree_util.tree_map(lambda _: P(axis),
+                                               stacked_params),
+                   rep(first_params), rep(last_params)),
+        check_vma=False)
     scale_a = jnp.float32(1.0) if loss_scale is None \
         else jnp.asarray(loss_scale, jnp.float32)
     with manual_region(), RecordEvent("pipeline:1f1b"):
@@ -682,13 +684,15 @@ def pipeline_interleaved_grads(stage_fn: Callable, stacked_params, feeds,
         return _pipe_outputs(axis, _axes, nm, n_dp, loss_acc,
                              gm_acc, gf_acc, gl_acc)
 
-    from .shard_utils import manual_region, shard_map_compat
-    mapped = shard_map_compat(
-        per_device, mesh,
-        (in_spec_params, feed_spec, rep(first_params), rep(last_params),
-         lf_spec, P()),
-        (P(), jax.tree_util.tree_map(lambda _: P(axis), stacked_params),
-         rep(first_params), rep(last_params)))
+    from .shard_utils import manual_region
+    mapped = jax.shard_map(
+        per_device, mesh=mesh,
+        in_specs=(in_spec_params, feed_spec, rep(first_params),
+                  rep(last_params), lf_spec, P()),
+        out_specs=(P(), jax.tree_util.tree_map(lambda _: P(axis),
+                                               stacked_params),
+                   rep(first_params), rep(last_params)),
+        check_vma=False)
     scale_a = jnp.float32(1.0) if loss_scale is None \
         else jnp.asarray(loss_scale, jnp.float32)
     from ..profiler import RecordEvent

@@ -98,9 +98,11 @@ def ring_flash_attention(q, k, v, mesh: Mesh = None, axis: str = "sep",
         per_device = functools.partial(_ring_contiguous, axis=axis,
                                        sp=sp, scale=scale, causal=causal)
 
-    from .shard_utils import shard_map_compat
     spec = P(None, axis, None, None)
-    mapped = shard_map_compat(per_device, mesh, (spec, spec, spec), spec)
+    mapped = jax.shard_map(
+        per_device, mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=spec, check_vma=False)
 
     if isinstance(q, Tensor):
         return apply_jax("ring_flash_attention", mapped, q, k, v)

@@ -94,9 +94,11 @@ def ulysses_attention(q, k, v, mesh: Mesh = None, axis: str = "sep",
         out = _full_seq_attention(qh, kh, vh, causal, scale)
         return h2s(out)
 
-    from .shard_utils import shard_map_compat
     spec = P(None, axis, None, None)
-    mapped = shard_map_compat(per_device, mesh, (spec, spec, spec), spec)
+    mapped = jax.shard_map(
+        per_device, mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=spec, check_vma=False)
 
     if isinstance(q, Tensor):
         return apply_jax("ulysses_attention", mapped, q, k, v)
@@ -147,14 +149,15 @@ class ReshardLayer:
             return jax.lax.all_to_all(xl, axis, split_axis=split_axis,
                                       concat_axis=concat_axis, tiled=True)
 
-        from .shard_utils import shard_map_compat
         ndim = as_jax(x).ndim
         in_spec = [None] * ndim
         in_spec[concat_axis] = axis
         out_spec = [None] * ndim
         out_spec[split_axis] = axis
-        mapped = shard_map_compat(per_device, mesh, (P(*in_spec),),
-                                  P(*out_spec))
+        mapped = jax.shard_map(
+            per_device, mesh=mesh,
+            in_specs=(P(*in_spec),),
+            out_specs=P(*out_spec), check_vma=False)
         if isinstance(x, Tensor):
             return apply_jax("sep_reshard", mapped, x)
         return mapped(as_jax(x))

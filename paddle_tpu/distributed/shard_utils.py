@@ -50,21 +50,6 @@ def in_manual_region() -> bool:
     return getattr(_manual_tls, "on", False)
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions (check_rep→check_vma rename)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return sm(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as esm
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def mesh_axis_size(axis) -> int:
     mesh = current_mesh()
     if mesh is None:

@@ -16,17 +16,31 @@ import numpy as np
 
 
 class _RNGState(threading.local):
+    """The key is built on first use, not at import: building a PRNG
+    key initialises the backend, and a process that merely imports the
+    package (a launcher, a DataLoader worker) must not take the chip."""
+
     def __init__(self):
-        self.key = jax.random.PRNGKey(0)
+        self._key = None
         self.seed_value = 0
+
+    @property
+    def key(self):
+        if self._key is None:
+            self._key = jax.random.PRNGKey(self.seed_value)
+        return self._key
+
+    @key.setter
+    def key(self, value):
+        self._key = value
 
 
 _state = _RNGState()
 
 
 def seed(value: int):
-    _state.key = jax.random.PRNGKey(int(value))
     _state.seed_value = int(value)
+    _state.key = None
     np.random.seed(int(value) % (2 ** 32))
     return _state
 

@@ -21,10 +21,7 @@ def segment_sum(data, segment_ids, name=None):
     n = int(np.asarray(as_jax(segment_ids)).max()) + 1
 
     def f(d, ids):
-        return jax.ops.segment_sum(d, ids.astype(np.int32), n) \
-            if hasattr(jax.ops, "segment_sum") else \
-            jax.numpy.zeros((n,) + d.shape[1:], d.dtype).at[
-                ids.astype(np.int32)].add(d)
+        return jax.ops.segment_sum(d, ids.astype(np.int32), n)
     return apply_jax("segment_sum", f, data, segment_ids)
 
 

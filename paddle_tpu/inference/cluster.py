@@ -1064,7 +1064,9 @@ class EngineCluster:
                 burn = max(burn,
                            eng._health.burn_rates().get("fast", 0.0))
             r = eng._roofline()
-            busy = max(busy, r["step_mfu"], r["step_hbm_bw_util"])
+            # None off the chip: no device peak, so no busy signal
+            busy = max(busy, r["step_mfu"] or 0.0,
+                       r["step_hbm_bw_util"] or 0.0)
         sig = {
             "replicas": len(dec),
             "slots": sum(self._engines[i].config.num_slots
@@ -1295,21 +1297,22 @@ class EngineCluster:
         # a PAIR from that ONE replica — a per-metric max could
         # combine an MFU and a bandwidth figure no single replica
         # exhibits, which is useless for bound classification
+        # (off the chip the utilizations are None — no device peak —
+        # and the first live replica stands in)
         if reps:
             busy = max(range(len(reps)), key=lambda i: (
-                reps[i]["roofline"]["step_mfu"],
-                reps[i]["roofline"]["step_hbm_bw_util"]))
+                reps[i]["roofline"]["step_mfu"] or 0.0,
+                reps[i]["roofline"]["step_hbm_bw_util"] or 0.0))
             roofline = {
-                "cpu_proxy": any(r["roofline"]["cpu_proxy"]
-                                 for r in reps),
+                "device": reps[busy]["roofline"]["device"],
                 "busiest_replica": live_idx[busy],
                 "step_mfu": reps[busy]["roofline"]["step_mfu"],
                 "step_hbm_bw_util":
                     reps[busy]["roofline"]["step_hbm_bw_util"],
             }
         else:                       # every replica down
-            roofline = {"cpu_proxy": False, "busiest_replica": None,
-                        "step_mfu": 0.0, "step_hbm_bw_util": 0.0}
+            roofline = {"device": None, "busiest_replica": None,
+                        "step_mfu": None, "step_hbm_bw_util": None}
         return {
             "num_replicas": len(self._decode_idx),
             "prefill_replicas": len(self._prefill_idx),

@@ -1395,21 +1395,21 @@ class ServingEngine:
         self._exec_cost = {}        # exec name -> cost_analysis dict
         self._step_time = {}        # exec name -> wall-seconds EMA
         self._step_ticks = {}       # exec name -> timed launches
-        self._peak_flops = monitor.device_peak_flops()
-        self._peak_hbm_bw = monitor.device_peak_hbm_bw()
-        self._ridge = self._peak_flops / self._peak_hbm_bw
-        self._cpu_proxy = jax.default_backend() != "tpu"
+        # the chip's published peaks (monitor.DEVICE_PEAKS); None on
+        # the CPU backend, where every utilization below stays None —
+        # a CPU run has no device peak to be a share of
+        self._peaks = monitor.device_peaks()
         self._m_mfu = monitor.gauge(
             "serving_step_mfu",
             "per-tick model FLOPs utilization of the tick executable "
             "(cost_analysis FLOPs / measured launch->sync time / chip "
-            "peak FLOPs; nominal peaks off-TPU — cpu_proxy)")
+            "peak FLOPs; unset off the chip)")
         self._m_bw_util = monitor.gauge(
             "serving_hbm_bw_util",
             "per-tick HBM-bandwidth utilization of the tick "
             "executable (cost_analysis bytes accessed / measured "
-            "launch->sync time / chip peak HBM bytes/s; nominal "
-            "peaks off-TPU — cpu_proxy)")
+            "launch->sync time / chip peak HBM bytes/s; unset off "
+            "the chip)")
         # -- on-demand profiling windows (ISSUE 15 layer 3) -----------
         # profile(n_ticks) arms a bounded jax.profiler capture around
         # the next N ticks; PADDLE_TPU_TRACE=0 keeps it inert
@@ -2873,8 +2873,8 @@ class ServingEngine:
             "profile_captures": self._prof.captures,
             "profile_ticks_remaining": self._prof.pending,
             # per-tick roofline attribution (always present — an
-            # un-ticked engine reports zeros; cpu_proxy flags
-            # nominal off-TPU peaks)
+            # un-ticked engine reports zeros; off the chip the
+            # peak-derived fields are None)
             "roofline": self._roofline(),
             "ttft_ms": self._d_ttft.summary(),
             "itl_ms": self._d_itl.summary(),
@@ -3675,16 +3675,15 @@ class ServingEngine:
         unchanged."""
         if self._mesh is None:
             return logits
-        from ..distributed.shard_utils import shard_map_compat
         nd = logits.ndim
         spec = P(*([None] * (nd - 1) + ["mp"]))
         logits = jax.lax.with_sharding_constraint(
             logits, NamedSharding(self._mesh, spec))
-        gather = shard_map_compat(
+        gather = jax.shard_map(
             lambda x: jax.lax.all_gather(x, "mp", axis=nd - 1,
                                          tiled=True),
-            self._mesh, in_specs=(spec,),
-            out_specs=P(*([None] * nd)))
+            mesh=self._mesh, in_specs=(spec,),
+            out_specs=P(*([None] * nd)), check_vma=False)
         return gather(logits)
 
     @contextlib.contextmanager
@@ -3742,21 +3741,13 @@ class ServingEngine:
             if self._moe_tap_on else contextlib.nullcontext()
         try:
             with self._trace_ctx(), _quiet_donation(), tap:
-                trace = getattr(jitted, "trace", None)
-                if trace is not None:
-                    traced = trace(*args)
-                    exec_ = traced.lower().compile()
-                    if self._mesh is not None:
-                        self._census[name] = monitor.collective_census(
-                            traced.jaxpr)
-                    kc = monitor.kernel_census(compiled=exec_,
-                                               jaxpr=traced.jaxpr)
-                else:
-                    # older jax: no jit().trace — the executable still
-                    # compiles once; the collective census (and the
-                    # byte counters it feeds) stays empty
-                    exec_ = jitted.lower(*args).compile()
-                    kc = monitor.kernel_census(compiled=exec_)
+                traced = jitted.trace(*args)
+                exec_ = traced.lower().compile()
+                if self._mesh is not None:
+                    self._census[name] = monitor.collective_census(
+                        traced.jaxpr)
+                kc = monitor.kernel_census(compiled=exec_,
+                                           jaxpr=traced.jaxpr)
                 self._kcensus[name] = kc
                 # roofline static half: the executable's cost-model
                 # FLOPs + HBM bytes (per-tick MFU / bandwidth
@@ -4624,8 +4615,8 @@ class ServingEngine:
                         table_dev, pos)
             # roofline sample for the chunk executable (wall clock
             # around the launch — on async backends only the final
-            # chunk's first-token materialization syncs, so off-TPU
-            # treat the chunk row as structure, like every cpu_proxy)
+            # chunk's first-token materialization syncs, so the chunk
+            # row's time is an enqueue time there)
             self._note_step_time("chunk", time.monotonic() - t_c0)
             if self._trace is not None:
                 self._trace.emit(
@@ -4712,16 +4703,16 @@ class ServingEngine:
         self._step_time[name] = dt if ema is None \
             else 0.7 * ema + 0.3 * dt
         self._step_ticks[name] = self._step_ticks.get(name, 0) + 1
-        if name == ("verify" if self._gamma else "decode"):
+        if name == ("verify" if self._gamma else "decode") \
+                and self._peaks is not None:
             cost = self._exec_cost.get(name)
             if cost:
                 if cost.get("flops"):
                     self._m_mfu.set(
-                        cost["flops"] / dt / self._peak_flops)
+                        cost["flops"] / dt / self._peaks[0])
                 if cost.get("bytes_accessed"):
                     self._m_bw_util.set(
-                        cost["bytes_accessed"] / dt
-                        / self._peak_hbm_bw)
+                        cost["bytes_accessed"] / dt / self._peaks[1])
 
     def _roofline(self) -> dict:
         """Live per-executable roofline attribution (the
@@ -4731,28 +4722,34 @@ class ServingEngine:
         HBM-bandwidth utilization. ``bound`` classifies each
         executable against the chip's ridge point (peak FLOPs / peak
         HBM bytes/s — arithmetic intensity below it means the
-        executable saturates bandwidth before compute). Off TPU the
-        chip peaks are nominal constants: read every number as
-        structure, not truth (``cpu_proxy``)."""
+        executable saturates bandwidth before compute). On the CPU
+        backend there is no chip peak: ``device`` says so and every
+        peak-derived field (``mfu``, ``hbm_bw_util``, ``bound``, the
+        peaks, the ridge) is ``None`` — the counts (FLOPs, bytes,
+        ticks) and the host step time remain."""
+        peaks = self._peaks
+        ridge = peaks[0] / peaks[1] if peaks else None
         per = {}
         for name, cost in self._exec_cost.items():
             f = float(cost.get("flops", 0.0) or 0.0)
             b = float(cost.get("bytes_accessed", 0.0) or 0.0)
             ai = (f / b) if b else 0.0
             dt = self._step_time.get(name)
-            per[name] = {
+            row = {
                 "flops": f, "bytes_accessed": b,
                 "arithmetic_intensity": round(ai, 4),
-                "bound": "compute" if ai >= self._ridge
-                else "bandwidth",
                 "ticks": self._step_ticks.get(name, 0),
                 "step_time_ms": round(1000.0 * dt, 4)
                 if dt is not None else None,
-                "mfu": round(f / dt / self._peak_flops, 6)
-                if dt and f else 0.0,
-                "hbm_bw_util": round(b / dt / self._peak_hbm_bw, 6)
-                if dt and b else 0.0,
+                "bound": None, "mfu": None, "hbm_bw_util": None,
             }
+            if peaks:
+                row["bound"] = "compute" if ai >= ridge else "bandwidth"
+                row["mfu"] = round(f / dt / peaks[0], 6) \
+                    if dt and f else 0.0
+                row["hbm_bw_util"] = round(b / dt / peaks[1], 6) \
+                    if dt and b else 0.0
+            per[name] = row
         tick = "verify" if self._gamma else "decode"
         t = per.get(tick, {})
         # speculative token credit: the verify window's FLOPs/bytes
@@ -4763,16 +4760,18 @@ class ServingEngine:
         # verify node budget)
         acc = (self._n_spec_emitted / self._n_spec_verifies
                if self._n_spec_verifies else 0.0)
-        return {"cpu_proxy": self._cpu_proxy,
+        idle = 0.0 if peaks else None
+        return {"device": jax.devices()[0].device_kind,
                 "tick_executable": tick,
-                "step_mfu": t.get("mfu", 0.0),
-                "step_hbm_bw_util": t.get("hbm_bw_util", 0.0),
+                "step_mfu": t.get("mfu", idle),
+                "step_hbm_bw_util": t.get("hbm_bw_util", idle),
                 "verify_tokens_credited_per_tick": round(acc, 4),
                 "verify_node_budget": (self._gamma + 1)
                 if self._gamma else 1,
-                "peak_flops_per_s": self._peak_flops,
-                "peak_hbm_bytes_per_s": self._peak_hbm_bw,
-                "ridge_flops_per_byte": round(self._ridge, 4),
+                "peak_flops_per_s": peaks[0] if peaks else None,
+                "peak_hbm_bytes_per_s": peaks[1] if peaks else None,
+                "ridge_flops_per_byte": round(ridge, 4)
+                if peaks else None,
                 "per_executable": per}
 
     def profile(self, n_ticks: int, path: Optional[str] = None):

@@ -12,6 +12,7 @@ host decode with the device step.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import os
 import queue
@@ -340,10 +341,31 @@ def default_collate_fn(batch):
     return batch
 
 
+@contextlib.contextmanager
+def _worker_environ():
+    """Environment a worker process starts with: JAX held to the CPU.
+    A worker re-imports this package (and may build Tensors while
+    collating); the chip belongs to the trainer that owns the loader,
+    and a second process reaching for it fails or hangs. The variable
+    must be in place before the child's first import, so it is set
+    around ``Process.start()`` — the child snapshots ``os.environ``
+    there — and restored (the parent's own JAX read it long ago)."""
+    prev = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        yield
+    finally:
+        if prev is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = prev
+
+
 def _spawn_worker_main(w, n, shm_name, capacity, loader):
     """Entry point of a spawned DataLoader worker: open the parent's shm
     ring and stream this worker's share of batches into it. Runs in a
-    fresh interpreter (spawn), so no inherited JAX locks."""
+    fresh interpreter (spawn), so no inherited JAX locks, under
+    ``_worker_environ`` (never on the trainer's chip)."""
     from ..native import ShmChannel
     channel = ShmChannel(shm_name, capacity=capacity, create=False)
     code = 0
@@ -472,7 +494,8 @@ class DataLoader:
                     p = ctx.Process(
                         target=_spawn_worker_main,
                         args=(w, n, names[w], cap, self), daemon=True)
-                    p.start()  # pickles args here
+                    with _worker_environ():
+                        p.start()  # pickles args here
                     procs.append(p)
             except Exception as exc:
                 import warnings

@@ -34,9 +34,8 @@ import sys
 from .registry import (Counter, Gauge, Histogram, Info, Registry,
                        get_registry, metrics_dir, metrics_enabled,
                        prometheus_path)
-from .accounting import (analytic_mfu, collective_census,
-                         device_peak_flops, device_peak_hbm_bw,
-                         executable_cost, kernel_census,
+from .accounting import (DEVICE_PEAKS, analytic_mfu, collective_census,
+                         device_peaks, executable_cost, kernel_census,
                          record_compiled_step, sample_device_memory,
                          step_report, step_reports)
 from .digest import LatencyDigest, P2Quantile
@@ -56,8 +55,7 @@ __all__ = [
     "ProfilerWindow", "next_flow_id",
     "record_compiled_step", "collective_census", "kernel_census",
     "step_report", "step_reports", "sample_device_memory",
-    "analytic_mfu", "device_peak_flops", "device_peak_hbm_bw",
-    "executable_cost",
+    "analytic_mfu", "DEVICE_PEAKS", "device_peaks", "executable_cost",
     "ALERT_SEVERITY", "BurnRateMonitor", "CollapseDetector",
     "EwmaSpikeDetector", "HealthMonitor", "IncidentCapture",
     "RatioDetector", "StormDetector", "TrendDetector",

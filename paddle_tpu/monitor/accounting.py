@@ -32,8 +32,7 @@ from .registry import get_registry
 __all__ = ["record_compiled_step", "collective_census",
            "kernel_census", "step_report", "step_reports",
            "sample_device_memory", "analytic_mfu",
-           "device_peak_flops", "device_peak_hbm_bw",
-           "executable_cost"]
+           "DEVICE_PEAKS", "device_peaks", "executable_cost"]
 
 # jaxpr primitive -> census op family
 _COLLECTIVE_PRIMS = {
@@ -142,6 +141,34 @@ _HLO_ENTRY_RE = _re.compile(r"^ENTRY [^\n]*\{\n(.*?)^\}",
 _HLO_INSTR_RE = _re.compile(
     r"\s+(?:ROOT\s+)?[%\w\.\-]+ = (?:\([^=]*?\)|\S+) "
     r"([a-zA-Z][\w\-]*)\(")
+_HLO_OP_NAME_RE = _re.compile(r'op_name="([^"]+)"')
+_HLO_TRANSFORM_RE = _re.compile(r"\w+\((.*)\)")   # jvp(...), transpose(...)
+
+
+def _mosaic_kernels(hlo_text: str) -> Dict[str, int]:
+    """The Mosaic (Pallas) kernels a COMPILED program holds, in every
+    computation (loop and shard_map bodies included): ``{name: count}``
+    over its ``tpu_custom_call`` instructions. XLA keeps no kernel name
+    on the instruction; what survives is the op metadata, whose
+    ``op_name`` ends ``.../<scope>/pallas_call`` — ``<scope>`` being the
+    ``jax.named_scope`` every kernel of ``ops/pallas`` is invoked under
+    (``kernel_scope``), wrapped by the transformations it was traced
+    under (``transpose(jvp(<scope>))`` for a backward kernel). A call
+    with no scope of its own counts under the enclosing component."""
+    names: Dict[str, int] = {}
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        m = _HLO_OP_NAME_RE.search(line)
+        parts = m.group(1).split("/") if m else []
+        calls = [i for i, p in enumerate(parts)
+                 if p.startswith("pallas_call")]
+        name = parts[calls[-1] - 1] if calls and calls[-1] > 0 else ""
+        while (w := _HLO_TRANSFORM_RE.fullmatch(name)) is not None:
+            name = w.group(1)
+        name = name or "?"
+        names[name] = names.get(name, 0) + 1
+    return dict(sorted(names.items()))
 
 
 def kernel_census(compiled=None, jaxpr=None) -> dict:
@@ -155,6 +182,10 @@ def kernel_census(compiled=None, jaxpr=None) -> dict:
       computation (``compiled.as_text()``), excluding pure
       bookkeeping — each is approximately one kernel thunk on the
       compiling backend. The truth on real TPU hardware.
+      ``hlo_mosaic_kernels`` counts the Mosaic custom calls of EVERY
+      computation by kernel scope name — on a TPU, the Pallas kernels
+      that actually compiled in (empty for an interpreted or
+      XLA-fallback graph).
     - ``launch_proxy`` (+ ``launch_by_op``): a jaxpr walk (the PR 2
       collective-census machinery, same recursion through
       pjit/scan/while/shard_map bodies) counting launch-rooted
@@ -163,8 +194,7 @@ def kernel_census(compiled=None, jaxpr=None) -> dict:
       interpreter, so a CPU census of the fused decode tick shows the
       same collapse the TPU compile gets.
 
-    Either input may be omitted; unavailable views are simply absent
-    (older jax without ``as_text`` degrades gracefully)."""
+    Either input may be omitted; its view is then absent."""
     out = {}
     if jaxpr is not None:
         n = [0]
@@ -191,33 +221,27 @@ def kernel_census(compiled=None, jaxpr=None) -> dict:
                         if hasattr(inner, "eqns"):
                             walk(e)
 
-        try:
-            walk(jaxpr)
-            out["launch_proxy"] = n[0]
-            out["launch_by_op"] = dict(sorted(by.items()))
-        except Exception:       # pragma: no cover - census never fatal
-            pass
+        walk(jaxpr)
+        out["launch_proxy"] = n[0]
+        out["launch_by_op"] = dict(sorted(by.items()))
     if compiled is not None:
-        try:
-            txt = compiled.as_text()
-        except Exception:       # pragma: no cover - older jax
-            txt = None
-        if txt:
-            m = _HLO_ENTRY_RE.search(txt)
-            body = m.group(1) if m else ""
-            by = {}
-            for line in body.splitlines():
-                im = _HLO_INSTR_RE.match(line)
-                if im is None:
-                    continue
-                op = im.group(1)
-                if op in _HLO_SKIP_OPS:
-                    continue
-                by[op] = by.get(op, 0) + 1
-            out["hlo_kernels"] = sum(by.values())
-            out["hlo_fusions"] = by.get("fusion", 0)
-            out["hlo_custom_calls"] = by.get("custom-call", 0)
-            out["hlo_by_op"] = dict(sorted(by.items()))
+        txt = compiled.as_text()
+        m = _HLO_ENTRY_RE.search(txt)
+        body = m.group(1) if m else ""
+        by = {}
+        for line in body.splitlines():
+            im = _HLO_INSTR_RE.match(line)
+            if im is None:
+                continue
+            op = im.group(1)
+            if op in _HLO_SKIP_OPS:
+                continue
+            by[op] = by.get(op, 0) + 1
+        out["hlo_kernels"] = sum(by.values())
+        out["hlo_fusions"] = by.get("fusion", 0)
+        out["hlo_custom_calls"] = by.get("custom-call", 0)
+        out["hlo_by_op"] = dict(sorted(by.items()))
+        out["hlo_mosaic_kernels"] = _mosaic_kernels(txt)
     return out
 
 
@@ -325,51 +349,33 @@ def step_reports() -> Dict[str, dict]:
     return dict(_STEP_REPORTS)
 
 
-def device_peak_flops() -> float:
-    """Peak bf16 FLOP/s of the local chip (mirrors bench.py's table;
-    CPU returns a nominal 1 TF/s so analytic MFU stays defined)."""
-    import jax
-    try:
-        dev = jax.devices()[0]
-    except Exception:
-        return 1e12
-    kind = getattr(dev, "device_kind", "").lower()
-    if "v5p" in kind or "v5 p" in kind:
-        return 459e12
-    if "v4" in kind:
-        return 275e12
-    if "v6" in kind:
-        return 918e12
-    if "v5" in kind or "lite" in kind:
-        return 197e12
-    if getattr(dev, "platform", "") == "cpu":
-        return 1e12
-    return 197e12
+# Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``:
+# kind -> (bf16 FLOP/s, HBM bytes/s, source). THE one table (bench.py
+# imports it). A device that is not here is an error, not a default —
+# a utilization against a guessed peak is not a measurement.
+DEVICE_PEAKS = {
+    "TPU v5 lite": (197e12, 819e9,
+                    'Google Cloud documentation, "TPU v5e": 197 TFLOP/s '
+                    "bf16, 819 GB/s HBM per chip"),
+}
 
 
-def device_peak_hbm_bw() -> float:
-    """Peak HBM bytes/s of the local chip — the roofline's bandwidth
-    ceiling, paired with :func:`device_peak_flops` (their ratio is the
-    ridge point in FLOPs/byte). CPU returns a nominal 100 GB/s so
-    bandwidth utilization stays defined; consumers flag such numbers
-    ``cpu_proxy`` exactly like the MFU table."""
+def device_peaks():
+    """``(peak bf16 FLOP/s, peak HBM bytes/s)`` of the local chip from
+    ``DEVICE_PEAKS``, or ``None`` on the CPU backend (a CPU run has no
+    device peak: callers report no utilization there). An accelerator
+    whose ``device_kind`` is not in the table raises ``KeyError``."""
     import jax
-    try:
-        dev = jax.devices()[0]
-    except Exception:
-        return 1e11
-    kind = getattr(dev, "device_kind", "").lower()
-    if "v5p" in kind or "v5 p" in kind:
-        return 2.765e12
-    if "v4" in kind:
-        return 1.2e12
-    if "v6" in kind:
-        return 1.64e12
-    if "v5" in kind or "lite" in kind:
-        return 8.1e11
-    if getattr(dev, "platform", "") == "cpu":
-        return 1e11
-    return 8.1e11
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    if dev.device_kind not in DEVICE_PEAKS:
+        raise KeyError(
+            f"no published peak for device_kind {dev.device_kind!r} "
+            f"(platform {dev.platform!r}); add it to "
+            "monitor.accounting.DEVICE_PEAKS with its source")
+    flops, bw, _source = DEVICE_PEAKS[dev.device_kind]
+    return flops, bw
 
 
 def executable_cost(compiled) -> dict:
@@ -390,13 +396,18 @@ def executable_cost(compiled) -> dict:
 def analytic_mfu(name: str, step_time_s: float,
                  peak_flops: Optional[float] = None) -> Optional[float]:
     """Cost-model MFU: recorded FLOPs/step over measured step time over
-    chip peak. None when the step has no recorded FLOPs."""
+    chip peak. None when the step has no recorded FLOPs, or on the CPU
+    backend (no device peak) unless ``peak_flops`` is given."""
     rep = _STEP_REPORTS.get(name) or {}
     flops = rep.get("flops")
     if not flops or step_time_s <= 0:
         return None
-    return float(flops) / step_time_s / (peak_flops
-                                         or device_peak_flops())
+    if peak_flops is None:
+        peaks = device_peaks()
+        if peaks is None:
+            return None
+        peak_flops = peaks[0]
+    return float(flops) / step_time_s / peak_flops
 
 
 def sample_device_memory(step: Optional[int] = None) -> dict:

@@ -362,28 +362,19 @@ def armed(module) -> bool:
 
 def _use_lora_gmm(n_rows: int, d_in: int, rank: int, d_out: int):
     """Route one projection's delta to the fused grouped-matmul
-    kernels? Mirrors ``distributed.moe._use_fused_gmm``: default (=1)
-    only on a real TPU backend at aligned shapes; ``interpret`` runs
-    the same kernels under the Pallas interpreter for CPU coverage;
-    ``0`` kills. Alignment: activations/outputs on 128 lanes, rows on
-    the 8-sublane f32 tile. The TPU path additionally needs the RANK
-    on 128 lanes (the A-matmul's output tile) — typical rank-8..64
-    adapters take the einsum fallback there, which XLA fuses well; the
-    kernel path is for stacked/padded-rank deployments."""
-    env = os.environ.get("PADDLE_TPU_LORA_GMM", "1")
-    if env == "0":
+    kernels? Mirrors ``distributed.moe._use_fused_gmm``: only under
+    ``PADDLE_TPU_LORA_GMM=interpret`` (the kernels under the Pallas
+    interpreter, for CPU coverage of the kernel graph), at aligned
+    shapes: activations/outputs on 128 lanes, rows and rank on the
+    8-sublane f32 tile. NEVER compiled: Mosaic refuses the moe_gmm
+    kernels' single-row DMA slices of a tiled array (see
+    ``_use_fused_gmm``), so on a TPU backend every delta takes the
+    einsum, which XLA fuses well at rank 8..64."""
+    if os.environ.get("PADDLE_TPU_LORA_GMM", "1") != "interpret":
         return False
     aligned = (d_in % 128 == 0 and d_out % 128 == 0
                and n_rows % 8 == 0 and rank % 8 == 0)
-    if env == "interpret":
-        return "interpret" if aligned else False
-    try:
-        backend = jax.default_backend()
-    except Exception:
-        return False
-    if backend != "tpu":
-        return False
-    return "tpu" if (aligned and rank % 128 == 0) else False
+    return "interpret" if aligned else False
 
 
 def _ragged_delta(rows, row_adapter, A, B, mode):

@@ -26,7 +26,9 @@ all four boundaries the ROADMAP names:
   tanh-``gelu`` for GPT's second MLP linear, none for the
   O-projection) and the residual add in the epilogue: the attention
   output (or MLP hidden) goes MXU -> residual without touching HBM in
-  between.
+  between. The contraction is tiled (grid ``(col_tiles, k_tiles)``,
+  f32 accumulator in VMEM), so the resident buffers do not grow with
+  the FFN width.
 
 Both reuse the ragged row layout by construction — they are row-wise
 over the packed ``[R, hidden]`` buffer, so decode (1 row/slot),
@@ -62,12 +64,17 @@ int8 from ``quantize_for_inference``) fall back per layer.
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention_kernel import kernel_scope
 
 __all__ = ["resolve_fused_mode", "fused_decode_scope",
            "fused_decode_mode", "fused_params_ok", "norm_matmul",
@@ -76,6 +83,18 @@ __all__ = ["resolve_fused_mode", "fused_decode_scope",
            "pallas_matmul_residual"]
 
 _COL_TILE = 128
+# contraction tiles the projection->residual kernel walks, largest
+# first (Qwen2-7B: 3584 = 7 x 512 and 18944 = 37 x 512)
+_K_TILES = (2048, 1024, 512, 256, 128)
+# column tiles of the projection->residual kernel, widest first: each
+# column tile re-applies the activation along its whole K walk, so a
+# wide tile cuts that recompute and the grid's step count
+_RES_COL_TILES = (512, 256, 128)
+# VMEM the kernels ask Mosaic for (its default grant is 16 MB of the
+# v5e core's 128 MB) and the share of it ``_eligible`` lets the buffer
+# estimate reach — the rest is the compiler's own temporaries
+_VMEM_LIMIT = 32 << 20
+_VMEM_BUDGET = 24 << 20
 
 
 def _tile_count(n: int) -> int:
@@ -87,6 +106,25 @@ def _tile_count(n: int) -> int:
     while n % t:
         t -= 1
     return t
+
+
+def _k_tile(k: int) -> int:
+    """Contraction tile for a ``k``-deep projection: the largest of
+    ``_K_TILES`` dividing ``k``, else ``k`` whole (interpret-mode
+    shapes; ``_eligible`` requires a 128-multiple on TPU)."""
+    for t in _K_TILES:
+        if k % t == 0:
+            return t
+    return k
+
+
+def _res_col_tile(n: int) -> int:
+    """Column tile of the projection->residual kernel: the widest of
+    ``_RES_COL_TILES`` dividing ``n``, else ``_tile_count``'s."""
+    for t in _RES_COL_TILES:
+        if n % t == 0:
+            return t
+    return n // _tile_count(n)
 
 
 # ---------------------------------------------------------------------------
@@ -146,10 +184,7 @@ def fused_params_ok(*params) -> bool:
     for p in params:
         if p is None:
             continue
-        try:
-            if not jnp.issubdtype(as_jax(p).dtype, jnp.floating):
-                return False
-        except Exception:
+        if not jnp.issubdtype(as_jax(p).dtype, jnp.floating):
             return False
     return True
 
@@ -162,9 +197,12 @@ def _norm_mm_kernel(*refs, eps, kind, has_beta, nw, offs, tiles,
                     has_bias):
     """Grid ``(sum(tiles),)`` over the concatenated column tiles of
     all ``nw`` weights. Step 0 computes the normalized activation into
-    VMEM scratch (f32, cast through the activation dtype exactly like
-    the unfused norm so kernel and fallback agree to rounding); every
-    step contracts it against its weight's current column tile."""
+    VMEM scratch with the unfused norm's recipe and roundings (f32
+    statistics, cast to the activation dtype BEFORE the weight
+    multiply, activation-dtype result); every step contracts it
+    against its weight's current column tile — dots take the
+    activation dtype and accumulate f32 (FA-2's recipe: an f32 upcast
+    before the dot runs the MXU several times slower)."""
     i = 2 + (1 if has_beta else 0)
     x_ref, g_ref = refs[0], refs[1]
     b_ref = refs[2] if has_beta else None
@@ -188,34 +226,36 @@ def _norm_mm_kernel(*refs, eps, kind, has_beta, nw, offs, tiles,
             m = jnp.mean(xf, axis=-1, keepdims=True)
             var = jnp.mean((xf - m) * (xf - m), axis=-1, keepdims=True)
             y = (xf - m) * jax.lax.rsqrt(var + eps)
-        # the unfused path casts to the activation dtype BEFORE the
-        # weight multiply — mirror it so bf16 parity holds
-        y = y.astype(x_ref.dtype).astype(jnp.float32)
-        y = y * g_ref[...].astype(jnp.float32)[None, :]
+        # f32 products of activation-dtype operands are exact, so one
+        # rounding here is the unfused ``y.astype(dt) * gamma``
+        y = y.astype(y_scr.dtype).astype(jnp.float32) \
+            * g_ref[...].astype(jnp.float32)
         if has_beta:
-            y = y + b_ref[...].astype(jnp.float32)[None, :]
-        y_scr[...] = y
+            y = y.astype(y_scr.dtype).astype(jnp.float32) \
+                + b_ref[...].astype(jnp.float32)
+        y_scr[...] = y.astype(y_scr.dtype)
 
-    y = y_scr[...]
     for idx in range(nw):
         @pl.when((j >= offs[idx]) & (j < offs[idx] + tiles[idx]))
         def _project(idx=idx):
             acc = jax.lax.dot_general(
-                y, w_refs[idx][...].astype(jnp.float32),
+                y_scr[...], w_refs[idx][...].astype(y_scr.dtype),
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             if bias_refs[idx] is not None:
-                acc = acc + bias_refs[idx][...].astype(
-                    jnp.float32)[None, :]
+                acc = acc + bias_refs[idx][...].astype(jnp.float32)
             o_refs[idx][...] = acc.astype(o_refs[idx].dtype)
 
 
-def _mm_res_kernel(*refs, act, has_bias, n_in):
-    """Grid ``(col_tiles,)`` over the output width. Step 0 computes
-    the (optionally activated) matmul input into VMEM scratch; every
-    step contracts it against one weight column tile, adds bias +
-    residual tile in the epilogue, and stores — the projection input
-    and its residual sum never round-trip HBM."""
+def _mm_res_kernel(*refs, act, has_bias, n_in, n_k):
+    """Grid ``(col_tiles, k_tiles)``, contraction innermost. Every
+    step applies the (elementwise, so K-tileable) activation to its
+    input tile and accumulates the product with the weight tile in f32
+    scratch; the last K step adds bias + residual tile and stores —
+    the projection input and its residual sum never round-trip HBM,
+    and no buffer grows with the contraction depth (Qwen2-7B's
+    18944-deep down-projection fits the same VMEM as a 512-deep one).
+    Dots take the activation dtype, accumulating f32."""
     x_refs = refs[:n_in]
     i = n_in
     w_ref = refs[i]
@@ -224,141 +264,146 @@ def _mm_res_kernel(*refs, act, has_bias, n_in):
     i += 1 if has_bias else 0
     res_ref = refs[i]
     o_ref = refs[i + 1]
-    a_scr = refs[i + 2]
-    j = pl.program_id(0)
+    acc_scr = refs[i + 2]
+    kk = pl.program_id(1)
 
-    @pl.when(j == 0)
-    def _activate():
-        if act == "swiglu":
-            a = jax.nn.silu(x_refs[0][...].astype(jnp.float32)) \
-                * x_refs[1][...].astype(jnp.float32)
-        elif act == "gelu_tanh":
-            a = jax.nn.gelu(x_refs[0][...].astype(jnp.float32),
-                            approximate=True)
-        else:
-            a = x_refs[0][...].astype(jnp.float32)
-        a_scr[...] = a
+    @pl.when(kk == 0)
+    def _zero():
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    acc = jax.lax.dot_general(
-        a_scr[...], w_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    if b_ref is not None:
-        acc = acc + b_ref[...].astype(jnp.float32)[None, :]
-    acc = acc + res_ref[...].astype(jnp.float32)
-    o_ref[...] = acc.astype(o_ref.dtype)
+    dt = x_refs[0].dtype
+    if act == "swiglu":
+        # the module's order: silu in the activation dtype, then * up
+        a = (jax.nn.silu(x_refs[0][...].astype(jnp.float32)).astype(dt)
+             .astype(jnp.float32)
+             * x_refs[1][...].astype(jnp.float32)).astype(dt)
+    elif act == "gelu_tanh":
+        a = jax.nn.gelu(x_refs[0][...].astype(jnp.float32),
+                        approximate=True).astype(dt)
+    else:
+        a = x_refs[0][...]
+    acc_scr[...] += jax.lax.dot_general(
+        a, w_ref[...].astype(dt), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+    @pl.when(kk == n_k - 1)
+    def _store():
+        acc = acc_scr[...]
+        if b_ref is not None:
+            acc = acc + b_ref[...].astype(jnp.float32)
+        acc = acc + res_ref[...].astype(jnp.float32)
+        o_ref[...] = acc.astype(o_ref.dtype)
 
 
-try:    # pallas/tpu lowering may be absent on this jax build
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+def _row_vec(v):
+    """``[n]`` parameter as a ``[1, n]`` operand: a rank-1 bf16 block
+    must be a 256-multiple for Mosaic, a ``(1, 128k)`` block of a
+    rank-2 array need not."""
+    return v.reshape(1, -1)
 
-    from .flash_attention_kernel import _CompilerParams
 
-    def pallas_norm_matmul(x2, gamma, beta, ws, bs, *, eps, kind,
-                           interpret=None):
-        """x2: ``[R, d]`` packed rows; gamma/beta: ``[d]`` norm params
-        (beta None for RMSNorm); ws: 1..3 weights ``[d, n_i]``; bs:
-        matching biases ``[n_i]`` or None. Returns a tuple of
-        ``[R, n_i]`` outputs. ``kind``: ``"rms" | "ln"``."""
-        import functools
-        r, d = x2.shape
-        nw = len(ws)
-        widths = [w.shape[-1] for w in ws]
-        tiles = [_tile_count(n) for n in widths]
-        tcs = [n // t for n, t in zip(widths, tiles)]
-        offs = list(np.cumsum([0] + tiles[:-1]))
-        has_bias = [b is not None for b in bs]
-        kernel = functools.partial(
-            _norm_mm_kernel, eps=np.float32(eps), kind=kind,
-            has_beta=beta is not None, nw=nw, offs=offs, tiles=tiles,
-            has_bias=has_bias)
+def pallas_norm_matmul(x2, gamma, beta, ws, bs, *, eps, kind,
+                       interpret=None):
+    """x2: ``[R, d]`` packed rows; gamma/beta: ``[d]`` norm params
+    (beta None for RMSNorm); ws: 1..3 weights ``[d, n_i]``; bs:
+    matching biases ``[n_i]`` or None. Returns a tuple of
+    ``[R, n_i]`` outputs. ``kind``: ``"rms" | "ln"``."""
+    r, d = x2.shape
+    nw = len(ws)
+    widths = [w.shape[-1] for w in ws]
+    tiles = [_tile_count(n) for n in widths]
+    tcs = [n // t for n, t in zip(widths, tiles)]
+    offs = [int(o) for o in np.cumsum([0] + tiles[:-1])]
+    has_bias = [b is not None for b in bs]
+    kernel = functools.partial(
+        _norm_mm_kernel, eps=np.float32(eps), kind=kind,
+        has_beta=beta is not None, nw=nw, offs=offs, tiles=tiles,
+        has_bias=has_bias)
 
-        def _w_map(off, t):
-            return lambda j: (0, jnp.clip(j - off, 0, t - 1))
+    def _w_map(off, t):
+        # clamped outside the weight's own tile range: the block index
+        # stops changing, so Pallas skips the dead DMAs
+        return lambda j: (0, jnp.clip(j - off, 0, t - 1))
 
-        def _b_map(off, t):
-            return lambda j: (jnp.clip(j - off, 0, t - 1),)
-
-        in_specs = [
-            pl.BlockSpec((r, d), lambda j: (0, 0)),
-            pl.BlockSpec((d,), lambda j: (0,)),
-        ]
-        if beta is not None:
-            in_specs.append(pl.BlockSpec((d,), lambda j: (0,)))
-        for w, tc, off, t in zip(ws, tcs, offs, tiles):
-            in_specs.append(pl.BlockSpec((d, tc), _w_map(off, t)))
-        args = [x2, gamma] + ([beta] if beta is not None else []) \
-            + list(ws)
-        for b, tc, off, t in zip(bs, tcs, offs, tiles):
-            if b is not None:
-                in_specs.append(pl.BlockSpec((tc,), _b_map(off, t)))
-                args.append(b)
-        out_specs = [pl.BlockSpec((r, tc), _w_map(off, t))
-                     for tc, off, t in zip(tcs, offs, tiles)]
-        out_shape = [jax.ShapeDtypeStruct((r, n), x2.dtype)
-                     for n in widths]
-        outs = pl.pallas_call(
-            kernel,
-            grid=(int(sum(tiles)),),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=out_shape,
-            scratch_shapes=[pltpu.VMEM((r, d), jnp.float32)],
-            compiler_params=_CompilerParams(
-                dimension_semantics=("arbitrary",)),
-            interpret=_interpret_flag(interpret),
-        )(*args)
-        return tuple(outs)
-
-    def pallas_matmul_residual(xs, w, b, residual, *, act=None,
-                               interpret=None):
-        """xs: 1 (or 2, for swiglu) inputs ``[R, K]``; w: ``[K, n]``;
-        b: ``[n]`` or None; residual: ``[R, n]``. Returns
-        ``residual + act(xs) @ w (+ b)`` as ``[R, n]``."""
-        import functools
-        r, kdim = xs[0].shape
-        n = w.shape[-1]
-        t = _tile_count(n)
-        tc = n // t
-        kernel = functools.partial(
-            _mm_res_kernel, act=act, has_bias=b is not None,
-            n_in=len(xs))
-        in_specs = [pl.BlockSpec((r, kdim), lambda j: (0, 0))
-                    for _ in xs]
-        in_specs.append(pl.BlockSpec((kdim, tc), lambda j: (0, j)))
-        args = list(xs) + [w]
+    whole = lambda j: (0, 0)
+    in_specs = [pl.BlockSpec((r, d), whole),
+                pl.BlockSpec((1, d), whole)]
+    args = [x2, _row_vec(gamma)]
+    if beta is not None:
+        in_specs.append(pl.BlockSpec((1, d), whole))
+        args.append(_row_vec(beta))
+    for tc, off, t in zip(tcs, offs, tiles):
+        in_specs.append(pl.BlockSpec((d, tc), _w_map(off, t)))
+    args += list(ws)
+    for b, tc, off, t in zip(bs, tcs, offs, tiles):
         if b is not None:
-            in_specs.append(pl.BlockSpec((tc,), lambda j: (j,)))
-            args.append(b)
-        in_specs.append(pl.BlockSpec((r, tc), lambda j: (0, j)))
-        args.append(residual)
-        out = pl.pallas_call(
-            kernel,
-            grid=(t,),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((r, tc), lambda j: (0, j)),
-            out_shape=jax.ShapeDtypeStruct((r, n), residual.dtype),
-            scratch_shapes=[pltpu.VMEM((r, kdim), jnp.float32)],
-            compiler_params=_CompilerParams(
-                dimension_semantics=("arbitrary",)),
-            interpret=_interpret_flag(interpret),
-        )(*args)
-        return out
+            in_specs.append(pl.BlockSpec((1, tc), _w_map(off, t)))
+            args.append(_row_vec(b))
+    out_specs = [pl.BlockSpec((r, tc), _w_map(off, t))
+                 for tc, off, t in zip(tcs, offs, tiles)]
+    out_shape = [jax.ShapeDtypeStruct((r, n), x2.dtype)
+                 for n in widths]
+    call = pl.pallas_call(
+        kernel,
+        grid=(int(sum(tiles)),),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((r, d), x2.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret_flag(interpret),
+    )
+    with kernel_scope("fused_norm_matmul"):
+        return tuple(call(*args))
 
-    _kernel_import_error = None
-except Exception as _e:     # pragma: no cover - environment dependent
-    pallas_norm_matmul = None
-    pallas_matmul_residual = None
-    _kernel_import_error = _e
+
+def pallas_matmul_residual(xs, w, b, residual, *, act=None,
+                           interpret=None):
+    """xs: 1 (or 2, for swiglu) inputs ``[R, K]``; w: ``[K, n]``;
+    b: ``[n]`` or None; residual: ``[R, n]``. Returns
+    ``residual + act(xs) @ w (+ b)`` as ``[R, n]``."""
+    r, kdim = xs[0].shape
+    n = w.shape[-1]
+    tc = _res_col_tile(n)
+    tk = _k_tile(kdim)
+    n_k = kdim // tk
+    kernel = functools.partial(
+        _mm_res_kernel, act=act, has_bias=b is not None,
+        n_in=len(xs), n_k=n_k)
+    col = lambda j, kk: (0, j)
+    in_specs = [pl.BlockSpec((r, tk), lambda j, kk: (0, kk))
+                for _ in xs]
+    in_specs.append(pl.BlockSpec((tk, tc), lambda j, kk: (kk, j)))
+    args = list(xs) + [w]
+    if b is not None:
+        in_specs.append(pl.BlockSpec((1, tc), col))
+        args.append(_row_vec(b))
+    in_specs.append(pl.BlockSpec((r, tc), col))
+    args.append(residual)
+    call = pl.pallas_call(
+        kernel,
+        grid=(n // tc, n_k),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((r, tc), col),
+        out_shape=jax.ShapeDtypeStruct((r, n), residual.dtype),
+        scratch_shapes=[pltpu.VMEM((r, tc), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret_flag(interpret),
+    )
+    with kernel_scope("fused_matmul_residual"):
+        return call(*args)
 
 
 def _interpret_flag(interpret):
+    """An explicit request wins; otherwise interpreted only on the CPU
+    backend (a backend query that raises propagates)."""
     if interpret is not None:
         return interpret
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
+    return jax.default_backend() == "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -410,59 +455,67 @@ def _xla_matmul_residual(xs, w, b, residual, *, act=None):
 
 def _warn_fallback(kind, shape):
     """A TPU trace that asked for the fused kernel but fell back lost
-    a fusion boundary — count it on the shared serving_kernel_fallback
-    telemetry (same counter/dict the paged-attention entry points
-    bump, so ``stats()['kernel_fallbacks']`` folds these in)."""
-    from . import paged_attention as _pa
-    _pa._fallback_counts[kind] = _pa._fallback_counts.get(kind, 0) + 1
-    try:
-        from ... import monitor
-        monitor.counter(
-            "serving_kernel_fallback",
-            "paged-attention entry points routed to the XLA gather "
-            "fallback on a TPU backend (kernel missing or shape "
-            "ineligible)", labels=("path",)).labels(path=kind).inc()
-    except Exception:       # pragma: no cover - never break the trace
-        pass
-    if kind in _pa._fallback_warned:
-        return
-    _pa._fallback_warned.add(kind)
-    import warnings
-    warnings.warn(
-        "%s: shape %s not kernel-eligible (dims must be %d-multiples,"
-        " rows an 8-multiple); using the XLA fallback"
-        % (kind, tuple(shape), _COL_TILE))
+    a fusion boundary — counted on the shared serving_kernel_fallback
+    telemetry (``stats()['kernel_fallbacks']`` folds these in), warned
+    once per entry point."""
+    from .paged_attention import count_fallback
+    if count_fallback(kind):
+        import warnings
+        warnings.warn(
+            "%s: shape %s not kernel-eligible (dims must be "
+            "%d-multiples, rows an 8-multiple, buffers within %d MB "
+            "of VMEM); using the XLA fallback"
+            % (kind, tuple(shape), _COL_TILE, _VMEM_BUDGET >> 20))
 
 
-# VMEM the kernels may budget for resident buffers (scratch + the
-# whole-[R, d] input block + double-buffered weight/bias/residual
-# tiles); conservative against the ~16 MB/core of current TPUs so an
-# oversized shape takes the graceful XLA fallback instead of failing
-# Mosaic compilation at engine construction
-_VMEM_BUDGET = 12 << 20
+def _norm_mm_vmem(rows, d, widths, isz):
+    """Resident bytes of ``pallas_norm_matmul``: the double-buffered
+    whole-row input block, the normalized scratch, step 0's f32
+    temporaries, and per weight a double-buffered column tile plus its
+    output tile."""
+    tc = max(n // _tile_count(n) for n in widths)
+    return (3 * rows * d * isz + 3 * rows * d * 4
+            + len(widths) * 2 * (d * tc + rows * tc) * isz)
 
 
-def _vmem_bytes(rows, d, widths, n_in=1):
-    tc = max(min(n, _COL_TILE) for n in widths)
-    return 4 * ((1 + n_in) * rows * d   # f32 scratch + n input blocks
-                + 2 * d * tc            # double-buffered weight tile
-                + 2 * rows * tc)        # output (+ residual) tiles
+def _mm_res_vmem(rows, kdim, n, n_in, isz):
+    """Resident bytes of ``pallas_matmul_residual``: per input a
+    double-buffered K tile, the activation's f32 temporaries, the
+    double-buffered weight tile, residual + output tiles and the f32
+    accumulator. Independent of the contraction depth beyond ``tk``."""
+    tk, tc = _k_tile(kdim), _res_col_tile(n)
+    return (2 * n_in * rows * tk * isz + 3 * rows * tk * 4
+            + 2 * tk * tc * isz + 4 * rows * tc * isz + rows * tc * 4)
 
 
-def _eligible(d, widths, rows, strict, n_in=1):
+def _eligible(dims, rows, strict, vmem_bytes):
     """Kernel eligibility. ``strict`` (the real-TPU path): every dim a
-    128-multiple and the packed row count an 8-sublane multiple (so
-    Mosaic never pads a tile) AND the resident buffers fit the VMEM
-    budget (``n_in`` > 1: swiglu keeps both gate/up blocks resident);
-    interpret mode accepts any shape the tiling divides."""
+    128-multiple and the packed row count an 8-sublane multiple — the
+    shapes cross-lowered in ``tests/test_tpu_lowering.py`` and compiled
+    by ``chip_smoke.py`` — AND the resident buffers within the VMEM
+    budget; interpret mode accepts any shape the tiling divides."""
     if rows > 4096 or rows < 1:
         return False
     if strict:
-        return d % _COL_TILE == 0 \
-            and all(n % _COL_TILE == 0 for n in widths) \
-            and rows % 8 == 0 \
-            and _vmem_bytes(rows, d, widths, n_in) <= _VMEM_BUDGET
+        return all(n % _COL_TILE == 0 for n in dims) \
+            and rows % 8 == 0 and vmem_bytes <= _VMEM_BUDGET
     return True
+
+
+def _route(kind, shape, dims, rows, vmem_bytes):
+    """``(use_kernel, interpret)`` for one fused entry point under the
+    armed mode: ``interpret`` runs the kernel under the interpreter on
+    any backend; ``kernel`` takes it on a TPU backend for eligible
+    shapes and counts the refusal otherwise (off TPU the mode means
+    the bitwise-unfused XLA path)."""
+    mode = fused_decode_mode()
+    if mode == "interpret":
+        return _eligible(dims, rows, False, vmem_bytes), True
+    if mode == "kernel" and jax.default_backend() == "tpu":
+        if _eligible(dims, rows, True, vmem_bytes):
+            return True, None
+        _warn_fallback(kind, shape)
+    return False, None
 
 
 def fused_norm_matmul(x, gamma, beta, ws, bs, *, eps, kind):
@@ -471,26 +524,18 @@ def fused_norm_matmul(x, gamma, beta, ws, bs, *, eps, kind):
     XLA fallback. ``x`` keeps its ``[..., d]`` leading shape — the
     fallback runs on it UNRESHAPED so its ops are exactly the module
     path's; only the kernel flattens to packed rows."""
-    mode = fused_decode_mode()
     d = x.shape[-1]
     widths = [w.shape[-1] for w in ws]
     rows = int(np.prod(x.shape[:-1]))
-    use_kernel = interp = False
-    if mode == "interpret":
-        use_kernel = interp = _eligible(d, widths, rows, False) \
-            and pallas_norm_matmul is not None
-    elif mode == "kernel":
-        on_tpu = jax.default_backend() == "tpu"
-        use_kernel = on_tpu and pallas_norm_matmul is not None \
-            and _eligible(d, widths, rows, True)
-        if on_tpu and not use_kernel:
-            _warn_fallback("fused_norm_matmul", x.shape)
+    use_kernel, interp = _route(
+        "fused_norm_matmul", x.shape, [d] + widths, rows,
+        _norm_mm_vmem(rows, d, widths, x.dtype.itemsize))
     if not use_kernel:
         return _xla_norm_matmul(x, gamma, beta, ws, bs, eps=eps,
                                 kind=kind)
     outs = pallas_norm_matmul(
         x.reshape(rows, d), gamma, beta, list(ws), list(bs), eps=eps,
-        kind=kind, interpret=True if interp else None)
+        kind=kind, interpret=interp)
     return tuple(o.reshape(x.shape[:-1] + (o.shape[-1],))
                  for o in outs)
 
@@ -499,26 +544,17 @@ def fused_matmul_residual(xs, w, b, residual, *, act=None):
     """Array-level dispatcher for the projection->residual epilogue
     (optionally swiglu/gelu prologue); same routing contract as
     ``fused_norm_matmul``."""
-    mode = fused_decode_mode()
     kdim = xs[0].shape[-1]
     n = w.shape[-1]
     rows = int(np.prod(xs[0].shape[:-1]))
-    use_kernel = interp = False
-    if mode == "interpret":
-        use_kernel = interp = _eligible(kdim, [n], rows, False) \
-            and pallas_matmul_residual is not None
-    elif mode == "kernel":
-        on_tpu = jax.default_backend() == "tpu"
-        use_kernel = on_tpu and pallas_matmul_residual is not None \
-            and _eligible(kdim, [n], rows, True, n_in=len(xs))
-        if on_tpu and not use_kernel:
-            _warn_fallback("fused_matmul_residual", xs[0].shape)
+    use_kernel, interp = _route(
+        "fused_matmul_residual", xs[0].shape, [kdim, n], rows,
+        _mm_res_vmem(rows, kdim, n, len(xs), xs[0].dtype.itemsize))
     if not use_kernel:
         return _xla_matmul_residual(xs, w, b, residual, act=act)
     out = pallas_matmul_residual(
         [x.reshape(rows, kdim) for x in xs], w, b,
-        residual.reshape(rows, n), act=act,
-        interpret=True if interp else None)
+        residual.reshape(rows, n), act=act, interpret=interp)
     return out.reshape(residual.shape)
 
 

@@ -15,14 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...framework.core import Tensor, apply_jax, as_jax
-
-
-try:
-    from .flash_attention_kernel import pallas_flash_attention
-    _kernel_import_error = None
-except Exception as _e:  # pallas/tpu lowering unavailable on this build
-    pallas_flash_attention = None
-    _kernel_import_error = _e
+from .flash_attention_kernel import pallas_flash_attention
 
 
 def _xla_attention(q, k, v, bias, is_causal, scale):
@@ -40,20 +33,10 @@ def _xla_attention(q, k, v, bias, is_causal, scale):
 
 
 def _pallas_available():
-    try:
-        on_tpu = jax.default_backend() == "tpu"
-    except Exception:
-        return False
-    if on_tpu and pallas_flash_attention is None:
-        global _fallback_logged
-        if not _fallback_logged:
-            _fallback_logged = True
-            import warnings
-            warnings.warn(
-                "flash_attention: Pallas kernel unavailable on this jax "
-                "build (%r); using the XLA fallback" % _kernel_import_error)
-        return False
-    return on_tpu
+    """The flash kernels take a TPU backend; a backend query that
+    raises propagates (no silent XLA run on a machine whose
+    accelerator failed to come up)."""
+    return jax.default_backend() == "tpu"
 
 
 def _kernel_eligible(q, k, bias):
@@ -69,6 +52,39 @@ def _kernel_eligible(q, k, bias):
 _fallback_logged = False
 
 
+# the fleet mesh axes that split the batch / the heads of an activation
+# (``shard_utils.batch_shard``, the Column/RowParallel head split)
+_BATCH_AXES = ("dp", "sharding", "ep")
+_HEAD_AXIS = "mp"
+
+
+def _gspmd_shard_spec(q, k):
+    """How the kernel must run when the ambient fleet mesh makes this a
+    GSPMD-partitioned program: Mosaic kernels cannot be partitioned
+    automatically ("Please wrap the call in a shard_map" — the first
+    hybrid train step on four chips, PR 21), so the call is mapped over
+    the mesh by hand, batch over the data axes and heads over ``mp``.
+
+    Returns ``None`` when there is nothing to partition (no mesh, one
+    device, or already inside a shard_map body, where axes are manual
+    and shapes local), ``False`` when the shape does not divide over the
+    mesh (XLA attention, which GSPMD does partition), else ``(mesh,
+    PartitionSpec)`` for q/k/v/out."""
+    from jax.sharding import PartitionSpec as P
+    from ...distributed.shard_utils import current_mesh
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1 \
+            or jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    batch = tuple(a for a in _BATCH_AXES if mesh.shape.get(a, 1) > 1)
+    n_batch = int(np.prod([mesh.shape[a] for a in batch])) if batch else 1
+    mp = mesh.shape.get(_HEAD_AXIS, 1)
+    if q.shape[0] % n_batch or q.shape[2] % mp or k.shape[2] % mp:
+        return False
+    return mesh, P(batch or None, None, _HEAD_AXIS if mp > 1 else None,
+                   None)
+
+
 def flash_attention_core(q, k, v, bias=None, is_causal=False, scale=None):
     """Pure-array flash attention; q/k/v: [B, L, H, D]. K/V may carry
     fewer (grouped) heads — the Pallas kernel consumes them natively and
@@ -76,16 +92,26 @@ def flash_attention_core(q, k, v, bias=None, is_causal=False, scale=None):
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
     if _pallas_available():
-        if _kernel_eligible(q, k, bias):
+        part = _gspmd_shard_spec(q, k) \
+            if _kernel_eligible(q, k, bias) else False
+        if part is None:
             return pallas_flash_attention(q, k, v, causal=is_causal,
                                           sm_scale=scale)
+        if part:
+            mesh, spec = part
+            return jax.shard_map(
+                lambda q_, k_, v_: pallas_flash_attention(
+                    q_, k_, v_, causal=is_causal, sm_scale=scale),
+                mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                check_vma=False)(q, k, v)
         global _fallback_logged
         if not _fallback_logged:
             _fallback_logged = True
             import warnings
             warnings.warn(
                 "flash_attention: shape %s / bias=%s not eligible for the "
-                "Pallas kernel; using the XLA fallback (logged once)"
+                "Pallas kernel (or not divisible over the mesh); using "
+                "the XLA fallback (logged once)"
                 % (tuple(q.shape), bias is not None))
     return _xla_attention(q, k, v, bias, is_causal, scale)
 
@@ -139,6 +165,21 @@ def _flashmask_kernel_eligible(q, idx):
             and q.shape[2] % idx.shape[1] == 0)
 
 
+def flashmask_dense_bias(idx, seq_len, causal, dtype):
+    """The O(L²) additive bias ``[B, H_m, L, L]`` the compact bounds
+    ``idx [B, H_m, L, 1|2]`` stand for — the dense lowering the
+    compact-form kernel exists to avoid, and its reference."""
+    rows = jnp.arange(seq_len)[:, None]  # query index
+    cols = jnp.arange(seq_len)[None, :]  # key index
+    start = idx[..., 0]  # [B, Hm, L]: mask rows >= start per key column
+    masked = rows[None, None] >= start[:, :, None, :]
+    if idx.shape[-1] == 2:
+        masked = masked & (rows[None, None] < idx[..., 1][:, :, None, :])
+    if causal:
+        masked = masked | (cols[None, None] > rows[None, None])
+    return jnp.where(masked, -1e9, 0.0).astype(dtype)
+
+
 def flashmask_attention(query, key, value, startend_row_indices=None,
                         dropout=0.0, causal=False, name=None):
     """FlashMask sparse-mask attention parity
@@ -166,23 +207,7 @@ def flashmask_attention(query, key, value, startend_row_indices=None,
             from ...nn.functional.common import dropout as _dropout
             out = _dropout(out, dropout, training=True)
         return out
-    L = q.shape[1]
-    rows = jnp.arange(L)[:, None]  # query index
-    cols = jnp.arange(L)[None, :]  # key index
-    if idx.shape[-1] == 1:
-        # causal: mask rows >= start for each key column
-        start = idx[..., 0]  # [B, Hk, L]
-        masked = rows[None, None] >= start[:, :, None, :]
-        if causal:
-            masked = masked | (cols[None, None] > rows[None, None])
-    else:
-        start = idx[..., 0]
-        end = idx[..., 1]
-        masked = (rows[None, None] >= start[:, :, None, :]) & \
-                 (rows[None, None] < end[:, :, None, :])
-        if causal:
-            masked = masked | (cols[None, None] > rows[None, None])
-    bias = jnp.where(masked, -1e9, 0.0).astype(q.dtype)
+    bias = flashmask_dense_bias(idx, q.shape[1], causal, q.dtype)
     # bias is [B, Hk, Lq, Lk]; broadcast over query heads
     mask_t = Tensor(bias)
     return scaled_dot_product_attention(query, key, value, mask_t, dropout,

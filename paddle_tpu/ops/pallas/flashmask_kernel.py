@@ -34,9 +34,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention_kernel import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q,
-                                     NEG_INF, _block_sizes,
-                                     _CompilerParams, _interpret,
-                                     _kv_row, disable_x64)
+                                     NEG_INF, _block_sizes, _interpret,
+                                     _kv_row, kernel_scope)
 
 
 def _mask_block(s, start, end, qi, ki, block_q, block_k, causal):
@@ -227,11 +226,11 @@ def _fm_fwd(q, k, v, start, end, scale, causal, block_q, block_k,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )
-    with disable_x64():
+    with kernel_scope("flashmask_attention_fwd"):
         o, lse = call(q, k, v, start, end)
     return o, lse
 
@@ -271,11 +270,11 @@ def _fm_bwd(scale, causal, block_q, block_k, h, h_kv, h_m, res, do):
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, lq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )
-    with disable_x64():
+    with kernel_scope("flashmask_attention_dq"):
         dq = dq_call(q, k, v, do, lse, delta, start, end)
 
     n_t = group * n_q
@@ -321,11 +320,11 @@ def _fm_bwd(scale, causal, block_q, block_k, h, h_kv, h_m, res, do):
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )
-    with disable_x64():
+    with kernel_scope("flashmask_attention_dkv"):
         dk, dv = dkv_call(q, k, v, do, lse, delta, start, end)
     return dq, dk, dv, None, None
 

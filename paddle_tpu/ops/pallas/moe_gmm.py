@@ -36,10 +36,15 @@ writes only rows the current group owns, so every output row is
 written exactly once.
 
 All kernels take ``interpret=`` so the CPU test suite can run them
-bit-for-bit under the Pallas interpreter; the production gate
-(``distributed.moe._use_fused_gmm``) only enables them on a real TPU
-backend at MXU-scale aligned shapes, exactly like the megablox gate
-they extend. Kill switch: ``PADDLE_TPU_MOE_FUSED_GMM=0``.
+bit-for-bit under the Pallas interpreter — today the ONLY way they run:
+they lower for TPU (``tests/test_tpu_lowering.py``) but Mosaic refuses
+to compile the per-row async copies below ("Slice shape along dimension
+0 must be aligned to tiling (8), but is 1": a one-row slice of a tiled
+array is not a legal DMA operand, in HBM or VMEM — ``chip_smoke.py`` on
+a v5e, PR 21), so the production gates
+(``distributed.moe._use_fused_gmm``, ``ops.lora._use_lora_gmm``) route
+no shape here on a TPU backend. ROADMAP S1 holds the verdict to reach:
+a row gather Mosaic accepts, or deletion.
 """
 from __future__ import annotations
 
@@ -140,12 +145,10 @@ def _call_grouped(x, rhs, group_sizes, *, src_rows, dst_rows, swiglu,
     ``swiglu`` the n dim is ``2f`` and the output is ``[m, f]``).
     ``dst_rows [m]``: scatter permutation for the output rows (must be
     a permutation — every output row is written exactly once).
-    Metadata AND the kernel trace run in 32-bit mode: the framework
-    default enables x64, under which weak-f64/i64 constants leak into
-    the trace and Mosaic cannot legalize them (the ``_gmm32``
-    lesson)."""
-    from .flash_attention_kernel import disable_x64
-    with disable_x64():
+    Metadata AND the kernel trace run in 32-bit mode under
+    ``kernel_scope`` (the ``_gmm32`` lesson)."""
+    from .flash_attention_kernel import kernel_scope
+    with kernel_scope("moe_gmm"):
         return _call_grouped_32(
             x, rhs, group_sizes, src_rows=src_rows, dst_rows=dst_rows,
             swiglu=swiglu, transpose_rhs=transpose_rhs, tiling=tiling,
@@ -188,7 +191,7 @@ def _call_grouped_32(x, rhs, group_sizes, *, src_rows, dst_rows,
     in_specs = []
     args = []
     if gather:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
         args.append(x)
     else:
         in_specs.append(pl.BlockSpec(
@@ -203,7 +206,7 @@ def _call_grouped_32(x, rhs, group_sizes, *, src_rows, dst_rows,
         args.append(rhs)
 
     if scatter:
-        out_specs = pl.BlockSpec(memory_space=pltpu.ANY)
+        out_specs = pl.BlockSpec(memory_space=pl.ANY)
     else:
         out_specs = pl.BlockSpec(
             (tm, tn), lambda n_i, g_i, k_i, *pref: (pref[2][g_i], n_i))
@@ -311,17 +314,12 @@ def _call_grouped_32(x, rhs, group_sizes, *, src_rows, dst_rows,
         scratch_shapes=scratch,
     )
     flops = 2 * m * k * n_full
-    try:
-        cparams = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"))
-    except AttributeError:                     # newer jax renamed it
-        cparams = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"))
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=cparams,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=flops, transcendentals=m * n if swiglu else 0,
             bytes_accessed=(m * k + k * n_full * e + m * n)

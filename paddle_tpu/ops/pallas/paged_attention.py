@@ -80,6 +80,10 @@ import threading
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention_kernel import kernel_scope
 
 __all__ = ["paged_decode_attention", "pallas_paged_attention",
            "paged_verify_attention", "pallas_paged_verify_attention",
@@ -159,12 +163,10 @@ _FORCE_INTERPRET = False  # tests flip this to run the kernel on CPU
 
 
 def _interpret() -> bool:
-    if _FORCE_INTERPRET:
-        return True
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
+    """Interpreted only when asked for or on the CPU backend; a backend
+    query that raises propagates (never a silent interpreter run on a
+    machine whose accelerator failed to come up)."""
+    return _FORCE_INTERPRET or jax.default_backend() == "cpu"
 
 
 def _force_kernel_routing() -> bool:
@@ -181,28 +183,69 @@ def _force_kernel_routing() -> bool:
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _dequant_tile(k_ref, sc_ref):
+def _row_pad(rep, q_dtype) -> int:
+    """Rows one kv group's ``rep = H / H_kv`` query heads occupy in the
+    kernels: the next power of two >= ``rep`` that also fills a whole
+    sublane tile of the query dtype (8 rows of f32, 16 of bf16). Mosaic
+    then sees only tile-aligned matmul operands (Qwen2-7B's ``rep = 7``
+    would otherwise be a ragged 7-row MXU operand), and the verify
+    kernel recovers a row's window token with a shift instead of an
+    integer division by ``rep``. The wrappers zero-pad the extra rows
+    and drop them from the output."""
+    rp = 32 // jnp.dtype(q_dtype).itemsize
+    while rp < rep:
+        rp *= 2
+    return rp
+
+
+def _pad_rep(q5, rp):
+    """Zero-pad the ``rep`` axis (second to last) of ``[..., rep, D]``
+    to ``rp`` rows."""
+    pad = [(0, 0)] * q5.ndim
+    pad[-2] = (0, rp - q5.shape[-2])
+    return jnp.pad(q5, pad)
+
+
+def _pool_view(pool):
+    """``[NB, BS, H_kv, D]`` pool as the contiguous ``[NB, BS,
+    H_kv * D]`` view the kernels index: a ``(1, BS, D)`` block at lane
+    offset ``g * D`` is kv head ``g``'s ``[BS, D]`` tile, and its last
+    two dims are (whole dim, 128-multiple) — the only K/V block shape
+    Mosaic's block rule admits for ``H_kv > 1`` without moving the
+    pool to another layout."""
+    nb, bs, hkv, d = pool.shape
+    return pool.reshape(nb, bs, hkv * d)
+
+
+def _dequant_tile(k_ref, sc_ref, g):
     """In-VMEM dequant of one pooled K/V block tile after its DMA:
-    int8 ``[BS, D]`` x per-(position, head) f32 scale ``[BS]``. The
-    result STAYS f32 through the dots (accuracy over MXU rate on a
+    int8 ``[BS, D]`` x kv head ``g``'s per-position f32 scale. The
+    scale block carries every kv head (``[BS, H_kv]`` — a single-head
+    column is not a legal Mosaic block); head ``g``'s column is picked
+    by an iota mask + lane sum, exact because every other term is 0.
+    The result STAYS f32 through the dots (accuracy over MXU rate on a
     bandwidth-bound op: re-rounding to bf16 would stack a second
     ~0.2% grid error on the int8 step and measurably cost greedy
     token-match) — the same recipe ``paged_cache.kv_dequantize`` runs
     in the gather fallback, so kernel and fallback read identical
     values from identical stored bytes."""
-    return (k_ref[0, :, 0, :].astype(jnp.float32)
-            * sc_ref[0, :, 0][:, None])
+    sc = sc_ref[0]                                    # [BS, H_kv]
+    head = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+    sc_g = jnp.sum(jnp.where(head == g, sc, np.float32(0.0)),
+                   axis=1, keepdims=True)             # [BS, 1]
+    return k_ref[0].astype(jnp.float32) * sc_g
 
 
 def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
-                   scale, block_size, n_blocks, t_q=1, rep=None,
+                   scale, block_size, n_blocks, t_q=1, row_shift=0,
                    quantized=False, tree_bits=None):
     """Shared body for single-token decode (``t_q=1``) and the
     speculative multi-query verify window (``t_q=gamma+1``): the
-    ``t_q * rep`` softmax rows carry a per-row causal bound — row
-    ``r`` belongs to window token ``t = r // rep`` and may see cache
-    positions ``< lens_ref[s] + t`` (``lens_ref`` counts positions
-    visible to window token 0, that token itself included).
+    ``t_q * rp`` softmax rows (``rp = 1 << row_shift``, see
+    ``_row_pad``) carry a per-row causal bound — row ``r`` belongs to
+    window token ``t = r >> row_shift`` and may see cache positions
+    ``< lens_ref[s] + t`` (``lens_ref`` counts positions visible to
+    window token 0, that token itself included).
     ``tree_bits`` (static per-node ancestor bitmasks,
     ``tree_ancestor_bits``) swaps that linear bound for the token-tree
     mask: window row ``t`` sees the committed prefix + root
@@ -217,6 +260,7 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     s = pl.program_id(0)
+    g = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -230,14 +274,14 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
     # reach hold no live tokens — predicate off their FLOPs entirely
     @pl.when(j * block_size < ctx + (t_q - 1))
     def _compute():
-        q = q_ref[0, 0]                       # [t_q * rep, D]
+        q = q_ref[0, 0]                       # [t_q * rp, D]
         if quantized:
             q = q.astype(jnp.float32)         # match the f32 dequant
-            k = _dequant_tile(k_ref, ks_ref)
-            v = _dequant_tile(v_ref, vs_ref)
+            k = _dequant_tile(k_ref, ks_ref, g)
+            v = _dequant_tile(v_ref, vs_ref, g)
         else:
-            k = k_ref[0, :, 0, :]             # [BS, D]
-            v = v_ref[0, :, 0, :]
+            k = k_ref[0]                      # [BS, D]
+            v = v_ref[0]
         sc = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -247,9 +291,10 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
             bound = ctx
             sc = jnp.where(cols < bound, sc, NEG_INF)
         elif tree_bits is None:
-            # causal within the window: row r is window token r//rep
-            bound = ctx + jax.lax.broadcasted_iota(
-                jnp.int32, sc.shape, 0) // rep
+            # causal within the window: row r is window token
+            # r >> row_shift
+            bound = ctx + (jax.lax.broadcasted_iota(
+                jnp.int32, sc.shape, 0) >> row_shift)
             sc = jnp.where(cols < bound, sc, NEG_INF)
         else:
             # token-tree verify window: window node j sits at cache
@@ -257,7 +302,7 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
             # node (rel < 0 = committed prefix + root); row t keeps a
             # column iff that node is on its own ancestor path
             node = jax.lax.broadcasted_iota(
-                jnp.int32, sc.shape, 0) // rep
+                jnp.int32, sc.shape, 0) >> row_shift
             bits = jnp.zeros(sc.shape, jnp.int32)
             for i, b in enumerate(tree_bits):
                 bits = jnp.where(node == i, np.int32(b), bits)
@@ -312,6 +357,7 @@ def _ragged_kernel(qlens_ref, starts_ref, tables_ref, lens_ref, *args,
         o_ref, m_scr, l_scr, acc_scr = rest
     s = pl.program_id(0)
     t = pl.program_id(1)
+    g = pl.program_id(2)
     j = pl.program_id(3)
 
     @pl.when(j == 0)
@@ -323,14 +369,14 @@ def _ragged_kernel(qlens_ref, starts_ref, tables_ref, lens_ref, *args,
     ctx = lens_ref[s] + t          # cols < ctx visible to this row
     @pl.when((t < qlens_ref[s]) & (j * block_size < ctx))
     def _compute():
-        q = q_ref[0, 0]                       # [rep, D]
+        q = q_ref[0, 0]                       # [rp, D]
         if quantized:
             q = q.astype(jnp.float32)         # match the f32 dequant
-            k = _dequant_tile(k_ref, ks_ref)
-            v = _dequant_tile(v_ref, vs_ref)
+            k = _dequant_tile(k_ref, ks_ref, g)
+            v = _dequant_tile(v_ref, vs_ref, g)
         else:
-            k = k_ref[0, :, 0, :]             # [BS, D]
-            v = v_ref[0, :, 0, :]
+            k = k_ref[0]                      # [BS, D]
+            v = v_ref[0]
         sc = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -350,9 +396,12 @@ def _ragged_kernel(qlens_ref, starts_ref, tables_ref, lens_ref, *args,
             rel = cols - lens_ref[s]
             ok_tree = (rel < 0) | (
                 ((bits >> jnp.clip(rel, 0, 31)) & 1) > 0)
+            # (boolean algebra, not a select between masks: Mosaic
+            # cannot legalize arith.select on i1 vectors)
             is_tree = (tree_ref[s] > 0) & (t < len(tree_bits))
-            sc = jnp.where(
-                jnp.where(is_tree, ok_tree, cols < ctx), sc, NEG_INF)
+            keep = (is_tree & ok_tree) | (
+                jnp.logical_not(is_tree) & (cols < ctx))
+            sc = jnp.where(keep, sc, NEG_INF)
         m_prev = m_scr[:, :1]
         l_prev = l_scr[:, :1]
         m_cur = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
@@ -373,246 +422,197 @@ def _ragged_kernel(qlens_ref, starts_ref, tables_ref, lens_ref, *args,
         o_ref[0, 0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
 
 
-try:  # pallas/tpu lowering may be absent on this jax build
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+def _unpack_pools(k_pool, v_pool):
+    """(k_view, v_view, [k_scale, v_scale] or [], quantized): each
+    pool as its ``_pool_view``; quantized pools split into the int8
+    data views plus the scale operands the kernels dequantize with."""
+    from ..paged_cache import QuantKV
+    if isinstance(k_pool, QuantKV):
+        return (_pool_view(k_pool.data), _pool_view(v_pool.data),
+                [k_pool.scale, v_pool.scale], True)
+    return _pool_view(k_pool), _pool_view(v_pool), [], False
 
-    from .flash_attention_kernel import _CompilerParams
 
-    def _unpack_pools(k_pool, v_pool):
-        """(k_data, v_data, [k_scale, v_scale] or [], quantized):
-        quantized pools split into the int8 data operands plus the
-        scale operands the kernels dequantize with."""
-        from ..paged_cache import QuantKV
-        if isinstance(k_pool, QuantKV):
-            return (k_pool.data, v_pool.data,
-                    [k_pool.scale, v_pool.scale], True)
-        return k_pool, v_pool, [], False
+def _softmax_scratch(rows, d):
+    """Online-softmax state (running max, denominator, weighted
+    values) for ``rows`` query rows."""
+    return [pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32)]
 
-    def pallas_paged_attention(q, k_pool, v_pool, block_tables,
-                               context_lens, sm_scale=None,
-                               interpret=None):
-        """q: [S, H, D]; pools: [NB, BS, H_kv, D] (or ``QuantKV`` int8
-        pools — dequantized per block tile in VMEM); block_tables:
-        [S, MB] int32; context_lens: [S] int32 (valid positions per
-        slot, current token included). Returns [S, H, D]."""
-        s, h, d = q.shape
-        nb, bs, hkv, _ = k_pool.shape
-        kd, vd, scales, quant = _unpack_pools(k_pool, v_pool)
-        mb = block_tables.shape[1]
-        rep = h // hkv
-        scale = np.float32(sm_scale if sm_scale is not None
-                           else 1.0 / math.sqrt(d))
-        q4 = q.reshape(s, hkv, rep, d)
-        kernel = functools.partial(
-            _decode_kernel, scale=scale, block_size=bs, n_blocks=mb,
-            quantized=quant)
 
-        def kv_block(si, g, j, tables, lens):
-            # chase the slot's block table; out-of-range grid steps read
-            # the null block (tables are null-filled past the slot's
-            # allocation) and are predicated off in the kernel
-            return (tables[si, j], 0, g, 0)
+def pallas_paged_attention(q, k_pool, v_pool, block_tables,
+                           context_lens, sm_scale=None,
+                           interpret=None):
+    """q: [S, H, D]; pools: [NB, BS, H_kv, D] (or ``QuantKV`` int8
+    pools — dequantized per block tile in VMEM); block_tables:
+    [S, MB] int32; context_lens: [S] int32 (valid positions per
+    slot, current token included). Returns [S, H, D]."""
+    return pallas_paged_verify_attention(
+        q[:, None], k_pool, v_pool, block_tables, context_lens,
+        sm_scale=sm_scale, interpret=interpret)[:, 0]
 
-        def sc_block(si, g, j, tables, lens):
-            return (tables[si, j], 0, g)
 
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(s, hkv, mb),
-            in_specs=[
-                pl.BlockSpec((1, 1, rep, d),
-                             lambda si, g, j, tables, lens:
-                             (si, g, 0, 0)),
-                pl.BlockSpec((1, bs, 1, d), kv_block),
-                pl.BlockSpec((1, bs, 1, d), kv_block),
-            ] + [pl.BlockSpec((1, bs, 1), sc_block)] * len(scales),
-            out_specs=pl.BlockSpec((1, 1, rep, d),
-                                   lambda si, g, j, tables, lens:
-                                   (si, g, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((rep, 128), jnp.float32),
-                pltpu.VMEM((rep, 128), jnp.float32),
-                pltpu.VMEM((rep, d), jnp.float32),
-            ],
-        )
-        out = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((s, hkv, rep, d), q.dtype),
-            compiler_params=_CompilerParams(
-                dimension_semantics=("parallel", "parallel",
-                                     "arbitrary")),
-            interpret=_interpret() if interpret is None else interpret,
-        )(block_tables.astype(jnp.int32),
-          context_lens.astype(jnp.int32), q4, kd, vd, *scales)
-        return out.reshape(s, h, d)
+def pallas_paged_verify_attention(q, k_pool, v_pool, block_tables,
+                                  context_lens, sm_scale=None,
+                                  interpret=None, tree_anc=None):
+    """Multi-query (speculative verify) variant. q: [S, T, H, D]
+    (T = gamma + 1 window tokens per slot, already written to the
+    pool; T = 1 is the decode step); context_lens: [S] int32 —
+    positions visible to window token 0, itself included (token ``t``
+    sees ``context_lens + t`` positions). ``tree_anc`` (static parent
+    tuple, ``len = T-1``) masks every slot's window by ancestor path
+    instead of the linear in-window bound (``tree_ancestor_bits``).
+    Returns [S, T, H, D]."""
+    s, t, h, d = q.shape
+    nb, bs, hkv, _ = k_pool.shape
+    kd, vd, scales, quant = _unpack_pools(k_pool, v_pool)
+    mb = block_tables.shape[1]
+    rep = h // hkv
+    rp = _row_pad(rep, q.dtype)
+    scale = np.float32(sm_scale if sm_scale is not None
+                       else 1.0 / math.sqrt(d))
+    tree_bits = None
+    if tree_anc is not None:
+        tree_bits = tree_ancestor_bits(tree_anc)
+        if len(tree_bits) != t:
+            raise ValueError(
+                f"spec tree has {len(tree_bits)} nodes but the "
+                f"verify window carries {t} rows")
+    # rows grouped kv-head-major: [S, hkv, T*rp, D] so one K/V
+    # block DMA feeds every window token of the kv group
+    rows = t * rp
+    q4 = _pad_rep(q.reshape(s, t, hkv, rep, d), rp) \
+        .transpose(0, 2, 1, 3, 4).reshape(s, hkv, rows, d)
+    kernel = functools.partial(
+        _decode_kernel, scale=scale, block_size=bs, n_blocks=mb,
+        t_q=t, row_shift=rp.bit_length() - 1, quantized=quant,
+        tree_bits=tree_bits)
 
-    def pallas_paged_verify_attention(q, k_pool, v_pool, block_tables,
-                                      context_lens, sm_scale=None,
-                                      interpret=None, tree_anc=None):
-        """Multi-query (speculative verify) variant. q: [S, T, H, D]
-        (T = gamma + 1 window tokens per slot, already written to the
-        pool); context_lens: [S] int32 — positions visible to window
-        token 0, itself included (token ``t`` sees ``context_lens + t``
-        positions). ``tree_anc`` (static parent tuple, ``len = T-1``)
-        masks every slot's window by ancestor path instead of the
-        linear in-window bound (``tree_ancestor_bits``). Returns
-        [S, T, H, D]."""
-        s, t, h, d = q.shape
-        nb, bs, hkv, _ = k_pool.shape
-        kd, vd, scales, quant = _unpack_pools(k_pool, v_pool)
-        mb = block_tables.shape[1]
-        rep = h // hkv
-        scale = np.float32(sm_scale if sm_scale is not None
-                           else 1.0 / math.sqrt(d))
-        tree_bits = None
-        if tree_anc is not None:
-            tree_bits = tree_ancestor_bits(tree_anc)
-            if len(tree_bits) != t:
-                raise ValueError(
-                    f"spec tree has {len(tree_bits)} nodes but the "
-                    f"verify window carries {t} rows")
-        # rows grouped kv-head-major: [S, hkv, T*rep, D] so one K/V
-        # block DMA feeds every window token of the kv group
-        q4 = q.reshape(s, t, hkv, rep, d).transpose(0, 2, 1, 3, 4) \
-            .reshape(s, hkv, t * rep, d)
-        kernel = functools.partial(
-            _decode_kernel, scale=scale, block_size=bs, n_blocks=mb,
-            t_q=t, rep=rep, quantized=quant, tree_bits=tree_bits)
+    def q_block(si, g, j, tables, lens):
+        return (si, g, 0, 0)
 
-        def kv_block(si, g, j, tables, lens):
-            return (tables[si, j], 0, g, 0)
+    def kv_block(si, g, j, tables, lens):
+        # chase the slot's block table; out-of-range grid steps read
+        # the null block (tables are null-filled past the slot's
+        # allocation) and are predicated off in the kernel
+        return (tables[si, j], 0, g)
 
-        def sc_block(si, g, j, tables, lens):
-            return (tables[si, j], 0, g)
+    def sc_block(si, g, j, tables, lens):
+        return (tables[si, j], 0, 0)
 
-        rows = t * rep
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(s, hkv, mb),
-            in_specs=[
-                pl.BlockSpec((1, 1, rows, d),
-                             lambda si, g, j, tables, lens:
-                             (si, g, 0, 0)),
-                pl.BlockSpec((1, bs, 1, d), kv_block),
-                pl.BlockSpec((1, bs, 1, d), kv_block),
-            ] + [pl.BlockSpec((1, bs, 1), sc_block)] * len(scales),
-            out_specs=pl.BlockSpec((1, 1, rows, d),
-                                   lambda si, g, j, tables, lens:
-                                   (si, g, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((rows, 128), jnp.float32),
-                pltpu.VMEM((rows, 128), jnp.float32),
-                pltpu.VMEM((rows, d), jnp.float32),
-            ],
-        )
-        out = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((s, hkv, rows, d), q.dtype),
-            compiler_params=_CompilerParams(
-                dimension_semantics=("parallel", "parallel",
-                                     "arbitrary")),
-            interpret=_interpret() if interpret is None else interpret,
-        )(block_tables.astype(jnp.int32),
-          context_lens.astype(jnp.int32), q4, kd, vd, *scales)
-        return out.reshape(s, hkv, t, rep, d).transpose(0, 2, 1, 3, 4) \
-            .reshape(s, t, h, d)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(s, hkv, mb),
+        in_specs=[
+            pl.BlockSpec((1, 1, rows, d), q_block),
+            pl.BlockSpec((1, bs, d), kv_block),
+            pl.BlockSpec((1, bs, d), kv_block),
+        ] + [pl.BlockSpec((1, bs, hkv), sc_block)] * len(scales),
+        out_specs=pl.BlockSpec((1, 1, rows, d), q_block),
+        scratch_shapes=_softmax_scratch(rows, d),
+    )
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s, hkv, rows, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel",
+                                 "arbitrary")),
+        interpret=_interpret() if interpret is None else interpret,
+    )
+    with kernel_scope("paged_decode_attention" if t == 1
+                      else "paged_verify_attention"):
+        out = call(block_tables.astype(jnp.int32),
+                   context_lens.astype(jnp.int32), q4, kd, vd, *scales)
+    return out.reshape(s, hkv, t, rp, d)[:, :, :, :rep] \
+        .transpose(0, 2, 1, 3, 4).reshape(s, t, h, d)
 
-    def pallas_ragged_paged_attention(q, k_pool, v_pool, block_tables,
-                                      context_lens, q_lens, row_starts,
-                                      row_slot=None, w_max=None,
-                                      sm_scale=None, interpret=None,
-                                      tree_anc=None, tree_slots=None):
-        """Ragged mixed-batch variant. q: [R, H, D] — ONE packed row
-        buffer holding every live query row of a serving tick, slot
-        ``s`` owning rows ``row_starts[s] .. row_starts[s] +
-        q_lens[s]``; ``context_lens[s]`` = positions visible to the
-        slot's first row, itself included (row ``t`` sees
-        ``context_lens[s] + t``). ``w_max`` is the static per-slot
-        row-count ceiling (the grid's window dimension). ``row_slot``
-        is accepted for fallback-signature parity and unused here.
-        ``tree_anc`` (static parent tuple) + ``tree_slots`` ([S] int32
-        flags, ``None`` = every slot) mask the flagged slots' verify
-        windows by ancestor path — unflagged slots (prefill chunks and
-        their trickle rows) keep the linear bound. Returns [R, H, D];
-        rows past a slot's ``q_lens`` are never read or written (dead
-        grid rows target a trailing scratch row)."""
-        r, h, d = q.shape
-        nb, bs, hkv, _ = k_pool.shape
-        kd, vd, scales, quant = _unpack_pools(k_pool, v_pool)
-        s, mb = block_tables.shape
-        w = int(w_max)
-        rep = h // hkv
-        scale = np.float32(sm_scale if sm_scale is not None
-                           else 1.0 / math.sqrt(d))
-        tree_bits = None
-        tree_args = []
-        if tree_anc is not None:
-            tree_bits = tree_ancestor_bits(tree_anc)
-            if tree_slots is None:
-                tree_slots = jnp.ones((s,), jnp.int32)
-            tree_args = [tree_slots.astype(jnp.int32)]
-        # trailing scratch row r: dead grid rows park their (skipped)
-        # reads and (zero) writes there so live packed rows are never
-        # clobbered
-        q4 = jnp.concatenate(
-            [q.reshape(r, hkv, rep, d),
-             jnp.zeros((1, hkv, rep, d), q.dtype)], axis=0)
-        kernel = functools.partial(
-            _ragged_kernel, scale=scale, block_size=bs, n_blocks=mb,
-            quantized=quant, tree_bits=tree_bits)
 
-        # *rest tolerates both prefetch arities (4 linear, 5 tree)
-        def q_map(si, t, g, j, qlens, starts, *rest):
-            return (jnp.where(t < qlens[si], starts[si] + t, r),
-                    g, 0, 0)
+def pallas_ragged_paged_attention(q, k_pool, v_pool, block_tables,
+                                  context_lens, q_lens, row_starts,
+                                  row_slot=None, w_max=None,
+                                  sm_scale=None, interpret=None,
+                                  tree_anc=None, tree_slots=None):
+    """Ragged mixed-batch variant. q: [R, H, D] — ONE packed row
+    buffer holding every live query row of a serving tick, slot
+    ``s`` owning rows ``row_starts[s] .. row_starts[s] +
+    q_lens[s]``; ``context_lens[s]`` = positions visible to the
+    slot's first row, itself included (row ``t`` sees
+    ``context_lens[s] + t``). ``w_max`` is the static per-slot
+    row-count ceiling (the grid's window dimension). ``row_slot``
+    is accepted for fallback-signature parity and unused here.
+    ``tree_anc`` (static parent tuple) + ``tree_slots`` ([S] int32
+    flags, ``None`` = every slot) mask the flagged slots' verify
+    windows by ancestor path — unflagged slots (prefill chunks and
+    their trickle rows) keep the linear bound. Returns [R, H, D];
+    rows past a slot's ``q_lens`` are never read or written (dead
+    grid rows target a trailing scratch row)."""
+    r, h, d = q.shape
+    nb, bs, hkv, _ = k_pool.shape
+    kd, vd, scales, quant = _unpack_pools(k_pool, v_pool)
+    s, mb = block_tables.shape
+    w = int(w_max)
+    rep = h // hkv
+    rp = _row_pad(rep, q.dtype)
+    scale = np.float32(sm_scale if sm_scale is not None
+                       else 1.0 / math.sqrt(d))
+    tree_bits = None
+    tree_args = []
+    if tree_anc is not None:
+        tree_bits = tree_ancestor_bits(tree_anc)
+        if tree_slots is None:
+            tree_slots = jnp.ones((s,), jnp.int32)
+        tree_args = [tree_slots.astype(jnp.int32)]
+    # one pad: the rep axis out to rp rows, and a trailing scratch row
+    # r where dead grid rows park their (skipped) reads and (zero)
+    # writes so live packed rows are never clobbered
+    q4 = jnp.pad(q.reshape(r, hkv, rep, d),
+                 ((0, 1), (0, 0), (0, rp - rep), (0, 0)))
+    kernel = functools.partial(
+        _ragged_kernel, scale=scale, block_size=bs, n_blocks=mb,
+        quantized=quant, tree_bits=tree_bits)
 
-        def kv_block(si, t, g, j, qlens, starts, tables, *rest):
-            return (tables[si, j], 0, g, 0)
+    # *rest tolerates both prefetch arities (4 linear, 5 tree)
+    def q_map(si, t, g, j, qlens, starts, *rest):
+        return (jnp.where(t < qlens[si], starts[si] + t, r),
+                g, 0, 0)
 
-        def sc_block(si, t, g, j, qlens, starts, tables, *rest):
-            return (tables[si, j], 0, g)
+    def kv_block(si, t, g, j, qlens, starts, tables, *rest):
+        return (tables[si, j], 0, g)
 
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4 + len(tree_args),
-            grid=(s, w, hkv, mb),
-            in_specs=[
-                pl.BlockSpec((1, 1, rep, d), q_map),
-                pl.BlockSpec((1, bs, 1, d), kv_block),
-                pl.BlockSpec((1, bs, 1, d), kv_block),
-            ] + [pl.BlockSpec((1, bs, 1), sc_block)] * len(scales),
-            out_specs=pl.BlockSpec((1, 1, rep, d), q_map),
-            scratch_shapes=[
-                pltpu.VMEM((rep, 128), jnp.float32),
-                pltpu.VMEM((rep, 128), jnp.float32),
-                pltpu.VMEM((rep, d), jnp.float32),
-            ],
-        )
-        out = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((r + 1, hkv, rep, d),
-                                           q.dtype),
-            compiler_params=_CompilerParams(
-                # slot and window dims revisit the scratch row on dead
-                # steps, so both stay sequential; kv_head blocks are
-                # disjoint
-                dimension_semantics=("arbitrary", "arbitrary",
-                                     "parallel", "arbitrary")),
-            interpret=_interpret() if interpret is None else interpret,
-        )(q_lens.astype(jnp.int32), row_starts.astype(jnp.int32),
-          block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-          *tree_args, q4, kd, vd, *scales)
-        return out[:r].reshape(r, h, d)
+    def sc_block(si, t, g, j, qlens, starts, tables, *rest):
+        return (tables[si, j], 0, 0)
 
-    _kernel_import_error = None
-except Exception as _e:  # pragma: no cover - environment dependent
-    pallas_paged_attention = None
-    pallas_paged_verify_attention = None
-    pallas_ragged_paged_attention = None
-    _kernel_import_error = _e
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4 + len(tree_args),
+        grid=(s, w, hkv, mb),
+        in_specs=[
+            pl.BlockSpec((1, 1, rp, d), q_map),
+            pl.BlockSpec((1, bs, d), kv_block),
+            pl.BlockSpec((1, bs, d), kv_block),
+        ] + [pl.BlockSpec((1, bs, hkv), sc_block)] * len(scales),
+        out_specs=pl.BlockSpec((1, 1, rp, d), q_map),
+        scratch_shapes=_softmax_scratch(rp, d),
+    )
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((r + 1, hkv, rp, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # slot and window dims revisit the scratch row on dead
+            # steps, so both stay sequential; kv_head blocks are
+            # disjoint
+            dimension_semantics=("arbitrary", "arbitrary",
+                                 "parallel", "arbitrary")),
+        interpret=_interpret() if interpret is None else interpret,
+    )
+    with kernel_scope("ragged_paged_attention"):
+        out = call(q_lens.astype(jnp.int32), row_starts.astype(jnp.int32),
+                   block_tables.astype(jnp.int32),
+                   context_lens.astype(jnp.int32), *tree_args, q4, kd, vd,
+                   *scales)
+    return out[:r, :, :rep].reshape(r, h, d)
 
 
 # ---------------------------------------------------------------------------
@@ -779,13 +779,45 @@ def _xla_ragged_paged(q, k_pool, v_pool, block_tables, context_lens,
                         lambda o: o, out)
 
 
-def _kernel_eligible(q, k_pool):
-    # block_size must be a whole number of sublane tiles for the pool
-    # dtype: 8 for f32, 16 for bf16/f16, 32 for int8/fp8
-    sublanes = 32 // max(jnp.dtype(k_pool.dtype).itemsize, 1)
-    return (q.shape[-1] in (64, 128, 256)
+# query rows one kv group may bring to a grid step (window tokens x
+# ``_row_pad``): online-softmax state, the q/out blocks and the score
+# tile all scale with it, and 1024 rows keep them under ~6 MB of the
+# 16 MB Mosaic grants a kernel by default
+_MAX_GROUP_ROWS = 1024
+
+
+def _kernel_eligible(num_heads, head_dim, q_dtype, k_pool, window=1):
+    """Exactly the shapes the kernels above lower and compile for
+    (cross-lowered for TPU in ``tests/test_tpu_lowering.py``, compiled
+    by Mosaic in ``chip_smoke.py``): the ``_pool_view`` K/V block is
+    ``(BS, D)`` at a lane offset, so ``D`` must be a whole number of
+    128-lane tiles (64 is not) and ``BS`` a whole number of sublane
+    tiles of the pool dtype (8 f32, 16 bf16/f16, 32 int8/fp8);
+    ``window`` query tokens per slot must fit ``_MAX_GROUP_ROWS``."""
+    hkv = k_pool.shape[2]
+    sublanes = 32 // jnp.dtype(k_pool.dtype).itemsize
+    if num_heads % hkv:
+        return False
+    rows = window * _row_pad(num_heads // hkv, q_dtype)
+    return (head_dim % 128 == 0
             and k_pool.shape[1] % sublanes == 0
-            and q.shape[1] % k_pool.shape[2] == 0)
+            and rows <= _MAX_GROUP_ROWS)
+
+
+def _use_kernel(kind, q_shape, num_heads, head_dim, q_dtype, k_pool,
+                window=1):
+    """The ONE routing decision of the three entry points: the Pallas
+    kernel on a TPU backend (or under ``PADDLE_TPU_PAGED_KERNEL=
+    interpret``) for eligible shapes, the gather fallback otherwise.
+    An ineligible shape on TPU is counted (``kernel_fallback_counts``);
+    nothing here is caught — a kernel the gate chose that then fails
+    to trace or compile is an error."""
+    on_tpu = jax.default_backend() == "tpu"
+    use = (on_tpu or _force_kernel_routing()) and _kernel_eligible(
+        num_heads, head_dim, q_dtype, k_pool, window)
+    if on_tpu and not use:
+        _warn_fallback(kind, q_shape, k_pool.shape)
+    return use
 
 
 _fallback_warned = set()    # paths that already logged their fallback
@@ -801,53 +833,43 @@ def kernel_fallback_counts() -> dict:
     return dict(_fallback_counts)
 
 
-def _warn_fallback(kind, q_shape, pool_shape, kernel_missing):
-    """TPU diagnostic: running the gather fallback in production means
-    the decode/verify hot loop lost the kernel. Every refusal bumps
-    the ``serving_kernel_fallback`` monitor counter (JSONL-exported,
-    mirrored in engine ``stats()``); the warning itself fires once per
-    entry point (the reasons can differ)."""
+def count_fallback(kind) -> bool:
+    """Record one refusal of entry point ``kind`` (also the fused
+    decode kernels' — one counter for every serving kernel): the
+    ``serving_kernel_fallback`` monitor counter (JSONL-exported) and
+    the dict ``ServingEngine.stats()`` mirrors. Returns True the first
+    time ``kind`` is seen, so callers warn once per entry point."""
     _fallback_counts[kind] = _fallback_counts.get(kind, 0) + 1
-    try:
-        from ... import monitor
-        monitor.counter(
-            "serving_kernel_fallback",
-            "paged-attention entry points routed to the XLA gather "
-            "fallback on a TPU backend (kernel missing or shape "
-            "ineligible)", labels=("path",)).labels(path=kind).inc()
-    except Exception:       # pragma: no cover - never break the trace
-        pass
-    if kind in _fallback_warned:
-        return
+    from ... import monitor
+    monitor.counter(
+        "serving_kernel_fallback",
+        "serving kernel entry points routed to their XLA fallback on "
+        "a TPU backend (shape not kernel-eligible)",
+        labels=("path",)).labels(path=kind).inc()
+    first = kind not in _fallback_warned
     _fallback_warned.add(kind)
-    import warnings
-    if kernel_missing:
-        reason = "kernel unavailable on this jax build (%r)" \
-            % (_kernel_import_error,)
-    else:
-        reason = ("shape %s / pool %s not kernel-eligible "
-                  "(head_dim must be 64/128/256, block_size a "
-                  "sublane-tile multiple for the pool dtype)"
-                  % (tuple(q_shape), tuple(pool_shape)))
-    warnings.warn("%s: %s; using the gather fallback" % (kind, reason))
+    return first
+
+
+def _warn_fallback(kind, q_shape, pool_shape):
+    """TPU diagnostic: running the gather fallback in production means
+    the decode/verify hot loop lost the kernel — counted every time,
+    warned once per entry point."""
+    if count_fallback(kind):
+        import warnings
+        warnings.warn(
+            "%s: shape %s / pool %s not kernel-eligible (head_dim "
+            "must be a 128-multiple, block_size a sublane-tile "
+            "multiple for the pool dtype); using the gather fallback"
+            % (kind, tuple(q_shape), tuple(pool_shape)))
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
                            sm_scale=None):
     """Ragged paged decode attention; q: [S, H, D] (one token per slot).
     Routes to the Pallas kernel on TPU, the gather fallback elsewhere."""
-    use_kernel = False
-    try:
-        use_kernel = (jax.default_backend() == "tpu"
-                      or _force_kernel_routing()) \
-            and pallas_paged_attention is not None \
-            and _kernel_eligible(q, k_pool)
-    except Exception:
-        use_kernel = False
-    if jax.default_backend() == "tpu" and not use_kernel:
-        _warn_fallback("paged_decode_attention", q.shape, k_pool.shape,
-                       pallas_paged_attention is None)
-    if use_kernel:
+    if _use_kernel("paged_decode_attention", q.shape, q.shape[1],
+                   q.shape[2], q.dtype, k_pool):
         return pallas_paged_attention(q, k_pool, v_pool, block_tables,
                                       context_lens, sm_scale=sm_scale)
     return _xla_paged_attention(q, k_pool, v_pool, block_tables,
@@ -895,23 +917,10 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables,
     ``spec_tree_scope``) mask the flagged slots' windows by ancestor
     path. Routes to the ragged Pallas grid on TPU, the two-lane
     verify fallback elsewhere."""
-    import types
     wn = int(narrow_iota.shape[0])
     w = int(win_iota.shape[0])
-    q_tok = types.SimpleNamespace(
-        shape=(block_tables.shape[0], q.shape[1], q.shape[2]))
-    use_kernel = False
-    try:
-        use_kernel = (jax.default_backend() == "tpu"
-                      or _force_kernel_routing()) \
-            and pallas_ragged_paged_attention is not None \
-            and _kernel_eligible(q_tok, k_pool)
-    except Exception:
-        use_kernel = False
-    if jax.default_backend() == "tpu" and not use_kernel:
-        _warn_fallback("ragged_paged_attention", q.shape, k_pool.shape,
-                       pallas_ragged_paged_attention is None)
-    if use_kernel:
+    if _use_kernel("ragged_paged_attention", q.shape, q.shape[1],
+                   q.shape[2], q.dtype, k_pool):
         return pallas_ragged_paged_attention(
             q, k_pool, v_pool, block_tables, context_lens, q_lens,
             row_starts, row_slot=row_slot, w_max=w, sm_scale=sm_scale,
@@ -984,7 +993,7 @@ def sharded_ragged_attention_step(qh, kh, vh, k_pool, v_pool,
     and ALL row metadata replicated. No collective inside; the step's
     only cross-shard traffic stays the engine's logits gather."""
     import jax.sharding as _js
-    from ...distributed.shard_utils import current_mesh, shard_map_compat
+    from ...distributed.shard_utils import current_mesh
     P = _js.PartitionSpec
     mesh = current_mesh()
     heads = P(None, "mp", None)           # [R, H, D] head split
@@ -1006,12 +1015,12 @@ def sharded_ragged_attention_step(qh, kh, vh, k_pool, v_pool,
                                          tree_anc=tree_anc,
                                          tree_slots=ts)
 
-        f = shard_map_compat(
-            local, mesh,
+        f = jax.shard_map(
+            local, mesh=mesh,
             in_specs=(heads, heads, heads, kspec, vspec,
                       P(None, None), rows, rows, rows, rows, rows,
                       rows, rows, rows),
-            out_specs=(heads, kspec, vspec))
+            out_specs=(heads, kspec, vspec), check_vma=False)
         return f(qh, kh, vh, k_pool, v_pool, block_tables, cache_lens,
                  q_lens, row_starts, row_slot, row_pos, narrow_iota,
                  win_iota, tree_slots)
@@ -1023,11 +1032,11 @@ def sharded_ragged_attention_step(qh, kh, vh, k_pool, v_pool,
                                      sm_scale=sm_scale, tree_anc=None,
                                      tree_slots=None)
 
-    f = shard_map_compat(
-        local, mesh,
+    f = jax.shard_map(
+        local, mesh=mesh,
         in_specs=(heads, heads, heads, kspec, vspec, P(None, None),
                   rows, rows, rows, rows, rows, rows, rows),
-        out_specs=(heads, kspec, vspec))
+        out_specs=(heads, kspec, vspec), check_vma=False)
     return f(qh, kh, vh, k_pool, v_pool, block_tables, cache_lens,
              q_lens, row_starts, row_slot, row_pos, narrow_iota,
              win_iota)
@@ -1064,11 +1073,7 @@ def serving_tp_active() -> bool:
     lowering there (the same reasoning as the r5 ragged_dot gate)."""
     if not getattr(_SERVING_TP, "on", False):
         return False
-    try:
-        from ...distributed.shard_utils import (current_mesh,
-                                                in_manual_region)
-    except Exception:       # pragma: no cover - partial install
-        return False
+    from ...distributed.shard_utils import current_mesh, in_manual_region
     mesh = current_mesh()
     return (mesh is not None and int(mesh.shape.get("mp", 1)) > 1
             and not in_manual_region())
@@ -1083,11 +1088,7 @@ def tp_shard_degree(num_heads, num_kv_heads) -> int:
     it if it can)."""
     if not getattr(_SERVING_TP, "on", False):
         return 1
-    try:
-        from ...distributed.shard_utils import (current_mesh,
-                                                in_manual_region)
-    except Exception:       # pragma: no cover - partial install
-        return 1
+    from ...distributed.shard_utils import current_mesh, in_manual_region
     mesh = current_mesh()
     if mesh is None or in_manual_region():
         return 1
@@ -1118,7 +1119,7 @@ def sharded_paged_attention_step(qh, kh, vh, k_pool, v_pool,
     traffic is the logits gather the serving engine adds before
     sampling."""
     import jax.sharding as _js
-    from ...distributed.shard_utils import current_mesh, shard_map_compat
+    from ...distributed.shard_utils import current_mesh
     P = _js.PartitionSpec
     mesh = current_mesh()
     heads = P(None, None, "mp", None)     # q/k/v head dim
@@ -1128,11 +1129,11 @@ def sharded_paged_attention_step(qh, kh, vh, k_pool, v_pool,
         return paged_attention_step(q, k, v, kp, vp, tables, lens,
                                     sm_scale=sm_scale)
 
-    f = shard_map_compat(
-        local, mesh,
+    f = jax.shard_map(
+        local, mesh=mesh,
         in_specs=(heads, heads, heads, kspec, vspec,
                   P(None, None), P(None)),
-        out_specs=(heads, kspec, vspec))
+        out_specs=(heads, kspec, vspec), check_vma=False)
     return f(qh, kh, vh, k_pool, v_pool, block_tables, cache_lens)
 
 
@@ -1157,23 +1158,8 @@ def paged_verify_attention(q, k_pool, v_pool, block_tables,
     # "not a verify window", so the linear bound stands
     if tree_anc is not None and len(tree_anc) + 1 != q.shape[1]:
         tree_anc = None
-    import types
-    # shape-only stand-in for one window token so the shared
-    # eligibility predicate applies without building a traced slice
-    q_tok = types.SimpleNamespace(
-        shape=(q.shape[0], q.shape[2], q.shape[3]))
-    use_kernel = False
-    try:
-        use_kernel = (jax.default_backend() == "tpu"
-                      or _force_kernel_routing()) \
-            and pallas_paged_verify_attention is not None \
-            and _kernel_eligible(q_tok, k_pool)
-    except Exception:
-        use_kernel = False
-    if jax.default_backend() == "tpu" and not use_kernel:
-        _warn_fallback("paged_verify_attention", q.shape, k_pool.shape,
-                       pallas_paged_verify_attention is None)
-    if use_kernel:
+    if _use_kernel("paged_verify_attention", q.shape, q.shape[2],
+                   q.shape[3], q.dtype, k_pool, window=q.shape[1]):
         return pallas_paged_verify_attention(
             q, k_pool, v_pool, block_tables, context_lens,
             sm_scale=sm_scale, tree_anc=tree_anc)

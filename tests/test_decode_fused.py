@@ -49,11 +49,11 @@ def llama_tiny():
 
 @pytest.fixture
 def llama_eligible():
-    """Kernel-eligible shape (head_dim 64, 128-multiple widths) for
+    """Kernel-eligible shape (head_dim 128, 128-multiple widths) for
     interpret-mode engine runs and the census collapse."""
     paddle.seed(7)
-    cfg = LlamaConfig.tiny(vocab=256, hidden=256, layers=2, heads=4,
-                           kv_heads=2, ffn=512)
+    cfg = LlamaConfig.tiny(vocab=256, hidden=256, layers=2, heads=2,
+                           kv_heads=1, ffn=512)
     m = LlamaForCausalLM(cfg)
     m.eval()
     return m

@@ -188,8 +188,10 @@ def test_merged_disagg_trace_one_pid_per_replica(llama_tiny):
     st = cl.stats()
     roof = st["roofline"]
     rep = st["replicas"][roof["busiest_replica"]]["roofline"]
-    assert roof["step_mfu"] == rep["step_mfu"] > 0
-    assert roof["step_hbm_bw_util"] == rep["step_hbm_bw_util"] > 0
+    assert roof["device"] == rep["device"] == "cpu"
+    # tier-1 runs on CPU: no device peak, so no utilization
+    assert roof["step_mfu"] is rep["step_mfu"] is None
+    assert roof["step_hbm_bw_util"] is rep["step_hbm_bw_util"] is None
     cl.shutdown()
 
 
@@ -314,38 +316,60 @@ def test_trace_kill_switch_cluster_bit_parity(llama_tiny,
 
 
 def test_roofline_stats_ragged_engine(llama_tiny):
-    """The default (ragged) engine reports per-executable MFU +
-    HBM-bandwidth utilization fused from the XLA cost model and the
-    measured tick time, with a bound classification against the
-    chip's ridge point; cpu_proxy flags the nominal peaks here."""
+    """The default (ragged) engine reports per-executable cost-model
+    FLOPs / bytes fused with the measured tick time. Tier-1 runs on
+    CPU, which has no device peak: every peak-derived field (MFU,
+    HBM-bandwidth utilization, bound, peaks, ridge) is None there —
+    never a number against a nominal peak."""
     rng = np.random.RandomState(13)
     eng = ServingEngine(llama_tiny, ServingConfig(
         num_slots=2, block_size=8, max_model_len=64,
         prefill_chunk=16))
     roof0 = eng.stats()["roofline"]
-    assert roof0["step_mfu"] == 0.0 and roof0["per_executable"] == {}
+    assert roof0["step_mfu"] is None and roof0["per_executable"] == {}
     eng.serve(_prompts(rng, (6, 14, 9)), max_new_tokens=5)
     roof = eng.stats()["roofline"]
     eng.shutdown()
-    assert roof["cpu_proxy"] is True            # tier-1 runs on CPU
+    assert roof["device"] == "cpu"
     assert roof["tick_executable"] == "decode"
-    assert roof["step_mfu"] > 0.0
-    assert roof["step_hbm_bw_util"] > 0.0
-    assert roof["ridge_flops_per_byte"] == pytest.approx(
-        roof["peak_flops_per_s"] / roof["peak_hbm_bytes_per_s"])
+    for k in ("step_mfu", "step_hbm_bw_util", "peak_flops_per_s",
+              "peak_hbm_bytes_per_s", "ridge_flops_per_byte"):
+        assert roof[k] is None, k
     row = roof["per_executable"]["decode"]
     assert row["flops"] > 0 and row["bytes_accessed"] > 0
     assert row["arithmetic_intensity"] == pytest.approx(
         row["flops"] / row["bytes_accessed"], rel=1e-3)
-    assert row["bound"] in ("compute", "bandwidth")
+    assert row["ticks"] > 0 and row["step_time_ms"] > 0
+    assert row["bound"] is None and row["mfu"] is None \
+        and row["hbm_bw_util"] is None
+
+
+def test_roofline_against_device_peaks(llama_tiny, monkeypatch):
+    """With a device peak (the v5e row of monitor.DEVICE_PEAKS, handed
+    to the engine as the chip would) the same block carries MFU,
+    bandwidth utilization and the bound against the ridge point, and
+    the headline gauges track the tick executable."""
+    flops, bw, _src = monitor.DEVICE_PEAKS["TPU v5 lite"]
+    monkeypatch.setattr(monitor, "device_peaks", lambda: (flops, bw))
+    rng = np.random.RandomState(13)
+    eng = ServingEngine(llama_tiny, ServingConfig(
+        num_slots=2, block_size=8, max_model_len=64,
+        prefill_chunk=16))
+    assert eng.stats()["roofline"]["step_mfu"] == 0.0
+    eng.serve(_prompts(rng, (6, 14, 9)), max_new_tokens=5)
+    roof = eng.stats()["roofline"]
+    eng.shutdown()
+    assert roof["peak_flops_per_s"] == flops
+    assert roof["step_mfu"] > 0.0
+    assert roof["step_hbm_bw_util"] > 0.0
+    assert roof["ridge_flops_per_byte"] == pytest.approx(flops / bw)
+    row = roof["per_executable"]["decode"]
     assert row["bound"] == ("compute" if row["arithmetic_intensity"]
                             >= roof["ridge_flops_per_byte"]
                             else "bandwidth")
-    assert row["ticks"] > 0 and row["step_time_ms"] > 0
     assert row["mfu"] == pytest.approx(
-        row["flops"] / (row["step_time_ms"] / 1000.0)
-        / roof["peak_flops_per_s"], rel=0.05)
-    # the headline gauges track the tick executable
+        row["flops"] / (row["step_time_ms"] / 1000.0) / flops,
+        rel=0.05)
     assert monitor.gauge("serving_step_mfu").value() > 0.0
     assert monitor.gauge("serving_hbm_bw_util").value() > 0.0
 
@@ -361,7 +385,7 @@ def test_roofline_stats_legacy_and_spec_paths(llama_tiny):
     eng.serve(_prompts(rng, (6, 20)), max_new_tokens=4)
     roof = eng.stats()["roofline"]
     eng.shutdown()
-    assert roof["per_executable"]["decode"]["mfu"] > 0
+    assert roof["per_executable"]["decode"]["step_time_ms"] > 0
     assert roof["per_executable"]["chunk"]["ticks"] > 0
     assert roof["per_executable"]["chunk"]["flops"] > 0
 
@@ -374,8 +398,8 @@ def test_roofline_stats_legacy_and_spec_paths(llama_tiny):
     roof = eng.stats()["roofline"]
     eng.shutdown()
     assert roof["tick_executable"] == "verify"
-    assert roof["step_mfu"] > 0.0
-    assert roof["per_executable"]["verify"]["hbm_bw_util"] > 0.0
+    assert roof["per_executable"]["verify"]["bytes_accessed"] > 0.0
+    assert roof["per_executable"]["verify"]["ticks"] > 0
 
 
 def test_roofline_accounting_compiles_nothing(llama_tiny):

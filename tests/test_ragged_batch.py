@@ -405,8 +405,8 @@ def test_ragged_stats_keys_and_fallback_counter(llama_tiny,
     c = monitor.counter("serving_kernel_fallback", labels=("path",))
     before = c.labels(path="test_path").value()
     n0 = pa.kernel_fallback_counts().get("test_path", 0)
-    pa._warn_fallback("test_path", (1, 4, 64), (8, 8, 2, 64), False)
-    pa._warn_fallback("test_path", (1, 4, 64), (8, 8, 2, 64), False)
+    pa._warn_fallback("test_path", (1, 4, 64), (8, 8, 2, 64))
+    pa._warn_fallback("test_path", (1, 4, 64), (8, 8, 2, 64))
     assert pa.kernel_fallback_counts()["test_path"] == n0 + 2
     assert c.labels(path="test_path").value() == before + 2
     path = monitor.export_jsonl(str(tmp_path / "metrics.jsonl"))

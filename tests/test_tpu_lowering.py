@@ -1,0 +1,243 @@
+"""Compile every Pallas entry point for a TPU v5e — on the CPU.
+
+Two strengths of the same check, strongest available first:
+
+- **AOT compile.** ``jax.experimental.topologies`` describes a v5e slice
+  from the installed libtpu with no chip attached, and ``jit(f).lower(
+  abstract args placed on it).compile()`` then runs the WHOLE TPU
+  compiler — XLA and Mosaic — here. It reproduces the chip's verdicts to
+  the letter (the moe_gmm kernels fail with the same "Slice shape along
+  dimension 0 must be aligned to tiling" the first chip run of PR 21
+  printed), so a kernel Mosaic refuses fails in tier-1, not on the chip.
+- **Cross-lowering**, where no topology can be built:
+  ``jit(f).trace(*args).lower(lowering_platforms=("tpu",))`` still runs
+  the Pallas -> Mosaic lowering and its block-shape checks.
+
+Two sweeps:
+
+- every kernel case of ``chip_smoke.py`` at its production shape (the
+  shapes the smoke then runs on the chip and compares with XLA mirrors);
+- every shape the eligibility predicates (``paged_attention.
+  _kernel_eligible``, ``decode_fused._eligible``) admit across pool
+  dtypes, KV-head counts, head dims and biased projections must come out
+  as a ``tpu_custom_call`` — the predicates may not admit what the
+  compiler rejects.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import chip_smoke
+from paddle_tpu.ops import paged_cache as pc
+from paddle_tpu.ops.pallas import decode_fused as df
+from paddle_tpu.ops.pallas import flash_attention_kernel as fak
+from paddle_tpu.ops.pallas import flashmask_kernel as fmk
+from paddle_tpu.ops.pallas import paged_attention as pa
+
+
+@functools.cache
+def _v5e_placement():
+    """A replicated sharding on one device of a described (not attached)
+    v5e slice, or None where libtpu cannot describe one."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception:       # no libtpu here: cross-lowering still runs
+        return None
+    return NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)),
+                         PartitionSpec())
+
+
+def _tpu_text(fn, *args):
+    """The program ``fn(*args)`` becomes for a TPU: compiled HLO where a
+    v5e can be described, lowered StableHLO otherwise."""
+    placement = _v5e_placement()
+    if placement is None:
+        return jax.jit(fn).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+    abstract = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=placement), args)
+    return jax.jit(fn).lower(*abstract).compile().as_text()
+
+
+def _lowers_to_mosaic(fn, *args):
+    return "tpu_custom_call" in _tpu_text(fn, *args)
+
+
+class _CheapRng:
+    """``chip_smoke`` builds its operands from a numpy Generator; only
+    shapes and dtypes matter to a lowering, so normals come back as a
+    zero-stride view (no 136 M-sample draws for the FFN weights)."""
+
+    def __init__(self):
+        self._rng = np.random.default_rng(0)
+
+    def standard_normal(self, shape):
+        return np.broadcast_to(np.float32(0.5), shape)
+
+    def __getattr__(self, name):
+        return getattr(self._rng, name)
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """The flash kernels pick interpret mode from the backend; a
+    cross-lowering must take the compiled path."""
+    monkeypatch.setattr(fak, "_interpret", lambda: False)
+    monkeypatch.setattr(fmk, "_interpret", lambda: False)
+
+
+_CASES = chip_smoke.kernel_cases(True, interpret=False)
+_MOSAIC = [n for n, _ in _CASES if chip_smoke.EXPECT[n][0] == "mosaic"]
+
+
+@pytest.mark.parametrize("name", _MOSAIC)
+def test_smoke_kernel_case_compiles_for_tpu(name, compiled_kernels):
+    kern, _mirror, args = dict(_CASES)[name](_CheapRng())
+    assert _lowers_to_mosaic(kern, *args), name
+
+
+def test_every_expected_kernel_entry_has_a_case():
+    want = {k for k in chip_smoke.EXPECT if k.startswith("kernel.")}
+    assert want == {n for n, _ in _CASES}
+
+
+_REFUSED = sorted(n for n, _ in _CASES if chip_smoke.EXPECT[n][0] == "xla")
+
+
+@pytest.mark.parametrize("name", _REFUSED)
+def test_mosaic_still_refuses_the_row_dma_kernels(name, compiled_kernels):
+    """The written reason these entries are ``xla`` in the smoke's table,
+    kept executable: Mosaic rejects their per-row DMA slices. The day a
+    toolchain accepts them this fails, and the gates can open."""
+    if _v5e_placement() is None:
+        pytest.skip("needs the TPU compiler (libtpu topology)")
+    kern, _mirror, args = dict(_CASES)[name](_CheapRng())
+    # (a MosaicError, a private jax class, chained on a JaxRuntimeError)
+    with pytest.raises(Exception, match="must be aligned to tiling"):
+        _tpu_text(kern, *args)
+
+
+def test_gates_route_nothing_to_kernels_mosaic_refuses(monkeypatch):
+    """The moe_gmm kernels lower, then fail Mosaic's compile (per-row
+    DMA slices of a tiled array, ``chip_smoke.EXPECT``): their gates must
+    route nothing to them on a TPU backend, whatever the shape."""
+    from paddle_tpu.distributed import moe
+    from paddle_tpu.ops import lora
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for env in ("1", None):
+        for var in ("PADDLE_TPU_MOE_FUSED_GMM", "PADDLE_TPU_LORA_GMM"):
+            monkeypatch.delenv(var, raising=False)
+            if env is not None:
+                monkeypatch.setenv(var, env)
+        assert moe._use_fused_gmm(8192, 2048, 1408) is False
+        assert moe._use_fused_gmm(8192, 2048, 1408, fused=True) is False
+        assert lora._use_lora_gmm(136, 3584, 128, 3584) is False
+    assert _REFUSED == ["kernel.gather_gmm", "kernel.gather_gmm_swiglu",
+                        "kernel.lora_gmm", "kernel.scatter_gmm"]
+
+
+def _pools(hkv, d, bs, quant):
+    data = jnp.zeros((9, bs, hkv, d), jnp.int8 if quant else jnp.bfloat16)
+    if not quant:
+        return data
+    return pc.QuantKV(data, jnp.zeros((9, bs, hkv), jnp.float32))
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("hkv,d", [(1, 128), (4, 128), (8, 128),
+                                   (4, 64), (1, 64), (2, 256)])
+def test_paged_eligibility_is_what_compiles(hkv, d, quant):
+    """For every (pool dtype, Hkv, D) at the pool dtype's sublane-tile
+    block size: a shape ``_kernel_eligible`` admits compiles to Mosaic
+    in all three entry points (linear and tree-masked); head dim 64 is
+    the shape it refuses."""
+    rep, s, mb, t = 7, 2, 4, 3
+    h, bs = hkv * rep, 32 if quant else 16
+    pool = _pools(hkv, d, bs, quant)
+    tables = jnp.zeros((s, mb), jnp.int32)
+    lens = jnp.ones((s,), jnp.int32)
+    ok = pa._kernel_eligible(h, d, jnp.bfloat16, pool, window=t)
+    assert ok == (d % 128 == 0)
+    if not ok:
+        return
+    q = jnp.zeros((s, t, h, d), jnp.bfloat16)
+    assert _lowers_to_mosaic(
+        lambda q, k, v: pa.pallas_paged_attention(
+            q[:, 0], k, v, tables, lens, interpret=False), q, pool, pool)
+    assert _lowers_to_mosaic(
+        lambda q, k, v: pa.pallas_paged_verify_attention(
+            q, k, v, tables, lens, interpret=False, tree_anc=(0, 0)),
+        q, pool, pool)
+    rows = jnp.zeros((s * t + 8, h, d), jnp.bfloat16)
+    assert _lowers_to_mosaic(
+        lambda q, k, v: pa.pallas_ragged_paged_attention(
+            q, k, v, tables, lens, lens, lens, w_max=8, interpret=False,
+            tree_anc=(0, 0)), rows, pool, pool)
+
+
+def test_paged_eligibility_wants_whole_sublane_tiles():
+    """Block size per pool dtype: 16 rows of bf16, 32 of int8."""
+    assert pa._kernel_eligible(28, 128, jnp.bfloat16,
+                               _pools(4, 128, 16, False))
+    assert not pa._kernel_eligible(28, 128, jnp.bfloat16,
+                                   _pools(4, 128, 8, False))
+    assert pa._kernel_eligible(28, 128, jnp.bfloat16,
+                               _pools(4, 128, 32, True))
+    assert not pa._kernel_eligible(28, 128, jnp.bfloat16,
+                                   _pools(4, 128, 16, True))
+
+
+def test_paged_eligibility_bounds_the_window():
+    pool = _pools(4, 128, 16, False)
+    # 64 window tokens x 16 padded rows per group fit; 128 do not
+    assert pa._kernel_eligible(28, 128, jnp.bfloat16, pool, window=64)
+    assert not pa._kernel_eligible(28, 128, jnp.bfloat16, pool, window=128)
+    assert not pa._kernel_eligible(30, 128, jnp.bfloat16, pool)  # 30 % 4
+
+
+@pytest.mark.parametrize("rows", [8, 136])
+@pytest.mark.parametrize("d,widths,kdim", [
+    (3584, (3584, 512, 512), 18944),      # Qwen2-7B QKV / down-proj
+    (1536, (1536, 256, 256), 8960),       # Qwen2-1.5B
+    (256, (384, 128), 640),
+])
+def test_fused_decode_eligibility_is_what_compiles(rows, d, widths, kdim):
+    """Biased norm->projections and the (K-tiled) projection->residual
+    at every width ``_eligible`` admits on TPU, Qwen2-7B's FFN
+    included."""
+    bf = jnp.bfloat16
+    isz = 2
+    assert df._eligible([d, *widths], rows, True,
+                        df._norm_mm_vmem(rows, d, list(widths), isz))
+    x, g = jnp.zeros((rows, d), bf), jnp.zeros((d,), bf)
+    ws = [jnp.zeros((d, n), bf) for n in widths]
+    bs = [jnp.zeros((n,), bf) for n in widths]
+    assert _lowers_to_mosaic(
+        lambda x, g, ws, bs: df.pallas_norm_matmul(
+            x, g, g, ws, bs, eps=1e-6, kind="ln", interpret=False),
+        x, g, ws, bs)
+    assert df._eligible([kdim, d], rows, True,
+                        df._mm_res_vmem(rows, kdim, d, 2, isz))
+    xs = [jnp.zeros((rows, kdim), bf)] * 2
+    assert _lowers_to_mosaic(
+        lambda xs, w, b, r: df.pallas_matmul_residual(
+            xs, w, b, r, act="swiglu", interpret=False),
+        xs, jnp.zeros((kdim, d), bf), g, jnp.zeros((rows, d), bf))
+
+
+def test_fused_decode_eligibility_refuses_what_cannot_fit():
+    isz = 2
+    # unaligned dims and row counts take the XLA path on TPU
+    assert not df._eligible([3584, 100], 8, True, 0)
+    assert not df._eligible([3584, 128], 7, True, 0)
+    # a whole-row block past the VMEM budget
+    assert not df._eligible(
+        [16384, 128], 1024, True,
+        df._norm_mm_vmem(1024, 16384, [128], isz))
