@@ -729,7 +729,7 @@ class _Pipe:
     may feed a pipelined dispatch."""
     __slots__ = ("outs", "active", "given", "n_pending", "q_lens",
                  "rid_of", "pend_pos0", "t_tick", "t_l0", "pure",
-                 "carry")
+                 "carry", "tick", "dispatch")
 
     def __init__(self, **kw):
         for k in self.__slots__:
@@ -1449,6 +1449,11 @@ class ServingEngine:
                 tr.set_thread(1 + i, f"slot {i}")
             tr.set_thread(self._tid_queue, "queue")
             self._trace = tr
+        # what the tick-phase spans carry: the ordinal of the tick in
+        # progress (a ``spill`` reads it from wherever it is called)
+        # and the blocks that growth has allocated so far
+        self._tick_ord = 0
+        self._n_grown = 0
         # always-on per-engine SLO digests (P², bounded memory) —
         # independent of the trace kill switch; surfaced as stats()
         # keys, the serving_*_ms quantile gauges, and the JSONL/prom
@@ -1850,7 +1855,8 @@ class ServingEngine:
         if self._trace is not None:
             self._trace.emit(
                 f"req{req.request_id} queued", tid=self._tid_queue,
-                t0=req.submit_time, t1=now, args={"outcome": outcome})
+                t0=req.submit_time, t1=now,
+                args={"rid": req.request_id, "outcome": outcome})
         return wait
 
     @property
@@ -2179,7 +2185,17 @@ class ServingEngine:
         tokens."""
         from ..generation import speculative as _spec
         t_tick = time.monotonic()
+        # tick-phase spans (docs/OPS.md "Tick phases"): each carries
+        # the ordinal of the tick it belongs to
+        tr = self._trace
+        tick = self._tick_ord = self._n_decode_steps
+        ph = tr.phase("admit", tick=tick).begin() \
+            if tr is not None else None
         emitted = self._admit()
+        if ph is not None:
+            ph.end(admitted=sum(s is not None and s.admit_t >= ph.t0
+                                for s in self._slots),
+                   queued=len(self._queue))
         cfg = self.config
         g = self._gamma
         n_slots = cfg.num_slots
@@ -2194,11 +2210,18 @@ class ServingEngine:
             # room for this tick's write positions (the verify window
             # overhangs by up to gamma speculated slots); growth under
             # an overcommitted pool may preempt — survivors only
+            n0 = self._n_grown
+            ph = tr.phase("grow", tick=tick).begin() \
+                if tr is not None else None
             active = self._ensure_blocks(active, horizon=g + 1)
+            if ph is not None:
+                ph.end(blocks=self._n_grown - n0)
             if not active and not pending:
                 return None, emitted
 
         # -- pack the tick's work into per-slot row counts -------------
+        ph = tr.phase("pack", tick=tick).begin() \
+            if tr is not None else None
         q_lens = np.zeros(n_slots, np.int64)
         base = np.zeros(n_slots, np.int64)
         given = {}              # slot -> prefill rows granted this tick
@@ -2233,6 +2256,8 @@ class ServingEngine:
             given[i] = k
             budget -= k
         if not int(q_lens.sum()):
+            if ph is not None:
+                ph.end(rows=0)
             return None, emitted    # budget exhausted by earlier slots
         row_slot, row_pos, row_starts, last_rows = _pc.ragged_row_meta(
             q_lens, base, self._rows, self._overflow)
@@ -2365,12 +2390,18 @@ class ServingEngine:
         pend_pos0 = {i: int(self._slots[i].pend_pos)
                      for i in given}
         t_l0 = time.monotonic()
+        if ph is not None:
+            ph.end(rows=int(q_lens.sum()))
         if self._last_dispatch_t is not None:
             self._d_host_gap.observe(
                 1000.0 * (t_l0 - self._last_dispatch_t))
         self._last_dispatch_t = t_l0
         with _quiet_donation():
-            outs = self._ragged_exec(*args)
+            if tr is None:
+                outs = self._ragged_exec(*args)
+            else:
+                with tr.phase("launch", tick=tick, dispatch="packed"):
+                    outs = self._ragged_exec(*args)
         if self._async_on:
             # the pools advance at DISPATCH (device futures): the next
             # launch consumes them before this tick's commit runs
@@ -2390,17 +2421,19 @@ class ServingEngine:
             outs=outs, active=list(active), given=given,
             n_pending=len(pending), q_lens=q_lens, rid_of=rid_of,
             pend_pos0=pend_pos0, t_tick=t_tick, t_l0=t_l0, pure=pure,
-            carry=(outs[2], outs[3]) if pure else None)
+            carry=(outs[2], outs[3]) if pure else None,
+            tick=tick, dispatch="packed")
         return pipe, emitted
 
-    def _ragged_commit(self, pipe) -> List[tuple]:
+    def _ragged_commit(self, pipe, flush=False) -> List[tuple]:
         """Commit half of one ragged tick: fetch tokens, advance
         slots, retire, commit prefill progress, emit trace spans.
         Under async pipelining this runs one tick AFTER its dispatch —
         a slot retired, cancelled, preempted or migrated in between is
         skipped, dropping the speculative extra tick's token exactly
         (its KV write already null-routed on device via the carry's
-        ``done`` mask, so there is nothing to trim)."""
+        ``done`` mask, so there is nothing to trim). ``flush`` only
+        labels the ``commit`` phase: the pipeline was drained."""
         outs = pipe.outs
         g = self._gamma
         n_slots = self.config.num_slots
@@ -2415,15 +2448,30 @@ class ServingEngine:
                          if self._slots[i] is not None
                          and self._slots[i].rid == rid_of[i]]
 
+        # -- fetch: the host blocks on the device here -----------------
+        self._tick_ord = pipe.tick
+        ph = tr.phase("fetch", tick=pipe.tick).begin() \
+            if tr is not None else None
+        tok_arr = np.asarray(outs[0])   # decode tokens | prefill firsts
+        k = 1
+        if g:
+            out = np.asarray(outs[1])
+            accept = np.asarray(outs[2])
+            k = 4 if self._heads is not None else 3
+            if self._heads is not None:
+                props_next = np.asarray(outs[3])
+        if self._health is not None:            # host fetch gated on
+            self._nf_last = bool(outs[k])       # the kill switch only
+        if not self._async_on:
+            self._pools = outs[k + 1]
+        t_sync = time.monotonic()
+        if ph is not None:
+            ph.end()
+            ph = tr.phase("commit", tick=pipe.tick, flush=flush).begin()
+
         # -- commit decode / verify rows -------------------------------
         acc_lens = {}
         if not g:
-            tok_arr = np.asarray(outs[0])
-            if self._health is not None:        # host fetch gated on
-                self._nf_last = bool(outs[1])   # the kill switch only
-            if not self._async_on:
-                self._pools = outs[2]
-            t_sync = time.monotonic()
             for i in committed:
                 slot = self._slots[i]
                 tok = int(tok_arr[i])
@@ -2436,17 +2484,6 @@ class ServingEngine:
                 if tok == self._eos or slot.n_emitted >= slot.max_new:
                     self._retire(i)
         else:
-            tok_arr = np.asarray(outs[0])       # prefill first tokens
-            out = np.asarray(outs[1])
-            accept = np.asarray(outs[2])
-            k = 4 if self._heads is not None else 3
-            if self._heads is not None:
-                props_next = np.asarray(outs[3])
-            if self._health is not None:        # gated host fetch
-                self._nf_last = bool(outs[k])
-            if not self._async_on:
-                self._pools = outs[k + 1]
-            t_sync = time.monotonic()
             for i in committed:
                 acc_lens[i] = self._commit_verify_window(
                     i, out[i], accept[i], emitted)
@@ -2494,12 +2531,14 @@ class ServingEngine:
                         t1=t_sync,
                         args={"rid": rid_of[i], "rows": int(k),
                               "pos": pend_pos0[i]})
+            ph.end(tokens=len(emitted))
             self._trace_tick(
                 t_tick, "verify" if g else "decode", "ragged",
                 rows=int(q_lens.sum()), active=len(active),
                 pending=pipe.n_pending,
                 occupancy=round(
-                    (len(active) + pipe.n_pending) / n_slots, 3))
+                    (len(active) + pipe.n_pending) / n_slots, 3),
+                dispatch=pipe.dispatch)
         return emitted
 
     # -- async tick pipeline (docs/OPS.md "Async tick pipeline") ------
@@ -2527,7 +2566,7 @@ class ServingEngine:
             # then dispatch sync-shaped
             self._pipe = None
             self._n_pipe_flushes += 1
-            emitted.extend(self._ragged_commit(prev))
+            emitted.extend(self._ragged_commit(prev, flush=True))
         pipe, pre = self._ragged_dispatch()
         emitted.extend(pre)
         if pipe is None:
@@ -2615,7 +2654,15 @@ class ServingEngine:
             # zeroed all its rows) — a pipelined tick would be a pure
             # no-op launch
             return False
-        return self._pipe_grow(pipe)
+        tr = self._trace
+        tick = self._tick_ord = self._n_decode_steps
+        n0 = self._n_grown
+        ph = tr.phase("grow", tick=tick).begin() \
+            if tr is not None else None
+        ok = self._pipe_grow(pipe)
+        if ph is not None:
+            ph.end(blocks=self._n_grown - n0)
+        return ok
 
     def _pipe_grow(self, pipe) -> bool:
         """Grow blocks for the pipelined tick's write positions: the
@@ -2638,6 +2685,7 @@ class ServingEngine:
                     return False
                 self._tables[i, len(slot.blocks)] = blk
                 slot.blocks.append(blk)
+                self._n_grown += 1
                 self._tables_dev = None
                 self._reserved -= 1
         return True
@@ -2650,6 +2698,10 @@ class ServingEngine:
         are EXACTLY the steady-state sync tick's (the carry rows ARE
         next tick's packs), so pipelining adds zero executables."""
         t_tick = time.monotonic()
+        tr = self._trace
+        tick = self._tick_ord = self._n_decode_steps
+        ph = tr.phase("pack", tick=tick).begin() \
+            if tr is not None else None
         carry_rows, carry_slots = prev.carry
         if self._tables_dev is None:
             self._tables_dev = self._dev(self._tables)
@@ -2660,12 +2712,18 @@ class ServingEngine:
         args.append(self._samp_operand())
         args.append(self._next_key())
         t_l0 = time.monotonic()
+        if ph is not None:
+            ph.end(rows=len(prev.active))
         if self._last_dispatch_t is not None:
             self._d_host_gap.observe(
                 1000.0 * (t_l0 - self._last_dispatch_t))
         self._last_dispatch_t = t_l0
         with _quiet_donation():
-            outs = self._ragged_exec(*args)
+            if tr is None:
+                outs = self._ragged_exec(*args)
+            else:
+                with tr.phase("launch", tick=tick, dispatch="carry"):
+                    outs = self._ragged_exec(*args)
         self._pools = outs[-1]
         self._m_steps.inc()
         self._n_decode_steps += 1
@@ -2684,14 +2742,14 @@ class ServingEngine:
         q_lens = np.zeros(n_slots, np.int64)
         for i in active:
             q_lens[i] = 1
-        if self._trace is not None:
-            self._trace.instant("pipelined dispatch", tid=0,
-                                args={"active": len(active)})
+        if tr is not None:
+            tr.instant("pipelined dispatch", tid=0,
+                       args={"active": len(active)})
         return _Pipe(
             outs=outs, active=active, given={}, n_pending=0,
             q_lens=q_lens, rid_of=dict(prev.rid_of), pend_pos0={},
             t_tick=t_tick, t_l0=t_l0, pure=True,
-            carry=(outs[2], outs[3]))
+            carry=(outs[2], outs[3]), tick=tick, dispatch="carry")
 
     def _flush_pipe(self) -> List[tuple]:
         """Commit any in-flight pipelined tick NOW. Every
@@ -2707,7 +2765,7 @@ class ServingEngine:
         pipe, self._pipe = self._pipe, None
         if pipe is not None:
             self._n_pipe_flushes += 1
-            out.extend(self._ragged_commit(pipe))
+            out.extend(self._ragged_commit(pipe, flush=True))
         return out
 
     def run(self) -> Dict[int, np.ndarray]:
@@ -2961,6 +3019,10 @@ class ServingEngine:
         while self._queue:
             self._queue_exit(self._queue.popleft(), "shutdown")
         self._sync_cache_metrics()
+        if self._trace is not None:
+            # the spans outlive the engine: a post-mortem dump still
+            # finds them in ``tracing.live_tracers()``
+            _tracing.retire(self._trace)
         if check_leaks:
             live = [b for s in self._slots if s is not None
                     for b in s.blocks]
@@ -4421,12 +4483,18 @@ class ServingEngine:
         later prefix hit restores it instead of re-prefilling. The
         export launch is issued before the evicting caller's next
         write, so the bytes read are the published ones."""
+        tr = self._trace
+        ph = tr.phase("spill", tick=self._tick_ord,
+                      block=int(b)).begin() if tr is not None else None
         payload = _pc.payload_rows(self._export_payload([b]), 1)
-        if self._host_tier.put(("pub", h), payload,
-                               _pc.payload_nbytes(payload)):
+        nbytes = _pc.payload_nbytes(payload)
+        stored = self._host_tier.put(("pub", h), payload, nbytes)
+        if stored:
             self._n_spilled += 1
             self._m_spill.inc()
         self._m_host_bytes.set(self._host_tier.bytes_used)
+        if ph is not None:
+            ph.end(bytes=int(nbytes), stored=bool(stored))
 
     def _restore_published(self, h):
         """Host-tier prefix restore: a prompt hash that misses the
@@ -4885,6 +4953,7 @@ class ServingEngine:
                     break
                 self._tables[i, len(slot.blocks)] = blk
                 slot.blocks.append(blk)
+                self._n_grown += 1
                 self._tables_dev = None
                 self._reserved -= 1
             if grown:

@@ -25,6 +25,14 @@ Design constraints, in order:
    the serving engine maps tid 0 to its tick timeline, tid ``1+i`` to
    slot ``i``'s request timeline, and the last tid to the admission
    queue.
+5. **One clock with the device.** A *phase* (``Tracer.phase``) is a
+   tid-0 span that is also entered as a
+   ``jax.profiler.TraceAnnotation("paddle_tpu:<name>")``, so any live
+   profiler session (``engine.profile(n)``) shows the program's own
+   spans in the ``/host:CPU`` plane beside the device ops.
+6. **Post-mortem.** ``retire()`` keeps the last few shut-down
+   engines' tracers alive, so ``live_tracers()`` and the process-wide
+   ``dump_chrome_trace()`` still see an engine that is gone.
 
 Clocks are ``time.monotonic()`` (the same base the serving scheduler
 stamps ``submit_time`` with), exported in integer microseconds as the
@@ -46,8 +54,8 @@ from typing import Any, Dict, List, Optional
 from .registry import get_registry
 
 __all__ = ["Tracer", "tracing_enabled", "trace_buffer_capacity",
-           "live_tracers", "dump_chrome_trace", "next_flow_id",
-           "ProfilerWindow"]
+           "live_tracers", "retire", "dump_chrome_trace",
+           "next_flow_id", "ProfilerWindow", "PHASE_PREFIX"]
 
 _TRACE_ENV = "PADDLE_TPU_TRACE"
 _CAP_ENV = "PADDLE_TPU_TRACE_EVENTS"
@@ -62,6 +70,13 @@ _FLOW_IDS = itertools.count(1)
 # every live Tracer, so a process-wide dump can merge engines into one
 # Perfetto file (each keeps its own pid lane)
 _TRACERS: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+# the tracers of the last engines that shut down, held strongly: a
+# post-mortem dump (and the benchmark's readers, which run once the
+# engine is deleted) would otherwise find nothing
+_RETIRED: "deque[Tracer]" = deque(maxlen=4)
+# what a phase is called in a profiler capture (never ``bench:`` — the
+# benchmark's trace reduction keys on that prefix for its own spans)
+PHASE_PREFIX = "paddle_tpu:"
 
 
 def next_flow_id() -> int:
@@ -80,6 +95,40 @@ def trace_buffer_capacity() -> int:
         return max(16, int(os.environ.get(_CAP_ENV, 65536)))
     except ValueError:
         return 65536
+
+
+class _Phase:
+    """One open phase (see :meth:`Tracer.phase`): a context manager,
+    or ``begin()`` ... ``end(**more_args)`` where the interval does
+    not fit a ``with`` block."""
+    __slots__ = ("_tracer", "name", "args", "t0", "_ann")
+
+    def __init__(self, tracer, name, args):
+        self._tracer = tracer
+        self.name = name
+        self.args = args
+        self.t0 = None
+        self._ann = None
+
+    def begin(self):
+        import jax
+        self._ann = jax.profiler.TraceAnnotation(PHASE_PREFIX + self.name)
+        self.t0 = time.monotonic()
+        self._ann.__enter__()
+        return self
+
+    def end(self, **more_args):
+        self._ann.__exit__(None, None, None)
+        dur = max(time.monotonic() - self.t0, 0.0)
+        if more_args:
+            self.args.update(more_args)
+        self._tracer._append(("X", self.name, 0, self.t0, dur,
+                              self.args or None))
+
+    __enter__ = begin
+
+    def __exit__(self, *exc):
+        self.end()
 
 
 class Tracer:
@@ -188,6 +237,17 @@ class Tracer:
             self._append(("X", name, int(tid), t0,
                           max(time.monotonic() - t0, 0.0),
                           args or None))
+
+    def phase(self, name: str, **args) -> _Phase:
+        """One phase of the owner's tick, on tid 0: the ring gets an
+        ``"X"`` record as from :meth:`span`, and the same interval is
+        entered as ``jax.profiler.TraceAnnotation("paddle_tpu:" +
+        name)``, which a live profiler session writes into its
+        ``/host:CPU`` plane — the program's spans on the device
+        trace's clock. Use as ``with tr.phase("launch", tick=n):`` or
+        ``ph = tr.phase("pack", tick=n).begin()`` ...
+        ``ph.end(rows=r)``."""
+        return _Phase(self, name, args)
 
     # -- introspection ------------------------------------------------
 
@@ -398,8 +458,16 @@ class ProfilerWindow:
 
 
 def live_tracers() -> List[Tracer]:
-    """Every Tracer still referenced somewhere in the process."""
+    """Every Tracer still referenced somewhere in the process: those
+    of live engines, and the last 4 handed to :func:`retire`."""
     return sorted(_TRACERS, key=lambda t: t.pid)
+
+
+def retire(tracer: Tracer) -> None:
+    """Keep ``tracer`` alive after its owner is gone (an engine hands
+    its tracer over in ``shutdown()``). Only the last 4 are kept."""
+    if tracer not in _RETIRED:
+        _RETIRED.append(tracer)
 
 
 def dump_chrome_trace(path: str,

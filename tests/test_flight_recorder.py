@@ -367,9 +367,10 @@ def test_roofline_against_device_peaks(llama_tiny, monkeypatch):
     assert row["bound"] == ("compute" if row["arithmetic_intensity"]
                             >= roof["ridge_flops_per_byte"]
                             else "bandwidth")
-    assert row["mfu"] == pytest.approx(
-        row["flops"] / (row["step_time_ms"] / 1000.0) / flops,
-        rel=0.05)
+    # like with like: the block rounds its shares to 6 decimals, and at
+    # this size the share is a few units of the last one
+    want = row["flops"] / (row["step_time_ms"] / 1000.0) / flops
+    assert row["mfu"] == pytest.approx(round(want, 6), abs=1e-6)
     assert monitor.gauge("serving_step_mfu").value() > 0.0
     assert monitor.gauge("serving_hbm_bw_util").value() > 0.0
 

@@ -5,17 +5,24 @@ ragged wave, the ``PADDLE_TPU_TRACE=0`` kill switch (bit-for-bit inert,
 zero steady-state recompiles, span-free hot path), always-present
 ``stats()`` latency keys across fp/int8/spec/TP engines, terminal
 queue-wait outcomes (no survivor bias), Prometheus exposition, and a
-tiny-scale goodput-bench smoke."""
+tiny-scale goodput-bench smoke. Tick phases (ISSUE 24): what the host
+does inside one ragged tick, as spans that are also profiler
+annotations, and tracers that outlive their engine."""
+import gc
 import json
 import os
 import subprocess
 import sys
 
+import time
+
+import jax
 import numpy as np
 import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import monitor
+from paddle_tpu.monitor import tracing
 from paddle_tpu.monitor.digest import LatencyDigest, P2Quantile
 from paddle_tpu.monitor.registry import Registry
 from paddle_tpu.monitor.tracing import Tracer
@@ -184,13 +191,17 @@ def test_engine_trace_spans_mixed_ragged_wave(llama_tiny):
     assert all(e["args"]["rows"] == 1 for e in decodes)
     chunks = by_name["prefill chunk"]
     assert chunks and all(e["tid"] in (1, 2) for e in chunks)
-    admits = by_name["admit"]
+    # (tid 0 carries a tick PHASE of the same name, a span)
+    admits = [e for e in by_name["admit"] if e["ph"] == "i"]
     assert len(admits) == len(prompts)
     assert all("prefix_hit" in e["args"] for e in admits)
     queued = [e for e in evs if e["name"].endswith(" queued")]
     assert len(queued) == len(prompts)
     assert all(e["tid"] == 3 for e in queued)          # queue tid
     assert all(e["args"]["outcome"] == "admitted" for e in queued)
+    # spans of one request share an identifier a reader need not parse
+    assert all(e["name"] == f"req{e['args']['rid']} queued"
+               for e in queued)
     # request spans contain their slot's per-tick spans (same tid,
     # time containment — what Perfetto renders as nesting)
     reqs = {e["name"]: e for e in evs
@@ -210,6 +221,216 @@ def test_engine_trace_spans_mixed_ragged_wave(llama_tiny):
     tids = {e["tid"] for e in doc["traceEvents"] if e["ph"] != "M"}
     assert tids <= {0, 1, 2, 3}
     eng.shutdown()
+
+
+PHASES = ("admit", "grow", "spill", "pack", "launch", "fetch", "commit")
+
+
+def _phases(tracer):
+    """The tick-phase spans of a tracer, by start."""
+    return sorted((e for e in tracer.events()
+                   if e["tid"] == 0 and e["name"] in PHASES
+                   and e["ph"] == "X"), key=lambda e: e["t0"])
+
+
+def _record_annotations(monkeypatch):
+    """Stand a recorder in for ``jax.profiler.TraceAnnotation``; returns
+    the list of names entered."""
+    entered = []
+
+    class Recorder:
+        def __init__(self, name, **_kw):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    return entered
+
+
+def test_tick_phases_cover_every_ragged_tick(llama_tiny):
+    """A mixed ragged wave: every tick has its pack / launch / fetch /
+    commit, every admission lies in an ``admit`` phase, every phase
+    names its tick, and phases only ever overlap by nesting."""
+    rng = np.random.RandomState(3)
+    eng = ServingEngine(llama_tiny, ServingConfig(
+        num_slots=2, block_size=8, max_model_len=64, prefill_chunk=16))
+    prompts = [rng.randint(1, 128, (n,)) for n in (6, 20, 9, 14)]
+    _mixed_wave(eng, prompts, 5)
+    phases = _phases(eng.tracer)
+    ticks = [e for e in eng.tracer.events() if e["name"] == "tick"]
+    n_ticks = eng.stats()["decode_steps"]
+    assert len(ticks) == n_ticks > 0
+    assert all(e["args"]["dispatch"] == "packed" for e in ticks)
+    assert all(isinstance(e["args"]["tick"], int) for e in phases)
+    by_tick = {}
+    for e in phases:
+        by_tick.setdefault(e["args"]["tick"], []).append(e["name"])
+    for n in range(n_ticks):
+        for name in ("pack", "launch", "fetch", "commit"):
+            assert by_tick[n].count(name) == 1, (n, by_tick[n])
+        # one tick's phases, in the order the host runs them
+        order = [p for p in by_tick[n] if p in PHASES[3:] or p == "admit"]
+        assert order == ["admit", "pack", "launch", "fetch", "commit"]
+    admits = [e for e in phases if e["name"] == "admit"]
+    assert sum(e["args"]["admitted"] for e in admits) == len(prompts)
+    assert all(e["args"]["queued"] >= 0 for e in admits)
+    launches = [e for e in phases if e["name"] == "launch"]
+    assert all(e["args"]["dispatch"] == "packed" for e in launches)
+    commits = [e for e in phases if e["name"] == "commit"]
+    assert sum(e["args"]["tokens"] for e in commits) == 5 * len(prompts)
+    assert not any(e["args"]["flush"] for e in commits)
+    assert all(e["args"]["rows"] > 0 for e in phases if e["name"] == "pack")
+    # one host thread: a phase starts after the one before it ended,
+    # unless it is a spill inside an admit or a grow
+    for a, b in zip(phases, phases[1:]):
+        if b["t0"] < a["t0"] + a["dur"]:
+            assert b["name"] == "spill" and a["name"] in ("admit", "grow")
+            assert b["t0"] + b["dur"] <= a["t0"] + a["dur"]
+    eng.shutdown()
+
+
+@pytest.mark.parametrize("tier_bytes, stored", [(64 << 20, True),
+                                                (64, False)])
+def test_spill_phase_per_evicted_block(llama_tiny, tier_bytes, stored):
+    """A tiny pool that fills with published blocks: every eviction is
+    one ``spill`` span inside an ``admit`` or a ``grow``, whether the
+    host tier took the block (the counter rises) or refused it."""
+    rng = np.random.RandomState(23)
+    eng = ServingEngine(llama_tiny, ServingConfig(
+        num_slots=2, block_size=8, max_model_len=64, prefill_chunk=16,
+        host_kv_tier_bytes=tier_bytes))
+    st0 = eng.stats()
+    for _ in range(8):      # one at a time: no pressure, no preemption
+        eng.serve([rng.randint(1, 128, (30,))], max_new_tokens=4)
+    st = eng.stats()
+    assert st["preemptions"] == 0
+    phases = _phases(eng.tracer)
+    spills = [e for e in phases if e["name"] == "spill"]
+    assert spills and all(e["args"]["stored"] is stored for e in spills)
+    refused = sum(not e["args"]["stored"] for e in spills)
+    assert len(spills) == \
+        st["kv_blocks_spilled"] - st0["kv_blocks_spilled"] + refused
+    assert all(e["args"]["bytes"] > 0 and e["args"]["block"] > 0
+               for e in spills)
+    for e in spills:
+        outer = [p for p in phases if p["name"] in ("admit", "grow")
+                 and p["t0"] <= e["t0"]
+                 and e["t0"] + e["dur"] <= p["t0"] + p["dur"]]
+        assert len(outer) == 1
+        assert outer[0]["args"]["tick"] == e["args"]["tick"]
+    grown = sum(e["args"]["blocks"] for e in phases if e["name"] == "grow")
+    assert grown > 0
+    eng.shutdown()
+
+
+def test_async_steady_decode_launches_from_the_carry(llama_tiny):
+    """Depth-1 async: steady decode launches from the device-resident
+    carry, and both the launch and its tick say so; the commits that
+    drain the pipeline are marked as flushes."""
+    rng = np.random.RandomState(29)
+    eng = ServingEngine(llama_tiny, ServingConfig(
+        num_slots=2, block_size=8, max_model_len=64, prefill_chunk=16,
+        async_depth=1))
+    eng.serve([rng.randint(1, 128, (n,)) for n in (6, 9)],
+              max_new_tokens=12)
+    phases = _phases(eng.tracer)
+    how = [e["args"]["dispatch"] for e in phases if e["name"] == "launch"]
+    assert how.count("carry") > 0 and how.count("packed") > 0
+    ticks = {e["args"]["tick"]: e["args"]["dispatch"]
+             for e in phases if e["name"] == "launch"}
+    for e in eng.tracer.events():
+        if e["name"] == "tick":
+            assert e["args"]["dispatch"] in ("packed", "carry")
+    assert sorted(ticks) == list(range(eng.stats()["decode_steps"]))
+    n_carry = sum(e["name"] == "pipelined dispatch"
+                  for e in eng.tracer.events())
+    assert n_carry == how.count("carry")
+    commits = [e for e in phases if e["name"] == "commit"]
+    assert len(commits) == len(how)
+    assert any(e["args"]["flush"] for e in commits)
+    eng.shutdown()
+
+
+def test_phase_annotations_mirror_the_ring(llama_tiny, monkeypatch):
+    """What reaches a profiler capture is what the ring holds: the
+    same names, prefixed, in the same order."""
+    entered = _record_annotations(monkeypatch)
+    rng = np.random.RandomState(31)
+    eng = ServingEngine(llama_tiny, ServingConfig(
+        num_slots=2, block_size=8, max_model_len=64, prefill_chunk=16))
+    for _ in range(5):
+        eng.serve([rng.randint(1, 128, (30,))], max_new_tokens=4)
+    names = [e["name"] for e in _phases(eng.tracer)]
+    assert "spill" in names and "admit" in names
+    assert entered == ["paddle_tpu:" + n for n in names]
+    assert tracing.PHASE_PREFIX == "paddle_tpu:"
+    eng.shutdown()
+
+
+def test_tracer_phase_is_a_span_and_an_annotation(monkeypatch):
+    entered = _record_annotations(monkeypatch)
+    tr = Tracer("phases")
+    with tr.phase("launch", tick=3, dispatch="packed"):
+        pass
+    ph = tr.phase("pack", tick=4).begin()
+    ph.end(rows=7)
+    a, b = tr.events()
+    assert (a["ph"], a["name"], a["tid"]) == ("X", "launch", 0)
+    assert a["args"] == {"tick": 3, "dispatch": "packed"}
+    assert b["args"] == {"tick": 4, "rows": 7} and b["t0"] >= a["t0"]
+    assert entered == ["paddle_tpu:launch", "paddle_tpu:pack"]
+
+
+def test_retired_tracers_outlive_their_engines(llama_tiny):
+    """``shutdown()`` hands the tracer over: the process-wide dump still
+    sees an engine that was deleted and collected — the last 4 of them."""
+    pids = []
+    for _ in range(5):
+        eng = ServingEngine(llama_tiny, ServingConfig(
+            num_slots=2, block_size=8, max_model_len=64,
+            prefill_chunk=16))
+        eng.serve([np.arange(1, 7)], max_new_tokens=2)
+        pids.append(eng.tracer.pid)
+        eng.shutdown()
+        eng.shutdown()          # handing over twice keeps one reference
+        del eng
+        gc.collect()
+    live = {t.pid: t for t in tracing.live_tracers()}
+    assert set(pids[1:]) <= set(live) and pids[0] not in live
+    assert any(e["name"] == "tick" for e in live[pids[-1]].events())
+
+
+def test_tick_phase_recording_stays_under_its_budget():
+    """The recording calls of one tick (eight phases, their args) cost
+    well under 100 microseconds of host time: the best of 5 rounds of
+    200 ticks, so that a loaded machine does not decide it."""
+    tr = Tracer("cost", capacity=4096)
+    best = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for n in range(200):
+            ph = tr.phase("admit", tick=n).begin()
+            sp = tr.phase("spill", tick=n, block=5).begin()
+            sp.end(bytes=4096, stored=True)
+            ph.end(admitted=1, queued=3)
+            ph = tr.phase("grow", tick=n).begin()
+            ph.end(blocks=1)
+            ph = tr.phase("pack", tick=n).begin()
+            ph.end(rows=136)
+            with tr.phase("launch", tick=n, dispatch="packed"):
+                pass
+            ph = tr.phase("fetch", tick=n).begin()
+            ph.end()
+            ph = tr.phase("commit", tick=n, flush=False).begin()
+            ph.end(tokens=8)
+        best = min(best, (time.perf_counter() - t0) / 200)
+    assert best < 100e-6, f"{best * 1e6:.1f} us a tick"
 
 
 def test_engine_trace_spec_accepted_len(llama_tiny):
@@ -240,6 +461,7 @@ def test_trace_kill_switch_bit_for_bit_inert(llama_tiny, monkeypatch):
     run."""
     rng = np.random.RandomState(11)
     prompts = [rng.randint(1, 128, (n,)) for n in (6, 14, 9)]
+    entered = _record_annotations(monkeypatch)
 
     def serve():
         eng = ServingEngine(llama_tiny, ServingConfig(
@@ -253,9 +475,12 @@ def test_trace_kill_switch_bit_for_bit_inert(llama_tiny, monkeypatch):
         return [o.tolist() for o in outs], st1, st2
 
     on, st_on, _ = serve()
+    assert entered
+    del entered[:]
     monkeypatch.setenv("PADDLE_TPU_TRACE", "0")
     off, st_off1, st_off2 = serve()
     assert on == off, "trace kill switch changed served tokens"
+    assert entered == [], "a killed engine entered a profiler annotation"
     assert st_off1["tracing"] is False
     assert st_off1["trace_events"] == 0
     assert st_on["tracing"] is True and st_on["trace_events"] > 0
