@@ -376,32 +376,44 @@ def kernel_cases(full, interpret=None):
                          rows=rows):
             q, kp, vp, tables, lens = _paged_inputs(
                 rng, pd, quant, (rows, pd["H"], pd["D"]))
-            # a mixed tick: one wide prefill chunk, verify windows and
-            # decode rows, one idle slot
-            q_lens = np.asarray([1, wn] * (pd["S"] // 2), np.int64)
-            q_lens[0], q_lens[-1] = w - 3, 0
+            # three ticks through the one kernel: a mixed one (one wide
+            # prefill chunk, verify windows and decode rows, one idle
+            # slot), the commonest one, decode only (a row a slot), and
+            # tree verify windows (every other slot flagged, node k + 1
+            # the child of node k // 2) beside a linear chunk
+            mixed = np.asarray([1, wn] * (pd["S"] // 2), np.int64)
+            mixed[0], mixed[-1] = w - 3, 0
+            windows = np.full(pd["S"], wn, np.int64)
+            windows[-1] = w // 2
+            flags = jnp.asarray([1, 0] * (pd["S"] // 2), jnp.int32)
+            tree = dict(tree_anc=tuple(k // 2 for k in range(wn - 1)),
+                        tree_slots=flags)
             base = np.minimum(np.asarray(lens), pd["MB"] * pd["BS"] - w)
-            row_slot, _pos, row_starts, _last = pc.ragged_row_meta(
-                q_lens, base, rows, pd["MB"] * pd["BS"])
             ctx = jnp.asarray(base + 1, jnp.int32)
-            ql, rs, sl = (jnp.asarray(x, jnp.int32)
-                          for x in (q_lens, row_starts, row_slot))
-            live = np.zeros(rows, bool)
-            for s0, n in zip(row_starts, q_lens):
-                live[s0:s0 + n] = True
-            live = jnp.asarray(live)[:, None, None]
+            ticks = []
+            for q_lens, kw in ((mixed, {}), (np.ones(pd["S"], np.int64), {}),
+                               (windows, tree)):
+                row_slot, _pos, row_starts, _last = pc.ragged_row_meta(
+                    q_lens, base, rows, pd["MB"] * pd["BS"])
+                live = np.zeros(rows, bool)
+                for s0, n in zip(row_starts, q_lens):
+                    live[s0:s0 + n] = True
+                ticks.append(tuple(
+                    jnp.asarray(x, jnp.int32)
+                    for x in (q_lens, row_starts, row_slot))
+                    + (jnp.asarray(live)[:, None, None], kw))
 
-            def kern(q, kp, vp, tables, ctx, ql, rs):
-                out = pa.pallas_ragged_paged_attention(
+            def kern(q, kp, vp, tables, ctx):
+                return tuple(jnp.where(live, pa.pallas_ragged_paged_attention(
                     q, kp, vp, tables, ctx, ql, rs, w_max=w,
-                    interpret=interpret)
-                return jnp.where(live, out, 0)   # pad rows are garbage
+                    interpret=interpret, **kw), 0)  # pad rows are garbage
+                    for ql, rs, _sl, live, kw in ticks)
 
-            def mirror(q, kp, vp, tables, ctx, ql, rs):
-                out = pa._xla_ragged_paged(q, kp, vp, tables, ctx, ql, rs,
-                                           sl, wn, w)
-                return jnp.where(live, out, 0)
-            return kern, mirror, (q, kp, vp, tables, ctx, ql, rs)
+            def mirror(q, kp, vp, tables, ctx):
+                return tuple(jnp.where(live, pa._xla_ragged_paged(
+                    q, kp, vp, tables, ctx, ql, rs, sl, wn, w, **kw), 0)
+                    for ql, rs, sl, live, kw in ticks)
+            return kern, mirror, (q, kp, vp, tables, ctx)
 
         cases += [(f"kernel.paged_decode.{tag}", decode_build),
                   (f"kernel.paged_verify.{tag}", verify_build),

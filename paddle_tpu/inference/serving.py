@@ -729,7 +729,7 @@ class _Pipe:
     may feed a pipelined dispatch."""
     __slots__ = ("outs", "active", "given", "n_pending", "q_lens",
                  "rid_of", "pend_pos0", "t_tick", "t_l0", "pure",
-                 "carry", "tick", "dispatch")
+                 "carry", "tick", "dispatch", "attn_grid")
 
     def __init__(self, **kw):
         for k in self.__slots__:
@@ -1067,7 +1067,8 @@ class ServingEngine:
         # static packed width: every active slot's decode/verify rows
         # plus one tick's prefill row budget always fit
         self._rows = cfg.num_slots * (gamma + 1) + self._prefill_rows
-        # static per-slot row ceiling (the ragged grid's window dim)
+        # static per-slot row ceiling (the rows of a slot the ragged
+        # kernel attends)
         self._wmax = max(gamma + 1,
                          min(self._chunk, self._prefill_rows)
                          if self._chunked else 1)
@@ -1077,6 +1078,7 @@ class ServingEngine:
         self._ragged_exec = None
         self._ragged_draft_exec = None
         self._pools = self._init_caches(model, nb)
+        self._attn_geometry = self._ragged_attn_geometry(model)
         self._draft_model = draft_model \
             if gamma and cfg.drafter == "model" else None
         if self._draft_model is not None:
@@ -1825,6 +1827,37 @@ class ServingEngine:
         self._n_cancelled += 1
         self._m_occupancy.set(self.num_active)
 
+    def _ragged_attn_geometry(self, model):
+        """What ``paged_attention.ragged_grid_units`` needs beside a
+        tick's ``q_lens`` and lengths, from the model's config and the
+        pools as built; ``None`` (the ``tick`` span then carries no
+        ``attn_units`` / ``attn_live``) off the ragged path or for a
+        model that does not state its head count."""
+        heads = getattr(getattr(model, "config", None),
+                        "num_attention_heads", None)
+        if not self._ragged or not heads:
+            return None
+        pool = self._pools[0][0]
+        pool = getattr(pool, "data", pool)      # QuantKV: the int8 half
+        dtype = pool.dtype
+        if not jnp.issubdtype(dtype, jnp.floating):
+            dtype = jnp.dtype(getattr(model.config, "dtype", "float32"))
+        return dict(rows=self._rows, w_max=self._wmax,
+                    num_heads=int(heads), num_kv_heads=int(pool.shape[2]),
+                    q_dtype=dtype, block_size=self._bs,
+                    max_blocks=self._mb)
+
+    def _attn_grid(self, q_lens, context_lens):
+        """The ``tick`` span's ``attn_units`` / ``attn_live`` of one
+        layer's ragged attention call this tick (docs/OPS.md "Tick
+        phases"), or nothing where no span would carry them: numpy on
+        ``num_slots`` entries."""
+        if self._trace is None or self._attn_geometry is None:
+            return {}
+        units, live = _pa.ragged_grid_units(q_lens, context_lens,
+                                            **self._attn_geometry)
+        return {"attn_units": units, "attn_live": live}
+
     def _trace_tick(self, t_tick, exec_name: str, path: str, **extra):
         """One engine-tick span (tid 0) — ALL three step paths emit
         through here so the tick-span schema (exec/path/queued/
@@ -2389,6 +2422,8 @@ class ServingEngine:
                   for i in active + list(given)}
         pend_pos0 = {i: int(self._slots[i].pend_pos)
                      for i in given}
+        # row t of slot s sees base[s] + t + 1 positions
+        attn_grid = self._attn_grid(q_lens, base + 1)
         t_l0 = time.monotonic()
         if ph is not None:
             ph.end(rows=int(q_lens.sum()))
@@ -2422,7 +2457,7 @@ class ServingEngine:
             n_pending=len(pending), q_lens=q_lens, rid_of=rid_of,
             pend_pos0=pend_pos0, t_tick=t_tick, t_l0=t_l0, pure=pure,
             carry=(outs[2], outs[3]) if pure else None,
-            tick=tick, dispatch="packed")
+            tick=tick, dispatch="packed", attn_grid=attn_grid)
         return pipe, emitted
 
     def _ragged_commit(self, pipe, flush=False) -> List[tuple]:
@@ -2538,7 +2573,7 @@ class ServingEngine:
                 pending=pipe.n_pending,
                 occupancy=round(
                     (len(active) + pipe.n_pending) / n_slots, 3),
-                dispatch=pipe.dispatch)
+                dispatch=pipe.dispatch, **pipe.attn_grid)
         return emitted
 
     # -- async tick pipeline (docs/OPS.md "Async tick pipeline") ------
@@ -2740,8 +2775,10 @@ class ServingEngine:
         self._note_kv_read(sum(
             self._slots[i].cache_len + 2 for i in active))
         q_lens = np.zeros(n_slots, np.int64)
+        ctx = np.zeros(n_slots, np.int64)
         for i in active:
             q_lens[i] = 1
+            ctx[i] = self._slots[i].cache_len + 2
         if tr is not None:
             tr.instant("pipelined dispatch", tid=0,
                        args={"active": len(active)})
@@ -2749,7 +2786,8 @@ class ServingEngine:
             outs=outs, active=active, given={}, n_pending=0,
             q_lens=q_lens, rid_of=dict(prev.rid_of), pend_pos0={},
             t_tick=t_tick, t_l0=t_l0, pure=True,
-            carry=(outs[2], outs[3]), tick=tick, dispatch="carry")
+            carry=(outs[2], outs[3]), tick=tick, dispatch="carry",
+            attn_grid=self._attn_grid(q_lens, ctx))
 
     def _flush_pipe(self) -> List[tuple]:
         """Commit any in-flight pipelined tick NOW. Every

@@ -45,12 +45,41 @@ rest of the way per *Ragged Paged Attention*: ONE invocation consumes
 a packed row buffer ``[R, H, D]`` holding every live query row of a
 serving tick — decoding slots (1 row), speculative verify windows
 (gamma+1 rows) and prefill chunks (up to ``chunk`` rows) — partitioned
-by scalar-prefetched per-slot ``q_lens``/``row_starts``. The grid is
-``(slot, window_row, kv_head, block)``: the q/out BlockSpec chases
-``row_starts[s] + t`` into the packed buffer (dead rows — ``t >=
-q_lens[s]`` — are routed to a trailing scratch row and predicated
-off), and each row keeps the verify variant's causal bound
-``lens + t``. The XLA fallback scatters the packed rows into the
+by per-slot ``q_lens``/``row_starts``. Its index space is what the
+tick holds, not what it could hold:
+
+- **query tiles.** The jitted wrapper cuts each slot's rows into tiles
+  of ``tq`` consecutive window rows (``tq * rp`` = 128 operand rows,
+  ``rp`` the padded heads of a kv group: ``_ragged_geometry``) and
+  builds the tile list from ``q_lens`` (``_ragged_tiles``: a cumsum
+  over the slots): tile -> slot, first window row, live rows, kv tiles
+  to walk. The list's static length is ``S + ceil(R / tq)``, which
+  every split of ``R`` rows over ``S`` slots fits. The rows are
+  gathered into a tile-aligned ``[tiles, H_kv, tq * rp, D]`` buffer in
+  XLA and the outputs gathered back per packed row, so a tile's dead
+  rows never reach a live packed row; rows no slot owns come back
+  zero. The gathers stand OUTSIDE ``kernel_scope``: the scope (and so
+  the kernel's roofline share) holds the ``pallas_call`` alone.
+- **the grid** is ``(query tile, kv_head)``, and the scalar-prefetched
+  tile list rides in SMEM. A step holds one tile for one kv head — the
+  ``[tq * rp, D]`` layout of the verify kernel, row ``r`` being window
+  token ``row0 + (r >> row_shift)`` with the causal bound ``lens +
+  that token`` — against kv tiles of ``kb`` pool blocks = 128 cache
+  positions (8 blocks of 16, 4 of int8's 32).
+- **the walk ends where the data ends.** The K/V pools stay in HBM; a
+  loop of exactly ``ceil((lens + last live row of the tile) / 128)``
+  iterations chases ``block_tables[slot]`` with double-buffered async
+  copies (tile ``j + 1`` in flight while tile ``j`` is multiplied),
+  each iteration one online-softmax update. A tile past the live count
+  loops zero times and stores zeros. At the default serving shape (8
+  slots, 136 rows, 4 kv heads, a 64-block table) that is 100 grid
+  steps of at most 8 iterations, where the ``(slot, window_row,
+  kv_head, block)`` grid this replaced walked 262,144 steps whatever
+  was live.
+
+``ragged_grid_units`` counts the same index space on the host from the
+same rule, for the engine's ``tick`` span (``attn_units`` /
+``attn_live``). The XLA fallback scatters the packed rows into the
 per-slot padded ``[S, W, H, D]`` layout and calls the SAME
 ``_xla_paged_verify`` einsum, so every row is bitwise the per-width
 fallback's output — the serving engine's CPU parity between the
@@ -58,11 +87,12 @@ ragged step and the per-width zoo is exact by construction.
 
 QUANTIZED POOLS (``paged_cache.QuantKV`` — int8 data + per-(block,
 position, head) f32 absmax scales): all three kernel variants take
-the scale pools as two extra block-chased operands and dequantize
-each K/V tile in VMEM right after its DMA (int8 -> f32 * scale, kept
-f32 through the dots — accuracy over MXU rate on a bandwidth-bound
-op), so the HBM stream per decode step halves while the softmax math
-is unchanged. The gather fallbacks read the SAME stored
+the scale pools as two extra block-chased operands (the ragged
+kernel's copies fetch them beside the data, their head axis padded to
+a lane tile) and dequantize each K/V tile in VMEM right after its DMA
+(int8 -> f32 * scale, kept f32 through the dots — accuracy over MXU
+rate on a bandwidth-bound op), so the HBM stream per decode step
+halves while the softmax math is unchanged. The gather fallbacks read the SAME stored
 bytes through ``paged_cache.gather_dense`` (which applies the
 identical dequant recipe), so fallback-vs-interpret-kernel parity
 holds for int8 pools exactly as for fp pools. Kernel eligibility
@@ -217,23 +247,28 @@ def _pool_view(pool):
     return pool.reshape(nb, bs, hkv * d)
 
 
-def _dequant_tile(k_ref, sc_ref, g):
-    """In-VMEM dequant of one pooled K/V block tile after its DMA:
-    int8 ``[BS, D]`` x kv head ``g``'s per-position f32 scale. The
-    scale block carries every kv head (``[BS, H_kv]`` — a single-head
-    column is not a legal Mosaic block); head ``g``'s column is picked
-    by an iota mask + lane sum, exact because every other term is 0.
-    The result STAYS f32 through the dots (accuracy over MXU rate on a
+def _dequant_rows(data, sc, g):
+    """In-VMEM dequant of pooled K/V rows after their DMA: int8
+    ``[rows, D]`` x kv head ``g``'s per-position f32 scale. The scale
+    rows carry every kv head (``[rows, H_kv]`` — a single-head column
+    is not a legal Mosaic block); head ``g``'s column is picked by an
+    iota mask + lane sum, exact because every other term is 0. The
+    result STAYS f32 through the dots (accuracy over MXU rate on a
     bandwidth-bound op: re-rounding to bf16 would stack a second
     ~0.2% grid error on the int8 step and measurably cost greedy
     token-match) — the same recipe ``paged_cache.kv_dequantize`` runs
     in the gather fallback, so kernel and fallback read identical
     values from identical stored bytes."""
-    sc = sc_ref[0]                                    # [BS, H_kv]
     head = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
     sc_g = jnp.sum(jnp.where(head == g, sc, np.float32(0.0)),
-                   axis=1, keepdims=True)             # [BS, 1]
-    return k_ref[0].astype(jnp.float32) * sc_g
+                   axis=1, keepdims=True)             # [rows, 1]
+    return data.astype(jnp.float32) * sc_g
+
+
+def _dequant_tile(k_ref, sc_ref, g):
+    """``_dequant_rows`` of one block-chased ``(1, BS, D)`` K/V block
+    and its ``(1, BS, H_kv)`` scale block."""
+    return _dequant_rows(k_ref[0], sc_ref[0], g)
 
 
 def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
@@ -330,78 +365,188 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
         o_ref[0, 0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
 
 
-def _ragged_kernel(qlens_ref, starts_ref, tables_ref, lens_ref, *args,
-                   scale, block_size, n_blocks, quantized=False,
-                   tree_bits=None):
-    """Ragged mixed-batch body: grid ``(slot, window_row, kv_head,
-    block)``. Each live grid row is window token ``t`` of slot ``s``
-    (the q/out BlockSpec chased ``row_starts[s] + t`` into the packed
-    buffer); its causal bound is the verify variant's ``lens + t``
-    (``lens_ref`` counts positions visible to the slot's FIRST window
-    token, itself included). Dead rows (``t >= q_lens[s]``) read/write
-    the trailing scratch row and skip all FLOPs. ``tree_bits`` (static
-    ancestor bitmasks) adds a FIFTH scalar-prefetch operand
-    ``tree_ref`` [S]: slots flagged ``> 0`` carry a token-tree verify
-    window and mask columns by ancestor path instead of the linear
-    bound — unflagged slots (prefill chunks and their narrow trickle
-    rows) keep the linear mask untouched. ``quantized``: same extra
-    scale operands + in-VMEM dequant as ``_decode_kernel``."""
+# rows (window tokens x ``_row_pad``) and cache positions one ragged
+# grid step brings to the MXU: one 128 x 128 tile each way
+_TILE_ROWS = 128
+_TILE_POSITIONS = 128
+
+
+def _ragged_geometry(rows, slots, rep, q_dtype, block_size, max_blocks):
+    """Static index space of one ragged call, from what the operands
+    show: ``(rp, tq, kb, n_tiles, n_kv)`` — ``rp`` rows a kv group's
+    heads pad to (``_row_pad``), ``tq`` window rows of one slot a query
+    tile holds (``tq * rp`` = ``_TILE_ROWS``, within
+    ``_MAX_GROUP_ROWS``), ``kb`` pool blocks a kv tile chases
+    (``kb * block_size`` = ``_TILE_POSITIONS`` where the block is
+    smaller), ``n_tiles = slots + ceil(rows / tq)`` query tiles launched
+    (every split of ``rows`` over ``slots`` fits: each slot wastes less
+    than one tile) and ``n_kv`` kv tiles a full table holds."""
+    rp = _row_pad(rep, q_dtype)
+    tq = max(1, _TILE_ROWS // rp)
+    kb = max(1, min(_TILE_POSITIONS // block_size, max_blocks))
+    return (rp, tq, kb, slots + -(-rows // tq), -(-max_blocks // kb))
+
+
+def _ragged_tiles(xp, ql, context_lens, tq, kv_span, n_tiles, n_kv):
+    """The query tiles of one ragged call, ``[n_tiles]`` each: ``slot``
+    the tile belongs to, ``row0`` its first window row, ``rows`` live
+    in it (0 = a tile past the live count) and ``kv`` tiles of
+    ``kv_span`` cache positions its walk visits — it ends where the
+    tile's LAST live row's causal bound ``context_lens + row`` ends.
+    ``ql`` is ``q_lens`` held to ``w_max``. ``xp`` is ``jnp`` inside
+    the jitted wrapper and ``numpy`` on the host
+    (``ragged_grid_units``): one rule, so the engine's counter cannot
+    drift from what the kernel walks."""
+    n_slots = ql.shape[0]
+    per_slot = (ql + (tq - 1)) // tq
+    ends = xp.cumsum(per_slot)
+    t = xp.arange(n_tiles, dtype=ends.dtype)
+    slot = xp.sum((t[:, None] >= ends[None, :]).astype(ends.dtype),
+                  axis=1)
+    live = slot < n_slots
+    slot = xp.minimum(slot, n_slots - 1)
+    row0 = (t - (ends - per_slot)[slot]) * tq
+    rows = xp.where(live, xp.clip(ql[slot] - row0, 0, tq), 0)
+    reach = context_lens[slot] + row0 + rows - 1
+    kv = xp.where(rows > 0,
+                  xp.clip((reach + (kv_span - 1)) // kv_span, 0, n_kv),
+                  0)
+    return slot, row0, rows, kv
+
+
+def ragged_grid_units(q_lens, context_lens, *, rows, w_max, num_heads,
+                      num_kv_heads, q_dtype, block_size, max_blocks):
+    """Host-side count of what ONE ``pallas_ragged_paged_attention``
+    call visits for this tick's ``q_lens`` / ``context_lens`` (numpy,
+    no device read): ``(units, live)`` in (query tile, kv head, kv
+    tile) units. A live tile's grid step runs one loop iteration per
+    kv tile of its walk, all live; a tile past the live count is still
+    one launched grid step, predicated off — so ``units - live`` is
+    the dead steps and ``live / units`` the share of the walk that is
+    work."""
+    ql = np.minimum(np.asarray(q_lens, np.int64), w_max)
+    _, tq, kb, n_tiles, n_kv = _ragged_geometry(
+        rows, ql.shape[0], num_heads // num_kv_heads, q_dtype,
+        block_size, max_blocks)
+    _, _, _, kv = _ragged_tiles(
+        np, ql, np.asarray(context_lens, np.int64), tq,
+        kb * block_size, n_tiles, n_kv)
+    live = int(kv.sum()) * num_kv_heads
+    return live + int((kv == 0).sum()) * num_kv_heads, live
+
+
+def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tables_ref, lens_ref,
+                   *args, scale, block_size, kv_blocks, max_blocks,
+                   head_dim, row_shift, quantized=False, tree_bits=None):
+    """Ragged mixed-batch body: grid ``(query tile, kv_head)``. A step
+    holds ``tq`` consecutive window rows of ONE slot for one kv head
+    (``[tq * rp, D]``, row ``r`` = window token ``trow_ref[t] +
+    (r >> row_shift)``, causal bound ``lens + that token``) and walks
+    the slot's cache in tiles of ``kv_blocks`` pool blocks, chased
+    through ``tables_ref[slot]`` by double-buffered async copies out of
+    the HBM pools, for exactly ``tkv_ref[t]`` iterations — the tile's
+    own live reach, 0 for a tile past the live count (which stores
+    zeros). ``tree_bits`` (static ancestor bitmasks) adds a SIXTH
+    scalar-prefetch operand ``tree_ref`` [S]: slots flagged ``> 0``
+    mask their first ``len(tree_bits)`` window rows by ancestor path
+    instead of the linear bound — unflagged slots (prefill chunks and
+    their narrow trickle rows) keep the linear mask untouched.
+    ``quantized``: the scale pools ride the same copies and each K/V
+    tile dequantizes in VMEM as in ``_decode_kernel``."""
     if tree_bits is not None:
-        tree_ref, q_ref, k_ref, v_ref, *rest = args
-    else:
-        tree_ref = None
-        q_ref, k_ref, v_ref, *rest = args
+        tree_ref, *args = args
     if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf,
+         ks_buf, vs_buf, sems, m_scr, l_scr, acc_scr) = args
+        streams = ((k_hbm, k_buf, True), (v_hbm, v_buf, True),
+                   (ks_hbm, ks_buf, False), (vs_hbm, vs_buf, False))
     else:
-        o_ref, m_scr, l_scr, acc_scr = rest
-    s = pl.program_id(0)
-    t = pl.program_id(1)
-    g = pl.program_id(2)
-    j = pl.program_id(3)
+        (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
+         m_scr, l_scr, acc_scr) = args
+        streams = ((k_hbm, k_buf, True), (v_hbm, v_buf, True))
+    t = pl.program_id(0)
+    g = pl.program_id(1)
+    slot = tslot_ref[t]
+    row0 = trow_ref[t]
+    n_kv = tkv_ref[t]
+    lens = lens_ref[slot]
+    bs, kb = block_size, kv_blocks
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def copies(j, buf):
+        """The async copies that bring kv tile ``j`` into buffer half
+        ``buf``: per pool block one ``[BS, D]`` tile of kv head ``g``
+        (and, quantized, its ``[BS, H_kv]`` scale block). Blocks past
+        the table's end re-read its last entry; their columns lie past
+        every row's bound."""
+        out = []
+        for i in range(kb):
+            blk = tables_ref[slot, jnp.minimum(j * kb + i,
+                                               max_blocks - 1)]
+            for n, (hbm, vmem, per_head) in enumerate(streams):
+                src = (hbm.at[blk, :, pl.ds(g * head_dim, head_dim)]
+                       if per_head else hbm.at[blk])
+                out.append(pltpu.make_async_copy(
+                    src, vmem.at[buf, pl.ds(i * bs, bs), :],
+                    sems.at[n, buf]))
+        return out
 
-    ctx = lens_ref[s] + t          # cols < ctx visible to this row
-    @pl.when((t < qlens_ref[s]) & (j * block_size < ctx))
-    def _compute():
-        q = q_ref[0, 0]                       # [rp, D]
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(n_kv > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    def walk(j, carry):
+        buf = jax.lax.rem(j, 2)
+
+        @pl.when(j + 1 < n_kv)
+        def _next():
+            for c in copies(j + 1, 1 - buf):
+                c.start()
+
+        for c in copies(j, buf):
+            c.wait()
+        q = q_ref[0, 0]                       # [tq * rp, D]
         if quantized:
             q = q.astype(jnp.float32)         # match the f32 dequant
-            k = _dequant_tile(k_ref, ks_ref, g)
-            v = _dequant_tile(v_ref, vs_ref, g)
+            k = _dequant_rows(k_buf[buf], ks_buf[buf], g)
+            v = _dequant_rows(v_buf[buf], vs_buf[buf], g)
         else:
-            k = k_ref[0]                      # [BS, D]
-            v = v_ref[0]
+            k = k_buf[buf]                    # [kb * BS, D]
+            v = v_buf[buf]
         sc = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        cols = j * block_size + jax.lax.broadcasted_iota(
+        cols = j * (kb * bs) + jax.lax.broadcasted_iota(
             jnp.int32, sc.shape, 1)
+        # row r is window token row0 + (r >> row_shift)
+        node = row0 + (jax.lax.broadcasted_iota(
+            jnp.int32, sc.shape, 0) >> row_shift)
+        linear = cols < lens + node
         if tree_bits is None:
-            sc = jnp.where(cols < ctx, sc, NEG_INF)
+            keep = linear
         else:
             # tree slots: rel = cols - lens names the window node this
             # column holds (rel < 0 = committed prefix + root); row t
             # keeps it iff it is on t's ancestor path. Every tree
-            # column satisfies cols < ctx, so the outer block-skip
-            # guard above stays a strict superset.
-            bits = jnp.int32(0)
+            # column satisfies the linear bound, so the walk's end
+            # (the last live row's linear reach) stays a superset.
+            bits = jnp.zeros(sc.shape, jnp.int32)
             for i, b in enumerate(tree_bits):
-                bits = jnp.where(t == i, np.int32(b), bits)
-            rel = cols - lens_ref[s]
+                bits = jnp.where(node == i, np.int32(b), bits)
+            rel = cols - lens
             ok_tree = (rel < 0) | (
                 ((bits >> jnp.clip(rel, 0, 31)) & 1) > 0)
             # (boolean algebra, not a select between masks: Mosaic
             # cannot legalize arith.select on i1 vectors)
-            is_tree = (tree_ref[s] > 0) & (t < len(tree_bits))
+            is_tree = (jnp.full(sc.shape, tree_ref[slot]) > 0) & (
+                node < len(tree_bits))
             keep = (is_tree & ok_tree) | (
-                jnp.logical_not(is_tree) & (cols < ctx))
-            sc = jnp.where(keep, sc, NEG_INF)
+                jnp.logical_not(is_tree) & linear)
+        sc = jnp.where(keep, sc, NEG_INF)
         m_prev = m_scr[:, :1]
         l_prev = l_scr[:, :1]
         m_cur = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
@@ -414,12 +559,12 @@ def _ragged_kernel(qlens_ref, starts_ref, tables_ref, lens_ref, *args,
         acc_scr[:] = alpha * acc_scr[:] + pv
         m_scr[:] = jnp.broadcast_to(m_cur, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        return carry
 
-    @pl.when(j == n_blocks - 1)
-    def _finish():
-        l = l_scr[:, :1]
-        safe_l = jnp.where(l == 0.0, np.float32(1.0), l)
-        o_ref[0, 0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_kv, walk, 0)
+    l = l_scr[:, :1]
+    safe_l = jnp.where(l == 0.0, np.float32(1.0), l)
+    o_ref[0, 0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
 
 
 def _unpack_pools(k_pool, v_pool):
@@ -540,21 +685,26 @@ def pallas_ragged_paged_attention(q, k_pool, v_pool, block_tables,
     q_lens[s]``; ``context_lens[s]`` = positions visible to the
     slot's first row, itself included (row ``t`` sees
     ``context_lens[s] + t``). ``w_max`` is the static per-slot
-    row-count ceiling (the grid's window dimension). ``row_slot``
-    is accepted for fallback-signature parity and unused here.
-    ``tree_anc`` (static parent tuple) + ``tree_slots`` ([S] int32
-    flags, ``None`` = every slot) mask the flagged slots' verify
+    row-count ceiling (rows of a slot past it are not attended).
+    ``row_slot`` is accepted for fallback-signature parity and unused
+    here. ``tree_anc`` (static parent tuple) + ``tree_slots`` ([S]
+    int32 flags, ``None`` = every slot) mask the flagged slots' verify
     windows by ancestor path — unflagged slots (prefill chunks and
-    their trickle rows) keep the linear bound. Returns [R, H, D];
-    rows past a slot's ``q_lens`` are never read or written (dead
-    grid rows target a trailing scratch row)."""
+    their trickle rows) keep the linear bound.
+
+    The packed rows are cut into query tiles (``_ragged_tiles``) here,
+    in XLA: gathered into a tile-aligned ``[tiles, H_kv, tq * rp, D]``
+    buffer going in and gathered back per packed row coming out, so a
+    tile's dead rows never reach a live packed row. Returns [R, H, D];
+    rows no slot owns come back zero."""
     r, h, d = q.shape
     nb, bs, hkv, _ = k_pool.shape
     kd, vd, scales, quant = _unpack_pools(k_pool, v_pool)
     s, mb = block_tables.shape
     w = int(w_max)
     rep = h // hkv
-    rp = _row_pad(rep, q.dtype)
+    rp, tq, kb, n_tiles, n_kv = _ragged_geometry(r, s, rep, q.dtype, bs,
+                                                 mb)
     scale = np.float32(sm_scale if sm_scale is not None
                        else 1.0 / math.sqrt(d))
     tree_bits = None
@@ -564,55 +714,73 @@ def pallas_ragged_paged_attention(q, k_pool, v_pool, block_tables,
         if tree_slots is None:
             tree_slots = jnp.ones((s,), jnp.int32)
         tree_args = [tree_slots.astype(jnp.int32)]
-    # one pad: the rep axis out to rp rows, and a trailing scratch row
-    # r where dead grid rows park their (skipped) reads and (zero)
-    # writes so live packed rows are never clobbered
-    q4 = jnp.pad(q.reshape(r, hkv, rep, d),
-                 ((0, 1), (0, 0), (0, rp - rep), (0, 0)))
+    ql = jnp.minimum(q_lens.astype(jnp.int32), w)
+    starts = row_starts.astype(jnp.int32)
+    lens = context_lens.astype(jnp.int32)
+    tslot, trow, _, tkv = (a.astype(jnp.int32) for a in _ragged_tiles(
+        jnp, ql, lens, tq, kb * bs, n_tiles, n_kv))
+    # tile t's rows in the packed buffer (clipped: a dead row reads
+    # some live row's q and its output is never gathered back)
+    src = jnp.clip((starts[tslot] + trow)[:, None]
+                   + jnp.arange(tq, dtype=jnp.int32)[None, :], 0, r - 1)
+    # rows grouped kv-head-major: [tiles, hkv, tq * rp, D] so one K/V
+    # tile feeds every window row of the kv group
+    rows = tq * rp
+    q4 = _pad_rep(q[src].reshape(n_tiles, tq, hkv, rep, d), rp) \
+        .transpose(0, 2, 1, 3, 4).reshape(n_tiles, hkv, rows, d)
+    # a scale block leaves HBM by an async copy of whole lane tiles:
+    # the head axis is padded out to one (the layout HBM holds it in
+    # anyway — a minor dim under 128 lanes is stored padded to them)
+    scales = [jnp.pad(sc, ((0, 0), (0, 0), (0, -hkv % 128)))
+              for sc in scales]
     kernel = functools.partial(
-        _ragged_kernel, scale=scale, block_size=bs, n_blocks=mb,
+        _ragged_kernel, scale=scale, block_size=bs, kv_blocks=kb,
+        max_blocks=mb, head_dim=d, row_shift=rp.bit_length() - 1,
         quantized=quant, tree_bits=tree_bits)
 
-    # *rest tolerates both prefetch arities (4 linear, 5 tree)
-    def q_map(si, t, g, j, qlens, starts, *rest):
-        return (jnp.where(t < qlens[si], starts[si] + t, r),
-                g, 0, 0)
+    def q_block(t, g, *prefetch):
+        return (t, g, 0, 0)
 
-    def kv_block(si, t, g, j, qlens, starts, tables, *rest):
-        return (tables[si, j], 0, g)
-
-    def sc_block(si, t, g, j, qlens, starts, tables, *rest):
-        return (tables[si, j], 0, 0)
-
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    n_streams = 2 + len(scales)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 + len(tree_args),
-        grid=(s, w, hkv, mb),
-        in_specs=[
-            pl.BlockSpec((1, 1, rp, d), q_map),
-            pl.BlockSpec((1, bs, d), kv_block),
-            pl.BlockSpec((1, bs, d), kv_block),
-        ] + [pl.BlockSpec((1, bs, hkv), sc_block)] * len(scales),
-        out_specs=pl.BlockSpec((1, 1, rp, d), q_map),
-        scratch_shapes=_softmax_scratch(rp, d),
+        num_scalar_prefetch=5 + len(tree_args),
+        grid=(n_tiles, hkv),
+        in_specs=[pl.BlockSpec((1, 1, rows, d), q_block)]
+        + [hbm] * n_streams,
+        out_specs=pl.BlockSpec((1, 1, rows, d), q_block),
+        scratch_shapes=[pltpu.VMEM((2, kb * bs, d), kd.dtype)] * 2
+        + [pltpu.VMEM((2, kb * bs, sc.shape[2]), jnp.float32)
+           for sc in scales]
+        + [pltpu.SemaphoreType.DMA((n_streams, 2))]
+        + _softmax_scratch(rows, d),
     )
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r + 1, hkv, rp, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, hkv, rows, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            # slot and window dims revisit the scratch row on dead
-            # steps, so both stay sequential; kv_head blocks are
-            # disjoint
-            dimension_semantics=("arbitrary", "arbitrary",
-                                 "parallel", "arbitrary")),
+            # no state crosses a step: every (tile, kv head) starts and
+            # waits its own copies and writes its own output block
+            dimension_semantics=("parallel", "parallel")),
         interpret=_interpret() if interpret is None else interpret,
     )
     with kernel_scope("ragged_paged_attention"):
-        out = call(q_lens.astype(jnp.int32), row_starts.astype(jnp.int32),
-                   block_tables.astype(jnp.int32),
-                   context_lens.astype(jnp.int32), *tree_args, q4, kd, vd,
-                   *scales)
-    return out[:r, :, :rep].reshape(r, h, d)
+        out = call(tslot, trow, tkv, block_tables.astype(jnp.int32),
+                   lens, *tree_args, q4, kd, vd, *scales)
+    out = out.reshape(n_tiles, hkv, tq, rp, d)[:, :, :, :rep] \
+        .transpose(0, 2, 1, 3, 4).reshape(n_tiles * tq, h, d)
+    # packed row -> (its slot's tile, row in the tile)
+    row = jnp.arange(r, dtype=jnp.int32)[:, None]
+    owned = (row >= starts[None, :]) & (row < (starts + ql)[None, :])
+    owner = jnp.argmax(owned, axis=1).astype(jnp.int32)
+    per_slot = (ql + (tq - 1)) // tq
+    first_tile = (jnp.cumsum(per_slot) - per_slot).astype(jnp.int32)
+    local = row[:, 0] - starts[owner]
+    back = (first_tile[owner] + local // tq) * tq + local % tq
+    has = jnp.any(owned, axis=1)
+    return jnp.where(has[:, None, None],
+                     out[jnp.where(has, back, 0)], 0)
 
 
 # ---------------------------------------------------------------------------
@@ -915,7 +1083,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables,
     call may carry more than ``w_narrow`` rows; the serving scheduler
     guarantees it). ``tree_anc``/``tree_slots`` (see
     ``spec_tree_scope``) mask the flagged slots' windows by ancestor
-    path. Routes to the ragged Pallas grid on TPU, the two-lane
+    path. Routes to the ragged Pallas kernel on TPU, the two-lane
     verify fallback elsewhere."""
     wn = int(narrow_iota.shape[0])
     w = int(win_iota.shape[0])

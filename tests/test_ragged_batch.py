@@ -181,13 +181,87 @@ def test_ragged_fallback_bitwise_equals_per_width_paths():
                                       np.asarray(ref[0]))
 
 
-def test_ragged_kernel_matches_fallback_interpret():
-    """The ragged Pallas grid (interpret mode on CPU) agrees with the
-    gather fallback on a mixed batch including a NULL/zero-row slot and
-    block-boundary starts."""
+# The mixes the kernel's index space can get wrong — (q_lens, base
+# lengths before the tick, w_narrow, tree-flagged slots, int8 pools) on
+# 8 slots, block 8, a 24-block table (reach 192: two 128-position kv
+# tiles, the second one short), w_max 128. f32 query heads pad to 8
+# rows, so a query tile holds 16 window rows of a slot.
+_KERNEL_MIXES = {
+    "decode_only": ([1] * 8, [5, 15, 0, 24, 63, 100, 150, 191], 1),
+    "chunk_128_beside_7_decodes":
+        ([1, 1, 1, 128, 1, 1, 1, 1], [5, 15, 0, 40, 63, 100, 150, 191],
+         1),
+    "chunk_not_a_tile_multiple":
+        ([1, 0, 77, 1, 1, 0, 1, 1], [9, 0, 30, 24, 63, 0, 150, 17], 1),
+    "contexts_127_128_129_and_the_last_block":
+        ([1, 1, 1, 1, 3, 17, 1, 1],
+         [126, 127, 128, 191, 125, 112, 184, 190], 3),
+    "empty_slot_between_live_ones":
+        ([1, 0, 1, 0, 0, 40, 0, 1], [5, 77, 0, 24, 63, 100, 150, 129],
+         1),
+    "three_rows_a_slot": ([3] * 8, [5, 15, 0, 24, 63, 125, 126, 189], 3),
+    "tree_flagged_beside_linear":
+        ([3, 3, 20, 3, 0, 3, 2, 3], [5, 15, 0, 24, 63, 125, 126, 189], 3,
+         [1, 1, 0, 1, 0, 1, 0, 1]),
+    "int8_pools":
+        ([1, 3, 0, 50, 1, 3, 1, 1], [5, 15, 0, 24, 63, 125, 126, 189], 3,
+         None, True),
+}
+
+
+@pytest.mark.parametrize("mix", list(_KERNEL_MIXES))
+def test_ragged_kernel_matches_fallback_interpret(mix):
+    """The ragged Pallas kernel (interpret mode on CPU) agrees with the
+    gather fallback on every live row of each mix, and returns zeros in
+    every packed row no slot owns."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import paged_cache as pc
     from paddle_tpu.ops.pallas import paged_attention as pa
-    if pa.pallas_ragged_paged_attention is None:
-        pytest.skip("pallas unavailable on this jax build")
+    q_lens, base, wn, tree, quant = (
+        _KERNEL_MIXES[mix] + (None, False))[:5]
+    q_lens, base = np.asarray(q_lens, np.int64), np.asarray(base, np.int64)
+    S, H, Hkv, D, BS, MB, W = 8, 8, 4, 64, 8, 24, 128
+    R = S * wn + W
+    NB = 1 + S * MB
+    rng = np.random.RandomState(1)
+
+    def pool():
+        x = jnp.asarray(rng.randn(NB, BS, Hkv, D), jnp.float32)
+        return pc.QuantKV(*pc.kv_quantize(x)) if quant else x
+
+    kp, vp = pool(), pool()
+    tables = np.zeros((S, MB), np.int32)    # null past the allocation
+    alloc = pc.BlockAllocator(NB)
+    for s in range(S):
+        n = pc.blocks_for(int(base[s] + q_lens[s]), BS)
+        if n:
+            tables[s, :n] = alloc.alloc(n)
+    row_slot, _, row_starts, _ = pc.ragged_row_meta(q_lens, base, R,
+                                                    MB * BS)
+    q = jnp.asarray(rng.randn(R, H, D), jnp.float32)
+    args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(base + 1),
+            jnp.asarray(q_lens), jnp.asarray(row_starts))
+    kw = {}
+    if tree is not None:
+        kw = dict(tree_anc=(0, 0), tree_slots=jnp.asarray(tree))
+    ref = pa._xla_ragged_paged(*args, jnp.asarray(row_slot), wn, W, **kw)
+    out = np.asarray(pa.pallas_ragged_paged_attention(
+        *args, w_max=W, interpret=True, **kw))
+    owned = np.zeros(R, bool)
+    for s, n in enumerate(map(int, q_lens)):
+        s0 = int(row_starts[s])
+        owned[s0:s0 + n] = True
+        np.testing.assert_allclose(
+            out[s0:s0 + n], np.asarray(ref[s0:s0 + n]),
+            rtol=1e-5, atol=1e-5, err_msg=f"slot {s} rows diverged")
+    assert np.isfinite(out).all()
+    assert not out[~owned].any(), "a row past a slot's q_lens is written"
+
+
+def test_ragged_kernel_mixed_batch_interpret():
+    """The seed's mixed batch (4 slots, a 6-block table shorter than
+    one kv tile, a zero-row slot, a chunk at a block boundary)."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
     rng = np.random.RandomState(1)
     (q, kp, vp, tables, ctx, ql, rs, sl, W,
      q_lens, row_starts) = _mixed_batch(rng)
@@ -195,13 +269,58 @@ def test_ragged_kernel_matches_fallback_interpret():
                                W)
     out = pa.pallas_ragged_paged_attention(q, kp, vp, tables, ctx, ql,
                                            rs, w_max=W, interpret=True)
-    # compare live rows only (dead/pad rows are garbage by contract)
     for s, n in enumerate(map(int, np.asarray(q_lens))):
         s0 = int(row_starts[s])
         np.testing.assert_allclose(
             np.asarray(out[s0:s0 + n]), np.asarray(ref[s0:s0 + n]),
             rtol=1e-5, atol=1e-5,
             err_msg=f"slot {s} rows diverged")
+
+
+def _direct_grid_count(q_lens, ctx, tq, span, heads, n_tiles):
+    """(units, live) by walking slots, tiles and kv tiles one at a
+    time: what ``ragged_grid_units`` must equal."""
+    live = tiles = 0
+    for n, c in zip(q_lens, ctx):
+        for row0 in range(0, int(n), tq):
+            last = min(row0 + tq, int(n)) - 1       # last live row
+            live += -(-(int(c) + last) // span)     # sees c + last cols
+            tiles += 1
+    return (live + (n_tiles - tiles)) * heads, live * heads
+
+
+def test_tick_span_counts_the_attention_grid(llama_tiny, monkeypatch):
+    """``attn_units`` / ``attn_live`` on the ``tick`` span are the
+    (query tile, kv head, kv tile) units one layer's ragged attention
+    call visits, counted on the host in ``pack``: a hand-made tick (a
+    23-token prompt prefilled 8 rows a tick beside one decoding slot)
+    against a direct count."""
+    monkeypatch.setenv("PADDLE_TPU_RAGGED_BATCH", "1")
+    rng = np.random.RandomState(2)
+    eng = ServingEngine(llama_tiny, ServingConfig(
+        num_slots=3, block_size=8, max_model_len=192, prefill_chunk=8))
+    eng.submit(rng.randint(1, 128, (4,)), 30)
+    eng.step()                      # slot 0: 4 prompt rows, then decodes
+    eng.submit(rng.randint(1, 128, (150,)), 4)
+    for _ in range(22):
+        eng.step()
+    spans = [e["args"] for e in eng.tracer.events() if e["name"] == "tick"]
+    eng.shutdown()
+    # f32, 2 query heads a kv head: 8 padded rows, 16 window rows a
+    # tile; block 8: 16 blocks = 128 positions a kv tile; 3 slots +
+    # ceil((3 + 8) / 16) tiles launched
+    tq, span, heads, n_tiles = 16, 128, 2, 4
+    # tick 0: the 4-row prompt alone; ticks 1-19: one decode row at
+    # context 5, 6, ... beside an 8-row chunk at 0, 8, ...
+    want = [_direct_grid_count([4], [1], tq, span, heads, n_tiles)]
+    for k in range(19):
+        chunk = min(8, 150 - 8 * k)
+        want.append(_direct_grid_count(
+            [1, chunk], [5 + k, 8 * k + 1], tq, span, heads, n_tiles))
+    got = [(a["attn_units"], a["attn_live"]) for a in spans]
+    assert got[:20] == want
+    # the chunk's walk grows past one kv tile at position 128
+    assert want[15] == (8, 4) and want[17] == (10, 6)
 
 
 # ----------------------------------------------- engine-level exactness

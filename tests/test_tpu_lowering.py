@@ -182,6 +182,63 @@ def test_paged_eligibility_is_what_compiles(hkv, d, quant):
             tree_anc=(0, 0)), rows, pool, pool)
 
 
+# the default ServingConfig at the Qwen2-7B widths: 8 slots, 8 decode
+# rows + one 128-row chunk, 7 query heads a kv head, a 1024-token table
+_SERVING = dict(s=8, r=136, w=128, rep=7, d=128, reach=1024)
+
+
+def _serving_ragged_call(hkv, quant, tree):
+    """``(fn, args)``: one ragged attention call at the serving shape."""
+    c = _SERVING
+    bs = 32 if quant else 16
+    pool = _pools(hkv, c["d"], bs, quant)
+    tables = jnp.zeros((c["s"], c["reach"] // bs), jnp.int32)
+    lens = jnp.ones((c["s"],), jnp.int32)
+    q = jnp.zeros((c["r"], hkv * c["rep"], c["d"]), jnp.bfloat16)
+    return (lambda q, k, v, tables, lens: pa.pallas_ragged_paged_attention(
+        q, k, v, tables, lens, lens, lens, w_max=c["w"], interpret=False,
+        tree_anc=(0, 0) if tree else None)), (q, pool, pool, tables, lens)
+
+
+@pytest.mark.parametrize("tree", [False, True], ids=["linear", "tree"])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("hkv", [4, 1], ids=["hkv4", "tp4_shard"])
+def test_ragged_serving_shape_compiles(hkv, quant, tree):
+    """The shape both benchmark cells run in every layer of every tick
+    (and its int8-pool, tree-verify and one-kv-head TP=4 shard
+    variants) compiles to Mosaic: the async copies out of the HBM
+    pools, the dynamic walk and the tile metadata included."""
+    fn, args = _serving_ragged_call(hkv, quant, tree)
+    assert _lowers_to_mosaic(fn, *args)
+
+
+def test_ragged_launch_stays_far_below_the_old_grid():
+    """One call at the default config launches ``(query tile, kv
+    head)`` grid steps of at most ``n_kv`` loop iterations each: under
+    1/64 of the ``slot x window_row x kv_head x block`` walk (262,144
+    steps) this kernel replaced, so that walk cannot grow back
+    unnoticed."""
+    c = _SERVING
+    hkv, bs = 4, 16
+    fn, args = _serving_ragged_call(hkv, False, False)
+
+    def pallas_calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from pallas_calls(sub)
+
+    (call,) = pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)
+    grid = tuple(call.params["grid_mapping"].grid)
+    mb = c["reach"] // bs
+    _, tq, kb, n_tiles, n_kv = pa._ragged_geometry(
+        c["r"], c["s"], c["rep"], jnp.bfloat16, bs, mb)
+    assert grid == (n_tiles, hkv) == (c["s"] + -(-c["r"] // tq), hkv)
+    assert (tq, kb * bs, n_kv) == (8, 128, 8)
+    assert int(np.prod(grid)) * n_kv < c["s"] * c["w"] * hkv * mb // 64
+
+
 def test_paged_eligibility_wants_whole_sublane_tiles():
     """Block size per pool dtype: 16 rows of bf16, 32 of int8."""
     assert pa._kernel_eligible(28, 128, jnp.bfloat16,
