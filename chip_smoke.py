@@ -85,6 +85,7 @@ EXPECT = {
     "kernel.paged_verify.int8": ("mosaic", ("paged_verify_attention",)),
     "kernel.ragged_paged.bf16": ("mosaic", ("ragged_paged_attention",)),
     "kernel.ragged_paged.int8": ("mosaic", ("ragged_paged_attention",)),
+    "kernel.ragged_latent.bf16": ("mosaic", ("ragged_latent_attention",)),
     "kernel.norm_matmul.qkv_bias": ("mosaic", ("fused_norm_matmul",)),
     "kernel.norm_matmul.gate_up": ("mosaic", ("fused_norm_matmul",)),
     "kernel.matmul_residual.o_proj": ("mosaic", ("fused_matmul_residual",)),
@@ -92,6 +93,7 @@ EXPECT = {
                                            ("fused_matmul_residual",)),
     # (jax's own kernels: they surface under megablox's jitted gmm / tgmm)
     "kernel.megablox_gmm": ("mosaic", ("gmm", "tgmm")),
+    "kernel.megablox_gmm.share": ("mosaic", ("gmm",)),
     "kernel.gather_gmm": ("xla", _ROW_DMA),
     "kernel.gather_gmm_swiglu": ("xla", _ROW_DMA),
     "kernel.scatter_gmm": ("xla", _ROW_DMA),
@@ -419,6 +421,58 @@ def kernel_cases(full, interpret=None):
                   (f"kernel.paged_verify.{tag}", verify_build),
                   (f"kernel.ragged_paged.{tag}", ragged_build)]
 
+    # -- latent (MLA) attention over a one-array pool: 64 heads on one
+    # latent of key width 576 (640 lanes), value = its first 512 --------
+    ld = dict(S=32, H=64, W=576, V=512, BS=16, MB=512, w=512) if full \
+        else dict(S=4, H=8, W=576, V=512, BS=16, MB=8, w=24)
+
+    def latent_build(rng, ld=ld):
+        s_, mb, bs, w = ld["S"], ld["MB"], ld["BS"], ld["w"]
+        rows = s_ + w
+        nb = 1 + s_ * mb
+        (pool,) = pc.init_latent_pool(nb, bs, ld["W"], bf16)
+        lanes = pool.shape[2]
+        pool = jnp.asarray(
+            rng.standard_normal((nb, bs, lanes)) * 0.5,
+            bf16).at[..., ld["W"]:].set(0)
+        tables = jnp.asarray(
+            rng.permutation(np.arange(1, nb)).reshape(s_, mb), jnp.int32)
+        q = jnp.asarray(rng.standard_normal((rows, ld["H"], lanes)),
+                        bf16).at[..., ld["W"]:].set(0)
+        lens = rng.integers(1, mb * bs - w, s_)
+        ctx = jnp.asarray(lens + 1, jnp.int32)
+        # a mixed tick (one chunk, decode rows, one idle slot) and the
+        # decode-only tick
+        mixed = np.ones(s_, np.int64)
+        mixed[0], mixed[-1] = w - 3, 0
+        ticks = []
+        for q_lens in (mixed, np.ones(s_, np.int64)):
+            row_slot, _pos, row_starts, _last = pc.ragged_row_meta(
+                q_lens, lens, rows, mb * bs)
+            live = np.zeros(rows, bool)
+            for s0, n in zip(row_starts, q_lens):
+                live[s0:s0 + n] = True
+            ticks.append(tuple(jnp.asarray(x, jnp.int32)
+                               for x in (q_lens, row_starts, row_slot))
+                         + (jnp.asarray(live)[:, None, None],))
+
+        def kern(q, pool, tables, ctx):
+            return tuple(jnp.where(
+                live, pa.pallas_ragged_latent_attention(
+                    q, pool, tables, ctx, ql, rs, w, ld["V"], 0.1,
+                    interpret=interpret), 0)
+                for ql, rs, _sl, live in ticks)
+
+        def mirror(q, pool, tables, ctx):
+            verify = lambda q4, t, l, _n: pa._xla_latent_verify(  # noqa
+                q4, pool, t, l, ld["V"], 0.1)
+            return tuple(jnp.where(live, pa._xla_ragged_lanes(
+                q, verify, tables, ctx, ql, rs, sl, 1, w), 0)
+                for ql, rs, sl, live in ticks)
+        return kern, mirror, (q, pool, tables, ctx)
+
+    cases.append(("kernel.ragged_latent.bf16", latent_build))
+
     # -- fused decode projections at the Qwen2-7B widths --------------------
     # rows = the default engine's packed width: 8 slots + one 128-row chunk
     r, d, ffn, kvw = (136, 3584, 18944, 512) if full else (8, 128, 256, 128)
@@ -523,8 +577,26 @@ def kernel_cases(full, interpret=None):
 
     # megablox has no interpreter switch on this path: chip (or
     # cross-lowering) only
+    def share_build(rng):
+        """One chip's expert-parallel share: 16 groups of a few rows
+        each at the head of a 4,352-pair buffer, the tail in no group,
+        under the share's own tiling."""
+        sm, sk, sn, se = (4352, 7168, 4096, 16) if full \
+            else (256, 256, 256, 4)
+        gs = jnp.asarray(rng.integers(0, 2 * sm // (16 * se), se),
+                         jnp.int32)
+        lhs, rhs = mat(rng, sm, sk), mat(rng, se, sk, sn, std=0.02)
+        computed = (jnp.arange(sm) < jnp.sum(gs))[:, None]
+        tiling = (moe._SHARE_TM, 1024, 1024)
+        return (lambda a, b, g: jnp.where(
+                    computed, moe._gmm32(a, b, g, tiling), 0),
+                lambda a, b, g: jnp.where(
+                    computed, jax.lax.ragged_dot(a, b, g), 0),
+                (lhs, rhs, gs))
+
     if interpret is False or jax.default_backend() != "cpu":
-        cases.append(("kernel.megablox_gmm", megablox_build))
+        cases += [("kernel.megablox_gmm", megablox_build),
+                  ("kernel.megablox_gmm.share", share_build)]
 
     # -- the LoRA grouped-matmul route (rank on 128 lanes) -------------------
     n_ad, rank = (9, 128) if full else (3, 128)

@@ -48,7 +48,9 @@ __all__ = ["MoELayer", "NaiveGate", "GShardGate", "SwitchGate",
            "moe_dispatch_combine", "moe_dispatch_combine_dropless",
            "moe_dispatch_combine_grouped", "moe_stats",
            "reset_moe_stats", "moe_fused_enabled", "serving_stats_tap",
-           "serving_rows_mask", "ClipGradForMOEByGlobalNorm"]
+           "serving_rows_mask", "serving_share_counts",
+           "group_limited_gate", "moe_share_dispatch_combine",
+           "ClipGradForMOEByGlobalNorm"]
 
 
 from ..nn.clip import ClipGradByGlobalNorm as _ClipGradByGlobalNorm
@@ -463,6 +465,9 @@ def moe_dispatch_combine(x, gate_logits, num_expert, top_k=2,
 # 2.32 with (512,512,512)
 _GMM_TILING = (512, 1024, 512)
 _GMM_TILING_BWD = (512, 512, 512)
+# row tile of a chip's expert-parallel share (megablox wants the pair
+# buffer a whole number of row tiles)
+_SHARE_TM = 128
 
 
 @_functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -535,7 +540,16 @@ def _use_megablox(n_rows, d_in, d_out):
     under the fixed (512, 1024, 512) tiling). Since r6 this predicate
     also gates the PER-SHARD shapes inside the EP shard_map fast path —
     per-shard buffer shapes are static there, so the kernel is legal
-    under expert sharding. CPU test meshes and tiny shapes take the
+    under expert sharding. ``n_rows`` is the STATIC row count of the
+    buffer, not the rows that are live: a chip's expert-parallel share
+    (``moe_share_dispatch_combine``) passes its whole ``rows x k`` pair
+    buffer (4,352 at a 544-row serving tick, of which a sixteenth is
+    live), so a full-size tick takes the kernel and a small engine
+    ragged_dot; which one a serving tick traced is in ``ServingEngine.
+    stats()["moe_grouped_mm_kernel"]`` (``"megablox"``: a device
+    trace and the kernel census name those kernels ``gmm``, the library
+    function's own name inside ``kernel_scope("megablox_gmm")``). CPU
+    test meshes and tiny shapes take the
     ragged_dot path; a shape this gate admits that the kernel then
     refuses to trace or compile is an error, not a fallback."""
     return (jax.default_backend() == "tpu"
@@ -592,16 +606,16 @@ def _grouped_mm_drhs(lhs, g, group_sizes, num_groups):
 
 
 def _expert_swiglu_grouped(xs, gate_up, down, group_sizes, dtype,
-                           allow_pallas=True):
+                           allow_pallas=True, tiling=None):
     """Expert SwiGLU MLP over expert-sorted rows as TWO grouped
     matmuls (``[n, d] x [e, d, 2f] -> [n, 2f]``, swiglu,
     ``[n, f] x [e, f, d] -> [n, d]``)."""
     gu = _grouped_mm(xs, gate_up.astype(dtype), group_sizes,
-                     allow_pallas=allow_pallas)
+                     tiling=tiling, allow_pallas=allow_pallas)
     g, u = jnp.split(gu, 2, axis=-1)
     h = jax.nn.silu(g.astype(jnp.float32)).astype(dtype) * u
     return _grouped_mm(h, down.astype(dtype), group_sizes,
-                       allow_pallas=allow_pallas)
+                       tiling=tiling, allow_pallas=allow_pallas)
 
 
 # ---------------------------------------------------------------------------
@@ -761,6 +775,23 @@ def serving_rows_mask(mask):
         _SERVING_TAP.rows_mask = prev
 
 
+@contextlib.contextmanager
+def serving_share_counts(sink):
+    """Arm ``sink`` (a list) for every expert-parallel share
+    (``moe_share_dispatch_combine``) traced on this thread inside the
+    context: each appends one traced int32 ``[count + 1]`` — the live
+    pairs that fell on each expert held here, then the live rows it
+    routed. The serving engine stacks them into an output of its tick
+    executable, so the counts of a tick reach ``stats()`` and the
+    ``tick`` span with the tick's own fetch and no callback."""
+    prev = getattr(_SERVING_TAP, "share_counts", None)
+    _SERVING_TAP.share_counts = sink
+    try:
+        yield
+    finally:
+        _SERVING_TAP.share_counts = prev
+
+
 def _tap_routing(flat_e, e, top_k, counts):
     """If a serving sink is armed (trace time), emit this dispatch's
     per-expert load fractions and routing entropy (nats) at run time —
@@ -782,6 +813,99 @@ def _tap_routing(flat_e, e, top_k, counts):
                              load * jnp.log(jnp.maximum(load, 1e-12)),
                              0.0))
     jax.debug.callback(sink, load, ent)
+
+
+def group_limited_gate(logits, bias, *, n_group, topk_group, top_k,
+                       norm_topk_prob=True, routed_scaling_factor=1.0):
+    """The sigmoid / bias / group-limited router of the DeepSeek-V3
+    lineage (``noaux_tc``), in float32 as published. ``logits``
+    ``[s, e]`` are the gate's outputs; the scores are their sigmoid;
+    the CHOICE is made on ``scores + bias``
+    (``e_score_correction_bias``): the ``e`` experts lie in ``n_group``
+    consecutive groups, a group's score is the sum of its two largest
+    choice scores, the best ``topk_group`` groups stay (the others'
+    choice scores become 0) and the ``top_k`` largest choice scores
+    inside them are the experts. The WEIGHTS are the chosen experts'
+    scores without the bias, divided by their sum (``norm_topk_prob``),
+    times ``routed_scaling_factor``. Ties go to the lower index.
+    Returns ``(topk_idx [s, k] int32, topk_weight [s, k] f32)``."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    choice = scores + bias.astype(jnp.float32)
+    s, e = scores.shape
+    per = e // n_group
+    group_score = jnp.sum(
+        jax.lax.top_k(choice.reshape(s, n_group, per), 2)[0], axis=-1)
+    _, gidx = jax.lax.top_k(group_score, topk_group)
+    kept = jnp.zeros((s, n_group), bool).at[
+        jnp.arange(s)[:, None], gidx].set(True)
+    choice = jnp.where(jnp.repeat(kept, per, axis=1), choice, 0.0)
+    _, idx = jax.lax.top_k(choice, top_k)
+    w = jnp.take_along_axis(scores, idx, axis=1)
+    if norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * jnp.float32(routed_scaling_factor)
+
+
+def moe_share_dispatch_combine(x, topk_idx, topk_weight, gate_up, down,
+                               *, first, num_expert):
+    """One chip's share of an expert-parallel layer, with no exchange:
+    the layer was routed over all ``num_expert`` experts
+    (``topk_idx`` / ``topk_weight`` ``[s, k]``, e.g. from
+    ``group_limited_gate``) and holds experts ``first .. first +
+    count`` (``gate_up`` ``[count, d, 2f]``, ``down`` ``[count, f,
+    d]``). The (row, expert) pairs that fell on an expert held here
+    are sorted expert-major into a static buffer of ``s * k`` pairs
+    and run through the two grouped matmuls over the ``count`` groups;
+    the buffer's tail — pairs of absent experts, and under
+    ``serving_rows_mask`` the pad rows of a serving tick — belongs to
+    no group, so it is not computed, and it is gated to zero. What the
+    absent experts would have added is left out: ``y [s, d]`` is this
+    chip's part of the layer's routed output, and the parts of all
+    ranks add up to the whole (``tests/test_deepseek_v3.py``). Armed
+    by ``serving_share_counts`` it also reports its live pairs an
+    expert and its live rows."""
+    s, d = x.shape
+    k = topk_idx.shape[1]
+    count = gate_up.shape[0]
+    local = topk_idx.astype(jnp.int32) - jnp.int32(first)
+    held = (local >= 0) & (local < count)
+    mask = getattr(_SERVING_TAP, "rows_mask", None)
+    live = mask if mask is not None and mask.shape[0] == s \
+        else jnp.ones((s,), bool)
+    held = held & live[:, None]
+    # the grouped kernel walks whole row tiles: the pair buffer is
+    # s * k rounded up to one (the extra pairs are absent ones)
+    m = -(-s * k // _SHARE_TM) * _SHARE_TM
+    pad = m - s * k
+    flat_e = jnp.pad(local.reshape(-1), (0, pad))
+    flat_ok = jnp.pad(held.reshape(-1), (0, pad))
+    order, rank, counts = _sort_pairs(flat_e, count, valid=flat_ok)
+    sink = getattr(_SERVING_TAP, "share_counts", None)
+    if sink is not None:
+        sink.append(jnp.concatenate(
+            [counts, jnp.sum(live, dtype=jnp.int32)[None]]))
+    from ..ops.pallas.paged_attention import serving_tp_active
+    from ..profiler import RecordEvent
+    with RecordEvent("moe:dispatch"):
+        xs = x[jnp.minimum(order // k, s - 1)]              # [m, d]
+    # a group here is an expert's few rows of one tick (rows * k /
+    # num_expert on average): row tiles of 128 keep its matmuls under
+    # the time its weights take to arrive
+    tm = 512 if s * k // num_expert >= 512 else _SHARE_TM
+    with RecordEvent("moe:expert_mm"):
+        ys = _expert_swiglu_grouped(
+            xs, gate_up, down, counts, x.dtype,
+            allow_pallas=not serving_tp_active(),
+            tiling=(tm, 1024, 1024))
+    with RecordEvent("moe:combine"):
+        picked = ys[rank[:s * k]].reshape(s, k, d)
+        # rows of the buffer's tail were never computed: select, do not
+        # multiply by a zero weight
+        y = jnp.sum(jnp.where(
+            held[..., None],
+            picked.astype(jnp.float32) * topk_weight[..., None], 0.0),
+            axis=1)
+    return y.astype(x.dtype)
 
 
 def moe_dispatch_combine_dropless(x, gate_logits, num_expert, top_k,

@@ -232,7 +232,10 @@ class GenerationMixin:
         def model_step(params_a, tok_ids, caches, off, mask=None,
                       pos=None, block_tables=None, cache_lens=None,
                       ragged_meta=None):
-            t_caches = [(_wrap_out(k), _wrap_out(v)) for k, v in caches]
+            # a layer's cache is a tuple of arrays: a (k, v) pair, or
+            # the one array of a latent (MLA) cache
+            t_caches = [tuple(_wrap_out(c) for c in layer)
+                        for layer in caches]
             kwargs = {"caches": t_caches}
             if off is not None:
                 kwargs["offset"] = _wrap_out(off)
@@ -257,7 +260,8 @@ class GenerationMixin:
             out, _ = binder.call(
                 params_a, buffers, (_wrap_out(tok_ids),), kwargs)
             logits, new_caches = out
-            new_caches = [(as_jax(k), as_jax(v)) for k, v in new_caches]
+            new_caches = [tuple(as_jax(c) for c in layer)
+                          for layer in new_caches]
             if want_hidden:
                 logits, hidden = logits
                 return (as_jax(logits), as_jax(hidden)), new_caches

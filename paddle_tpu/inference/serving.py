@@ -729,7 +729,7 @@ class _Pipe:
     may feed a pipelined dispatch."""
     __slots__ = ("outs", "active", "given", "n_pending", "q_lens",
                  "rid_of", "pend_pos0", "t_tick", "t_l0", "pure",
-                 "carry", "tick", "dispatch", "attn_grid")
+                 "carry", "tick", "dispatch", "attn_grid", "moe")
 
     def __init__(self, **kw):
         for k in self.__slots__:
@@ -739,7 +739,10 @@ class _Pipe:
 class ServingEngine:
     """Continuous-batching serving over a causal-LM with the paged-KV
     protocol (``init_paged_caches`` + ``block_tables``/``cache_lens``
-    forward kwargs — Llama/Qwen2/GPT families).
+    forward kwargs — Llama/Qwen2/GPT families; a layer's cache is a
+    tuple of pool arrays, the ``(k, v)`` pair or a latent (MLA)
+    cache's one array, and the engine reaches them only through
+    ``ops/paged_cache``'s arity-free walkers).
 
     Usage::
 
@@ -1342,6 +1345,9 @@ class ServingEngine:
             "KV block-pool bytes each shard holds (kv_head slice)")
         pool_bytes = _pc.pool_bytes(self._pools)
         target_pool_bytes = pool_bytes
+        # a latent (MLA) cache is one array a layer
+        self._latent_pool_bytes = pool_bytes \
+            if len(self._pools[0]) == 1 else 0
         if self._draft_model is not None:
             pool_bytes += _pc.pool_bytes(self._dpools)
         self._pool_bytes_per_shard = pool_bytes // self._tp
@@ -1434,6 +1440,14 @@ class ServingEngine:
         self._moe_ent_last = 0.0
         self._moe_load_max_last = 0.0
         self._n_moe_dispatches = 0
+        # a chip's expert-parallel share (distributed/moe.
+        # moe_share_dispatch_combine): live rows routed and live pairs
+        # that fell on experts held here, summed over expert layers
+        # and ticks; the grouped-matmul kernel the tick traced
+        self._n_moe_rows = 0
+        self._n_moe_pairs_local = 0
+        self._moe_gmm_kernel = None
+        self._moe_share_out = False     # the tick returns share counts
         # -- request-lifecycle tracing + SLO latency digests ----------
         # One Tracer per engine (one trace-viewer pid): tid 0 is the
         # engine tick timeline, tid 1+i slot i's request timeline, the
@@ -1842,10 +1856,15 @@ class ServingEngine:
         dtype = pool.dtype
         if not jnp.issubdtype(dtype, jnp.floating):
             dtype = jnp.dtype(getattr(model.config, "dtype", "float32"))
-        return dict(rows=self._rows, w_max=self._wmax,
-                    num_heads=int(heads), num_kv_heads=int(pool.shape[2]),
-                    q_dtype=dtype, block_size=self._bs,
-                    max_blocks=self._mb)
+        geo = dict(rows=self._rows, w_max=self._wmax,
+                   num_heads=int(heads), num_kv_heads=int(pool.shape[2]),
+                   q_dtype=dtype, block_size=self._bs,
+                   max_blocks=self._mb)
+        if len(self._pools[0]) == 1:
+            # a latent (MLA) cache: every head reads the layer's one
+            # array, in the latent kernel's own tile
+            geo.update(num_kv_heads=1, tile=_pa.LATENT_TILE)
+        return geo
 
     def _attn_grid(self, q_lens, context_lens):
         """The ``tick`` span's ``attn_units`` / ``attn_live`` of one
@@ -1857,6 +1876,32 @@ class ServingEngine:
         units, live = _pa.ragged_grid_units(q_lens, context_lens,
                                             **self._attn_geometry)
         return {"attn_units": units, "attn_live": live}
+
+    def _launch_ragged(self, args):
+        """Run THE tick executable: ``(outs, share counts or None)``.
+        Where the model holds an expert-parallel share the executable
+        returns the share's counts as one more output, LAST; it is
+        taken off for this tick's commit, so every other reader of the
+        outputs sees the same tuple for every model."""
+        outs = self._ragged_exec(*args)
+        if self._moe_share_out:
+            return outs[:-1], outs[-1]
+        return outs, None
+
+    def _commit_moe_share(self, counts):
+        """The ``tick`` span's ``moe_pairs`` / ``moe_touched`` /
+        ``moe_hot`` from one tick's share counts (``[expert layers,
+        held + 1]``, fetched once the tick's tokens are), and the
+        running ``stats()`` counters."""
+        if counts is None:
+            return {}
+        counts = np.asarray(counts)
+        pairs = counts[:, :-1]
+        self._n_moe_rows += int(counts[:, -1].sum())
+        self._n_moe_pairs_local += int(pairs.sum())
+        return {"moe_pairs": int(pairs.sum()),
+                "moe_touched": int((pairs > 0).sum()),
+                "moe_hot": int(pairs.max())}
 
     def _trace_tick(self, t_tick, exec_name: str, path: str, **extra):
         """One engine-tick span (tid 0) — ALL three step paths emit
@@ -2433,10 +2478,10 @@ class ServingEngine:
         self._last_dispatch_t = t_l0
         with _quiet_donation():
             if tr is None:
-                outs = self._ragged_exec(*args)
+                outs, moe = self._launch_ragged(args)
             else:
                 with tr.phase("launch", tick=tick, dispatch="packed"):
-                    outs = self._ragged_exec(*args)
+                    outs, moe = self._launch_ragged(args)
         if self._async_on:
             # the pools advance at DISPATCH (device futures): the next
             # launch consumes them before this tick's commit runs
@@ -2457,7 +2502,8 @@ class ServingEngine:
             n_pending=len(pending), q_lens=q_lens, rid_of=rid_of,
             pend_pos0=pend_pos0, t_tick=t_tick, t_l0=t_l0, pure=pure,
             carry=(outs[2], outs[3]) if pure else None,
-            tick=tick, dispatch="packed", attn_grid=attn_grid)
+            tick=tick, dispatch="packed", attn_grid=attn_grid,
+            moe=moe)
         return pipe, emitted
 
     def _ragged_commit(self, pipe, flush=False) -> List[tuple]:
@@ -2499,6 +2545,7 @@ class ServingEngine:
             self._nf_last = bool(outs[k])       # the kill switch only
         if not self._async_on:
             self._pools = outs[k + 1]
+        moe_args = self._commit_moe_share(pipe.moe)
         t_sync = time.monotonic()
         if ph is not None:
             ph.end()
@@ -2573,7 +2620,7 @@ class ServingEngine:
                 pending=pipe.n_pending,
                 occupancy=round(
                     (len(active) + pipe.n_pending) / n_slots, 3),
-                dispatch=pipe.dispatch, **pipe.attn_grid)
+                dispatch=pipe.dispatch, **pipe.attn_grid, **moe_args)
         return emitted
 
     # -- async tick pipeline (docs/OPS.md "Async tick pipeline") ------
@@ -2755,10 +2802,10 @@ class ServingEngine:
         self._last_dispatch_t = t_l0
         with _quiet_donation():
             if tr is None:
-                outs = self._ragged_exec(*args)
+                outs, moe = self._launch_ragged(args)
             else:
                 with tr.phase("launch", tick=tick, dispatch="carry"):
-                    outs = self._ragged_exec(*args)
+                    outs, moe = self._launch_ragged(args)
         self._pools = outs[-1]
         self._m_steps.inc()
         self._n_decode_steps += 1
@@ -2787,7 +2834,8 @@ class ServingEngine:
             q_lens=q_lens, rid_of=dict(prev.rid_of), pend_pos0={},
             t_tick=t_tick, t_l0=t_l0, pure=True,
             carry=(outs[2], outs[3]), tick=tick, dispatch="carry",
-            attn_grid=self._attn_grid(q_lens, ctx))
+            attn_grid=self._attn_grid(q_lens, ctx),
+            moe=moe)
 
     def _flush_pipe(self) -> List[tuple]:
         """Commit any in-flight pipelined tick NOW. Every
@@ -2950,6 +2998,15 @@ class ServingEngine:
             "moe_routing_entropy": self._moe_ent_last,
             "moe_expert_load_max": self._moe_load_max_last,
             "moe_dispatches": self._n_moe_dispatches,
+            # a chip's expert-parallel share: live rows routed and the
+            # pairs that fell on experts held here (both summed over
+            # expert layers); the grouped-matmul kernel the tick
+            # traced ("megablox" | "ragged_dot" | None); the bytes of
+            # a latent (MLA) cache's pools (0 for k/v pairs)
+            "moe_rows": self._n_moe_rows,
+            "moe_pairs_local": self._n_moe_pairs_local,
+            "moe_grouped_mm_kernel": self._moe_gmm_kernel,
+            "latent_pool_bytes": self._latent_pool_bytes,
             # request-lifecycle tracing + SLO latency digests: ALWAYS
             # present (zeroed summaries on an idle engine; the digests
             # run regardless of the PADDLE_TPU_TRACE kill switch) —
@@ -4512,7 +4569,7 @@ class ServingEngine:
                     return _pc.QuantKV(jax.device_put(x.data, dsh),
                                        jax.device_put(x.scale, ssh))
                 return jax.device_put(x, dsh)
-        return [(d(k), d(v)) for k, v in payload]
+        return [tuple(d(x) for x in rows) for rows in payload]
 
     def _spill_evicted(self, b, h):
         """Allocator eviction hook (``BlockAllocator.on_evict``): an
@@ -5296,6 +5353,7 @@ class ServingEngine:
         pad = self._pad
         n_slots = self.config.num_slots
         overflow = self._overflow
+        share = []      # what expert-parallel shares report, per layer
 
         def ragged(params, pools, tables, rows_pack, slots_pack, *rest):
             if lora_on:
@@ -5340,6 +5398,7 @@ class ServingEngine:
                         gmm_ok=lora_gmm_ok))
                 ctx.enter_context(
                     _moe.serving_rows_mask(row_pos < self._overflow))
+                ctx.enter_context(_moe.serving_share_counts(share))
                 logits, pools = step(
                     params, ids[None, :], pools, None,
                     block_tables=tables, cache_lens=base,
@@ -5469,9 +5528,21 @@ class ServingEngine:
                 axis=1).astype(jnp.int32)
             return first_tok, out, accept, props, nf, pools
 
-        jitted = jax.jit(ragged, donate_argnums=(1,))
+        def ragged_tick(*a):
+            outs = ragged(*a)
+            if not share:
+                return outs
+            # one more output, LAST: [expert layers, held + 1] live
+            # pairs an expert held here, then live rows; _launch_ragged
+            # takes it off again
+            self._moe_share_out = True
+            return tuple(outs) + (jnp.stack(share),)
+
+        jitted = jax.jit(ragged_tick, donate_argnums=(1,))
         name = "verify" if g else "decode"
         exec_ = self._aot_compile(name, jitted, args)
+        if self._moe:
+            self._moe_gmm_kernel = _moe.MOE_STATS["grouped_mm_kernel"]
         if self._mesh is not None:
             self._tp_step_bytes = self._tp_census_bytes(name)
             if g and self._draft_model is not None:

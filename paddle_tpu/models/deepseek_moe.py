@@ -1,6 +1,10 @@
-"""DeepSeek-MoE model family (PaddleNLP ``paddlenlp/transformers/
-deepseek_v2/modeling.py`` fine-grained-expert lineage) — BASELINE
-config 5 second entry.
+"""DeepSeek-MoE (the first, 16B generation) model family — BASELINE
+config 5 second entry: DENSE grouped-query attention (the shared
+``LlamaAttention``, per-head K and V in the cache) with softmax-routed
+fine-grained experts. It is NOT the ``deepseek_v2`` / ``deepseek_v3``
+lineage, whatever an earlier docstring said: those have latent (MLA)
+attention, a latent cache and a sigmoid group-limited router, and live
+in ``models/deepseek_v3.py``.
 
 Architecture signatures vs Qwen2-MoE: the first ``first_k_dense_replace``
 layers use a dense MLP; sparse layers combine fine-grained routed experts

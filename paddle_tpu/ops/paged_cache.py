@@ -63,7 +63,8 @@ import numpy as np
 
 __all__ = ["NULL_BLOCK", "BlockAllocator", "blocks_for", "init_pool",
            "write_prefill", "write_decode", "write_tokens",
-           "write_rows", "gather_dense", "chain_hashes",
+           "write_rows", "scatter_rows", "init_latent_pool",
+           "gather_dense", "chain_hashes",
            "iter_chain_hashes", "copy_blocks", "pool_sharding",
            "pool_head_slice", "ragged_row_meta", "QuantKV",
            "kv_quantize", "kv_dequantize", "resolve_kv_cache_dtype",
@@ -173,9 +174,23 @@ def resolve_kv_cache_dtype(requested=None):
 
 
 def pool_bytes(pools) -> int:
-    """Total bytes of a per-layer ``[(k, v), ...]`` pool list — int8
-    pools count data AND scales (telemetry/bench accounting)."""
-    return sum(int(kp.nbytes) + int(vp.nbytes) for kp, vp in pools)
+    """Total bytes of a per-layer pool list — ``[(k, v), ...]`` pairs
+    or a latent cache's ``[(c,), ...]`` — int8 pools count data AND
+    scales (telemetry/bench accounting)."""
+    return sum(int(p.nbytes) for layer in pools for p in layer)
+
+
+def _each(fn, *layers):
+    """``fn`` over every array of a layer's cache, whatever its arity:
+    a layer's cache is a tuple of arrays (or ``QuantKV``) whose leading
+    dims are ``[NB, BS]`` — the ``(k_pool, v_pool)`` pair of per-head
+    attention, the one ``(latent_pool,)`` of latent (MLA) attention.
+    With a payload beside the pools the two must agree in arity."""
+    if len({len(layer) for layer in layers}) != 1:
+        raise ValueError(
+            "layer cache arity mismatch: "
+            f"{[len(layer) for layer in layers]} arrays a layer")
+    return tuple(fn(*ps) for ps in zip(*layers))
 
 
 class BlockAllocator:
@@ -472,6 +487,22 @@ def _sharded_zeros(shape, dtype, sharding):
                    out_shardings=sharding)
 
 
+def init_latent_pool(num_blocks: int, block_size: int, width: int,
+                     dtype) -> tuple:
+    """Zeroed ``(latent_pool,)`` of latent (MLA) attention: ONE array
+    ``[num_blocks, block_size, W]`` a layer holding each position's
+    compressed key/value ``c_kv`` and its shared rotary key ``k_pe``
+    side by side, ``W`` = ``width`` rounded up to whole 128-lane tiles
+    (the pad lanes stay zero). That is the layout HBM gives a minor
+    dim anyway and the one the latent attention kernel copies from
+    (``ops/pallas/paged_attention.py``), so no op of a serving tick
+    relays the pool out. Every head reads the same latent, so there is
+    no head axis to shard: under tensor parallelism the pool is
+    replicated."""
+    lanes = -(-int(width) // 128) * 128
+    return (jnp.zeros((num_blocks, block_size, lanes), dtype),)
+
+
 def pool_sharding(mesh):
     """The tensor-parallel pool placement: ``[NB, BS, H_kv, D]`` split
     on the kv_heads dim over the mesh's ``mp`` axis. Every shard holds
@@ -597,19 +628,19 @@ def write_tokens(k_pool, v_pool, block_tables, cache_lens, k_new, v_new):
     return _store(k_pool, bi, off, k_new), _store(v_pool, bi, off, v_new)
 
 
-def write_rows(k_pool, v_pool, block_tables, row_slot, row_pos,
-               k_new, v_new):
-    """Append a RAGGED mixed batch: row ``r`` of ``k_new/v_new``
-    ([R, H_kv, D]) lands at cache position ``row_pos[r]`` of slot
-    ``row_slot[r]`` — the per-row generalization of ``write_decode``
-    (every row its own slot) and ``write_tokens`` (a slot may own any
-    number of consecutive rows). One scatter serves decode rows
-    (1/slot), speculative verify windows (gamma+1/slot) and prefill
-    chunk rows in a single launch. Pad rows carry an overflow
-    ``row_pos`` (past the table's reach) and are routed to the null
-    block, so the packed buffer's static width never writes anything
-    live."""
-    bs = k_pool.shape[1]
+def scatter_rows(layer, block_tables, row_slot, row_pos, news):
+    """Append a RAGGED mixed batch to one layer's cache, whatever its
+    arity: row ``r`` of each array of ``news`` (``[R, ...]``, the
+    trailing dims of its pool) lands at cache position ``row_pos[r]``
+    of slot ``row_slot[r]`` — the per-row generalization of
+    ``write_decode`` (every row its own slot) and ``write_tokens`` (a
+    slot may own any number of consecutive rows). One scatter serves
+    decode rows (1/slot), speculative verify windows (gamma+1/slot)
+    and prefill chunk rows in a single launch. Pad rows carry an
+    overflow ``row_pos`` (past the table's reach) and are routed to
+    the null block, so the packed buffer's static width never writes
+    anything live. Returns the layer's updated tuple."""
+    bs = layer[0].shape[1]
     mb = block_tables.shape[1]
     pos = row_pos.astype(jnp.int32)
     slot = row_slot.astype(jnp.int32)
@@ -617,7 +648,16 @@ def write_rows(k_pool, v_pool, block_tables, row_slot, row_pos,
     bi = block_tables.astype(jnp.int32)[slot, jnp.minimum(blk, mb - 1)]
     bi = jnp.where((pos >= 0) & (blk < mb), bi, NULL_BLOCK)   # [R]
     off = pos % bs
-    return _store(k_pool, bi, off, k_new), _store(v_pool, bi, off, v_new)
+    return _each(lambda pool, rows: _store(pool, bi, off, rows),
+                 tuple(layer), tuple(news))
+
+
+def write_rows(k_pool, v_pool, block_tables, row_slot, row_pos,
+               k_new, v_new):
+    """``scatter_rows`` on a ``(k_pool, v_pool)`` pair: ``k_new/v_new``
+    are ``[R, H_kv, D]``."""
+    return scatter_rows((k_pool, v_pool), block_tables, row_slot,
+                        row_pos, (k_new, v_new))
 
 
 def permute_window(k_pool, v_pool, block_tables, cache_lens, perm,
@@ -707,7 +747,8 @@ def ragged_row_meta(q_lens, base_lens, total_rows, overflow_pos):
 
 def copy_blocks(pools, src, dst):
     """Copy-on-write device op: duplicate block ``src`` into ``dst``
-    across every layer's (k_pool, v_pool) pair. ``src``/``dst`` are
+    across every array of every layer's cache (a pair, or a latent
+    cache's one array). ``src``/``dst`` are
     traced int32 scalars, so ONE jitted executable (donate the pools)
     serves every COW — the cost is a single block's K/V bytes per
     layer, no host roundtrip. The caller then swaps ``dst`` into the
@@ -720,14 +761,15 @@ def copy_blocks(pools, src, dst):
                            pool.scale.at[dst].set(pool.scale[src]))
         return pool.at[dst].set(pool[src])
 
-    return [(cp(kp), cp(vp)) for kp, vp in pools]
+    return [_each(cp, layer) for layer in pools]
 
 
 def export_blocks(pools, block_ids):
     """Disaggregated prefill->decode transfer, read side: gather the
     SELF-CONTAINED bytes of ``block_ids`` ([M] int32, padded with the
-    null block) out of every layer's (k, v) pool — fp pools as
-    ``[M, BS, H_kv, D]`` rows in the pool dtype, int8 pools as a
+    null block) out of every array of every layer's cache — fp pools as
+    ``[M, BS, ...]`` rows in the pool dtype (``[M, BS, H_kv, D]`` of a
+    k or v pool, ``[M, BS, W]`` of a latent pool), int8 pools as a
     :class:`QuantKV` of data ``[M, BS, H_kv, D]`` + scales
     ``[M, BS, H_kv]`` (a quantized block's bytes are self-contained
     thanks to the per-row scales, so data + scales IS the block). A
@@ -743,7 +785,7 @@ def export_blocks(pools, block_ids):
             return QuantKV(pool.data[ids], pool.scale[ids])
         return pool[ids]
 
-    return [(gx(kp), gx(vp)) for kp, vp in pools]
+    return [_each(gx, layer) for layer in pools]
 
 
 def import_blocks(pools, block_ids, payload):
@@ -777,8 +819,8 @@ def import_blocks(pools, block_ids, payload):
         raise ValueError(
             f"import_blocks: payload has {len(payload)} layers, pool "
             f"has {len(pools)}")
-    return [(sx(kp, kr), sx(vp, vr))
-            for (kp, vp), (kr, vr) in zip(pools, payload)]
+    return [_each(sx, layer, rows)
+            for layer, rows in zip(pools, payload)]
 
 
 def payload_to_host(payload):
@@ -793,14 +835,14 @@ def payload_to_host(payload):
             return QuantKV(np.asarray(x.data), np.asarray(x.scale))
         return np.asarray(x)
 
-    return [(h(k), h(v)) for k, v in payload]
+    return [_each(h, rows) for rows in payload]
 
 
 def payload_nbytes(payload) -> int:
     """Total bytes of an export/spill payload (int8: data + scales) —
     the host-tier accounting unit and the swap half of the
     recompute-vs-swap cost model."""
-    return sum(int(k.nbytes) + int(v.nbytes) for k, v in payload)
+    return pool_bytes(payload)
 
 
 def payload_rows(payload, n: int):
@@ -813,7 +855,7 @@ def payload_rows(payload, n: int):
             return QuantKV(x.data[:n], x.scale[:n])
         return x[:n]
 
-    return [(s(k), s(v)) for k, v in payload]
+    return [_each(s, rows) for rows in payload]
 
 
 def payload_pad(payload, m: int):
@@ -831,7 +873,7 @@ def payload_pad(payload, m: int):
                        np.asarray(x).dtype)
         return np.concatenate([np.asarray(x), pad], axis=0)
 
-    return [(p(k), p(v)) for k, v in payload]
+    return [_each(p, rows) for rows in payload]
 
 
 class HostKVTier:
@@ -941,7 +983,8 @@ class HostKVTier:
 
 
 def gather_dense(pool, block_tables):
-    """[S, MB*BS, H_kv, D] dense view of each slot's cache (positions
+    """[S, MB*BS, H_kv, D] dense view of each slot's cache (a latent
+    pool ``[NB, BS, W]`` gives ``[S, MB*BS, W]``; positions
     beyond the slot's length read whatever the pooled blocks hold — the
     caller masks by length). The jnp fallback attention and tests use
     this; the TPU kernel never materializes it. Quantized pools come
@@ -954,4 +997,4 @@ def gather_dense(pool, block_tables):
         g = kv_dequantize(pool.data[tables], pool.scale[tables])
     else:
         g = pool[tables]                        # [S, MB, BS, H, D]
-    return g.reshape(s, mb * pool.shape[1], pool.shape[2], pool.shape[3])
+    return g.reshape((s, mb * pool.shape[1]) + tuple(pool.shape[2:]))

@@ -123,7 +123,9 @@ __all__ = ["paged_decode_attention", "pallas_paged_attention",
            "sharded_ragged_attention_step", "kernel_fallback_counts",
            "tp_shard_degree", "serving_tp_scope",
            "serving_tp_active", "tree_ancestor_bits",
-           "spec_tree_scope"]
+           "spec_tree_scope", "ragged_latent_attention",
+           "pallas_ragged_latent_attention",
+           "ragged_latent_attention_step", "LATENT_TILE"]
 
 NEG_INF = np.float32(-1e30)
 
@@ -371,19 +373,31 @@ _TILE_ROWS = 128
 _TILE_POSITIONS = 128
 
 
-def _ragged_geometry(rows, slots, rep, q_dtype, block_size, max_blocks):
+# the latent (MLA) kernel's tile: every head reads the one latent, so a
+# window token brings all its heads (64 rows at the published widths);
+# eight tokens' rows against 512 positions keep the latent's re-reads
+# and the online softmax's rescaling well under a tile's two products.
+# On one v5e, a 512-row chunk at context 2k beside 20 decode rows, 64
+# heads: 2.32 ms a call against 2.96 at (256, 256), 3.66 at (128, 256),
+# 3.20 at (1024, 256); a decode-only tick pays 0.98 against 0.88 for
+# the taller tile's dead rows (chip run, PR 26)
+LATENT_TILE = (512, 512)
+
+
+def _ragged_geometry(rows, slots, rep, q_dtype, block_size, max_blocks,
+                     tile=(_TILE_ROWS, _TILE_POSITIONS)):
     """Static index space of one ragged call, from what the operands
     show: ``(rp, tq, kb, n_tiles, n_kv)`` — ``rp`` rows a kv group's
     heads pad to (``_row_pad``), ``tq`` window rows of one slot a query
-    tile holds (``tq * rp`` = ``_TILE_ROWS``, within
+    tile holds (``tq * rp`` = ``tile[0]``, within
     ``_MAX_GROUP_ROWS``), ``kb`` pool blocks a kv tile chases
-    (``kb * block_size`` = ``_TILE_POSITIONS`` where the block is
+    (``kb * block_size`` = ``tile[1]`` where the block is
     smaller), ``n_tiles = slots + ceil(rows / tq)`` query tiles launched
     (every split of ``rows`` over ``slots`` fits: each slot wastes less
     than one tile) and ``n_kv`` kv tiles a full table holds."""
     rp = _row_pad(rep, q_dtype)
-    tq = max(1, _TILE_ROWS // rp)
-    kb = max(1, min(_TILE_POSITIONS // block_size, max_blocks))
+    tq = max(1, tile[0] // rp)
+    kb = max(1, min(tile[1] // block_size, max_blocks))
     return (rp, tq, kb, slots + -(-rows // tq), -(-max_blocks // kb))
 
 
@@ -415,11 +429,13 @@ def _ragged_tiles(xp, ql, context_lens, tq, kv_span, n_tiles, n_kv):
 
 
 def ragged_grid_units(q_lens, context_lens, *, rows, w_max, num_heads,
-                      num_kv_heads, q_dtype, block_size, max_blocks):
+                      num_kv_heads, q_dtype, block_size, max_blocks,
+                      tile=(_TILE_ROWS, _TILE_POSITIONS)):
     """Host-side count of what ONE ``pallas_ragged_paged_attention``
-    call visits for this tick's ``q_lens`` / ``context_lens`` (numpy,
-    no device read): ``(units, live)`` in (query tile, kv head, kv
-    tile) units. A live tile's grid step runs one loop iteration per
+    call (or, with ``num_kv_heads=1`` and ``tile=LATENT_TILE``, one
+    ``pallas_ragged_latent_attention`` call) visits for this tick's
+    ``q_lens`` / ``context_lens`` (numpy, no device read):
+    ``(units, live)`` in (query tile, kv head, kv tile) units. A live tile's grid step runs one loop iteration per
     kv tile of its walk, all live; a tile past the live count is still
     one launched grid step, predicated off — so ``units - live`` is
     the dead steps and ``live / units`` the share of the walk that is
@@ -427,7 +443,7 @@ def ragged_grid_units(q_lens, context_lens, *, rows, w_max, num_heads,
     ql = np.minimum(np.asarray(q_lens, np.int64), w_max)
     _, tq, kb, n_tiles, n_kv = _ragged_geometry(
         rows, ql.shape[0], num_heads // num_kv_heads, q_dtype,
-        block_size, max_blocks)
+        block_size, max_blocks, tile)
     _, _, _, kv = _ragged_tiles(
         np, ql, np.asarray(context_lens, np.int64), tq,
         kb * block_size, n_tiles, n_kv)
@@ -770,7 +786,13 @@ def pallas_ragged_paged_attention(q, k_pool, v_pool, block_tables,
                    lens, *tree_args, q4, kd, vd, *scales)
     out = out.reshape(n_tiles, hkv, tq, rp, d)[:, :, :, :rep] \
         .transpose(0, 2, 1, 3, 4).reshape(n_tiles * tq, h, d)
-    # packed row -> (its slot's tile, row in the tile)
+    return _untile(out, starts, ql, tq, r)
+
+
+def _untile(out, starts, ql, tq, r):
+    """Tile-ordered rows ``[n_tiles * tq, ...]`` back to the packed
+    buffer's ``[R, ...]``: packed row -> (its slot's tile, row in the
+    tile); rows no slot owns come back zero."""
     row = jnp.arange(r, dtype=jnp.int32)[:, None]
     owned = (row >= starts[None, :]) & (row < (starts + ql)[None, :])
     owner = jnp.argmax(owned, axis=1).astype(jnp.int32)
@@ -781,6 +803,153 @@ def pallas_ragged_paged_attention(q, k_pool, v_pool, block_tables,
     has = jnp.any(owned, axis=1)
     return jnp.where(has[:, None, None],
                      out[jnp.where(has, back, 0)], 0)
+
+
+def _latent_kernel(tslot_ref, trow_ref, tkv_ref, tables_ref, lens_ref,
+                   q_ref, c_hbm, o_ref, c_buf, sems, m_scr, l_scr,
+                   acc_scr, *, scale, block_size, kv_blocks, max_blocks,
+                   value_dim, row_shift):
+    """Latent (MLA) ragged body: grid ``(query tile,)``. Every head of
+    a window token reads the SAME cached row — the compressed
+    ``c_kv`` and the shared rotary key ``k_pe`` side by side, ``W``
+    lanes — so a step holds ``tq`` consecutive window rows of ONE slot
+    with all their heads (``[tq * rp, W]``, row ``r`` = window token
+    ``trow_ref[t] + (r >> row_shift)``, the absorbed query: ``q_nope
+    W_UK`` beside ``q_pe``) and walks the slot's cache as
+    ``_ragged_kernel`` does: ``tkv_ref[t]`` tiles of ``kv_blocks``
+    pool blocks, chased through ``tables_ref[slot]`` by double-buffered
+    async copies out of the HBM pool. One copied tile serves both
+    products: the scores contract all ``W`` lanes, the values are the
+    tile's first ``value_dim`` lanes."""
+    t = pl.program_id(0)
+    slot = tslot_ref[t]
+    row0 = trow_ref[t]
+    n_kv = tkv_ref[t]
+    lens = lens_ref[slot]
+    bs, kb = block_size, kv_blocks
+
+    def copies(j, buf):
+        out = []
+        for i in range(kb):
+            blk = tables_ref[slot, jnp.minimum(j * kb + i,
+                                               max_blocks - 1)]
+            out.append(pltpu.make_async_copy(
+                c_hbm.at[blk], c_buf.at[buf, pl.ds(i * bs, bs), :],
+                sems.at[buf]))
+        return out
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(n_kv > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    def walk(j, carry):
+        buf = jax.lax.rem(j, 2)
+
+        @pl.when(j + 1 < n_kv)
+        def _next():
+            for c in copies(j + 1, 1 - buf):
+                c.start()
+
+        for c in copies(j, buf):
+            c.wait()
+        q = q_ref[0]                          # [tq * rp, W]
+        c_tile = c_buf[buf]                   # [kb * BS, W]
+        sc = jax.lax.dot_general(
+            q, c_tile, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        cols = j * (kb * bs) + jax.lax.broadcasted_iota(
+            jnp.int32, sc.shape, 1)
+        node = row0 + (jax.lax.broadcasted_iota(
+            jnp.int32, sc.shape, 0) >> row_shift)
+        sc = jnp.where(cols < lens + node, sc, NEG_INF)
+        m_prev = m_scr[:, :1]
+        l_prev = l_scr[:, :1]
+        m_cur = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.exp(sc - m_cur)
+        alpha = jnp.exp(m_prev - m_cur)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(c_tile.dtype), c_tile[:, :value_dim],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        acc_scr[:] = alpha * acc_scr[:] + pv
+        m_scr[:] = jnp.broadcast_to(m_cur, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        return carry
+
+    jax.lax.fori_loop(0, n_kv, walk, 0)
+    l = l_scr[:, :1]
+    safe_l = jnp.where(l == 0.0, np.float32(1.0), l)
+    o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
+
+
+def pallas_ragged_latent_attention(q, pool, block_tables, context_lens,
+                                   q_lens, row_starts, w_max, value_dim,
+                                   sm_scale, interpret=None):
+    """Ragged mixed-batch attention over a latent (MLA) pool. q:
+    ``[R, H, W]`` — the absorbed queries of every live row of a tick,
+    laid out as the pool's rows are (``q_nope W_UK`` on the
+    ``c_kv`` lanes, ``q_pe`` on the ``k_pe`` lanes, zeros on the pad
+    lanes); pool: ``[NB, BS, W]``, ``W`` a whole number of 128-lane
+    tiles; the slot partition (``q_lens`` / ``row_starts`` /
+    ``context_lens`` / ``w_max``) as in
+    ``pallas_ragged_paged_attention``, whose tiling (``_ragged_tiles``)
+    this shares, with all ``H`` heads of a token in one tile. Returns
+    ``[R, H, value_dim]``: per head the softmax-weighted sum of the
+    cached rows' first ``value_dim`` lanes (``u_h``, still to be
+    expanded by ``W_UV``); rows no slot owns come back zero."""
+    r, h, wd = q.shape
+    nb, bs, _ = pool.shape
+    s, mb = block_tables.shape
+    rp, tq, kb, n_tiles, n_kv = _ragged_geometry(
+        r, s, h, q.dtype, bs, mb, LATENT_TILE)
+    ql = jnp.minimum(q_lens.astype(jnp.int32), int(w_max))
+    starts = row_starts.astype(jnp.int32)
+    lens = context_lens.astype(jnp.int32)
+    tslot, trow, _, tkv = (a.astype(jnp.int32) for a in _ragged_tiles(
+        jnp, ql, lens, tq, kb * bs, n_tiles, n_kv))
+    src = jnp.clip((starts[tslot] + trow)[:, None]
+                   + jnp.arange(tq, dtype=jnp.int32)[None, :], 0, r - 1)
+    rows = tq * rp
+    q3 = _pad_rep(q[src], rp).reshape(n_tiles, rows, wd)
+    kernel = functools.partial(
+        _latent_kernel, scale=np.float32(sm_scale), block_size=bs,
+        kv_blocks=kb, max_blocks=mb, value_dim=value_dim,
+        row_shift=rp.bit_length() - 1)
+
+    def q_block(t, *prefetch):
+        return (t, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(n_tiles,),
+        in_specs=[pl.BlockSpec((1, rows, wd), q_block),
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=pl.BlockSpec((1, rows, value_dim), q_block),
+        scratch_shapes=[pltpu.VMEM((2, kb * bs, wd), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))]
+        + _softmax_scratch(rows, value_dim),
+    )
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_tiles, rows, value_dim),
+                                       q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=_interpret() if interpret is None else interpret,
+    )
+    with kernel_scope("ragged_latent_attention"):
+        out = call(tslot, trow, tkv, block_tables.astype(jnp.int32),
+                   lens, q3, pool)
+    out = out.reshape(n_tiles, tq, rp, value_dim)[:, :, :h] \
+        .reshape(n_tiles * tq, h, value_dim)
+    return _untile(out, starts, ql, tq, r)
 
 
 # ---------------------------------------------------------------------------
@@ -872,11 +1041,37 @@ def _xla_paged_verify(q, k_pool, v_pool, block_tables, context_lens,
 def _xla_ragged_paged(q, k_pool, v_pool, block_tables, context_lens,
                       q_lens, row_starts, row_slot, w_narrow, w_max,
                       sm_scale=None, tree_anc=None, tree_slots=None):
-    """Ragged gather fallback in TWO lanes, both pure
-    ``_xla_paged_verify`` calls so every live row stays BITWISE the
-    sequential per-width fallback's output (softmax rows are
-    independent — the batched window width never changes a value;
-    test-pinned in f32 AND bf16):
+    """Ragged gather fallback in TWO lanes (``_xla_ragged_lanes``),
+    both pure ``_xla_paged_verify`` calls so every live row stays
+    BITWISE the sequential per-width fallback's output (softmax rows
+    are independent — the batched window width never changes a value;
+    test-pinned in f32 AND bf16).
+
+    ``tree_anc`` + ``tree_slots`` route the flagged slots' narrow-lane
+    windows through the ancestor-path tree mask (``w_narrow`` must
+    equal the tree's node count); the wide lane — always a prefill
+    chunk, never a verify window — stays linear."""
+    tree_rows = None
+    if tree_anc is not None and tree_slots is not None:
+        tree_rows = tree_slots
+
+    def verify(q4, tables, lens, narrow):
+        if not narrow:
+            return _xla_paged_verify(q4, k_pool, v_pool, tables, lens,
+                                     sm_scale=sm_scale)
+        return _xla_paged_verify(q4, k_pool, v_pool, tables, lens,
+                                 sm_scale=sm_scale, tree_anc=tree_anc,
+                                 tree_rows=tree_rows)
+
+    return _xla_ragged_lanes(q, verify, block_tables, context_lens,
+                             q_lens, row_starts, row_slot, w_narrow,
+                             w_max)
+
+
+def _xla_ragged_lanes(q, verify, block_tables, context_lens, q_lens,
+                      row_starts, row_slot, w_narrow, w_max):
+    """The two lanes of a ragged gather fallback over
+    ``verify(q [S', T, H, D], tables [S', MB], lens [S'], narrow)``:
 
     - **narrow lane**: every slot's first ``w_narrow`` rows (the
       decode / speculative-verify width, ``gamma + 1``) as one padded
@@ -890,12 +1085,7 @@ def _xla_ragged_paged(q, k_pool, v_pool, block_tables, context_lens,
     Attention FLOPs therefore scale with ``S * w_narrow + w_max`` —
     the live row count — instead of the ``S * w_max`` a naively padded
     layout would pay on every decode-only tick. Pad/dead rows produce
-    garbage the caller discards.
-
-    ``tree_anc`` + ``tree_slots`` route the flagged slots' narrow-lane
-    windows through the ancestor-path tree mask (``w_narrow`` must
-    equal the tree's node count); the wide lane — always a prefill
-    chunk, never a verify window — stays linear."""
+    garbage the caller discards."""
     r, h, d = q.shape
     s = block_tables.shape[0]
     wn = int(w_narrow)
@@ -913,12 +1103,7 @@ def _xla_ragged_paged(q, k_pool, v_pool, block_tables, context_lens,
     q_pad = q_pad.at[jnp.where(nar, slot, s),
                      jnp.where(nar, jnp.minimum(local, wn - 1),
                                0)].set(q)
-    tree_rows = None
-    if tree_anc is not None and tree_slots is not None:
-        tree_rows = tree_slots
-    out_n = _xla_paged_verify(q_pad[:s], k_pool, v_pool, block_tables,
-                              context_lens, sm_scale=sm_scale,
-                              tree_anc=tree_anc, tree_rows=tree_rows)
+    out_n = verify(q_pad[:s], block_tables, context_lens, True)
     out = out_n[jnp.clip(slot, 0, s - 1),
                 jnp.clip(local, 0, wn - 1)]                    # [R,H,D]
     if w <= wn:
@@ -930,10 +1115,8 @@ def _xla_ragged_paged(q, k_pool, v_pool, block_tables, context_lens,
         ws = starts[wide]
         rows_idx = jnp.clip(ws + jnp.arange(w, dtype=jnp.int32),
                             0, r - 1)
-        out_w = _xla_paged_verify(
-            q[rows_idx][None], k_pool, v_pool,
-            block_tables[wide][None], context_lens[wide][None],
-            sm_scale=sm_scale)[0]                              # [W,H,D]
+        out_w = verify(q[rows_idx][None], block_tables[wide][None],
+                       context_lens[wide][None], False)[0]     # [W,H,D]
         use_w = (slot == wide) & (lens32[wide] > wn) & live
         return jnp.where(use_w[:, None, None],
                          out_w[jnp.clip(local, 0, w - 1)], o)
@@ -945,6 +1128,27 @@ def _xla_ragged_paged(q, k_pool, v_pool, block_tables, context_lens,
     # cost the per-width verify, not verify + a dead chunk pass
     return jax.lax.cond(jnp.max(lens32) > wn, _with_wide,
                         lambda o: o, out)
+
+
+def _xla_latent_verify(q, pool, block_tables, context_lens, value_dim,
+                       sm_scale):
+    """The latent kernel's XLA mirror for one ``[S, T]`` window: the
+    dense per-slot view of the pooled rows, scores over all ``W``
+    lanes in f32, causal per window token, values = the rows' first
+    ``value_dim`` lanes. q ``[S, T, H, W]`` -> ``[S, T, H, value_dim]``."""
+    from ..paged_cache import gather_dense
+    t = q.shape[1]
+    c = gather_dense(pool, block_tables).astype(q.dtype)    # [S, L, W]
+    scores = jnp.einsum("sthw,slw->shtl", q, c,
+                        preferred_element_type=jnp.float32) \
+        * np.float32(sm_scale)
+    pos = jnp.arange(c.shape[1], dtype=jnp.int32)
+    bound = context_lens.astype(jnp.int32)[:, None] \
+        + jnp.arange(t, dtype=jnp.int32)[None, :]
+    allow = pos[None, None, :] < bound[:, :, None]          # [S, T, L]
+    scores = scores + jnp.where(allow, 0.0, -1e9)[:, None]
+    w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("shtl,slv->sthv", w, c[..., :value_dim])
 
 
 # query rows one kv group may bring to a grid step (window tokens x
@@ -1133,6 +1337,62 @@ def ragged_attention_step(qh, kh, vh, k_pool, v_pool, block_tables,
                                  sm_scale=sm_scale, tree_anc=tree_anc,
                                  tree_slots=tree_slots)
     return out, kp2, vp2
+
+
+def ragged_latent_attention(q, pool, block_tables, context_lens,
+                            q_lens, row_starts, row_slot, narrow_iota,
+                            win_iota, value_dim, sm_scale):
+    """Ragged mixed-batch attention over a latent (MLA) pool — the
+    one-array counterpart of ``ragged_paged_attention``, same slot
+    partition and iotas. Routes to ``pallas_ragged_latent_attention``
+    on TPU (or under ``PADDLE_TPU_PAGED_KERNEL=interpret``), to the
+    two-lane XLA mirror elsewhere. On a TPU backend a pool the kernel
+    cannot copy from (``W`` not whole lane tiles, ``BS`` not whole
+    sublane tiles) is counted as a fallback like the others."""
+    wn = int(narrow_iota.shape[0])
+    w = int(win_iota.shape[0])
+    on_tpu = jax.default_backend() == "tpu"
+    sublanes = 32 // jnp.dtype(pool.dtype).itemsize
+    ok = (pool.shape[2] % 128 == 0 and pool.shape[1] % sublanes == 0
+          and value_dim % 128 == 0
+          and LATENT_TILE[0] >= _row_pad(q.shape[1], q.dtype))
+    if (on_tpu or _force_kernel_routing()) and ok:
+        return pallas_ragged_latent_attention(
+            q, pool, block_tables, context_lens, q_lens, row_starts, w,
+            value_dim, sm_scale)
+    if on_tpu:
+        _warn_fallback("ragged_latent_attention", q.shape, pool.shape)
+
+    def verify(q4, tables, lens, _narrow):
+        return _xla_latent_verify(q4, pool, tables, lens, value_dim,
+                                  sm_scale)
+
+    return _xla_ragged_lanes(q, verify, block_tables, context_lens,
+                             q_lens, row_starts, row_slot, wn, w)
+
+
+def ragged_latent_attention_step(q, c_new, layer, block_tables,
+                                 cache_lens, q_lens, row_starts,
+                                 row_slot, row_pos, narrow_iota,
+                                 win_iota, value_dim, sm_scale):
+    """Write + attend of the ragged tick over a latent cache: scatter
+    this tick's rows ``c_new`` ``[R, W]`` into the layer's one pool at
+    ``(row_slot, row_pos)`` (pad rows null-route) and attend the
+    absorbed queries ``q`` ``[R, H, W]`` against each slot's
+    length-bounded block list. ``layer`` is the layer's cache, a
+    1-tuple. Returns ``(out [R, H, value_dim], (pool,))``. Tree
+    speculation masks are not built for the latent kernel."""
+    if _tree_ctx()[0] is not None:
+        raise NotImplementedError(
+            "tree-speculative verify over a latent (MLA) pool")
+    from ..paged_cache import scatter_rows
+    layer = scatter_rows(layer, block_tables, row_slot, row_pos,
+                         (c_new,))
+    out = ragged_latent_attention(
+        q, layer[0], block_tables, cache_lens.astype(jnp.int32) + 1,
+        q_lens, row_starts, row_slot, narrow_iota, win_iota, value_dim,
+        sm_scale)
+    return out, layer
 
 
 def _pool_pspec(pool):
