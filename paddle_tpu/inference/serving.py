@@ -1160,6 +1160,15 @@ class ServingEngine:
             # instead of dying — a later prefix hit restores them
             # through the fixed-width import scatter
             self._alloc.on_evict = self._spill_evicted
+        # the eviction spill's own one-block gather (never crosses
+        # engines, so not _mb_xfer wide); built by warm_migration() or
+        # the first eviction
+        self._spill_exec = None
+        self._spill_layout = None
+        self._spill_nbytes = 0          # one block, every layer
+        self._spill_pending = []        # (tier key, device arrays):
+        #                                 launched, not yet on the host
+        self._n_spill_bytes = 0         # bytes the spills brought over
         self._n_preempt = 0             # victim slots preempted
         self._n_spilled = 0             # KV blocks spilled to host
         self._n_restored = 0            # KV blocks restored from host
@@ -1955,14 +1964,15 @@ class ServingEngine:
         profiling window (``profile(n_ticks)``) brackets the tick —
         the capture starts before the first armed tick and stops
         after the last, bounding the profile to exactly N ticks."""
-        if self._health is None:
-            with self._prof.tick():
-                return self._step_dispatch()
         t0 = time.monotonic()
         c0 = self._n_exec_compiled
         with self._prof.tick():
             out = self._step_dispatch()
-        self._health_tick(t0, time.monotonic(), c0)
+            # what no ``commit`` took in: the legacy step paths, a tick
+            # that admitted and launched nothing
+            self._drain_spills()
+        if self._health is not None:
+            self._health_tick(t0, time.monotonic(), c0)
         return out
 
     def _health_tick(self, t0: float, t1: float, c0: int) -> None:
@@ -2550,6 +2560,9 @@ class ServingEngine:
         if ph is not None:
             ph.end()
             ph = tr.phase("commit", tick=pipe.tick, flush=flush).begin()
+        # the tick has completed, so the spill gathers launched ahead
+        # of it have too: their bytes are taken in without a wait
+        self._drain_spills()
 
         # -- commit decode / verify rows -------------------------------
         acc_lens = {}
@@ -2956,6 +2969,7 @@ class ServingEngine:
             "preemption_enabled": self._preempt_on,
             "preemptions": self._n_preempt,
             "kv_blocks_spilled": self._n_spilled,
+            "kv_spill_bytes_copied": self._n_spill_bytes,
             "kv_blocks_restored": self._n_restored,
             "host_tier_bytes": self._host_tier.bytes_used
             if self._host_tier is not None else 0,
@@ -3111,6 +3125,7 @@ class ServingEngine:
         (outcome="shutdown") — they would otherwise leave no latency
         record at all."""
         self._flush_pipe()      # surface in-flight tokens first
+        self._drain_spills()
         while self._queue:
             self._queue_exit(self._queue.popleft(), "shutdown")
         self._sync_cache_metrics()
@@ -3367,6 +3382,7 @@ class ServingEngine:
         republishes there), not linger on a replica that is going
         away."""
         self._flush_pipe()      # commit in-flight ticks before mutating
+        self._drain_spills()
         slot = self._slots[i]
         self._slot_props.pop(i, None)
         samp_row = self._slot_samp[i].copy()
@@ -3671,6 +3687,7 @@ class ServingEngine:
         dropped."""
         n = self._alloc.unpublish_all()
         if self._host_tier is not None:
+            self._drain_spills()
             n += self._host_tier.purge_published()
             self._m_host_bytes.set(self._host_tier.bytes_used)
         self._sync_cache_metrics()
@@ -3681,10 +3698,13 @@ class ServingEngine:
         path (scale-up warm): one null-block round trip, so the first
         real migration or handoff on this replica compiles nothing —
         the zero-steady-state-recompile pin holds across scale
-        cycles."""
+        cycles. Where evictions spill, their one-block gather is built
+        here too."""
         payload = _pc.payload_rows(self._export_payload([]), 0)
         if self._role != "prefill":
             self._import_payload([], payload)
+        if self._alloc.on_evict is not None and self._spill_exec is None:
+            self._compile_spill()
 
     # -- tracing ------------------------------------------------------
 
@@ -4514,7 +4534,8 @@ class ServingEngine:
     def _export_payload(self, blocks):
         """Gather ``blocks``' self-contained bytes to host DRAM through
         THE fixed-width export executable (shared with the
-        disaggregated handoff — compiled once per engine). The
+        disaggregated handoff — compiled once per engine; the eviction
+        spill has its own, ``_spill_evicted``). The
         ``payload_to_host`` materialization blocks on the gather, so
         the timing feeds the cost model's transfer-bandwidth EMA."""
         ids = np.zeros(self._mb_xfer, np.int32)
@@ -4571,25 +4592,79 @@ class ServingEngine:
                 return jax.device_put(x, dsh)
         return [tuple(d(x) for x in rows) for rows in payload]
 
+    def _compile_spill(self):
+        """The eviction spill's gather: ONE block wide, its result one
+        array per dtype (``ops/paged_cache.export_stacked``). The
+        fixed-width export is another executable on purpose: its shape
+        must match across engines, this one never leaves its own."""
+        self._spill_exec = self._aot_compile(
+            "spill", jax.jit(_pc.export_stacked),
+            (self._pools, self._dev(np.zeros(1, np.int32))))
+        self._spill_layout = _pc.stacked_layout(self._pools)
+        self._spill_nbytes = \
+            _pc.pool_bytes(self._pools) // self._alloc.num_blocks
+
     def _spill_evicted(self, b, h):
         """Allocator eviction hook (``BlockAllocator.on_evict``): an
         LRU-cached published block is being reclaimed — gather its
-        bytes to the host tier first, keyed by content hash, so a
-        later prefix hit restores it instead of re-prefilling. The
-        export launch is issued before the evicting caller's next
-        write, so the bytes read are the published ones."""
+        bytes for the host tier first, keyed by content hash, so a
+        later prefix hit restores it instead of re-prefilling. Only
+        the LAUNCH happens here: the gather of that one block is
+        issued before the evicting caller's next write (launches issue
+        in host order, so the bytes read are the published ones), its
+        copy to the host is started, and the tier books the entry;
+        ``_drain_spills`` takes the bytes in once the device has
+        finished. Whether the tier has room is known from shapes: a
+        refused block launches nothing."""
         tr = self._trace
         ph = tr.phase("spill", tick=self._tick_ord,
                       block=int(b)).begin() if tr is not None else None
-        payload = _pc.payload_rows(self._export_payload([b]), 1)
-        nbytes = _pc.payload_nbytes(payload)
-        stored = self._host_tier.put(("pub", h), payload, nbytes)
+        if self._spill_exec is None:
+            self._compile_spill()
+        tier, nbytes = self._host_tier, self._spill_nbytes
+        key, launched, copied = ("pub", h), None, 0
+        if nbytes <= tier.capacity:
+            # the id rides the call as numpy: one dispatch, not an
+            # upload and then a dispatch
+            launched = self._spill_exec(
+                self._pools, np.full(1, b, np.int32))
+            for a in launched:
+                a.copy_to_host_async()
+            copied = sum(int(a.nbytes) for a in launched)
+        stored = tier.put(key, launched, nbytes)
         if stored:
+            self._spill_pending.append((key, launched))
             self._n_spilled += 1
             self._m_spill.inc()
-        self._m_host_bytes.set(self._host_tier.bytes_used)
+        self._m_host_bytes.set(tier.bytes_used)
         if ph is not None:
-            ph.end(bytes=int(nbytes), stored=bool(stored))
+            ph.end(bytes=int(nbytes), stored=bool(stored),
+                   copied=copied)
+
+    def _drain_spills(self):
+        """Take the launched eviction spills in: each block's device
+        arrays become the numpy buffers its tier entry keeps (views of
+        one buffer per dtype, ``ops/paged_cache.stacked_payload``).
+        Called where the device has already finished — the tick's
+        ``commit`` after ``fetch`` — and before anything reads the
+        tier (a restore, a purge, a session export, shutdown), so no
+        more is ever in flight than one tick evicts. The host time it
+        takes is a ``spill`` span of its own, with ``drained``."""
+        pending = self._spill_pending
+        if not pending:
+            return
+        self._spill_pending = []
+        tr = self._trace
+        ph = tr.phase("spill", tick=self._tick_ord).begin() \
+            if tr is not None else None
+        for key, launched in pending:
+            host = [np.asarray(a) for a in launched]
+            self._n_spill_bytes += sum(int(a.nbytes) for a in host)
+            self._host_tier.fill(
+                key, launched,
+                _pc.stacked_payload(self._spill_layout, host))
+        if ph is not None:
+            ph.end(drained=len(pending))
 
     def _restore_published(self, h):
         """Host-tier prefix restore: a prompt hash that misses the
@@ -4600,6 +4675,7 @@ class ServingEngine:
         reference, owned by the caller's slot) or None."""
         if self._host_tier is None:
             return None
+        self._drain_spills()    # h may have been evicted this very admit
         payload = self._host_tier.get(("pub", h))
         if payload is None:
             return None

@@ -9,8 +9,10 @@ Design constraints, in order:
    tuple), and the record is written only at end(). An engine tick
    emits a handful of spans, each costing one deque.append.
 2. **Bounded memory.** The buffer is a ring (``deque(maxlen=...)``,
-   default 65536 events, env ``PADDLE_TPU_TRACE_EVENTS``): a
+   default 262144 events, env ``PADDLE_TPU_TRACE_EVENTS``): a
    long-lived engine overwrites its oldest spans instead of growing.
+   The default holds some minutes of a saturated engine (~19 events a
+   tick at 8 slots; a 16 ms tick fills 65536 in under a minute).
 3. **Opt-out kill switch.** ``PADDLE_TPU_TRACE=0`` disables tracing
    entirely; callers are expected to hold ``None`` instead of a Tracer
    and skip every call site (the serving engine does exactly this), so
@@ -89,12 +91,15 @@ def tracing_enabled() -> bool:
     return os.environ.get(_TRACE_ENV, "1") != "0"
 
 
+_CAP_DEFAULT = 262144
+
+
 def trace_buffer_capacity() -> int:
     """Ring-buffer capacity in events (``PADDLE_TPU_TRACE_EVENTS``)."""
     try:
-        return max(16, int(os.environ.get(_CAP_ENV, 65536)))
+        return max(16, int(os.environ.get(_CAP_ENV, _CAP_DEFAULT)))
     except ValueError:
-        return 65536
+        return _CAP_DEFAULT
 
 
 class _Phase:

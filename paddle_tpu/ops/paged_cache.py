@@ -71,7 +71,8 @@ __all__ = ["NULL_BLOCK", "BlockAllocator", "blocks_for", "init_pool",
            "pool_bytes", "scale_sharding", "model_fingerprint",
            "prompt_block_hashes", "export_blocks", "import_blocks",
            "HostKVTier", "payload_to_host", "payload_nbytes",
-           "payload_rows", "payload_pad"]
+           "payload_rows", "payload_pad", "export_stacked",
+           "stacked_layout", "stacked_payload"]
 
 # block id 0 is never allocated: inactive slots' tables point here, so
 # their scatter/gather indices stay valid while their data is garbage
@@ -823,6 +824,68 @@ def import_blocks(pools, block_ids, payload):
             for layer, rows in zip(pools, payload)]
 
 
+def _stacked_plan(pools):
+    """``(groups, layout)`` of :func:`export_stacked`: every array of
+    every layer (an int8 pool's data and scales apart) filed under its
+    ``(dtype, block shape)``, in pool order, and for each layer a tuple
+    shaped like its cache whose entries say where the array went —
+    ``(group, row)``, or a :class:`QuantKV` of two such."""
+    index, groups, layout = {}, [], []
+
+    def place(leaf):
+        g = index.setdefault(
+            (jnp.dtype(leaf.dtype), tuple(leaf.shape[1:])), len(groups))
+        if g == len(groups):
+            groups.append([])
+        groups[g].append(leaf)
+        return g, len(groups[g]) - 1
+
+    def file(pool):
+        if isinstance(pool, QuantKV):
+            return QuantKV(place(pool.data), place(pool.scale))
+        return place(pool)
+
+    for layer in pools:
+        layout.append(_each(file, layer))
+    return groups, layout
+
+
+def export_stacked(pools, block_ids):
+    """Eviction spill, read side: the bytes of ``block_ids`` ([n]
+    int32 — the evicted blocks, no padding) as ONE array per dtype, so
+    that the copy to the host is one transfer and the buffer it lands
+    in IS the block: every layer's k and v rows stacked ``[2L, n, BS,
+    H_kv, D]``, an int8 pool's data and scales as two stacks, a latent
+    pool's one array a layer as ``[L, n, BS, W]``. Never crosses
+    engines (:func:`export_blocks` does: its width is a contract), so
+    its width is its caller's own. :func:`stacked_layout` +
+    :func:`stacked_payload` turn the host copy back into the per-layer
+    payload ``import_blocks`` takes."""
+    ids = block_ids.astype(jnp.int32)
+    groups, _ = _stacked_plan(pools)
+    return tuple(jnp.stack([leaf[ids] for leaf in g]) for g in groups)
+
+
+def stacked_layout(pools):
+    """Where :func:`export_stacked` puts each array of ``pools`` (shapes
+    and dtypes only: nothing is read)."""
+    return _stacked_plan(pools)[1]
+
+
+def stacked_payload(layout, stacked):
+    """The per-layer payload (``[(k, v), ...]``, ``QuantKV`` halves, a
+    latent cache's ``[(c,), ...]``) as VIEWS into the host copies
+    ``stacked`` of an :func:`export_stacked` result: the payload owns
+    exactly the bytes of its blocks."""
+    def at(where):
+        if isinstance(where, QuantKV):
+            return QuantKV(at(where.data), at(where.scale))
+        g, row = where
+        return stacked[g][row]
+
+    return [_each(at, layer) for layer in layout]
+
+
 def payload_to_host(payload):
     """Materialize an :func:`export_blocks` payload into host DRAM:
     every jax array becomes a numpy copy (int8 pools keep their
@@ -847,9 +910,12 @@ def payload_nbytes(payload) -> int:
 
 def payload_rows(payload, n: int):
     """First ``n`` block rows of a payload — the export executable is
-    fixed-width, so a spill of fewer blocks slices the gather down
-    before parking it in host DRAM (the tier accounts REAL bytes, not
-    the padded width)."""
+    fixed-width, so a preemption swap or a migration of fewer blocks
+    slices the gather down. The rows are numpy VIEWS: the tier books
+    ``payload_nbytes`` of them, but the padded buffers stay alive
+    behind them for as long as the entry does (a victim's swap lives
+    until its resume; the eviction spill, whose entries live long,
+    goes through :func:`export_stacked` and owns only its block)."""
     def s(x):
         if isinstance(x, QuantKV):
             return QuantKV(x.data[:n], x.scale[:n])
@@ -878,17 +944,20 @@ def payload_pad(payload, m: int):
 
 class HostKVTier:
     """Host-DRAM block tier: an LRU byte-capacity cache of spilled KV
-    payloads (``payload_to_host`` output). Two kinds of entries share
-    it — LRU-EVICTED published blocks (keyed ``("pub", content_hash)``,
-    one block each: a prefix-cache hit that misses the device index can
-    restore the block instead of re-prefilling it) and PREEMPTED victim
+    payloads (``payload_to_host`` / ``stacked_payload`` output). Two
+    kinds of entries share it — LRU-EVICTED published blocks (keyed
+    ``("pub", content_hash)``, one block each: a prefix-cache hit that
+    misses the device index can restore the block instead of
+    re-prefilling it) and PREEMPTED victim
     payloads (keyed ``("victim", rid)``, the whole slot's live blocks:
     the swap half of preemptive scheduling — a resumed request imports
     the bytes back instead of recomputing them). The tier is pure host
     memory (numpy buffers) and pure bookkeeping: device transfers
-    happen in the engine through the ONE fixed-width
-    ``export_blocks``/``import_blocks`` executables, so the tier adds
-    zero compiled code.
+    happen in the engine — a victim's swap and every restore through
+    the fixed-width ``export_blocks``/``import_blocks`` pair, an
+    evicted block through its own one-block ``export_stacked`` gather,
+    which is booked here at launch and filled (:meth:`fill`) once its
+    bytes are on the host.
 
     ``capacity_bytes`` bounds resident bytes; inserting past it drops
     oldest entries first (a dropped victim payload forces that
@@ -934,6 +1003,18 @@ class HostKVTier:
         self._items[key] = (payload, nbytes, meta)
         self.bytes_used += nbytes
         self.spills += 1
+        return True
+
+    def fill(self, key, launched, payload) -> bool:
+        """Swap a resident entry's placeholder ``launched`` (what
+        ``put`` was given while the bytes were still on their way) for
+        the host ``payload``: same bytes booked, same place in the LRU
+        order, no counter moves. False where the entry has gone
+        meanwhile (dropped for room, purged, or put again)."""
+        it = self._items.get(key)
+        if it is None or it[0] is not launched:
+            return False
+        self._items[key] = (payload,) + it[1:]
         return True
 
     def get(self, key):

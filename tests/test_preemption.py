@@ -85,6 +85,22 @@ def _preempt_run(model, prompts, max_new=12, warm_ticks=4, **cfg_kw):
 # --------------------------------------------------- host-DRAM tier
 
 
+def _owned_bytes(payload):
+    """Host memory a tier payload keeps alive: the buffers its arrays
+    are views of, each counted once."""
+    from paddle_tpu.ops import paged_cache as pc
+    roots = {}
+    for rows in payload:
+        for x in rows:
+            halves = (x.data, x.scale) if isinstance(x, pc.QuantKV) \
+                else (x,)
+            for a in halves:
+                while isinstance(a.base, np.ndarray):
+                    a = a.base
+                roots[id(a)] = int(a.nbytes)
+    return sum(roots.values())
+
+
 def test_host_tier_roundtrip_bytes_fp_and_int8():
     """Spill -> host DRAM -> restore is a byte roundtrip: fp payloads
     byte-for-byte, int8 payloads data AND scales byte-for-byte (the
@@ -146,6 +162,56 @@ def test_host_tier_lru_capacity_and_drops():
     assert tier.restores == 0              # discard, not a restore
     with pytest.raises(ValueError, match="positive"):
         pc.HostKVTier(0)
+
+
+@pytest.mark.parametrize("kind", ["fp", "int8", "latent"])
+def test_stacked_export_is_the_block_and_roundtrips(kind):
+    """The eviction spill's gather (``export_stacked``): one array per
+    dtype whatever the pool kind — k and v of every layer in one, an
+    int8 pool's scales in a second, a latent pool's one array a layer
+    in one — whose host copy, seen through ``stacked_payload``, is the
+    per-layer payload ``import_blocks`` takes: byte-for-byte what the
+    fixed-width export gives for the same block, owning those bytes
+    and no more."""
+    from paddle_tpu.ops import paged_cache as pc
+    rng = np.random.RandomState(1)
+    BS, NB, M = 8, 7, 4
+    if kind == "latent":
+        src = [(pc.init_latent_pool(NB, BS, 128, jnp.float32)[0].at[1:4]
+                .set(rng.randn(3, BS, 128).astype(np.float32)),)
+               for _ in range(3)]
+    else:
+        dtype = "int8" if kind == "int8" else jnp.float32
+        tables = jnp.asarray(np.array([[1, 2, 3]], np.int32))
+        src = []
+        for _ in range(2):
+            kv = [jnp.asarray(rng.randn(1, 3 * BS, 2, 16), jnp.float32)
+                  for _ in range(2)]
+            src.append(pc.write_prefill(
+                *pc.init_pool(NB, BS, 2, 16, dtype), tables, *kv))
+    stacked = [np.asarray(a) for a in pc.export_stacked(
+        src, jnp.asarray(np.array([2], np.int32)))]
+    assert len(stacked) == (2 if kind == "int8" else 1)
+    got = pc.stacked_payload(pc.stacked_layout(src), stacked)
+    ids = jnp.asarray(np.array([2, 0, 0, 0], np.int32))
+    want = pc.payload_rows(pc.payload_to_host(
+        pc.export_blocks(src, ids)), 1)
+    assert pc.payload_nbytes(got) == pc.payload_nbytes(want) \
+        == sum(a.nbytes for a in stacked) == _owned_bytes(got)
+    dst = pc.import_blocks(
+        [pc._each(jnp.zeros_like, layer) if kind != "int8" else
+         pc.init_pool(NB, BS, 2, 16, "int8") for layer in src],
+        ids, pc.payload_pad(got, M))
+    for s_layer, d_layer, w_rows in zip(src, dst, want):
+        for sp, dp, w in zip(s_layer, d_layer, w_rows):
+            halves = [(sp.data, dp.data, w.data),
+                      (sp.scale, dp.scale, w.scale)] \
+                if kind == "int8" else [(sp, dp, w)]
+            for a, b, c in halves:
+                np.testing.assert_array_equal(np.asarray(a[2]),
+                                              np.asarray(b[2]))
+                np.testing.assert_array_equal(np.asarray(a[2]),
+                                              np.asarray(c[0]))
 
 
 # ------------------------------------- preempted == never-preempted
@@ -513,15 +579,25 @@ def test_cancel_inflight_cluster_forwards(llama_tiny):
 # ----------------------------------------- eviction spill / restore
 
 
-def test_evicted_published_block_restores_from_host_tier(llama_tiny):
+@pytest.mark.parametrize("pool_kw", [
+    {}, dict(kv_cache_dtype="int8"), dict(tp_degree=2)],
+    ids=["fp", "int8", "tp2"])
+def test_evicted_published_block_restores_from_host_tier(llama_tiny,
+                                                         pool_kw):
     """The hierarchical-KV half beyond preemption: LRU-evicted
     published blocks spill their bytes to the host tier, and a later
     prompt whose prefix hashes to them RESTORES instead of
     re-prefilling — token-exact, with the spill/restore counters
-    pinned."""
+    pinned. The spill gathers the evicted block alone (an int8 pool's
+    data AND scales, a TP pool's shards assembled): what crossed to
+    the host is what the tier booked, and an entry keeps no buffer
+    alive beyond its own block."""
+    import jax
+    if pool_kw.get("tp_degree", 1) > len(jax.devices()):
+        pytest.skip("needs >= 2 devices")
     rng = np.random.RandomState(57)
     eng = ServingEngine(llama_tiny, _scfg(
-        num_slots=1, max_model_len=48, num_blocks=5))
+        num_slots=1, max_model_len=48, num_blocks=5, **pool_kw))
     pA = rng.randint(1, 128, (16,))         # 2 full publishable blocks
     outA = eng.serve([pA.copy()], max_new_tokens=6)[0]
     eng.serve([rng.randint(1, 128, (16,))], max_new_tokens=6)
@@ -529,11 +605,99 @@ def test_evicted_published_block_restores_from_host_tier(llama_tiny):
     assert st1["cache_evictions"] >= 1
     assert st1["kv_blocks_spilled"] >= 1
     assert st1["host_tier_bytes"] > 0
+    # copied over stored is 1: nothing dropped yet, so the tier holds
+    # every byte that crossed
+    assert st1["kv_spill_bytes_copied"] == st1["host_tier_bytes"]
+    assert not eng._spill_pending
+    for payload, nbytes, _ in eng._host_tier._items.values():
+        assert _owned_bytes(payload) == nbytes
     outA2 = eng.serve([pA.copy()], max_new_tokens=6)[0]
     st2 = eng.stats()
     assert st2["kv_blocks_restored"] >= 1
     assert outA2.tolist() == outA.tolist()
     eng.shutdown()
+
+
+def _full_of_published(llama_tiny):
+    """An engine whose pool holds prompt A's two published blocks as
+    the oldest of its LRU list and too few plain-free blocks for the
+    next admission: ``(engine, prompt A, A's tokens, rng)``."""
+    rng = np.random.RandomState(61)
+    eng = ServingEngine(llama_tiny, _scfg(
+        num_slots=2, max_model_len=48, num_blocks=8))
+    pA = rng.randint(1, 128, (16,))
+    outA = eng.serve([pA.copy()], max_new_tokens=6)[0]
+    eng.serve([rng.randint(1, 128, (24,))], max_new_tokens=4)
+    assert eng.stats()["cache_evictions"] == 0
+    return eng, pA, outA, rng
+
+
+def test_block_evicted_and_looked_up_in_one_admit_restores(llama_tiny):
+    """One ``admit`` seats a fresh prompt, whose allocation evicts
+    prompt A's published blocks (their spills launched, not yet on the
+    host), and then prompt A itself: its lookup misses the device
+    index and must still hit the tier — the launched spills are taken
+    in before the tier is read, inside that same ``admit``."""
+    eng, pA, outA, rng = _full_of_published(llama_tiny)
+    eng.submit(rng.randint(1, 128, (24,)), 4)
+    rid = eng.submit(pA.copy(), 6)
+    eng.step()
+    st = eng.stats()
+    assert st["kv_blocks_spilled"] >= 2 and st["kv_blocks_restored"] == 2
+    ev = [e for e in eng.tracer.events() if e["tid"] == 0]
+    (admit,) = [e for e in ev if e["name"] == "admit"
+                and e["args"]["admitted"] == 2]
+    inside = [e for e in ev if e["name"] == "spill"
+              and admit["t0"] <= e["t0"]
+              and e["t0"] + e["dur"] <= admit["t0"] + admit["dur"]]
+    assert sum("block" in e["args"] for e in inside) >= 2
+    assert sum(e["args"].get("drained", 0) for e in inside) >= 2
+    assert eng.run()[rid].tolist() == outA.tolist()
+    eng.shutdown()
+
+
+def test_warm_migration_builds_the_spill_gather(llama_tiny):
+    """``warm_migration()`` leaves the eviction spill's one-block
+    gather built beside the export/import pair: a run of evictions
+    then compiles nothing."""
+    eng, _, _, rng = _full_of_published(llama_tiny)
+    eng.warm_migration()
+    st0 = eng.stats()
+    for _ in range(3):
+        eng.serve([rng.randint(1, 128, (24,))], max_new_tokens=4)
+    st = eng.stats()
+    assert st["kv_blocks_spilled"] - st0["kv_blocks_spilled"] >= 3
+    assert st["executables_compiled"] == st0["executables_compiled"]
+    eng.shutdown()
+
+
+@pytest.mark.parametrize("closer", ["shutdown", "purge_published",
+                                    "export_session"])
+def test_launched_spills_are_taken_in_before_the_tier_is_read(
+        llama_tiny, closer):
+    """A spill launched outside any tick (here by a bare allocation,
+    as ``admit_prefilled`` / ``admit_migrated`` make them) is on the
+    host before ``shutdown()``, ``purge_published()`` or a session
+    export return: nothing stays pending, and every byte launched is
+    accounted."""
+    eng, _, _, rng = _full_of_published(llama_tiny)
+    eng.submit(rng.randint(1, 128, (9,)), 8)
+    for _ in range(3):
+        eng.step()                  # slot 0 decodes; nothing evicted
+    st = eng.stats()
+    assert st["cache_evictions"] == st["kv_spill_bytes_copied"] == 0
+    eng._alloc.free(eng._alloc.alloc(eng._alloc.free_blocks))
+    n = len(eng._spill_pending)
+    assert n >= 2
+    getattr(eng, closer)(*((0,) if closer == "export_session" else ()))
+    assert not eng._spill_pending
+    assert eng.stats()["kv_spill_bytes_copied"] == n * eng._spill_nbytes
+    if closer == "purge_published":
+        assert not any(k[0] == "pub" for k in eng._host_tier._items)
+        assert eng.stats()["host_tier_bytes"] == 0
+    if closer != "shutdown":
+        eng.run()
+        eng.shutdown()
 
 
 # --------------------------------------------------- observability

@@ -105,7 +105,7 @@ def test_tracer_env_capacity(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_TRACE_EVENTS", "64")
     assert Tracer("cap").capacity == 64
     monkeypatch.setenv("PADDLE_TPU_TRACE_EVENTS", "bogus")
-    assert Tracer("cap2").capacity == 65536
+    assert Tracer("cap2").capacity == 262144
 
 
 def test_tracer_chrome_schema_nesting_and_ndjson(tmp_path):
@@ -287,10 +287,12 @@ def test_tick_phases_cover_every_ragged_tick(llama_tiny):
     assert not any(e["args"]["flush"] for e in commits)
     assert all(e["args"]["rows"] > 0 for e in phases if e["name"] == "pack")
     # one host thread: a phase starts after the one before it ended,
-    # unless it is a spill inside an admit or a grow
+    # unless it is a spill inside an admit or a grow (the launch) or
+    # inside a commit (the launched spills taken in)
     for a, b in zip(phases, phases[1:]):
         if b["t0"] < a["t0"] + a["dur"]:
-            assert b["name"] == "spill" and a["name"] in ("admit", "grow")
+            assert b["name"] == "spill" \
+                and a["name"] in ("admit", "grow", "commit")
             assert b["t0"] + b["dur"] <= a["t0"] + a["dur"]
     eng.shutdown()
 
@@ -299,8 +301,11 @@ def test_tick_phases_cover_every_ragged_tick(llama_tiny):
                                                 (64, False)])
 def test_spill_phase_per_evicted_block(llama_tiny, tier_bytes, stored):
     """A tiny pool that fills with published blocks: every eviction is
-    one ``spill`` span inside an ``admit`` or a ``grow``, whether the
-    host tier took the block (the counter rises) or refused it."""
+    one ``spill`` span (the one that carries ``block``) inside an
+    ``admit`` or a ``grow``, whether the host tier took the block (the
+    counter rises) or refused it (nothing is launched). The launched
+    ones are taken in under the same name: a ``spill`` span with
+    ``drained`` inside the tick's ``commit``."""
     rng = np.random.RandomState(23)
     eng = ServingEngine(llama_tiny, ServingConfig(
         num_slots=2, block_size=8, max_model_len=64, prefill_chunk=16,
@@ -311,13 +316,29 @@ def test_spill_phase_per_evicted_block(llama_tiny, tier_bytes, stored):
     st = eng.stats()
     assert st["preemptions"] == 0
     phases = _phases(eng.tracer)
-    spills = [e for e in phases if e["name"] == "spill"]
+    spills = [e for e in phases
+              if e["name"] == "spill" and "block" in e["args"]]
+    drains = [e for e in phases
+              if e["name"] == "spill" and "block" not in e["args"]]
     assert spills and all(e["args"]["stored"] is stored for e in spills)
     refused = sum(not e["args"]["stored"] for e in spills)
     assert len(spills) == \
         st["kv_blocks_spilled"] - st0["kv_blocks_spilled"] + refused
     assert all(e["args"]["bytes"] > 0 and e["args"]["block"] > 0
                for e in spills)
+    # the block alone crosses to the host, or nothing does
+    assert all(e["args"]["copied"] ==
+               (e["args"]["bytes"] if stored else 0) for e in spills)
+    assert sum(e["args"]["drained"] for e in drains) == \
+        len(spills) - refused
+    assert st["kv_spill_bytes_copied"] == \
+        sum(e["args"]["copied"] for e in spills)
+    for e in drains:
+        outer = [p for p in phases if p["name"] == "commit"
+                 and p["t0"] <= e["t0"]
+                 and e["t0"] + e["dur"] <= p["t0"] + p["dur"]]
+        assert len(outer) == 1
+        assert outer[0]["args"]["tick"] == e["args"]["tick"]
     for e in spills:
         outer = [p for p in phases if p["name"] in ("admit", "grow")
                  and p["t0"] <= e["t0"]
