@@ -303,7 +303,7 @@ def build_draft_loop(draft_step, *, gamma, do_sample, temperature=1.0,
 
 def accept_from_filtered(f, toks, dq, key, *, gamma, do_sample):
     """Window acceptance on ALREADY-FILTERED target logits — the
-    shared core of ``build_verify_step`` (per-width verify executable)
+    shared core of ``build_verify_step`` (``SpecGenerator``'s verify)
     and the serving engine's ragged mixed-batch step (which gathers
     its window logits out of one packed row buffer before calling
     this): given ``f`` [S, gamma+1, V] (the target's window logits
@@ -493,8 +493,7 @@ def accept_tree_from_filtered(f, toks, parents, key, *, do_sample):
 
 
 def build_verify_step(model_step, *, gamma, do_sample, temperature=1.0,
-                      top_k=0, top_p=1.0, onehot_draft=True,
-                      gather_logits=None, slot_params=False):
+                      top_k=0, top_p=1.0, onehot_draft=True):
     """Build the fixed-gamma multi-token verify step.
 
     The returned function runs ONE target forward over the window
@@ -515,82 +514,36 @@ def build_verify_step(model_step, *, gamma, do_sample, temperature=1.0,
     randomness). Sampling: rejection sampling against the draft
     distribution — one-hot of ``toks[:, 1:]`` when ``onehot_draft``
     (n-gram drafter), else the explicit ``dq`` operand — signature
-    ``verify(params, pools, tables, lens, toks[, dq], key)``.
-    ``gather_logits`` (tensor-parallel serving): applied to the window
-    logits before filtering, so acceptance/sampling always see the
-    full replicated vocab — the step's ONE cross-shard collective.
-    ``slot_params`` (the serving engine's per-slot sampling tensors):
-    every verify signature gains a ``samp`` [S, 3] operand right after
-    ``toks`` — (temperature, top_k, top_p) per slot as DATA, so
-    distinct sampling configs share one executable; the baked keyword
-    knobs are then ignored (greedy verifies never consume them either
-    way)."""
+    ``verify(params, pools, tables, lens, toks[, dq], key)``."""
     from . import _filter_logits
 
-    def _target(params, pools, tables, lens, toks, samp):
+    def _accept(params, pools, tables, lens, toks, dq, key):
         logits, pools = model_step(params, toks, pools, None,
                                    block_tables=tables,
                                    cache_lens=lens)
-        if gather_logits is not None:
-            logits = gather_logits(logits)
-        if slot_params:
-            t_, k_, p_ = samp[:, 0], samp[:, 1], samp[:, 2]
-        else:
-            t_, k_, p_ = temperature, top_k, top_p
         f = _filter_logits(logits, do_sample=do_sample,
-                           temperature=t_, top_k=k_,
-                           top_p=p_)                    # [S, G+1, V]
-        return f, pools
-
-    if not do_sample:
-        if slot_params:
-            def verify(params, pools, tables, lens, toks, samp):
-                f, pools = _target(params, pools, tables, lens, toks,
-                                   samp)
-                out, accept, picked = accept_from_filtered(
-                    f, toks, None, None, gamma=gamma, do_sample=False)
-                return out, accept, picked, pools
-        else:
-            def verify(params, pools, tables, lens, toks):
-                f, pools = _target(params, pools, tables, lens, toks,
-                                   None)
-                out, accept, picked = accept_from_filtered(
-                    f, toks, None, None, gamma=gamma, do_sample=False)
-                return out, accept, picked, pools
-        return verify
-
-    if slot_params:
-        if onehot_draft:
-            def verify(params, pools, tables, lens, toks, samp, key):
-                return _sample_accept(params, pools, tables, lens,
-                                      toks, samp, None, key)
-        else:
-            def verify(params, pools, tables, lens, toks, samp, dq,
-                       key):
-                return _sample_accept(params, pools, tables, lens,
-                                      toks, samp, dq, key)
-    elif onehot_draft:
-        def verify(params, pools, tables, lens, toks, key):
-            return _sample_accept(params, pools, tables, lens, toks,
-                                  None, None, key)
-    else:
-        def verify(params, pools, tables, lens, toks, dq, key):
-            return _sample_accept(params, pools, tables, lens, toks,
-                                  None, dq, key)
-
-    def _sample_accept(params, pools, tables, lens, toks, samp, dq,
-                       key):
-        f, pools = _target(params, pools, tables, lens, toks, samp)
+                           temperature=temperature, top_k=top_k,
+                           top_p=top_p)                 # [S, G+1, V]
         out, accept, picked = accept_from_filtered(
-            f, toks, dq, key, gamma=gamma, do_sample=True)
+            f, toks, dq, key, gamma=gamma, do_sample=do_sample)
         return out, accept, picked, pools
 
+    if not do_sample:
+        def verify(params, pools, tables, lens, toks):
+            return _accept(params, pools, tables, lens, toks, None,
+                           None)
+    elif onehot_draft:
+        def verify(params, pools, tables, lens, toks, key):
+            return _accept(params, pools, tables, lens, toks, None,
+                           key)
+    else:
+        def verify(params, pools, tables, lens, toks, dq, key):
+            return _accept(params, pools, tables, lens, toks, dq, key)
     return verify
 
 
 def build_tree_verify_step(model_step, *, parents, do_sample,
-                           temperature=1.0, top_k=0, top_p=1.0,
-                           gather_logits=None, slot_params=False):
+                           temperature=1.0, top_k=0, top_p=1.0):
     """Tree-topology twin of :func:`build_verify_step`: ONE target
     forward over the window ``toks = [cur, node_1..node_gamma]``
     (tree node order), masked by ancestor path instead of the linear
@@ -607,7 +560,7 @@ def build_tree_verify_step(model_step, *, parents, do_sample,
     Drafters here are always one-hot (n-gram top-k chains or Medusa
     heads propose concrete tokens), so there is no ``dq`` operand.
     Signatures mirror ``build_verify_step``'s one-hot forms:
-    ``verify(params, pools, tables, lens, toks[, samp][, key])`` ->
+    ``verify(params, pools, tables, lens, toks[, key])`` ->
     ``(out [S, T], accept [S, T-1], logp [S, T], pools)`` — the
     linear-contract shapes, so ``commit_window`` and generate()'s
     score accounting work unchanged. A chain ``parents`` makes the
@@ -618,23 +571,14 @@ def build_tree_verify_step(model_step, *, parents, do_sample,
     parents = tuple(int(p) for p in parents)
     tree_ancestor_bits(parents)          # validate before tracing
 
-    def _target(params, pools, tables, lens, toks, samp):
+    def _verify(params, pools, tables, lens, toks, key):
         with spec_tree_scope(parents):
             logits, pools = model_step(params, toks, pools, None,
                                        block_tables=tables,
                                        cache_lens=lens)
-        if gather_logits is not None:
-            logits = gather_logits(logits)
-        if slot_params:
-            t_, k_, p_ = samp[:, 0], samp[:, 1], samp[:, 2]
-        else:
-            t_, k_, p_ = temperature, top_k, top_p
         f = _filter_logits(logits, do_sample=do_sample,
-                           temperature=t_, top_k=k_,
-                           top_p=p_)                    # [S, T, V]
-        return f, pools
-
-    def _finish(f, pools, tables, lens, toks, key):
+                           temperature=temperature, top_k=top_k,
+                           top_p=top_p)                 # [S, T, V]
         out, accept, picked, path, n_acc = accept_tree_from_filtered(
             f, toks, parents, key, do_sample=do_sample)
         lens32 = lens.astype(jnp.int32)
@@ -643,29 +587,10 @@ def build_tree_verify_step(model_step, *, parents, do_sample,
         return out, accept, picked, pools
 
     if not do_sample:
-        if slot_params:
-            def verify(params, pools, tables, lens, toks, samp):
-                f, pools = _target(params, pools, tables, lens, toks,
-                                   samp)
-                return _finish(f, pools, tables, lens, toks, None)
-        else:
-            def verify(params, pools, tables, lens, toks):
-                f, pools = _target(params, pools, tables, lens, toks,
-                                   None)
-                return _finish(f, pools, tables, lens, toks, None)
+        def verify(params, pools, tables, lens, toks):
+            return _verify(params, pools, tables, lens, toks, None)
         return verify
-
-    if slot_params:
-        def verify(params, pools, tables, lens, toks, samp, key):
-            f, pools = _target(params, pools, tables, lens, toks,
-                               samp)
-            return _finish(f, pools, tables, lens, toks, key)
-    else:
-        def verify(params, pools, tables, lens, toks, key):
-            f, pools = _target(params, pools, tables, lens, toks,
-                               None)
-            return _finish(f, pools, tables, lens, toks, key)
-    return verify
+    return _verify
 
 
 def leading_accepts(accept_row) -> int:
@@ -681,10 +606,10 @@ def leading_accepts(accept_row) -> int:
 
 def commit_window(out_row, accept_row, room: int, eos: int):
     """Shared host-side window commit (``SpecGenerator.run`` AND the
-    serving engine's ``_step_spec`` — one implementation so the two
-    entry points can never diverge on the same token stream): from one
-    slot's verify outputs, the tokens to emit this step and the
-    accepted-draft count.
+    serving engine's ``_commit_verify_window`` — one implementation so
+    the two entry points can never diverge on the same token stream):
+    from one slot's verify outputs, the tokens to emit this step and
+    the accepted-draft count.
 
     Emits ``out_row[:n_acc + 1]`` truncated to ``room`` remaining
     tokens and cut after an EOS found anywhere inside the window.

@@ -5,35 +5,32 @@ scheduler play over AnalysisPredictor, rebuilt TPU-first for the
 compiler's static-shape world (arxiv 2603.09555) with the block-table
 paged KV layout of *Ragged Paged Attention* (arxiv 2604.15464):
 
-- **Fixed slots, one compiled decode step.** The engine owns
-  ``num_slots`` serving slots. Every decode step runs ALL slots through
-  one batched model call — token ids [S, 1], block tables [S, MB],
-  per-slot lengths [S] — whose shapes never change, so the step is
-  AOT-compiled exactly once and steady state runs ZERO recompiles
-  (assert via the ``serving_decode_compiles`` / ``serving_decode_steps``
-  monitor counters). Raggedness lives in the table/length VALUES.
+- **One tick, ONE executable.** The engine owns ``num_slots`` serving
+  slots. Every tick runs ONE AOT-compiled ragged step
+  (``_compile_ragged_step``) that consumes ALL active work as a single
+  packed row buffer: decoding slots contribute 1 query row,
+  speculative verify windows ``gamma + 1`` rows, and pending prompts
+  up to ``prefill_chunk`` rows each inside the tick's
+  ``ragged_prefill_rows`` budget — partitioned by per-slot ``q_lens``
+  and cumulative ``row_starts``, with the surrounding write/sample
+  fused into the same launch. The packed width is static, so
+  raggedness lives in VALUES and steady state runs ZERO recompiles:
+  executables per engine is 1 (2 with a draft model — its proposal
+  scan + prefill priming fuse into one draft ragged step; assert via
+  ``stats()["executables_compiled"]``), every tick is one dispatch
+  round-trip, and admission prefill overlaps running decodes (a
+  pending slot simply contributes prompt rows instead of a decode
+  row). See docs/OPS.md "Ragged mixed-batch serving".
 - **Paged KV.** All slots share one block pool per layer
   (``ops/paged_cache.py``); the host-side ``BlockAllocator`` hands
   blocks to admitted requests and reclaims them at retirement, so HBM
-  scales with live tokens, not ``slots x max_len``.
+  scales with live tokens, not ``slots x max_len``. The tick's
+  attention reads the pool through the ragged Pallas kernel on TPU
+  (``ops/pallas/paged_attention.py``) and its XLA mirror on CPU.
 - **Continuous batching.** ``step()`` admits queued requests into freed
-  slots, decodes one token for every active slot, streams tokens out,
-  and retires slots on EOS/max-len — freed blocks and slots are reused
-  by the next admission without ever draining the batch.
-- **Chunked prefill — ONE executable.** Admission prefills the prompt
-  in fixed-size chunks (``ServingConfig.prefill_chunk``, default 128)
-  through the SAME multi-query paged path the speculative verify step
-  rides (``paged_verify_attention`` with ``T = chunk``): each chunk
-  writes its K/V into the slot's blocks and attends to every
-  previously cached block plus its own in-chunk causal prefix. The
-  chunk step is AOT-compiled ONCE per engine — ``ceil(n / C)`` chunk
-  calls replace the old per-power-of-two-bucket prefill zoo, so
-  ``serving_prefill_compiles`` collapses from O(#buckets) (x draft
-  copies) to O(1) and no prompt pays bucket padding. Optionally the
-  scheduler interleaves prefill chunks between decode steps
-  (``max_prefill_chunks_per_step > 0``) to bound head-of-line latency
-  for running requests. Kill switch ``PADDLE_TPU_CHUNKED_PREFILL=0``
-  restores the bucketed dense prefill.
+  slots, runs the tick, streams tokens out, and retires slots on
+  EOS/max-len — freed blocks and slots are reused by the next
+  admission without ever draining the batch.
 - **Prefix caching (content-addressed blocks).** The ``BlockAllocator``
   keeps per-block refcounts and a content-hash index (rolling hash
   chains over token ids, seeded by a model/config fingerprint —
@@ -41,7 +38,7 @@ paged KV layout of *Ragged Paged Attention* (arxiv 2604.15464):
   sequence's FULL blocks into the index instead of dropping them; they
   park in an LRU list until memory pressure evicts them. Admission
   hashes the prompt's full blocks, maps the longest cached prefix
-  straight into the slot's block table (refcount++) and chunk-prefills
+  straight into the slot's block table (refcount++) and prefills
   only the suffix — shared system prompts, few-shot headers and
   multi-turn history prefill once per cache lifetime, not per request.
   A shared block the suffix must write into (full-prompt hit) is
@@ -49,47 +46,20 @@ paged KV layout of *Ragged Paged Attention* (arxiv 2604.15464):
   outputs are token-exact vs the cold path. Kill switch:
   ``PADDLE_TPU_PREFIX_CACHE=0``. See docs/OPS.md "Prefix caching &
   chunked prefill".
-- **Ragged decode attention** reads the pool through the Pallas kernel
-  on TPU (``ops/pallas/paged_attention.py``) and the gather fallback on
-  CPU, behind the models' ordinary cached-attention path — the same
-  code ``generate(cache_impl="paged")`` rides.
 - **Speculative decoding** (``num_speculative_tokens = gamma > 0``): a
-  drafter (model-free n-gram prompt lookup, or a smaller draft model
-  sharing the block tables) proposes gamma tokens per slot and ONE
-  fixed-shape multi-token verify forward (the multi-query paged
-  kernel) accepts 1..gamma+1 of them — still exactly one compiled
-  executable in steady state, because accept/reject lives in the
-  LENGTH values: rejected tokens roll back by decrementing
-  ``cache_lens`` and returning overhang blocks to the allocator (no
-  data movement). The scheduler reserves ``prompt + max_new + gamma``
-  blocks worst-case (the speculated window may overhang the final
-  token), retires EOS found anywhere inside the window, and streams
-  every accepted token through the ordinary callback. Kill switch:
+  drafter (model-free n-gram prompt lookup, a smaller draft model
+  sharing the block tables, or draft heads filling a token tree)
+  proposes gamma tokens per slot and the tick's verify rows accept
+  1..gamma+1 of them — accept/reject lives in the LENGTH values:
+  rejected tokens roll back by not advancing ``cache_lens`` and
+  returning overhang blocks to the allocator (no data movement). The
+  scheduler reserves ``prompt + max_new + gamma`` blocks worst-case
+  (the speculated window may overhang the final token), retires EOS
+  found anywhere inside the window, and streams every accepted token
+  through the ordinary callback. Kill switch:
   ``PADDLE_TPU_SPECULATIVE=0``; capacity-routed MoE is excluded (the
-  window tokens would compete for expert capacity — same reasoning as
-  prompt bucketing). See docs/OPS.md "Speculative decoding".
-
-- **Ragged mixed-batch serving — ONE executable per engine.** By
-  default every engine tick runs ONE AOT-compiled ragged step
-  (``_compile_ragged_step``) that consumes ALL active work as a single
-  packed row buffer: decoding slots contribute 1 query row, speculative
-  verify windows ``gamma + 1`` rows, and pending prefill chunks up to
-  ``prefill_chunk`` rows — partitioned by per-slot ``q_lens`` and
-  cumulative ``row_starts`` (*Ragged Paged Attention*, with the
-  surrounding write/sample fused into the same launch per the MPK
-  mega-kernelization direction). The per-width decode/verify/chunk
-  executables (and the interleave scheduler that juggled them)
-  collapse: steady-state executables per engine is 1 (2 with a draft
-  model — its proposal scan + prefill priming fuse into one draft
-  ragged step), every tick is one dispatch round-trip, and admission
-  prefill overlaps running decodes for free (prefill rows ride the
-  same launch — no head-of-line interleave budget needed, no NULL-row
-  table dance: a pending slot simply contributes 0 decode rows).
-  Greedy outputs are token-exact vs the per-width zoo (the ragged XLA
-  fallback is bitwise the per-width fallback per row). Kill switch
-  ``PADDLE_TPU_RAGGED_BATCH=0`` (or ``ServingConfig(
-  ragged_batch=False)``) restores the per-width executables
-  bit-for-bit. See docs/OPS.md "Ragged mixed-batch serving".
+  window tokens would compete for expert capacity). See docs/OPS.md
+  "Speculative decoding".
 
 - **Mega-kernelized decode tick** (``ServingConfig(fused_decode=
   True)``, the default): inside every serving executable the decoder
@@ -119,34 +89,32 @@ paged KV layout of *Ragged Paged Attention* (arxiv 2604.15464):
   math through ``gather_dense``. Steady-state decode is HBM-bound on
   KV reads, so bytes/step halve (~0.53x pool bytes vs bf16) and
   ~2x the slots fit a fixed pool budget. Prefix caching, COW,
-  speculative rollback, chunked prefill, the ragged engine and TP all
-  compose (stored bytes are a pure function of the tokens; the scale
-  pool shards on the same kv_head cut). Default (None) keeps the fp
-  pool bit-for-bit; ``PADDLE_TPU_KV_INT8=0`` is the kill switch. See
-  docs/OPS.md "KV cache quantization".
+  speculative rollback, chunked prefill and TP all compose (stored
+  bytes are a pure function of the tokens; the scale pool shards on
+  the same kv_head cut). Default (None) keeps the fp pool bit-for-bit;
+  ``PADDLE_TPU_KV_INT8=0`` is the kill switch. See docs/OPS.md "KV
+  cache quantization".
 
 - **Tensor-parallel serving** (``ServingConfig(tp_degree=N)``): every
-  serving executable — batched decode, fixed-gamma verify, fixed-chunk
-  prefill, the draft loop and the ``copy_blocks`` COW — is sharded
-  over a ``Mesh(devices[:N], ("mp",))`` axis (GSPMD, arxiv 2105.04663).
-  The KV block pool splits on its kv_heads dim (each shard owns a
-  contiguous kv_head slice of EVERY block, so the paged-attention
-  grid runs unmodified on its local slice inside ``shard_map`` —
-  ``ops/pallas/paged_attention.sharded_paged_attention_step``); model
-  params shard column/row-wise through the models' existing ``mp``
-  PartitionSpecs; block tables, ``cache_lens``, token ids and the
-  sampling PRNG key are replicated. The only EXPLICIT cross-shard
-  collective is one logits ``all_gather`` before sampling
-  (``_gather_logits`` — census-asserted; the per-layer reduces of the
-  row-parallel linears are GSPMD-inserted and proxied by the
-  ``sharding_constraint`` census row), so sampling consumes the same
-  replicated logits/key on every shard. Host state is untouched: ONE
-  ``BlockAllocator``, one scheduler, one prefix-cache index — block
-  ids are global and every shard's pool slice is indexed by the same
-  tables, so prefix caching, COW, speculative rollback and chunked
-  prefill all compose with TP for free. Kill switch
-  ``PADDLE_TPU_SERVE_TP=0`` restores the single-device path
-  bit-for-bit. See docs/OPS.md "Tensor-parallel serving".
+  serving executable — the tick, the draft step and the
+  ``copy_blocks`` COW — is sharded over a ``Mesh(devices[:N],
+  ("mp",))`` axis (GSPMD, arxiv 2105.04663). The KV block pool splits
+  on its kv_heads dim (each shard owns a contiguous kv_head slice of
+  EVERY block); model params shard column/row-wise through the
+  models' existing ``mp`` PartitionSpecs; block tables,
+  ``cache_lens``, token ids and the sampling PRNG key are replicated.
+  The only EXPLICIT cross-shard collective is one logits
+  ``all_gather`` before sampling (``_gather_logits`` —
+  census-asserted; the per-layer reduces of the row-parallel linears
+  are GSPMD-inserted and proxied by the ``sharding_constraint``
+  census row), so sampling consumes the same replicated logits/key on
+  every shard. Host state is untouched: ONE ``BlockAllocator``, one
+  scheduler, one prefix-cache index — block ids are global and every
+  shard's pool slice is indexed by the same tables, so prefix
+  caching, COW, speculative rollback and chunked prefill all compose
+  with TP for free. Kill switch ``PADDLE_TPU_SERVE_TP=0`` restores
+  the single-device path bit-for-bit. See docs/OPS.md
+  "Tensor-parallel serving".
 
 - **Disaggregated prefill -> decode** (``ServingConfig(role=
   "prefill" | "decode" | "both")``): a role="prefill" engine runs
@@ -163,12 +131,13 @@ paged KV layout of *Ragged Paged Attention* (arxiv 2604.15464):
   behind a session-affine router on top of this. See docs/OPS.md
   "Engine replication & disaggregated prefill".
 
-Admission is worst-case reserved: a request is admitted only when the
-pool can cover ``prompt + max_new`` blocks for it PLUS the outstanding
-reservations of every active slot, so mid-decode pool exhaustion is
-impossible by construction (no preemption path needed; a
+Admission is worst-case reserved: a request is admitted when the pool
+can cover ``prompt + max_new`` blocks for it PLUS the outstanding
+reservations of every active slot; the preemptive scheduler
+(``enable_preemption``, docs/OPS.md "Preemption & hierarchical KV
+offload") may overcommit past that and reclaims by preemption. A
 role="prefill" engine reserves only the prompt's blocks — its decode
-horizon lives on the importing replica).
+horizon lives on the importing replica.
 
 Telemetry (monitor registry, exported in the JSONL dump):
 ``serving_slot_occupancy`` gauge, ``serving_batch_utilization`` /
@@ -176,7 +145,7 @@ Telemetry (monitor registry, exported in the JSONL dump):
 outcome: admitted | cancelled | rejected | shutdown, so pre-admission
 exits leave a record too), ``serving_tokens_total`` /
 ``serving_decode_steps`` / ``serving_decode_compiles`` /
-``serving_prefill_compiles`` / ``serving_requests_completed`` /
+``serving_requests_completed`` /
 ``serving_prefix_blocks_reused`` / ``serving_prefix_tokens_reused`` /
 ``serving_cow_copies`` / ``serving_cache_evictions`` counters and the
 ``serving_prefix_hit_rate`` gauge.
@@ -186,9 +155,9 @@ Request-lifecycle tracing + SLO digests (docs/OPS.md "Request tracing
 (``monitor/tracing.py`` — one trace-viewer pid per engine, tid 0 the
 engine tick timeline, tid 1+i slot i, last tid the admission queue)
 recording ``submit -> queued -> admit (prefix-hit annotated) ->
-prefill chunk[i] -> decode/verify tick (rows, accepted_len, exec id)
+prefill chunk -> decode/verify tick (rows, accepted_len, exec id)
 -> retired`` plus per-tick engine spans (occupancy, kernel-fallback
-count) on all three step paths; ``engine.dump_trace(path)`` writes
+count); ``engine.dump_trace(path)`` writes
 Perfetto-loadable Chrome trace JSON. Kill switch ``PADDLE_TPU_TRACE=0``
 (bit-for-bit inert: tracing is host-side only). Independent of that
 switch, four always-on P² latency digests power ``stats()``'s
@@ -267,8 +236,6 @@ class ServingConfig:
     top_k: int = 0
     top_p: float = 1.0
     seed: int = 0
-    min_prefill_bucket: int = 16        # smallest prompt bucket (legacy
-    #                                     bucketed prefill only)
     # speculative decoding: draft gamma tokens per slot per step and
     # verify them in one multi-token forward (0 = off)
     num_speculative_tokens: int = 0
@@ -294,35 +261,21 @@ class ServingConfig:
     # bit-for-bit (heads engines fall back to the linear ngram
     # drafter). None = linear speculation, exactly as before.
     spec_tree: Optional[tuple] = None
-    # chunked prefill: ONE fixed-chunk AOT executable processes the
-    # prompt suffix in ceil(n / prefill_chunk) steps (multi-query paged
-    # attention, T = chunk). False (or PADDLE_TPU_CHUNKED_PREFILL=0)
-    # restores the per-bucket dense prefill.
-    chunked_prefill: bool = True
-    prefill_chunk: int = 128            # tokens per prefill chunk step
-    # content-addressed prefix reuse over the block pool (requires
-    # chunked prefill). False (or PADDLE_TPU_PREFIX_CACHE=0) disables
-    # hashing/publishing — blocks free eagerly as before.
+    # most prompt rows ONE slot rides into a tick: a prompt suffix
+    # prefills in ceil(n / prefill_chunk) ticks or more (the tick's
+    # row budget, ragged_prefill_rows, is shared by pending slots)
+    prefill_chunk: int = 128
+    # content-addressed prefix reuse over the block pool. False (or
+    # PADDLE_TPU_PREFIX_CACHE=0) disables hashing/publishing — blocks
+    # free eagerly.
     enable_prefix_cache: bool = True
-    # > 0: admission leaves prefill pending and each engine tick
-    # advances at most this many chunk steps (across all pending slots)
-    # before decoding — bounds head-of-line latency for running
-    # requests at the cost of later first tokens. 0 = prefill whole
-    # prompts at admission.
-    max_prefill_chunks_per_step: int = 0
     # False: retirement drops each request's token buffer instead of
     # holding it for run() — REQUIRED for long-lived streaming
     # deployments that consume tokens via stream_callback and drive
     # step() themselves (otherwise finished results accumulate
     # unboundedly; run() then returns {}).
     retain_results: bool = True
-    # ragged mixed-batch serving: ONE executable per engine consumes
-    # decode rows + verify windows + prefill chunk rows as a single
-    # packed ragged batch each tick. False (or
-    # PADDLE_TPU_RAGGED_BATCH=0) restores the per-width
-    # decode/verify/chunk executable zoo bit-for-bit.
-    ragged_batch: bool = True
-    # per-tick prefill row budget of the ragged step (the executable's
+    # per-tick prefill row budget of the tick (the executable's
     # packed width is num_slots * (gamma+1) + this). None = one
     # prefill_chunk's worth; shrink to trade time-to-first-token for
     # smaller per-tick padding when slots mostly decode.
@@ -341,7 +294,7 @@ class ServingConfig:
     # decode step, ~2x admissible slots at a fixed pool byte budget).
     # Composes with prefix caching/COW (quantize-on-store makes cached
     # bytes a pure function of the tokens), speculative verify/
-    # rollback, chunked prefill, the ragged engine and TP (the scale
+    # rollback, chunked prefill and TP (the scale
     # pool shards on the same kv_head cut). Env twin
     # PADDLE_TPU_KV_INT8: 0 = kill switch (fp pool, bit-for-bit), 1 =
     # int8 when this field is left None. On TPU use block_size=32 (the
@@ -380,9 +333,8 @@ class ServingConfig:
     # PADDLE_TPU_PREEMPT=0 kill switch, which beats an explicit True)
     # restores the worst-case-reservation FIFO scheduler bit-for-bit:
     # priorities are ignored, nothing spills, no host tier exists.
-    # Preemption needs the chunked-prefill path (the recompute resume
-    # IS a chunk prefill) and never runs on a role="prefill" engine
-    # (its slots only park for handoff).
+    # Preemption never runs on a role="prefill" engine (its slots
+    # only park for handoff).
     enable_preemption: bool = True
     # watermark admission headroom in blocks: a request is admitted
     # when the worst-case reservation fits (the old policy, unchanged
@@ -460,8 +412,8 @@ class ServingConfig:
     # every decode tick applies the per-slot deltas as ONE
     # mixed-adapter ragged grouped matmul inside the single existing
     # tick executable (adapter churn swaps stack VALUES at a fixed
-    # shape — zero steady-state recompiles). Requires the ragged
-    # engine. Kill switch PADDLE_TPU_LORA=0 restores the base engine
+    # shape — zero steady-state recompiles). Kill switch
+    # PADDLE_TPU_LORA=0 restores the base engine
     # bit-for-bit (no extra operand, no tagged module, no extra
     # per-slot row).
     lora_rank: int = 0
@@ -482,7 +434,7 @@ class ServingConfig:
     # to the delta weights; ~4x adapters per resident byte)
     lora_quant: bool = False
     # -- async tick pipeline (docs/OPS.md "Async tick pipeline") ------
-    # async_depth=1 arms depth-1 dispatch-ahead on the ragged engine:
+    # async_depth=1 arms depth-1 dispatch-ahead:
     # the tick executable additionally returns next-tick inputs
     # (per-slot sampled token, advanced lengths, a budget/EOS ``done``
     # mask) as DEVICE arrays, and on pure steady-state decode ticks
@@ -491,7 +443,7 @@ class ServingConfig:
     # (emit/retire/stats/tracing) lags one tick. Any slot-composition
     # event (admission, retirement, preemption, migration, handoff,
     # cancel) flushes the pipeline, so async ON == OFF stays greedy
-    # token-exact. Requires the ragged engine. Env twin
+    # token-exact. Env twin
     # PADDLE_TPU_ASYNC_TICK: 0 = kill switch (beats an explicit depth
     # — today's dispatch-then-block loop returns bit-for-bit, same
     # executables), 1 = depth-1 when this field is left None. Only
@@ -694,7 +646,7 @@ class MigratedSession:
 class _Slot:
     __slots__ = ("rid", "blocks", "worst_blocks", "cache_len",
                  "last_token", "n_emitted", "max_new", "history",
-                 "prompt", "pend_pos", "pend_row", "admit_t",
+                 "prompt", "pend_pos", "admit_t",
                  "handoff", "priority", "resume", "adapter_id")
 
     def __init__(self, rid, blocks, worst_blocks, cache_len, last_token,
@@ -718,7 +670,6 @@ class _Slot:
         self.history = history
         self.prompt = prompt            # int32 prompt (pending chunks)
         self.pend_pos = pend_pos        # next chunk start; None = done
-        self.pend_row = None            # device table row for chunks
 
 
 class _Pipe:
@@ -972,9 +923,6 @@ class ServingEngine:
         self._slot_samp = np.tile(self._samp_default,
                                   (cfg.num_slots, 1))
         self._samp_dev = None           # device mirror of _slot_samp
-        self._samp_row_dev = {}         # slot -> device [3] row (the
-        #                                 chunk/bucketed-prefill execs
-        #                                 take one slot's row)
         # -- mega-kernelized decode tick ------------------------------
         # resolved ONCE at construction (config flag + the
         # PADDLE_TPU_FUSED_DECODE env twin); GSPMD TP traces keep the
@@ -998,18 +946,10 @@ class ServingEngine:
         # emitted token by up to gamma written-then-rolled-back slots
         self._gamma = gamma
         self._ngram_max = int(cfg.spec_ngram_max)
-        # chunked prefill + prefix caching switches: prefix reuse NEEDS
-        # the chunked path (the bucketed dense prefill recomputes and
-        # rewrites the whole prompt, so mapping cached blocks under it
-        # would save nothing and the scatter would clobber them)
-        self._chunked = bool(cfg.chunked_prefill) and \
-            os.environ.get("PADDLE_TPU_CHUNKED_PREFILL", "1") != "0"
-        self._prefix_on = self._chunked \
-            and bool(cfg.enable_prefix_cache) \
+        self._prefix_on = bool(cfg.enable_prefix_cache) \
             and os.environ.get("PADDLE_TPU_PREFIX_CACHE", "1") != "0"
         self._chunk = max(1, min(int(cfg.prefill_chunk),
                                  int(cfg.max_model_len)))
-        self._chunk_budget = int(cfg.max_prefill_chunks_per_step)
         # KV-pool quantization: resolved ONCE at construction (config
         # + PADDLE_TPU_KV_INT8 env twin) — "int8" or None; raises on
         # an unsupported request before any pool is built
@@ -1022,14 +962,6 @@ class ServingEngine:
         nb = (1 + cfg.num_slots * self._mb) if cfg.num_blocks is None \
             else int(cfg.num_blocks)
         self._alloc = _pc.BlockAllocator(nb)
-        # -- ragged mixed-batch layout --------------------------------
-        self._ragged = bool(getattr(cfg, "ragged_batch", True)) and \
-            os.environ.get("PADDLE_TPU_RAGGED_BATCH", "1") != "0"
-        if self._spec_tree is not None and not self._ragged:
-            raise NotImplementedError(
-                "spec_tree requires the ragged engine (ragged_batch="
-                "True without PADDLE_TPU_RAGGED_BATCH=0); to disable "
-                "tree speculation itself use PADDLE_TPU_SPEC_TREE=0")
         # -- async tick pipeline (docs/OPS.md "Async tick pipeline") --
         # resolved ONCE at construction: config depth AND the
         # PADDLE_TPU_ASYNC_TICK env twin (0 = kill switch beating an
@@ -1043,15 +975,6 @@ class ServingEngine:
             _depth = 1 if _ae == "1" else 0
         else:
             _depth = int(_ad)
-        if _depth and not self._ragged:
-            if _ad is None:
-                _depth = 0      # env-armed: best-effort, legacy engine
-            else:
-                raise NotImplementedError(
-                    "async_depth requires the ragged engine "
-                    "(ragged_batch=True without "
-                    "PADDLE_TPU_RAGGED_BATCH=0); to disable the "
-                    "pipeline itself use PADDLE_TPU_ASYNC_TICK=0")
         self._async_on = _depth >= 1
         self._async_depth = 1 if self._async_on else 0
         self._pipe = None               # in-flight (uncommitted) tick
@@ -1060,21 +983,18 @@ class ServingEngine:
         self._last_dispatch_t = None    # host-gap digest anchor
         self._split_t0 = 0.0            # cluster phase-split health
         self._split_c0 = 0              # bracket (tick_dispatch)
-        if self._chunked:
-            want = cfg.ragged_prefill_rows
-            self._prefill_rows = max(1, min(
-                int(self._chunk if want is None else want),
-                int(cfg.max_model_len)))
-        else:
-            self._prefill_rows = 0      # bucketed prefill at admission
+        # -- the tick's packed row layout -----------------------------
+        want = cfg.ragged_prefill_rows
+        self._prefill_rows = max(1, min(
+            int(self._chunk if want is None else want),
+            int(cfg.max_model_len)))
         # static packed width: every active slot's decode/verify rows
         # plus one tick's prefill row budget always fit
         self._rows = cfg.num_slots * (gamma + 1) + self._prefill_rows
         # static per-slot row ceiling (the rows of a slot the ragged
         # kernel attends)
         self._wmax = max(gamma + 1,
-                         min(self._chunk, self._prefill_rows)
-                         if self._chunked else 1)
+                         min(self._chunk, self._prefill_rows))
         # pad rows park at a position past every table's reach — the
         # write null-routes and the rope/position gathers clamp
         self._overflow = self._mb * self._bs
@@ -1087,15 +1007,11 @@ class ServingEngine:
         if self._draft_model is not None:
             self._draft_model.eval()
             dbinder = _LayerBinder(self._draft_model)
-            self._dbinder = dbinder
             self._dparams = self._shard_params(dbinder) \
                 if self._mesh is not None else dbinder.param_arrays()
             self._draft_step = self._draft_model._build_model_step(
                 dbinder, dbinder.buffer_arrays())
             self._dpools = self._init_caches(self._draft_model, nb)
-            self._draft_prefill_execs = {}
-        self._verify_exec = None
-        self._draft_exec = None
         self._tables = np.zeros((cfg.num_slots, self._mb), np.int32)
         self._slots: List[Optional[_Slot]] = [None] * cfg.num_slots
         self._reserved = 0              # blocks promised to active slots
@@ -1113,10 +1029,6 @@ class ServingEngine:
         # sample a different token on every shard)
         self._key = self._dev(jax.random.PRNGKey(int(cfg.seed)))
         self._tables_dev = None         # device mirror of _tables
-        self._decode_exec = None
-        self._prefill_execs = {}        # legacy bucketed prefill
-        self._chunk_exec = None         # the ONE chunked-prefill exec
-        self._draft_chunk_exec = None
         self._cow_exec = None           # copy-on-write block duplicate
         self._draft_cow_exec = None
         # disaggregated prefill -> decode handoff (role="prefill"
@@ -1140,12 +1052,11 @@ class ServingEngine:
         # PADDLE_TPU_PREEMPT env twin (0 = kill switch beating an
         # explicit True — the worst-case FIFO scheduler returns
         # bit-for-bit); a prefill-role engine never decodes so it has
-        # nothing to preempt, and the recompute resume path IS a chunk
-        # prefill, so the bucketed-prefill fallback disables it too
+        # nothing to preempt
         self._preempt_on = bool(getattr(cfg, "enable_preemption",
                                         True)) \
             and os.environ.get("PADDLE_TPU_PREEMPT", "1") != "0" \
-            and self._role != "prefill" and self._chunked
+            and self._role != "prefill"
         wm = getattr(cfg, "admission_watermark_blocks", None)
         self._watermark = int(cfg.num_slots if wm is None else wm)
         self._resume_policy = str(getattr(cfg, "preempt_resume",
@@ -1193,8 +1104,8 @@ class ServingEngine:
         # THIS engine)
         self._n_decode_compiles = 0
         self._n_exec_compiled = 0       # EVERY executable this engine
-        #                                 built (decode+verify+chunk+
-        #                                 prefill+cow, target AND draft)
+        #                                 built (tick, cow, export,
+        #                                 import, spill; target AND draft)
         # snapshot of the op-layer's process-wide fallback counter:
         # stats() reports the DELTA, i.e. fallback events observed
         # since this engine was created, not another engine's history
@@ -1202,7 +1113,6 @@ class ServingEngine:
         self._n_decode_steps = 0
         self._n_tokens = 0
         self._n_completed = 0
-        self._n_prefill_compiles = 0
         self._n_prefill_chunks = 0
         self._n_prefix_blocks = 0       # cached blocks mapped into slots
         self._n_prefix_tokens = 0       # prompt tokens NOT re-prefilled
@@ -1231,14 +1141,6 @@ class ServingEngine:
         # change at a fixed shape: zero steady-state recompiles
         self._slot_adapter = np.zeros(cfg.num_slots, np.int64)
         if self._lora_on:
-            if not self._ragged or not self._chunked:
-                raise NotImplementedError(
-                    "multi-LoRA serving requires the ragged engine "
-                    "with chunked prefill (ragged_batch=True and "
-                    "chunked_prefill on, without their env kill "
-                    "switches) — prompt rows must ride the ragged "
-                    "tick so adapter deltas reach the prefill KV; to "
-                    "disable LoRA itself use PADDLE_TPU_LORA=0")
             specs = _lora.tag_modules(model, str(getattr(
                 cfg, "lora_targets", "attn")))
             if not specs:
@@ -1273,10 +1175,6 @@ class ServingEngine:
         self._m_decode_compiles = monitor.counter(
             "serving_decode_compiles",
             "decode-step compilations (steady state: stays at 1)")
-        self._m_prefill_compiles = monitor.counter(
-            "serving_prefill_compiles",
-            "prefill compilations per prompt bucket",
-            labels=("bucket",))
         self._m_completed = monitor.counter(
             "serving_requests_completed", "requests fully served")
         self._m_prefix_blocks = monitor.counter(
@@ -1374,7 +1272,6 @@ class ServingEngine:
         self._kv_pos_bytes = target_pool_bytes / float(
             self._pools[0][0].shape[0] * self._bs)
         self._kv_step_bytes_last = 0
-        self._kv_read_pend = 0      # legacy-path chunk reads this tick
         monitor.info(
             "serving_kv_cache_dtype",
             "KV block-pool storage dtype of the most recent engine "
@@ -1854,11 +1751,11 @@ class ServingEngine:
         """What ``paged_attention.ragged_grid_units`` needs beside a
         tick's ``q_lens`` and lengths, from the model's config and the
         pools as built; ``None`` (the ``tick`` span then carries no
-        ``attn_units`` / ``attn_live``) off the ragged path or for a
-        model that does not state its head count."""
+        ``attn_units`` / ``attn_live``) for a model that does not
+        state its head count."""
         heads = getattr(getattr(model, "config", None),
                         "num_attention_heads", None)
-        if not self._ragged or not heads:
+        if not heads:
             return None
         pool = self._pools[0][0]
         pool = getattr(pool, "data", pool)      # QuantKV: the int8 half
@@ -1913,10 +1810,9 @@ class ServingEngine:
                 "moe_hot": int(pairs.max())}
 
     def _trace_tick(self, t_tick, exec_name: str, path: str, **extra):
-        """One engine-tick span (tid 0) — ALL three step paths emit
-        through here so the tick-span schema (exec/path/queued/
-        kernel-fallback delta + per-path extras) cannot drift between
-        ragged and legacy traces. Caller guards on ``self._trace``."""
+        """One engine-tick span (tid 0): the tick-span schema
+        (exec/path/queued/kernel-fallback delta + extras) in one
+        place. Caller guards on ``self._trace``."""
         args = {"exec": exec_name, "path": path,
                 "queued": len(self._queue),
                 "kernel_fallbacks": int(sum(
@@ -1959,17 +1855,18 @@ class ServingEngine:
         verify a speculative window) for every active slot, retire
         finished sequences. Returns this tick's
         ``[(request_id, token), ...]`` (admission prefills included).
-        On the default ragged path one tick is ONE executable launch
-        covering decode + verify + prefill rows together. An armed
-        profiling window (``profile(n_ticks)``) brackets the tick —
-        the capture starts before the first armed tick and stops
-        after the last, bounding the profile to exactly N ticks."""
+        One tick is ONE executable launch covering decode + verify +
+        prefill rows together. An armed profiling window
+        (``profile(n_ticks)``) brackets the tick — the capture starts
+        before the first armed tick and stops after the last, bounding
+        the profile to exactly N ticks."""
         t0 = time.monotonic()
         c0 = self._n_exec_compiled
         with self._prof.tick():
-            out = self._step_dispatch()
-            # what no ``commit`` took in: the legacy step paths, a tick
-            # that admitted and launched nothing
+            out = self._step_async() if self._async_on \
+                else self._step_ragged()
+            # a tick that launched nothing has no ``commit`` to take in
+            # what was evicted since the last one
             self._drain_spills()
         if self._health is not None:
             self._health_tick(t0, time.monotonic(), c0)
@@ -2004,181 +1901,6 @@ class ServingEngine:
         self._m_burn.set(h._last_burn.get("fast", 0.0))
         self._m_alerts.set(float(len(h.firing())))
 
-    def _step_dispatch(self) -> List[tuple]:
-        if self._ragged:
-            if self._async_on:
-                return self._step_async()
-            return self._step_ragged()
-        if self._gamma:
-            return self._step_spec()
-        t_tick = time.monotonic()
-        emitted = self._admit()
-        self._advance_prefills(emitted)
-        active = [i for i, s in enumerate(self._slots)
-                  if s is not None and s.pend_pos is None
-                  and not s.handoff]
-        if not active:
-            if self._kv_read_pend:      # prefill-only tick: the chunk
-                self._note_kv_read(0)   # reads ARE the tick's traffic
-            return emitted
-        active = self._ensure_blocks(active)
-        if not active:                  # everyone preempted for blocks
-            return emitted
-
-        cfg = self.config
-        lens = np.zeros(cfg.num_slots, np.int32)
-        toks = np.full(cfg.num_slots, self._pad, np.int32)
-        for i in active:
-            lens[i] = self._slots[i].cache_len
-            toks[i] = self._slots[i].last_token
-        sub = self._next_key()
-        if self._tables_dev is None:    # only re-upload after changes
-            self._tables_dev = self._dev(self._tables)
-        samp = self._samp_operand()
-        if self._decode_exec is None:
-            self._decode_exec = self._compile_decode(lens, toks, samp,
-                                                     sub)
-        t_l0 = time.monotonic()
-        with _quiet_donation():
-            out, self._pools = self._decode_exec(
-                self._params, self._pools, self._tables_dev,
-                self._dev(lens), self._dev(toks), samp, sub)
-        out = np.asarray(out)
-        t_sync = time.monotonic()
-
-        self._m_steps.inc()
-        self._n_decode_steps += 1
-        self._note_step_time("decode", t_sync - t_l0)
-        if self._mesh is not None:
-            self._m_tp_bytes.inc(self._tp_step_bytes)
-            self._n_tp_bytes += self._tp_step_bytes
-        self._m_util.observe(len(active) / cfg.num_slots)
-        self._note_kv_read(int(lens.sum()) + len(active))
-        tr = self._trace
-        rid_of = {i: self._slots[i].rid for i in active} \
-            if tr is not None else None
-        for i in active:
-            slot = self._slots[i]
-            tok = int(out[i])
-            slot.cache_len += 1
-            slot.last_token = tok
-            slot.n_emitted += 1
-            slot.history.append(tok)
-            self._emit(slot.rid, tok)
-            emitted.append((slot.rid, tok))
-            if tok == self._eos or slot.n_emitted >= slot.max_new:
-                self._retire(i)
-        if tr is not None:
-            for i in active:
-                tr.emit("decode tick", tid=1 + i, t0=t_l0, t1=t_sync,
-                        args={"rid": rid_of[i], "rows": 1})
-            self._trace_tick(
-                t_tick, "decode", "legacy", active=len(active),
-                occupancy=round(len(active) / cfg.num_slots, 3))
-        return emitted
-
-    def _step_spec(self) -> List[tuple]:
-        """Speculative engine tick: draft gamma tokens per active slot,
-        verify the whole window in ONE fixed-shape target forward, and
-        commit 1..gamma+1 tokens per slot. The verify executable is
-        AOT-compiled once — accept/reject never changes a shape, only
-        the ``cache_lens`` values — so steady state stays at zero
-        recompiles exactly like the plain decode step. Rollback of a
-        rejected tail is ``cache_len`` simply not advancing over it,
-        plus ``_trim_blocks`` returning overhang blocks."""
-        from ..generation import speculative as _spec
-        t_tick = time.monotonic()
-        emitted = self._admit()
-        self._advance_prefills(emitted)
-        active = [i for i, s in enumerate(self._slots)
-                  if s is not None and s.pend_pos is None
-                  and not s.handoff]
-        if not active:
-            if self._kv_read_pend:      # prefill-only tick
-                self._note_kv_read(0)
-            return emitted
-        g = self._gamma
-        # room for the full window: positions cache_len .. cache_len+g
-        active = self._ensure_blocks(active, horizon=g + 1)
-        if not active:                  # everyone preempted for blocks
-            return emitted
-
-        cfg = self.config
-        lens = np.zeros(cfg.num_slots, np.int32)
-        toks = np.full((cfg.num_slots, g + 1), self._pad, np.int32)
-        for i in active:
-            lens[i] = self._slots[i].cache_len
-            toks[i, 0] = self._slots[i].last_token
-        if self._tables_dev is None:
-            self._tables_dev = self._dev(self._tables)
-        lens_dev = self._dev(lens)
-        t_l0 = time.monotonic()         # draft + verify launch window
-
-        samp = self._samp_operand()
-        dq = None
-        if self._draft_model is not None:
-            sub = self._next_key()
-            if self._draft_exec is None:
-                self._draft_exec = self._compile_draft(lens, toks,
-                                                       samp, sub)
-            with _quiet_donation():
-                props, dq, self._dpools = self._draft_exec(
-                    self._dparams, self._dpools, self._tables_dev,
-                    lens_dev, self._dev(toks[:, 0]), samp, sub)
-            toks[:, 1:] = np.asarray(props)
-        else:
-            for i in active:
-                toks[i, 1:] = _spec.ngram_propose(
-                    self._slots[i].history, g, self._ngram_max)
-
-        sub = self._next_key()
-        if self._verify_exec is None:
-            self._verify_exec = self._compile_verify(lens, toks, samp,
-                                                     dq, sub)
-        args = [self._params, self._pools, self._tables_dev, lens_dev,
-                self._dev(toks), samp]
-        if self._do_sample:
-            if dq is not None:
-                args.append(dq)
-            args.append(sub)
-        with _quiet_donation():
-            out, accept, _logp, self._pools = self._verify_exec(*args)
-        out = np.asarray(out)
-        accept = np.asarray(accept)
-        t_sync = time.monotonic()
-
-        self._m_steps.inc()
-        self._n_decode_steps += 1
-        # the draft loop (if any) shares the window — the verify row
-        # is conservatively charged the whole draft+verify interval
-        self._note_step_time("verify", t_sync - t_l0)
-        if self._mesh is not None:
-            self._m_tp_bytes.inc(self._tp_step_bytes)
-            self._n_tp_bytes += self._tp_step_bytes
-        self._m_util.observe(len(active) / cfg.num_slots)
-        # window row t attends lens + t + 1 positions
-        self._note_kv_read((g + 1) * int(lens.sum())
-                           + len(active) * (g + 1) * (g + 2) // 2)
-        tr = self._trace
-        rid_of = {i: self._slots[i].rid for i in active} \
-            if tr is not None else None
-        acc_lens = {}
-        for i in active:
-            acc_lens[i] = self._commit_verify_window(
-                i, out[i], accept[i], emitted)
-        if self._n_spec_proposed:
-            self._m_spec_rate.set(
-                self._n_spec_accepted / self._n_spec_proposed)
-        if tr is not None:
-            for i in active:
-                tr.emit("verify tick", tid=1 + i, t0=t_l0, t1=t_sync,
-                        args={"rid": rid_of[i], "rows": g + 1,
-                              "accepted_len": acc_lens[i]})
-            self._trace_tick(
-                t_tick, "verify", "legacy", active=len(active),
-                occupancy=round(len(active) / cfg.num_slots, 3))
-        return emitted
-
     def _tree_draft(self, i) -> np.ndarray:
         """One slot's gamma-node tree proposal for this tick, in node
         order. drafter='heads': the verify executable computed it LAST
@@ -2200,12 +1922,10 @@ class ServingEngine:
             self._spec_tree, chains), np.int32)
 
     def _commit_verify_window(self, i, out_row, accept_row, emitted):
-        """Commit one slot's verified speculative window — the SHARED
-        host-side half of acceptance (legacy ``_step_spec`` and the
-        ragged tick both call it, so emission/rollback/metric
-        semantics cannot drift between the paths): emit the kept
-        prefix, account acceptance, retire on EOS/max_new, else
-        advance ``cache_len`` over the accepted prefix (rollback of
+        """Commit one slot's verified speculative window — the
+        host-side half of acceptance: emit the kept prefix, account
+        acceptance, retire on EOS/max_new, else advance
+        ``cache_len`` over the accepted prefix (rollback of
         the rejected tail = NOT advancing over it) and trim overhang
         blocks. Returns the number of tokens emitted (the per-slot
         ``accepted_len`` the trace annotates verify-tick spans
@@ -2245,16 +1965,15 @@ class ServingEngine:
         return len(kept)
 
     def _step_ragged(self) -> List[tuple]:
-        """Ragged mixed-batch tick (the default path): pack every live
-        query row — 1 per decoding slot, ``gamma + 1`` per verifying
-        slot, up to the prefill row budget for pending prompts — into
+        """Ragged mixed-batch tick: pack every live query row — 1 per
+        decoding slot, ``gamma + 1`` per verifying slot, up to the prefill row budget for pending prompts — into
         ONE launch of the engine's single compiled executable, then
         commit tokens, prefill progress, speculative accept/reject and
         retirements host-side. The packed width is static
         (``num_slots * (gamma+1) + prefill_rows``); slots with no work
         contribute zero rows, so raggedness lives entirely in the
         ``q_lens``/``row_starts`` VALUES and steady state runs zero
-        recompiles exactly like the per-width path it replaces.
+        recompiles.
 
         The tick is split into a dispatch half (pack + launch) and a
         commit half (token fetch + host bookkeeping); this sync path
@@ -2905,15 +2624,14 @@ class ServingEngine:
             "decode_compiles": self._n_decode_compiles,
             "tokens_total": self._n_tokens,
             "requests_completed": self._n_completed,
-            "prefill_compiles": self._n_prefill_compiles,
+            # no executable is ever built for prefill alone: prompt
+            # rows ride the tick
+            "prefill_compiles": 0,
             "prefill_chunks": self._n_prefill_chunks,
-            # EVERY executable this engine built (decode + verify +
-            # chunk + prefill buckets + cow, target AND draft) — the
-            # ragged collapse is assertable from telemetry: 1 in
-            # steady state (2 with a draft model). Present on the
-            # legacy path too, where it counts the whole zoo.
+            # EVERY executable this engine built (the tick, cow,
+            # export/import, spill; target AND draft): 1 in steady
+            # state (2 with a draft model)
             "executables_compiled": self._n_exec_compiled,
-            "ragged_batch": self._ragged,
             # paged-attention entry points that lost the Pallas kernel
             # on a TPU backend since THIS engine was created (0 on
             # CPU; the op-layer counter is process-wide, so the
@@ -2939,7 +2657,6 @@ class ServingEngine:
             "kernel_launch_proxy_per_tick": self._kcensus.get(
                 "verify" if self._gamma else "decode", {}).get(
                 "launch_proxy", 0),
-            "chunked_prefill": self._chunked,
             "prefix_cache_enabled": self._prefix_on,
             "prefix_blocks_reused": self._n_prefix_blocks,
             "prefix_tokens_reused": self._n_prefix_tokens,
@@ -3553,9 +3270,7 @@ class ServingEngine:
             blocks, cached = self._map_prefix(ctx, n_ctx)
             self._reserved += worst - len(blocks)
             self._tables[i, :] = 0
-            if self._ragged or not (self._chunked
-                                    and self._chunk_budget > 0):
-                self._tables[i, :len(blocks)] = blocks
+            self._tables[i, :len(blocks)] = blocks
             self._tables_dev = None
             slot = _Slot(rid, blocks, worst, cached, None,
                          int(rec.max_new_tokens),
@@ -3583,9 +3298,6 @@ class ServingEngine:
             bidx = cached // self._bs
             if self._alloc.is_shared(blocks[bidx]):
                 self._cow(i, bidx)
-            if not self._ragged and self._chunk_budget <= 0:
-                tok = self._advance_prefill(i)
-                self._finish_prefill(i, tok, [])
         return rid
 
     def drain_sessions(self):
@@ -3912,7 +3624,7 @@ class ServingEngine:
         ``sharding_constraint`` row. The decode/verify census feeds the
         per-step collective-bytes counter. Every executable the engine
         ever builds flows through here, so ``executables_compiled`` in
-        ``stats()`` is exact on the ragged AND legacy paths."""
+        ``stats()`` is exact."""
         self._n_exec_compiled += 1
         tap = _moe.serving_stats_tap(self._observe_moe_routing) \
             if self._moe_tap_on else contextlib.nullcontext()
@@ -4030,7 +3742,6 @@ class ServingEngine:
         if not np.array_equal(self._slot_samp[i], row):
             self._slot_samp[i] = row
             self._samp_dev = None
-            self._samp_row_dev.pop(i, None)
 
     def _samp_operand(self):
         """The [num_slots, 3] per-slot sampling tensor, uploaded only
@@ -4074,15 +3785,6 @@ class ServingEngine:
         if d > 0:
             self._m_lora_swaps.inc(d)
             self._lora_swaps_seen = pool.swaps
-
-    def _samp_row(self, i):
-        """One slot's [3] sampling row for the single-slot executables
-        (chunk / bucketed prefill) — cached per admission so a long
-        prompt's chunk loop pays ONE upload, not one per chunk."""
-        row = self._samp_row_dev.get(i)
-        if row is None:
-            row = self._samp_row_dev[i] = self._dev(self._slot_samp[i])
-        return row
 
     def _select_rows(self, lg, key, samp):
         """Per-slot token selection: ``samp``'s trailing axis is
@@ -4161,18 +3863,7 @@ class ServingEngine:
             blocks, cached = self._map_prefix(req.prompt, n_real)
             self._reserved += worst - len(blocks)
             self._tables[i, :] = 0
-            if self._ragged or not (self._chunked
-                                    and self._chunk_budget > 0):
-                # the ragged step needs the row live at once (a pending
-                # slot contributes ZERO query rows, so nothing can
-                # touch its blocks early — no NULL-row dance needed);
-                # legacy interleaved prefill instead keeps the GLOBAL
-                # table row null until the prefill completes: the
-                # batched decode step masks pending slots by table
-                # (null-block writes/reads are harmless by
-                # construction, exactly like inactive slots) and the
-                # chunk executable reads its row from ``slot.blocks``
-                self._tables[i, :len(blocks)] = blocks
+            self._tables[i, :len(blocks)] = blocks
             self._tables_dev = None
             # observe BEFORE prefill so the histogram measures queue
             # wait, not prefill execution/compile time
@@ -4195,22 +3886,13 @@ class ServingEngine:
                           "prefix_hit": cached > 0,
                           "cached_tokens": int(cached),
                           "prompt_tokens": n_real})
-            if not self._chunked:
-                tok = self._prefill_bucketed(i, req, n_real)
-                self._finish_prefill(i, tok, emitted)
-            else:
-                # a shared suffix-boundary block (full-prompt cache
-                # hit) must be copy-on-write duplicated before the
-                # recomputed last token's K/V lands in it
-                bidx = cached // self._bs
-                if self._alloc.is_shared(blocks[bidx]):
-                    self._cow(i, bidx)
-                if not self._ragged and self._chunk_budget <= 0:
-                    tok = self._advance_prefill(i)
-                    self._finish_prefill(i, tok, emitted)
-                # else: prefill rows ride the ragged step (or, on the
-                # legacy interleaved path, chunks advance inside
-                # step() ticks between running slots' decodes)
+            # a shared suffix-boundary block (full-prompt cache hit)
+            # must be copy-on-write duplicated before the recomputed
+            # last token's K/V lands in it; the prompt's rows then
+            # ride the next ticks
+            bidx = cached // self._bs
+            if self._alloc.is_shared(blocks[bidx]):
+                self._cow(i, bidx)
         self._sync_cache_metrics()
         return emitted
 
@@ -4485,9 +4167,7 @@ class ServingEngine:
             blocks, cached = self._map_prefix(ctx, n_ctx)
             self._reserved += int(r["worst_blocks"]) - len(blocks)
             self._tables[i, :] = 0
-            if self._ragged or not (self._chunked
-                                    and self._chunk_budget > 0):
-                self._tables[i, :len(blocks)] = blocks
+            self._tables[i, :len(blocks)] = blocks
             self._tables_dev = None
             slot = _Slot(rid, blocks, int(r["worst_blocks"]), cached,
                          None, int(req.max_new_tokens),
@@ -4509,9 +4189,6 @@ class ServingEngine:
             bidx = cached // self._bs
             if self._alloc.is_shared(blocks[bidx]):
                 self._cow(i, bidx)
-            if not self._ragged and self._chunk_budget <= 0:
-                tok = self._advance_prefill(i)
-                self._finish_prefill(i, tok, emitted)
 
     def _resume_mode(self, r, payload) -> str:
         """Recompute-vs-swap, per victim: restore time ~= payload
@@ -4800,102 +4477,14 @@ class ServingEngine:
                     self._dev(np.int32(new)))
         self._alloc.free([old])
         slot.blocks[bidx] = new
-        slot.pend_row = None                 # (always pre-chunk today)
-        if self._tables[i, bidx] == old:     # row may be null (pending)
-            self._tables[i, bidx] = new
-            self._tables_dev = None
+        self._tables[i, bidx] = new
+        self._tables_dev = None
         self._n_cow += 1
         self._m_cow.inc()
 
-    def _advance_prefill(self, i, budget=None):
-        """Run up to ``budget`` chunk steps (None = to completion) of
-        slot ``i``'s pending prompt suffix through the ONE compiled
-        chunk executable. Returns the sampled first token when the
-        prefill completes, else None."""
-        slot = self._slots[i]
-        if self._chunk_exec is None:
-            self._chunk_exec = self._compile_chunk(self._next_key())
-        if self._draft_model is not None \
-                and self._draft_chunk_exec is None:
-            self._draft_chunk_exec = self._compile_draft_chunk()
-        c = self._chunk
-        n_real = int(slot.prompt.size)
-        if slot.pend_row is None:
-            # the row is invariant for the prefill's lifetime (the one
-            # possible COW happens at admission, before any chunk) —
-            # upload it once, not per interleaved tick
-            row = np.zeros((self._mb,), np.int32)
-            row[:len(slot.blocks)] = slot.blocks
-            slot.pend_row = self._dev(row)
-        table_dev = slot.pend_row
-        while budget is None or budget > 0:
-            part = slot.prompt[slot.pend_pos:slot.pend_pos + c]
-            # chunk row t attends pend_pos + t + 1 positions — folded
-            # into this tick's KV-read gauge at the next _note_kv_read
-            n_part = int(part.size)
-            self._kv_read_pend += n_part * slot.pend_pos \
-                + n_part * (n_part + 1) // 2
-            ids = np.full((1, c), self._pad, np.int32)
-            ids[0, :part.size] = part
-            ids_dev = self._dev(ids)
-            pos = self._dev(np.int32(slot.pend_pos))
-            t_c0 = time.monotonic()
-            with _quiet_donation():
-                tok, self._pools = self._chunk_exec(
-                    self._params, ids_dev, self._pools, table_dev,
-                    pos, self._dev(np.int32(int(part.size) - 1)),
-                    self._samp_row(i), self._next_key())
-            if self._draft_model is not None:
-                # prime the draft cache over the same positions (its
-                # pools ride the same block table)
-                with _quiet_donation():
-                    self._dpools = self._draft_chunk_exec(
-                        self._dparams, ids_dev, self._dpools,
-                        table_dev, pos)
-            # roofline sample for the chunk executable (wall clock
-            # around the launch — on async backends only the final
-            # chunk's first-token materialization syncs, so the chunk
-            # row's time is an enqueue time there)
-            self._note_step_time("chunk", time.monotonic() - t_c0)
-            if self._trace is not None:
-                self._trace.emit(
-                    f"prefill chunk[{slot.pend_pos // c}]",
-                    tid=1 + i, t0=t_c0,
-                    args={"rid": slot.rid,
-                          "pos": int(slot.pend_pos),
-                          "rows": n_part})
-            self._n_prefill_chunks += 1
-            slot.pend_pos += int(part.size)
-            slot.cache_len = slot.pend_pos
-            if budget is not None:
-                budget -= 1
-            if slot.pend_pos >= n_real:
-                slot.pend_pos = None
-                slot.pend_row = None
-                return int(tok)
-        return None
-
-    def _advance_prefills(self, emitted):
-        """Interleaved-prefill tick: spend the per-step chunk budget
-        across pending slots (lowest slot index first), finishing
-        admissions whose last chunk lands."""
-        if self._chunk_budget <= 0:
-            return
-        budget = self._chunk_budget
-        for i, s in enumerate(self._slots):
-            if budget <= 0:
-                break
-            if s is None or s.pend_pos is None:
-                continue
-            n0 = self._n_prefill_chunks
-            tok = self._advance_prefill(i, budget)
-            budget -= self._n_prefill_chunks - n0
-            if tok is not None:
-                self._finish_prefill(i, tok, emitted)
-
     def _finish_prefill(self, i, tok, emitted):
-        """Shared admission epilogue (synchronous and interleaved
-        prefill): record and emit the first token, retire immediately
+        """Admission epilogue, once a prompt's last row has ridden a
+        tick: record and emit the first token, retire immediately
         on EOS / max_new_tokens == 1. On a role="prefill" engine a
         surviving slot parks for ``pop_prefilled()`` instead of
         entering decode — the request's remaining tokens belong to the
@@ -4903,9 +4492,6 @@ class ServingEngine:
         slot = self._slots[i]
         slot.cache_len = int(slot.prompt.size)
         slot.pend_pos = None
-        if self._tables[i, 0] == 0:          # interleaved: publish the
-            self._tables[i, :len(slot.blocks)] = slot.blocks   # row now
-            self._tables_dev = None
         if slot.resume is not None:
             # recompute resume completing: the re-prefilled cache now
             # holds EXACTLY the preempted state — restore the
@@ -5028,13 +4614,8 @@ class ServingEngine:
     def _note_kv_read(self, positions):
         """Analytic KV HBM traffic of one tick: ``positions`` cache
         positions attended x bytes per position (the quantization win
-        shows up here directly — int8 halves the multiplier). Folds in
-        (and drains) the chunk-prefill positions the legacy path
-        accumulated earlier in the same tick (``_kv_read_pend``) — on
-        the ragged path prefill rows ride the one launch and are
-        already counted."""
-        b = int((positions + self._kv_read_pend) * self._kv_pos_bytes)
-        self._kv_read_pend = 0
+        shows up here directly — int8 halves the multiplier)."""
+        b = int(positions * self._kv_pos_bytes)
         self._kv_step_bytes_last = b
         self._m_kv_step.set(b)
 
@@ -5056,44 +4637,6 @@ class ServingEngine:
             self._m_accept.labels(q=q).set(round(v, 3))
         for q, v in self._d_host_gap.quantiles().items():
             self._m_host_gap.labels(q=q).set(round(v, 3))
-
-    def _prefill_bucketed(self, i, req, n_real) -> int:
-        """Legacy bucketed prefill (``PADDLE_TPU_CHUNKED_PREFILL=0`` /
-        ``chunked_prefill=False``): dense cached forward over the
-        right-padded prompt at a power-of-two bucket, K/V scattered
-        into the slot's blocks, first token selected at the prompt's
-        true last position. One compile per bucket."""
-        bucket = self._bucket(n_real)
-        ids = np.full((1, bucket), self._pad, np.int32)
-        ids[0, :n_real] = req.prompt
-        sub = self._next_key()
-        exec_ = self._prefill_execs.get(bucket)
-        if exec_ is None:
-            exec_ = self._compile_prefill(bucket, sub)
-            self._prefill_execs[bucket] = exec_
-        t_p0 = time.monotonic()
-        with _quiet_donation():
-            tok, self._pools = exec_(
-                self._params, self._dev(ids),
-                self._dev(np.int32(n_real)), self._pools,
-                self._dev(self._tables[i]), self._samp_row(i), sub)
-        if self._draft_model is not None:
-            # prime the draft model's cache with the same prompt K/V
-            # (its pools share the slot's block table)
-            dexec = self._draft_prefill_execs.get(bucket)
-            if dexec is None:
-                dexec = self._compile_draft_prefill(bucket)
-                self._draft_prefill_execs[bucket] = dexec
-            with _quiet_donation():
-                self._dpools = dexec(
-                    self._dparams, self._dev(ids),
-                    self._dev(np.int32(n_real)), self._dpools,
-                    self._dev(self._tables[i]))
-        if self._trace is not None:
-            self._trace.emit(
-                f"prefill bucket{bucket}", tid=1 + i, t0=t_p0,
-                args={"rid": req.request_id, "rows": n_real})
-        return int(tok)
 
     def _ensure_blocks(self, active, horizon=1):
         """Grow any slot whose next ``horizon`` write positions cross
@@ -5209,103 +4752,7 @@ class ServingEngine:
         self._n_completed += 1
         self._m_occupancy.set(self.num_active)
 
-    def _bucket(self, n) -> int:
-        from ..generation import _prompt_bucket
-        return _prompt_bucket(n, self.config.min_prefill_bucket)
-
     # -- compiled steps -----------------------------------------------
-
-    def _compile_decode(self, lens, toks, samp, key):
-        """AOT-compile the fixed-shape batched decode step ONCE; every
-        later tick reuses the executable (shape change is impossible —
-        slots, tables and lengths are static width; the per-slot
-        sampling knobs ride in ``samp`` as data)."""
-        def decode(params, pools, tables, lens, toks, samp, key):
-            # inactive slots (lens == 0) are pad rows — keep them out
-            # of the MoE routing telemetry
-            with _moe.serving_rows_mask(lens > 0):
-                logits, pools = self._model_step(
-                    params, toks[:, None], pools, None,
-                    block_tables=tables, cache_lens=lens)
-            row = self._gather_logits(logits[:, -1, :])
-            _, sub = jax.random.split(key)
-            tok, _ = self._select_rows(row, sub, samp)
-            return tok, pools
-
-        jitted = jax.jit(decode, donate_argnums=(1,))
-        exec_ = self._aot_compile(
-            "decode", jitted,
-            (self._params, self._pools, self._dev(self._tables),
-             self._dev(lens), self._dev(toks), samp, key))
-        if self._mesh is not None:
-            self._tp_step_bytes = self._tp_census_bytes("decode")
-        self._m_decode_compiles.inc()
-        self._n_decode_compiles += 1
-        return exec_
-
-    def _compile_chunk(self, key):
-        """AOT-compile THE fixed-chunk prefill step ONCE (the whole
-        prefill zoo, collapsed): ``[1, C]`` token ids run the same
-        multi-query paged machinery as the speculative verify window
-        (``paged_verify_attention`` with ``T = C`` query rows at
-        ``cache_len + t``) — each row attends to every previously
-        cached block plus its own in-chunk causal prefix, and K/V are
-        written into the slot's blocks as the chunk executes. The next
-        token is sampled at the chunk's last REAL row (non-final chunks
-        ignore it). Pad rows of a short final chunk write past the
-        table's reach (routed to the null block by ``write_tokens``)
-        and are never read, so ONE executable serves every prompt
-        length with zero padding-bucket waste."""
-        c = self._chunk
-
-        def chunk(params, ids, pools, table_row, pos, last, samp, key):
-            lens = jnp.reshape(pos.astype(jnp.int32), (1,))
-            live = jnp.arange(c, dtype=jnp.int32) <= last
-            with _moe.serving_rows_mask(live):
-                logits, pools = self._model_step(
-                    params, ids, pools, None,
-                    block_tables=table_row[None], cache_lens=lens)
-            row = jax.lax.dynamic_slice_in_dim(
-                logits, last, 1, axis=1)[:, 0, :]
-            row = self._gather_logits(row)
-            _, sub = jax.random.split(key)
-            tok, _ = self._select_rows(row, sub, samp)
-            return tok[0], pools
-
-        jitted = jax.jit(chunk, donate_argnums=(2,))
-        exec_ = self._aot_compile(
-            "chunk", jitted,
-            (self._params, self._dev(np.zeros((1, c), np.int32)),
-             self._pools, self._dev(np.zeros((self._mb,), np.int32)),
-             self._dev(np.int32(0)), self._dev(np.int32(0)),
-             self._dev(self._samp_default), key))
-        self._m_prefill_compiles.labels(bucket=f"chunk{c}").inc()
-        self._n_prefill_compiles += 1
-        return exec_
-
-    def _compile_draft_chunk(self):
-        """Draft-cache twin of ``_compile_chunk``: write the draft
-        model's K/V for the same chunk positions through the SAME block
-        table row (no token is selected — the target picks the first
-        token). Also compiled exactly once."""
-        c = self._chunk
-
-        def dchunk(dparams, ids, dpools, table_row, pos):
-            lens = jnp.reshape(pos.astype(jnp.int32), (1,))
-            _, dpools = self._draft_step(
-                dparams, ids, dpools, None,
-                block_tables=table_row[None], cache_lens=lens)
-            return dpools
-
-        jitted = jax.jit(dchunk, donate_argnums=(2,))
-        exec_ = self._aot_compile(
-            "draft_chunk", jitted,
-            (self._dparams, self._dev(np.zeros((1, c), np.int32)),
-             self._dpools, self._dev(np.zeros((self._mb,), np.int32)),
-             self._dev(np.int32(0))))
-        self._m_prefill_compiles.labels(bucket=f"draft-chunk{c}").inc()
-        self._n_prefill_compiles += 1
-        return exec_
 
     def _compile_cow(self, pools):
         """AOT-compile the copy-on-write block duplicate (src/dst ride
@@ -5315,90 +4762,18 @@ class ServingEngine:
             "cow", jitted, (pools, self._dev(np.int32(0)),
                             self._dev(np.int32(0))))
 
-    def _compile_prefill(self, bucket, key):
-        def prefill(params, ids, n_real, pools, table_row, samp, key):
-            dense = self.model.init_caches(1, bucket)
-            live = jnp.arange(bucket, dtype=jnp.int32) < n_real
-            with _moe.serving_rows_mask(live):
-                logits, dense = self._model_step(
-                    params, ids, dense, jnp.zeros((), jnp.int32))
-            pools = [
-                _pc.write_prefill(kp, vp, table_row[None], dk, dv,
-                                  n_real=n_real)
-                for (kp, vp), (dk, dv) in zip(pools, dense)]
-            last = jax.lax.dynamic_slice_in_dim(
-                logits, n_real - 1, 1, axis=1)[:, 0, :]
-            last = self._gather_logits(last)
-            _, sub = jax.random.split(key)
-            tok, _ = self._select_rows(last, sub, samp)
-            return tok[0], pools
-
-        jitted = jax.jit(prefill, donate_argnums=(3,))
-        exec_ = self._aot_compile(
-            f"prefill{bucket}", jitted,
-            (self._params, self._dev(np.zeros((1, bucket), np.int32)),
-             self._dev(np.int32(0)), self._pools,
-             self._dev(np.zeros((self._mb,), np.int32)),
-             self._dev(self._samp_default), key))
-        self._m_prefill_compiles.labels(bucket=bucket).inc()
-        self._n_prefill_compiles += 1
-        return exec_
-
-    def _compile_verify(self, lens, toks, samp, dq, key):
-        """AOT-compile the fixed-gamma multi-token verify step ONCE
-        (the speculative decode executable — counted in
-        ``decode_compiles`` so the zero-steady-state-recompile
-        assertion covers speculative mode too). The per-slot sampling
-        knobs ride as the ``samp`` operand (``slot_params`` mode of
-        ``build_verify_step``) — distinct configs, one executable."""
-        from ..generation import speculative as _spec
-        verify = _spec.build_verify_step(
-            self._model_step, gamma=self._gamma,
-            do_sample=self._do_sample,
-            onehot_draft=self._draft_model is None,
-            gather_logits=self._gather_logits
-            if self._mesh is not None else None, slot_params=True)
-        g = self._gamma
-
-        def verify_masked(params, pools, tables, lens, *rest):
-            # inactive slots contribute gamma+1 pad rows each — keep
-            # them out of the MoE routing telemetry
-            with _moe.serving_rows_mask(jnp.repeat(lens > 0, g + 1)):
-                return verify(params, pools, tables, lens, *rest)
-
-        jitted = jax.jit(verify_masked, donate_argnums=(1,))
-        args = [self._params, self._pools, self._dev(self._tables),
-                self._dev(lens), self._dev(toks), samp]
-        if self._do_sample:
-            if dq is not None:
-                args.append(dq)
-            args.append(key)
-        exec_ = self._aot_compile("verify", jitted, tuple(args))
-        if self._mesh is not None:
-            # a spec step executes the draft loop AND the verify gather.
-            # The draft's gather sits inside a lax.scan body, which the
-            # census walks ONCE — the engine knows the trip count
-            # (gamma+1 iterations), so scale it to the bytes that
-            # actually move per step
-            self._tp_step_bytes = self._tp_census_bytes("verify") \
-                + (self._gamma + 1) * self._tp_census_bytes("draft")
-        self._m_decode_compiles.inc()
-        self._n_decode_compiles += 1
-        return exec_
-
     def _compile_ragged_step(self, args):
-        """AOT-compile THE ragged mixed-batch executable ONCE — the
-        whole per-width zoo (decode + verify + chunk prefill),
-        collapsed: a packed ``[R]`` token buffer runs the model over
-        every live row (``ragged_meta`` partitions it by slot), K/V
-        scatter per row, and the sampling head takes each slot's
+        """AOT-compile THE ragged mixed-batch executable ONCE (decode
+        + verify + chunk prefill in one): a packed ``[R]`` token
+        buffer runs the model over every live row (``ragged_meta``
+        partitions it by slot), K/V scatter per row, and the sampling
+        head takes each slot's
         continuation row from ``last_rows`` — decode rows sample their
         only row, completing prefills their final prompt row, verify
         windows run the shared acceptance core on their gamma+1 rows.
         ONE logits gather serves all of it (under TP: still exactly
-        one explicit all_gather per step). Census name stays
-        ``decode``/``verify`` so telemetry keeps the per-step
-        collective contract of the per-width path."""
+        one explicit all_gather per step). Its census name is
+        ``verify`` on a speculating engine, else ``decode``."""
         from ..generation import _filter_logits
         from ..generation import speculative as _spec
         g = self._gamma
@@ -5408,7 +4783,7 @@ class ServingEngine:
         heads_on = self._heads is not None
         lora_on = self._lora_on
         # adapter row index in the slots pack: appended AFTER the tree
-        # flags (when present) by _step_ragged
+        # flags (when present) by _ragged_dispatch
         lora_row = 5 if tree is not None else 4
         lora_scaling = self._lora_pool.scaling if lora_on else 1.0
         # grouped-matmul path only off-mesh: under TP the delta einsum
@@ -5634,13 +5009,12 @@ class ServingEngine:
     def _compile_ragged_draft(self, args):
         """AOT-compile the draft model's HALF of a ragged spec tick
         ONCE — one fused executable: (1) prime the draft cache over
-        this tick's prefill rows (the ragged write, logits discarded —
-        the legacy per-chunk draft prefill twin, folded in), then
-        (2) run the gamma+1-step proposal scan. With a draft model the
-        engine's steady state is therefore exactly TWO executables."""
+        this tick's prefill rows (the ragged write, logits
+        discarded), then (2) run the gamma+1-step proposal scan. With
+        a draft model the engine's steady state is therefore exactly
+        TWO executables."""
         from ..generation import speculative as _spec
         g = self._gamma
-        prime = self._chunked and self._prefill_rows > 0
         loop = _spec.build_draft_loop(
             self._draft_step, gamma=g, do_sample=self._do_sample,
             want_probs=self._do_sample,
@@ -5651,27 +5025,25 @@ class ServingEngine:
             ids, row_slot, prime_pos = drows[0], drows[1], drows[2]
             base, prime_q, row_starts, scan_lens, cur = (
                 dslots[0], dslots[1], dslots[2], dslots[3], dslots[4])
-            if prime:
-                nwin = jnp.arange(g + 1, dtype=jnp.int32)
-                win = jnp.arange(self._wmax, dtype=jnp.int32)
-                meta = (prime_q, row_starts, row_slot, prime_pos,
-                        nwin, win)
+            nwin = jnp.arange(g + 1, dtype=jnp.int32)
+            win = jnp.arange(self._wmax, dtype=jnp.int32)
+            meta = (prime_q, row_starts, row_slot, prime_pos,
+                    nwin, win)
 
-                def _prime(dp):
-                    with _moe.serving_rows_mask(
-                            prime_pos < self._overflow):
-                        _, dp = self._draft_step(
-                            dparams, ids[None, :], dp, None,
-                            block_tables=tables, cache_lens=base,
-                            ragged_meta=meta)
-                    return dp
+            def _prime(dp):
+                with _moe.serving_rows_mask(
+                        prime_pos < self._overflow):
+                    _, dp = self._draft_step(
+                        dparams, ids[None, :], dp, None,
+                        block_tables=tables, cache_lens=base,
+                        ragged_meta=meta)
+                return dp
 
-                # no pending prefill rows this tick -> the prime
-                # forward would only null-route pad writes; skip the
-                # whole pass at runtime (same executable, zero
-                # steady-state recompiles)
-                dpools = jax.lax.cond(jnp.max(prime_q) > 0, _prime,
-                                      lambda dp: dp, dpools)
+            # no pending prefill rows this tick -> the prime forward
+            # would only null-route pad writes; skip the whole pass at
+            # runtime (same executable, zero steady-state recompiles)
+            dpools = jax.lax.cond(jnp.max(prime_q) > 0, _prime,
+                                  lambda dp: dp, dpools)
             # non-verifying slots scan at the overflow length — pad
             # rows, excluded from the draft's routing telemetry
             with _moe.serving_rows_mask(scan_lens < self._overflow):
@@ -5683,54 +5055,3 @@ class ServingEngine:
 
         jitted = jax.jit(dstep, donate_argnums=(1,))
         return self._aot_compile("draft", jitted, args)
-
-    def _compile_draft(self, lens, toks, samp, key):
-        """AOT-compile the draft model's gamma+1-step proposal scan
-        ONCE (drafter='model'). ``samp`` carries the per-slot sampling
-        knobs — the draft filters its proposal logits with the SAME
-        values the verify step filters the target's (the
-        rejection-sampling soundness requirement, per slot)."""
-        from ..generation import speculative as _spec
-        loop = _spec.build_draft_loop(
-            self._draft_step, gamma=self._gamma,
-            do_sample=self._do_sample,
-            want_probs=self._do_sample,
-            gather_logits=self._gather_logits
-            if self._mesh is not None else None, slot_params=True)
-
-        def draft_masked(dparams, dpools, tables, lens, cur, samp,
-                         key):
-            with _moe.serving_rows_mask(lens > 0):
-                return loop(dparams, dpools, tables, lens, cur, samp,
-                            key)
-
-        jitted = jax.jit(draft_masked, donate_argnums=(1,))
-        return self._aot_compile(
-            "draft", jitted,
-            (self._dparams, self._dpools, self._dev(self._tables),
-             self._dev(lens), self._dev(toks[:, 0]), samp, key))
-
-    def _compile_draft_prefill(self, bucket):
-        """Draft-cache twin of ``_compile_prefill``: scatter the draft
-        model's prompt K/V into its pools through the SAME block table
-        row (no token is selected — the target picks the first
-        token)."""
-        def dprefill(dparams, ids, n_real, dpools, table_row):
-            dense = self._draft_model.init_caches(1, bucket)
-            _, dense = self._draft_step(dparams, ids, dense,
-                                        jnp.zeros((), jnp.int32))
-            return [
-                _pc.write_prefill(kp, vp, table_row[None], dk, dv,
-                                  n_real=n_real)
-                for (kp, vp), (dk, dv) in zip(dpools, dense)]
-
-        jitted = jax.jit(dprefill, donate_argnums=(3,))
-        exec_ = self._aot_compile(
-            f"draft_prefill{bucket}", jitted,
-            (self._dparams, self._dev(np.zeros((1, bucket), np.int32)),
-             self._dev(np.int32(0)), self._dpools,
-             self._dev(np.zeros((self._mb,), np.int32))))
-        self._m_prefill_compiles.labels(
-            bucket=f"draft-{bucket}").inc()
-        self._n_prefill_compiles += 1
-        return exec_

@@ -446,7 +446,7 @@ class TrainStep:
     """Whole-train-step compilation: loss, grads, clip, optimizer update in
     one donated XLA program. This is the structural replacement for the
     reference's fused optimizer + CINN path and the entry point used by
-    ``paddle.Model.fit`` and ``bench.py``.
+    ``paddle.Model.fit`` and ``chip_smoke.py``'s train phase.
 
     The first call compiles through the AOT path (trace → lower →
     compile) and the executable is REUSED for every later call with the

@@ -149,32 +149,16 @@ def paged_attention_decode(qh, kh, vh, k_pool, v_pool, block_tables,
     positions ``cache_lens[s] + t``, then attend q against each slot's
     length-bounded block list through the ragged paged kernel
     (``ops/pallas/paged_attention.py``; gather fallback off-TPU).
-    ``T = 1`` is the continuous-batching decode step; ``T > 1`` is
-    both the speculative verify window AND the serving engine's
-    chunked prefill (``T = prefill_chunk``) — causal within the
-    window: token ``t`` sees ``cache_lens[s] + t + 1`` positions,
-    which over a prompt chunk starting at ``cache_lens`` IS exact
-    causal prefill against the already-cached blocks (including
-    blocks mapped from the prefix cache). Only ``generate()``'s
-    one-program paged loop still prefills through the dense cached
-    path + ``ops.paged_cache.write_prefill``.
-    Tensor-parallel serving: inside a TP engine's trace
-    (``serving_tp_scope``, a mesh with a live ``mp`` axis, divisible
-    head counts) the SAME body runs inside ``shard_map``
-    — each shard writes/attends its contiguous kv_head slice of the
-    pool, block tables and lengths replicated, no collective inside
-    (``ops/pallas/paged_attention.sharded_paged_attention_step``).
+    ``T = 1`` is ``generate(cache_impl="paged")``'s decode step;
+    ``T > 1`` is ``SpecGenerator``'s speculative verify window —
+    causal within the window: token ``t`` sees
+    ``cache_lens[s] + t + 1`` positions. The serving engine does not
+    come here: its tick is ``ragged_paged_attention_decode`` below.
     Returns (out [S, T, H, D], new_k_pool, new_v_pool)."""
-    from ..ops.pallas.paged_attention import (paged_attention_step,
-                                              sharded_paged_attention_step,
-                                              tp_shard_degree)
-    sm = 1.0 / math.sqrt(head_dim)
-    if tp_shard_degree(qh.shape[2], kh.shape[2]) > 1:
-        return sharded_paged_attention_step(qh, kh, vh, k_pool, v_pool,
-                                            block_tables, cache_lens,
-                                            sm_scale=sm)
+    from ..ops.pallas.paged_attention import paged_attention_step
     return paged_attention_step(qh, kh, vh, k_pool, v_pool,
-                                block_tables, cache_lens, sm_scale=sm)
+                                block_tables, cache_lens,
+                                sm_scale=1.0 / math.sqrt(head_dim))
 
 
 def ragged_paged_attention_decode(qh, kh, vh, k_pool, v_pool,
@@ -189,8 +173,9 @@ def ragged_paged_attention_decode(qh, kh, vh, k_pool, v_pool,
     position ``row_pos[r]`` of slot ``row_slot[r]``. The per-width
     ``paged_attention_decode`` above is the uniform-width special case
     of this step; the serving engine's ONE ragged executable is its
-    only caller. Tensor-parallel serving routes the same body through
-    ``shard_map`` exactly like the per-width wrapper. Returns
+    only caller. Tensor-parallel serving (inside a TP engine's trace:
+    ``serving_tp_scope``, a mesh with a live ``mp`` axis, divisible
+    head counts) routes the same body through ``shard_map``. Returns
     ``(out [R, H, D], new_k_pool, new_v_pool)``."""
     from ..ops.pallas.paged_attention import (
         ragged_attention_step, sharded_ragged_attention_step,

@@ -350,9 +350,10 @@ def step_reports() -> Dict[str, dict]:
 
 
 # Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``:
-# kind -> (bf16 FLOP/s, HBM bytes/s, source). THE one table (bench.py
-# imports it). A device that is not here is an error, not a default —
-# a utilization against a guessed peak is not a measurement.
+# kind -> (bf16 FLOP/s, HBM bytes/s, source). The engine's table
+# (``benchmark/lib/peaks.py`` keeps the benchmark's own copy). A
+# device that is not here is an error, not a default — a utilization
+# against a guessed peak is not a measurement.
 DEVICE_PEAKS = {
     "TPU v5 lite": (197e12, 819e9,
                     'Google Cloud documentation, "TPU v5e": 197 TFLOP/s '

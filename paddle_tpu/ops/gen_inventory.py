@@ -55,8 +55,7 @@ _KERNEL_NOTES = [
     "",
     "Framework-wide runtime telemetry: a labeled metrics registry",
     "(Counter/Gauge/Histogram/Info), compiled-step cost/memory",
-    "accounting, and hot-path instrumentation. See also BENCH",
-    "`bench_detail.json`'s `telemetry` block.",
+    "accounting, and hot-path instrumentation.",
     "",
     "Environment variables:",
     "",

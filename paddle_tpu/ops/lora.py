@@ -75,7 +75,7 @@ MLP_TARGETS = ("gate_proj", "up_proj", "down_proj", "linear1", "linear2")
 def lora_enabled() -> bool:
     """Kill switch: ``PADDLE_TPU_LORA=0`` restores the base engine
     bit-for-bit (the gate is resolved ONCE at engine construction, like
-    ``PADDLE_TPU_RAGGED_BATCH``)."""
+    ``PADDLE_TPU_PREEMPT``)."""
     return os.environ.get("PADDLE_TPU_LORA", "1") != "0"
 
 

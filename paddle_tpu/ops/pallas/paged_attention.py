@@ -32,13 +32,13 @@ only kernel delta is ``t_q * rep`` softmax rows with a per-row length
 bound instead of ``rep`` rows with one shared bound (the single-token
 decode kernel is the ``t_q = 1`` instantiation of the same body).
 
-CHUNKED PREFILL is the same multi-query variant at ``T = chunk``
-(serving's one fixed-chunk prefill executable,
-``inference/serving.py``): a chunk of the prompt enters as T query
-rows at ``cache_lens + t``, attending to every previously cached
-block (possibly mapped from the content-addressed prefix cache) plus
-its own in-chunk causal prefix — prefill, verify, and decode are one
-kernel body at three ``t_q`` widths.
+CHUNKED PREFILL is the same multi-query arithmetic at ``T = chunk``:
+a chunk of the prompt enters as T query rows at ``cache_lens + t``,
+attending to every previously cached block (possibly mapped from the
+content-addressed prefix cache) plus its own in-chunk causal prefix.
+The serving engine runs it, with decode and verify, through the
+ragged variant below; the per-width kernels serve
+``generate(cache_impl="paged")`` and ``SpecGenerator``.
 
 The RAGGED MIXED-BATCH variant (``ragged_paged_attention``) goes the
 rest of the way per *Ragged Paged Attention*: ONE invocation consumes
@@ -82,8 +82,7 @@ same rule, for the engine's ``tick`` span (``attn_units`` /
 ``attn_live``). The XLA fallback scatters the packed rows into the
 per-slot padded ``[S, W, H, D]`` layout and calls the SAME
 ``_xla_paged_verify`` einsum, so every row is bitwise the per-width
-fallback's output — the serving engine's CPU parity between the
-ragged step and the per-width zoo is exact by construction.
+fallback's output (test-pinned).
 
 QUANTIZED POOLS (``paged_cache.QuantKV`` — int8 data + per-(block,
 position, head) f32 absmax scales): all three kernel variants take
@@ -119,7 +118,6 @@ __all__ = ["paged_decode_attention", "pallas_paged_attention",
            "paged_verify_attention", "pallas_paged_verify_attention",
            "ragged_paged_attention", "pallas_ragged_paged_attention",
            "paged_attention_step", "ragged_attention_step",
-           "sharded_paged_attention_step",
            "sharded_ragged_attention_step", "kernel_fallback_counts",
            "tp_shard_degree", "serving_tp_scope",
            "serving_tp_active", "tree_ancestor_bits",
@@ -958,42 +956,25 @@ def pallas_ragged_latent_attention(q, pool, block_tables, context_lens,
 
 def _xla_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
                          sm_scale=None):
-    """Gather-based fallback: dense per-slot view of the pooled blocks,
-    masked by length. Mirrors ``cached_attention``'s dtype recipe
-    (f32 score accumulation, input-dtype PV contraction) so greedy
-    decode matches the dense path token-for-token."""
-    s, h, d = q.shape
-    hkv = k_pool.shape[2]
-    rep = h // hkv
-    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    from ..paged_cache import QuantKV, gather_dense
-    # quantized pools: gather_dense dequantizes to f32 and the math
-    # STAYS f32 (no re-round to the activation dtype) — the kernel's
-    # in-VMEM dequant recipe, value for value
-    ad = jnp.float32 if isinstance(k_pool, QuantKV) else q.dtype
-    k = gather_dense(k_pool, block_tables)      # [S, L, Hkv, D]
-    v = gather_dense(v_pool, block_tables)
-    lens = context_lens.astype(jnp.int32)
-    q5 = q.reshape(s, hkv, rep, d)
-    scores = jnp.einsum(
-        "sgrd,slgd->sgrl", q5, k.astype(ad),
-        preferred_element_type=jnp.float32) * scale
-    pos = jnp.arange(k.shape[1], dtype=jnp.int32)
-    bias = jnp.where(pos[None, :] < lens[:, None], 0.0, -1e9)
-    scores = scores + bias[:, None, None, :]
-    w = jax.nn.softmax(scores, axis=-1).astype(ad)
-    out = jnp.einsum("sgrl,slgd->sgrd", w, v.astype(ad))
-    return out.astype(q.dtype).reshape(s, h, d)
+    """Gather-based fallback of the single-token decode step: the
+    ``T = 1`` window of ``_xla_paged_verify``, so a decode row is
+    bitwise the same row of a verify window or of the ragged mirror
+    (one body, one order of operations)."""
+    return _xla_paged_verify(q[:, None], k_pool, v_pool, block_tables,
+                             context_lens, sm_scale=sm_scale)[:, 0]
 
 
 def _xla_paged_verify(q, k_pool, v_pool, block_tables, context_lens,
                       sm_scale=None, tree_anc=None, tree_rows=None):
-    """Multi-query gather fallback (speculative verify window): same
-    dtype recipe as ``_xla_paged_attention`` with a per-window-token
-    causal bound, so the verify forward is the numerics twin of T
-    sequential single-token decode steps — greedy acceptance stays
-    token-exact on CPU. ``tree_anc`` (static parent tuple) swaps the
-    linear bound for the ancestor-path tree mask, op-for-op the
+    """Multi-query gather fallback (speculative verify window): a
+    dense per-slot view of the pooled blocks, masked by a
+    per-window-token causal bound. Mirrors ``cached_attention``'s
+    dtype recipe (f32 score accumulation, input-dtype PV contraction)
+    so greedy decode matches the dense path token-for-token, and the
+    verify forward is the numerics twin of T sequential single-token
+    decode steps — greedy acceptance stays token-exact on CPU.
+    ``tree_anc`` (static parent tuple) swaps the linear bound for the
+    ancestor-path tree mask, op-for-op the
     kernels' recipe; ``tree_rows`` ([S] flags, ``None`` = all) selects
     which slots carry a tree window (the others keep the linear
     bound — a chain tree's mask IS the linear bound, so parity pins
@@ -1003,8 +984,9 @@ def _xla_paged_verify(q, k_pool, v_pool, block_tables, context_lens,
     rep = h // hkv
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     from ..paged_cache import QuantKV, gather_dense
-    # quantized pools: keep the dequantized f32 through the dots (the
-    # kernel's recipe — see _xla_paged_attention)
+    # quantized pools: gather_dense dequantizes to f32 and the math
+    # STAYS f32 (no re-round to the activation dtype) — the kernel's
+    # in-VMEM dequant recipe, value for value
     ad = jnp.float32 if isinstance(k_pool, QuantKV) else q.dtype
     k = gather_dense(k_pool, block_tables)      # [S, L, Hkv, D]
     v = gather_dense(v_pool, block_tables)
@@ -1251,13 +1233,11 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
 def paged_attention_step(qh, kh, vh, k_pool, v_pool, block_tables,
                          cache_lens, sm_scale=None):
     """Write this step's K/V into the pool and attend — the shared
-    decode/verify/chunk body behind the models' paged forward:
-    ``T = 1`` (qh ``[S, 1, H, D]``) is the continuous-batching decode
-    step, ``T > 1`` the speculative verify window and the serving
-    engine's chunked prefill. Also the PER-SHARD body of the
-    tensor-parallel wrapper below — on a kv_head slice of the pool the
-    grid/fallback run completely unmodified, since nothing here ever
-    mixes kv heads. Returns ``(out [S, T, H, D], k_pool, v_pool)``."""
+    per-width body behind the models' paged forward
+    (``generate(cache_impl="paged")``, ``SpecGenerator``): ``T = 1``
+    (qh ``[S, 1, H, D]``) is the decode step, ``T > 1`` the
+    speculative verify window. Returns
+    ``(out [S, T, H, D], k_pool, v_pool)``."""
     from ..paged_cache import write_decode, write_tokens
     lens = cache_lens.astype(jnp.int32)
     if qh.shape[1] == 1:
@@ -1415,11 +1395,17 @@ def sharded_ragged_attention_step(qh, kh, vh, k_pool, v_pool,
                                   sm_scale=None):
     """Tensor-parallel ``ragged_attention_step``: the same write+attend
     body inside ``shard_map`` over the mesh's ``mp`` axis — q/k/v
-    ``[R, H, D]`` and the pools split on their head dim (each shard a
-    contiguous kv_head group, exactly the per-width wrapper's cut;
-    int8 pools' scale halves ride the same cut), block tables, lengths
-    and ALL row metadata replicated. No collective inside; the step's
-    only cross-shard traffic stays the engine's logits gather."""
+    ``[R, H, D]`` and the pools ``[NB, BS, H_kv, D]`` split on their
+    head dim: each shard owns a contiguous kv_head GROUP slice, so GQA
+    routing, the Pallas grid and the XLA mirror all run unmodified on
+    local shapes (``rep = H/H_kv`` is shard-invariant; int8 pools'
+    scale halves ride the same cut). Block tables, lengths and ALL row
+    metadata are REPLICATED: block ids are global, one host allocator
+    serves every shard, and each shard's pool slice is indexed by the
+    same tables — which is why prefix caching, COW, speculative
+    rollback and chunked prefill compose with TP for free. No
+    collective inside; the step's only cross-shard traffic stays the
+    engine's logits gather."""
     import jax.sharding as _js
     from ...distributed.shard_utils import current_mesh
     P = _js.PartitionSpec
@@ -1524,45 +1510,6 @@ def tp_shard_degree(num_heads, num_kv_heads) -> int:
     if tp <= 1 or num_heads % tp or num_kv_heads % tp:
         return 1
     return tp
-
-
-def sharded_paged_attention_step(qh, kh, vh, k_pool, v_pool,
-                                 block_tables, cache_lens,
-                                 sm_scale=None):
-    """Tensor-parallel ``paged_attention_step``: the same write+attend
-    body inside ``shard_map`` over the current mesh's ``mp`` axis.
-
-    Per-shard layout (*GSPMD*-style sharding of the serving
-    executables, cut along kv_heads as in *Ragged Paged Attention*'s
-    per-head grid): q/k/v ``[S, T, H, D]`` and both pools
-    ``[NB, BS, H_kv, D]`` split on their head dim — each shard owns a
-    contiguous kv_head GROUP slice, so GQA routing, the Pallas grid
-    ``(slot, kv_head, block)`` and the XLA gather fallback all run
-    unmodified on local shapes (``rep = H/H_kv`` is shard-invariant).
-    Block tables and lengths are REPLICATED: block ids are global, one
-    host allocator serves every shard, and each shard's pool slice is
-    indexed by the same tables — which is why prefix caching, COW,
-    speculative rollback and chunked prefill compose with TP for free.
-    No collective runs in here at all; the step's only cross-shard
-    traffic is the logits gather the serving engine adds before
-    sampling."""
-    import jax.sharding as _js
-    from ...distributed.shard_utils import current_mesh
-    P = _js.PartitionSpec
-    mesh = current_mesh()
-    heads = P(None, None, "mp", None)     # q/k/v head dim
-    kspec, vspec = _pool_pspec(k_pool), _pool_pspec(v_pool)
-
-    def local(q, k, v, kp, vp, tables, lens):
-        return paged_attention_step(q, k, v, kp, vp, tables, lens,
-                                    sm_scale=sm_scale)
-
-    f = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(heads, heads, heads, kspec, vspec,
-                  P(None, None), P(None)),
-        out_specs=(heads, kspec, vspec), check_vma=False)
-    return f(qh, kh, vh, k_pool, v_pool, block_tables, cache_lens)
 
 
 def paged_verify_attention(q, k_pool, v_pool, block_tables,

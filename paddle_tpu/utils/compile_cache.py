@@ -6,8 +6,8 @@ placed from OUTSIDE the program: where ``JAX_COMPILATION_CACHE_DIR`` is
 set JAX reads it itself and nothing here sets another; otherwise the
 cache goes to ``<checkout>/.jax_cache`` — a fixed path, because the
 path is part of the cache key (a ``tempfile``/pid/time directory never
-hits). Entry points that run on the chip (``chip_smoke.py``,
-``bench.py``) call :func:`configure` before their first compile.
+hits). ``chip_smoke.py`` calls :func:`configure` before its first
+compile.
 """
 from __future__ import annotations
 

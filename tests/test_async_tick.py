@@ -52,7 +52,7 @@ def gpt_tiny():
 
 def _scfg(**kw):
     base = dict(num_slots=2, block_size=8, max_model_len=64,
-                prefill_chunk=8, min_prefill_bucket=8)
+                prefill_chunk=8)
     base.update(kw)
     return ServingConfig(**base)
 
@@ -194,11 +194,6 @@ def test_async_depth_validation(llama_tiny):
         _scfg(async_depth=2)
     with pytest.raises(ValueError, match="async_depth"):
         _scfg(async_depth=True)
-    # explicit depth on the legacy per-width engine is a loud error;
-    # the env-armed default silently degrades instead
-    with pytest.raises(NotImplementedError, match="async"):
-        ServingEngine(llama_tiny, _scfg(async_depth=1,
-                                        ragged_batch=False))
 
 
 # ------------------------------------------------ steady-state pins

@@ -43,7 +43,7 @@ def llama_tiny():
 
 def _scfg(**kw):
     base = dict(num_slots=2, block_size=8, max_model_len=96,
-                prefill_chunk=8, min_prefill_bucket=8)
+                prefill_chunk=8)
     base.update(kw)
     return ServingConfig(**base)
 

@@ -375,21 +375,10 @@ def test_roofline_against_device_peaks(llama_tiny, monkeypatch):
     assert monitor.gauge("serving_hbm_bw_util").value() > 0.0
 
 
-def test_roofline_stats_legacy_and_spec_paths(llama_tiny):
-    """The legacy per-width path attributes decode ticks AND chunk
-    prefills; a speculative engine attributes its verify tick — the
-    roofline block covers every step path, not just the default."""
+def test_roofline_stats_speculative_engine(llama_tiny):
+    """A speculative engine attributes its tick to the ``verify``
+    executable: the roofline block follows the tick's name."""
     rng = np.random.RandomState(17)
-    eng = ServingEngine(llama_tiny, ServingConfig(
-        num_slots=2, block_size=8, max_model_len=64,
-        prefill_chunk=16, ragged_batch=False))
-    eng.serve(_prompts(rng, (6, 20)), max_new_tokens=4)
-    roof = eng.stats()["roofline"]
-    eng.shutdown()
-    assert roof["per_executable"]["decode"]["step_time_ms"] > 0
-    assert roof["per_executable"]["chunk"]["ticks"] > 0
-    assert roof["per_executable"]["chunk"]["flops"] > 0
-
     phrase = rng.randint(1, 128, (6,))
     eng = ServingEngine(llama_tiny, ServingConfig(
         num_slots=2, block_size=8, max_model_len=64,

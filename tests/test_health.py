@@ -469,13 +469,13 @@ def test_cluster_watchdog_drains_stuck_replica(tmp_path, monkeypatch):
                              health_watchdog_floor_s=0.05,
                              health_watchdog_mult=1.0))
     eng1 = cl.engines[1]
-    orig = eng1._step_dispatch
+    orig = eng1._step_ragged
 
     def slow():
         time.sleep(0.12)            # > deadline, inside step()'s timer
         return orig()
 
-    eng1._step_dispatch = slow
+    eng1._step_ragged = slow
     rng = np.random.RandomState(5)
     rids = [cl.submit(rng.randint(1, 128, (9,)), 4) for _ in range(6)]
     with pytest.warns(UserWarning, match="watchdog"):

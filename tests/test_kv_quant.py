@@ -48,7 +48,7 @@ def llama_tiny():
 
 def _mk_engine(model, **kw):
     base = dict(num_slots=2, block_size=8, max_model_len=96,
-                prefill_chunk=8, min_prefill_bucket=8)
+                prefill_chunk=8)
     base.update(kw)
     return ServingEngine(model, ServingConfig(**base))
 
@@ -311,14 +311,23 @@ def test_engine_int8_exact_prefix_cache(llama_tiny):
     _assert_exact(cold, warm2, "int8 cold vs cached wave")
 
 
-def test_engine_int8_exact_ragged_on_off(llama_tiny):
+def test_engine_int8_matches_paged_generate(llama_tiny):
+    """The engine over an int8 pool emits what ``generate(cache_impl=
+    "paged", kv_cache_dtype="int8")`` emits for each prompt alone: the
+    per-width paged loop is an independent reference (dense prefill
+    scattered into the pool, one decode row a step) that stores and
+    reads the same quantized bytes."""
     prompts = _prompts(seed=7)
-    on, st_on = _serve(llama_tiny, prompts, kv_cache_dtype="int8",
-                       ragged_batch=True)
-    off, st_off = _serve(llama_tiny, prompts, kv_cache_dtype="int8",
-                         ragged_batch=False)
-    assert st_on["ragged_batch"] and not st_off["ragged_batch"]
-    _assert_exact(off, on, "int8 ragged vs legacy")
+    got, st = _serve(llama_tiny, prompts, kv_cache_dtype="int8")
+    assert st["kv_cache_dtype"] == "int8"
+    want = []
+    for p in prompts:
+        out, _ = llama_tiny.generate(
+            paddle.to_tensor(p[None].astype(np.int64)),
+            max_new_tokens=6, cache_impl="paged",
+            kv_cache_dtype="int8", decode_strategy="greedy_search")
+        want.append(np.asarray(out.numpy())[0])
+    _assert_exact(want, got, "int8 engine vs paged generate")
 
 
 def test_engine_int8_spec_ngram(llama_tiny):

@@ -441,15 +441,6 @@ def test_kill_switch_bit_parity(llama_tiny, monkeypatch):
         np.testing.assert_array_equal(got, want)
 
 
-def test_requires_ragged_chunked(llama_tiny):
-    """LoRA needs prompt rows on the ragged tick (dense bucketed
-    prefill would write base-model KV): construction fails fast."""
-    with pytest.raises(NotImplementedError, match="ragged"):
-        ServingEngine(llama_tiny, _scfg(ragged_batch=False))
-    with pytest.raises(NotImplementedError, match="chunked"):
-        ServingEngine(llama_tiny, _scfg(chunked_prefill=False))
-
-
 def test_stats_keys_always_present(llama_tiny):
     """The four lora_* stats keys ride every engine's stats() — LoRA
     configured or not — so dashboards never key-error."""

@@ -212,30 +212,27 @@ def _dense_refs(model, prompts, max_new):
     return outs
 
 
-def test_qwen2_moe_engine_greedy_exact_ragged_on_off():
-    """Qwen2-MoE (dropless) serves through ``ServingEngine`` — paged +
-    ragged paths — greedy token-exact vs ``generate(
-    cache_impl="dense")``, with the ragged and legacy per-width paths
-    agreeing."""
+def test_qwen2_moe_engine_greedy_exact():
+    """Qwen2-MoE (dropless) serves through ``ServingEngine`` greedy
+    token-exact vs ``generate(cache_impl="dense")``."""
     from paddle_tpu.inference import ServingConfig, ServingEngine
     model = _tiny_qwen2_moe()
     rng = np.random.RandomState(0)
     prompts = [rng.randint(1, 128, (n,)).astype(np.int32)
                for n in (5, 9, 13)]
     refs = _dense_refs(model, prompts, 6)
-    for ragged in (True, False):
-        eng = ServingEngine(model, ServingConfig(
-            num_slots=3, block_size=4, max_model_len=64,
-            max_new_tokens=6, prefill_chunk=8, ragged_batch=ragged))
-        outs = eng.serve([p.copy() for p in prompts], max_new_tokens=6)
-        st = eng.stats()
-        eng.shutdown()
-        for o, r in zip(outs, refs):
-            assert (np.asarray(o) == r).all(), (ragged, o, r)
-        assert st["moe"] is True
-        assert st["moe_dispatches"] > 0
-        assert st["moe_routing_entropy"] > 0.0
-        assert st["moe_expert_load_max"] > 0.0
+    eng = ServingEngine(model, ServingConfig(
+        num_slots=3, block_size=4, max_model_len=64,
+        max_new_tokens=6, prefill_chunk=8))
+    outs = eng.serve([p.copy() for p in prompts], max_new_tokens=6)
+    st = eng.stats()
+    eng.shutdown()
+    for o, r in zip(outs, refs):
+        assert (np.asarray(o) == r).all(), (o, r)
+    assert st["moe"] is True
+    assert st["moe_dispatches"] > 0
+    assert st["moe_routing_entropy"] > 0.0
+    assert st["moe_expert_load_max"] > 0.0
 
 
 def test_deepseek_moe_engine_greedy_exact():

@@ -44,7 +44,7 @@ def llama_tiny():
 
 def _scfg(**kw):
     base = dict(num_slots=2, block_size=8, max_model_len=96,
-                prefill_chunk=8, min_prefill_bucket=8)
+                prefill_chunk=8)
     base.update(kw)
     return ServingConfig(**base)
 
@@ -672,24 +672,33 @@ def test_warm_migration_builds_the_spill_gather(llama_tiny):
 
 
 @pytest.mark.parametrize("closer", ["shutdown", "purge_published",
-                                    "export_session"])
+                                    "export_session", "idle_step"])
 def test_launched_spills_are_taken_in_before_the_tier_is_read(
         llama_tiny, closer):
     """A spill launched outside any tick (here by a bare allocation,
     as ``admit_prefilled`` / ``admit_migrated`` make them) is on the
     host before ``shutdown()``, ``purge_published()`` or a session
-    export return: nothing stays pending, and every byte launched is
-    accounted."""
+    export return, and after a ``step()`` that launched nothing (no
+    ``commit`` ran to take it in: the tick's own closing drain did):
+    nothing stays pending, and every byte launched is accounted."""
     eng, _, _, rng = _full_of_published(llama_tiny)
     eng.submit(rng.randint(1, 128, (9,)), 8)
     for _ in range(3):
         eng.step()                  # slot 0 decodes; nothing evicted
+    if closer == "idle_step":
+        eng.run()                   # nothing seated, nothing queued
     st = eng.stats()
     assert st["cache_evictions"] == st["kv_spill_bytes_copied"] == 0
     eng._alloc.free(eng._alloc.alloc(eng._alloc.free_blocks))
     n = len(eng._spill_pending)
     assert n >= 2
-    getattr(eng, closer)(*((0,) if closer == "export_session" else ()))
+    if closer == "idle_step":
+        steps = st["decode_steps"]
+        eng.step()
+        assert eng.stats()["decode_steps"] == steps     # no launch
+    else:
+        getattr(eng, closer)(
+            *((0,) if closer == "export_session" else ()))
     assert not eng._spill_pending
     assert eng.stats()["kv_spill_bytes_copied"] == n * eng._spill_nbytes
     if closer == "purge_published":

@@ -3,14 +3,13 @@ block reuse (refcounts, hash->block index, LRU eviction), copy-on-write
 on shared-block appends, the ONE fixed-chunk prefill executable
 (zero steady-state prefill recompiles), greedy token exactness with
 prefix caching ON vs OFF (Llama / GPT / int8 / speculative), the
-chunk-attention kernel in interpret mode, both kill switches
-(``PADDLE_TPU_PREFIX_CACHE=0`` / ``PADDLE_TPU_CHUNKED_PREFILL=0``),
-and ``BlockAllocator.check_leaks`` at engine shutdown.
+chunk-attention kernel in interpret mode, the kill switch
+(``PADDLE_TPU_PREFIX_CACHE=0``), and ``BlockAllocator.check_leaks`` at
+engine shutdown.
 
 Tier-1 guard: every test here must run in the standard
 ``-m 'not slow'`` sweep — ``test_tier1_no_slow_marker`` pins that.
 """
-import os
 
 import numpy as np
 import pytest
@@ -33,7 +32,7 @@ def llama_tiny():
 
 def _mk_engine(model, **kw):
     base = dict(num_slots=2, block_size=8, max_model_len=96,
-                prefill_chunk=8, min_prefill_bucket=8)
+                prefill_chunk=8)
     base.update(kw)
     return ServingEngine(model, ServingConfig(**base))
 
@@ -338,28 +337,32 @@ def test_prefix_exactness_with_speculative(llama_tiny):
     assert st["prefix_blocks_reused"] > 0
 
 
-def test_interleaved_prefill_matches_synchronous(llama_tiny):
-    """``max_prefill_chunks_per_step`` spreads a prompt's chunks across
-    engine ticks (decode keeps running for admitted slots) without
-    changing a single emitted token."""
+def test_prefill_row_budget_spreads_prompt_same_tokens(llama_tiny):
+    """A small ``ragged_prefill_rows`` spreads a prompt's rows across
+    many more engine ticks (decode keeps running for admitted slots)
+    without changing a single emitted token."""
     rng = np.random.RandomState(8)
-    prompts = [rng.randint(1, 128, (n,)) for n in (21, 5, 33, 9)]
-    sync = _mk_engine(llama_tiny)
-    want = sync.serve(list(prompts), max_new_tokens=5)
-    sync.shutdown()
-    eng = _mk_engine(llama_tiny, max_prefill_chunks_per_step=1)
+    lens = (21, 5, 33, 9)
+    prompts = [rng.randint(1, 128, (n,)) for n in lens]
+    wide = _mk_engine(llama_tiny)
+    want = wide.serve(list(prompts), max_new_tokens=5)
+    st_wide = wide.stats()
+    wide.shutdown()
+    eng = _mk_engine(llama_tiny, ragged_prefill_rows=3)
     got = eng.serve(list(prompts), max_new_tokens=5)
     st = eng.stats()
     eng.shutdown()
     for a, b in zip(got, want):
         np.testing.assert_array_equal(a, b)
-    assert st["prefill_chunks"] >= sum(-(-n // 8) for n in
-                                       (21, 5, 33, 9))
+    # at most 3 prompt rows ride a tick: every prompt takes at least
+    # ceil(n / 3) of them, where the default budget takes ceil(n / 8)
+    assert st["prefill_chunks"] >= sum(-(-n // 3) for n in lens)
+    assert st["prefill_chunks"] > st_wide["prefill_chunks"]
     assert st["requests_completed"] == 4
 
 
 # ------------------------------------------ one executable + kill
-# switches
+# switch
 
 
 def test_zero_steadystate_prefill_recompiles(llama_tiny):
@@ -431,29 +434,7 @@ def test_kill_switch_prefix_cache(llama_tiny, monkeypatch):
     assert st["prefix_cache_enabled"] is False
     assert st["prefix_blocks_reused"] == 0
     assert st["cached_blocks"] == 0
-    assert st["chunked_prefill"] is True     # chunking unaffected
-
-
-def test_kill_switch_chunked_prefill(llama_tiny, monkeypatch):
-    """Chunked prefill off -> the legacy bucketed zoo returns (and
-    prefix caching, which needs it, is forced off) with identical
-    greedy tokens."""
-    rng = np.random.RandomState(7)
-    prompts = [rng.randint(1, 128, (n,)) for n in (5, 12, 21)]
-    eng = _mk_engine(llama_tiny)
-    want = eng.serve(list(prompts), max_new_tokens=5)
-    eng.shutdown()
-    monkeypatch.setenv("PADDLE_TPU_CHUNKED_PREFILL", "0")
-    leg = _mk_engine(llama_tiny)
-    got = leg.serve(list(prompts), max_new_tokens=5)
-    st = leg.stats()
-    leg.shutdown()
-    for a, b in zip(got, want):
-        np.testing.assert_array_equal(a, b)
-    assert st["chunked_prefill"] is False
-    assert st["prefix_cache_enabled"] is False
-    assert st["prefill_chunks"] == 0
-    assert st["prefill_compiles"] >= 2       # one per bucket again
+    assert st["prefill_chunks"] > 0          # chunking unaffected
 
 
 # -------------------------------------------- kernel parity + telemetry
@@ -508,8 +489,7 @@ def test_prefix_telemetry_in_stats_and_jsonl(tmp_path, llama_tiny):
     names = {json.loads(line)["name"] for line in open(path)}
     for want in ("serving_prefix_blocks_reused",
                  "serving_prefix_tokens_reused", "serving_cow_copies",
-                 "serving_cache_evictions", "serving_prefix_hit_rate",
-                 "serving_prefill_compiles"):
+                 "serving_cache_evictions", "serving_prefix_hit_rate"):
         assert want in names, f"{want} missing from JSONL export"
 
 

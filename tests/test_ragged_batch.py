@@ -4,19 +4,17 @@ as a single packed ragged batch. Covered here: the ragged row-layout
 helper and per-row pool scatter, interpret-mode parity of the ragged
 Pallas grid vs the XLA fallback on mixed batches (slots at block
 boundaries, zero-row/retired slots), bitwise equality of the fallback
-vs each sequential per-width path (T=1 decode, gamma+1 verify, chunk
-prefill), engine-level greedy token-exactness ragged ON vs OFF across
-Llama / GPT / int8 / speculative (ngram + draft model) / prefix-cache
-paths and under TP=2, the 1-executable (2 with draft) steady-state pin
-with zero recompiles under concurrent admissions, the
-``PADDLE_TPU_RAGGED_BATCH=0`` kill switch, and the
+vs each sequential per-width mirror (T=1 decode, gamma+1 verify, chunk
+prefill), engine-level greedy token-exactness against
+``generate(cache_impl="dense")`` across Llama / GPT / int8 /
+speculative (ngram + draft model) / prefix-cache engines and under
+TP=2, the 1-executable (2 with draft) steady-state pin with zero
+recompiles under concurrent admissions, and the
 ``serving_kernel_fallback`` telemetry satellite.
 
 Tier-1 guard: every test here must run in the standard
 ``-m 'not slow'`` sweep — ``test_tier1_no_slow_marker`` pins that.
 """
-import os
-
 import numpy as np
 import pytest
 
@@ -36,13 +34,12 @@ def llama_tiny():
     return m
 
 
-def _serve_waves(model, ragged, monkeypatch, prompts, max_new=6,
-                 waves=2, draft=None, **kw):
-    """Serve ``waves`` rounds of the same prompts with the ragged path
-    forced ON or OFF; returns (outputs, stats)."""
-    monkeypatch.setenv("PADDLE_TPU_RAGGED_BATCH", "1" if ragged else "0")
+def _serve_waves(model, prompts, max_new=6, waves=2, draft=None,
+                 **kw):
+    """Serve ``waves`` rounds of the same prompts; returns (outputs,
+    stats)."""
     base = dict(num_slots=2, block_size=8, max_model_len=96,
-                prefill_chunk=8, min_prefill_bucket=8)
+                prefill_chunk=8)
     base.update(kw)
     eng = ServingEngine(model, ServingConfig(**base), draft_model=draft)
     outs = []
@@ -51,6 +48,20 @@ def _serve_waves(model, ragged, monkeypatch, prompts, max_new=6,
     st = eng.stats()
     eng.shutdown()
     return outs, st
+
+
+def _dense_refs(model, prompts, max_new):
+    """The independent reference: greedy ``generate`` over the dense
+    cache, one prompt at a time — no block pool, no packed rows, no
+    engine."""
+    outs = []
+    for p in prompts:
+        out, _ = model.generate(
+            paddle.to_tensor(np.asarray(p)[None].astype(np.int64)),
+            max_new_tokens=max_new, cache_impl="dense",
+            decode_strategy="greedy_search")
+        outs.append(np.asarray(out.numpy())[0])
+    return outs
 
 
 def _assert_equal_streams(a, b, tag):
@@ -289,13 +300,12 @@ def _direct_grid_count(q_lens, ctx, tq, span, heads, n_tiles):
     return (live + (n_tiles - tiles)) * heads, live * heads
 
 
-def test_tick_span_counts_the_attention_grid(llama_tiny, monkeypatch):
+def test_tick_span_counts_the_attention_grid(llama_tiny):
     """``attn_units`` / ``attn_live`` on the ``tick`` span are the
     (query tile, kv head, kv tile) units one layer's ragged attention
     call visits, counted on the host in ``pack``: a hand-made tick (a
     23-token prompt prefilled 8 rows a tick beside one decoding slot)
     against a direct count."""
-    monkeypatch.setenv("PADDLE_TPU_RAGGED_BATCH", "1")
     rng = np.random.RandomState(2)
     eng = ServingEngine(llama_tiny, ServingConfig(
         num_slots=3, block_size=8, max_model_len=192, prefill_chunk=8))
@@ -324,25 +334,29 @@ def test_tick_span_counts_the_attention_grid(llama_tiny, monkeypatch):
 
 
 # ----------------------------------------------- engine-level exactness
-# ragged ON vs OFF
+# the engine == an independent reference
 
 
-def test_ragged_exact_llama_with_prefix_cache(llama_tiny, monkeypatch):
+def _llama(seed, **kw):
+    paddle.seed(seed)
+    cfg = dict(vocab=128, hidden=64, layers=2, heads=4, kv_heads=2,
+               ffn=128)
+    cfg.update(kw)
+    m = LlamaForCausalLM(LlamaConfig.tiny(**cfg))
+    m.eval()
+    return m
+
+
+def _case_llama_with_prefix_cache():
     rng = np.random.RandomState(0)
     sysp = rng.randint(1, 128, (24,))
     prompts = [np.concatenate([sysp, rng.randint(1, 128, (t,))])
                for t in (5, 9, 3)]
-    want, st_off = _serve_waves(llama_tiny, False, monkeypatch, prompts)
-    got, st_on = _serve_waves(llama_tiny, True, monkeypatch, prompts)
-    _assert_equal_streams(got, want, "llama ragged vs legacy")
-    assert st_on["ragged_batch"] is True
-    assert st_off["ragged_batch"] is False
-    assert st_on["prefix_blocks_reused"] > 0    # cache composes
-    assert st_on["executables_compiled"] == 1
-    assert st_off["executables_compiled"] > 1   # the zoo
+    return _llama(7), prompts, dict(), dict(
+        executables_compiled=1, prefix_blocks_reused=True)
 
 
-def test_ragged_exact_gpt(monkeypatch):
+def _case_gpt():
     from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
     paddle.seed(3)
     m = GPTForCausalLM(GPTConfig.tiny(vocab=96, hidden=64, layers=2,
@@ -351,75 +365,81 @@ def test_ragged_exact_gpt(monkeypatch):
     rng = np.random.RandomState(5)
     prompts = [rng.randint(1, 96, (n,)).astype(np.int64)
                for n in (5, 11, 8)]
-    want, _ = _serve_waves(m, False, monkeypatch, prompts, max_new=4,
-                           waves=1, max_model_len=64)
-    got, st = _serve_waves(m, True, monkeypatch, prompts, max_new=4,
-                           waves=1, max_model_len=64)
-    _assert_equal_streams(got, want, "gpt ragged vs legacy")
-    assert st["executables_compiled"] == 1
+    return m, prompts, dict(max_new=4, waves=1, max_model_len=64), \
+        dict(executables_compiled=1)
 
 
-def test_ragged_exact_int8(monkeypatch):
+def _case_int8():
     from paddle_tpu.nn.quant import quantize_for_inference
-    paddle.seed(11)
-    cfg = LlamaConfig.tiny(vocab=128, hidden=64, layers=2, heads=4,
-                           kv_heads=2, ffn=128)
-    m = LlamaForCausalLM(cfg)
-    m.eval()
+    m = _llama(11)
     assert quantize_for_inference(m) > 0
     rng = np.random.RandomState(9)
     prompts = [rng.randint(1, 128, (n,)).astype(np.int64)
                for n in (6, 10)]
-    want, _ = _serve_waves(m, False, monkeypatch, prompts, max_new=4,
-                           waves=1, max_model_len=64)
-    got, st = _serve_waves(m, True, monkeypatch, prompts, max_new=4,
-                           waves=1, max_model_len=64)
-    _assert_equal_streams(got, want, "int8 ragged vs legacy")
-    assert st["executables_compiled"] == 1
+    return m, prompts, dict(max_new=4, waves=1, max_model_len=64), \
+        dict(executables_compiled=1)
 
 
-def test_ragged_exact_speculative_ngram(llama_tiny, monkeypatch):
+def _case_speculative_ngram():
     rng = np.random.RandomState(4)
     sysp = np.tile(rng.randint(1, 128, (8,)), 3)
     prompts = [np.concatenate([sysp, rng.randint(1, 128, (t,))])
                for t in (4, 7)]
-    want, _ = _serve_waves(llama_tiny, False, monkeypatch, prompts,
-                           max_new=8, num_speculative_tokens=3)
-    got, st = _serve_waves(llama_tiny, True, monkeypatch, prompts,
-                           max_new=8, num_speculative_tokens=3)
-    _assert_equal_streams(got, want, "spec-ngram ragged vs legacy")
-    assert st["executables_compiled"] == 1
-    assert st["spec_tokens_proposed"] > 0
+    return _llama(7), prompts, \
+        dict(max_new=8, num_speculative_tokens=3), \
+        dict(executables_compiled=1, spec_tokens_proposed=True)
 
 
-def test_ragged_exact_speculative_draft_model(llama_tiny, monkeypatch):
-    paddle.seed(13)
-    draft = LlamaForCausalLM(LlamaConfig.tiny(
-        vocab=128, hidden=32, layers=1, heads=2, kv_heads=2, ffn=64))
-    draft.eval()
+def _case_speculative_draft_model():
+    draft = _llama(13, hidden=32, layers=1, heads=2, ffn=64)
     rng = np.random.RandomState(3)
     sysp = rng.randint(1, 128, (16,))
     prompts = [np.concatenate([sysp, rng.randint(1, 128, (t,))])
                for t in (5, 11)]
-    want, _ = _serve_waves(llama_tiny, False, monkeypatch, prompts,
-                           draft=draft, num_speculative_tokens=2,
-                           drafter="model")
-    got, st = _serve_waves(llama_tiny, True, monkeypatch, prompts,
-                           draft=draft, num_speculative_tokens=2,
-                           drafter="model")
-    _assert_equal_streams(got, want, "spec-draft ragged vs legacy")
     # target ragged step + fused draft (prime + scan): exactly two
-    assert st["executables_compiled"] == 2
+    return _llama(7), prompts, \
+        dict(draft=draft, num_speculative_tokens=2, drafter="model"), \
+        dict(executables_compiled=2, spec_tokens_proposed=True)
+
+
+_ENGINE_CASES = {
+    "llama_with_prefix_cache": _case_llama_with_prefix_cache,
+    "gpt": _case_gpt,
+    "int8": _case_int8,
+    "speculative_ngram": _case_speculative_ngram,
+    "speculative_draft_model": _case_speculative_draft_model,
+}
+
+
+@pytest.mark.parametrize("case", list(_ENGINE_CASES))
+def test_engine_streams_match_dense_generate(case):
+    """Every stream the engine emits — packed rows, the paged pool,
+    prefix reuse, speculation under greedy — is token for token what
+    greedy ``generate(cache_impl="dense")`` of the same model emits
+    for that prompt alone; each case keeps its own pins (the
+    one-executable count, blocks reused, drafts proposed)."""
+    model, prompts, kw, pins = _ENGINE_CASES[case]()
+    kw = dict(kw)
+    max_new, waves = kw.pop("max_new", 6), kw.pop("waves", 2)
+    got, st = _serve_waves(model, prompts, max_new=max_new,
+                           waves=waves, **kw)
+    want = _dense_refs(model, prompts, max_new) * waves
+    _assert_equal_streams(got, want, f"{case}: engine vs dense")
+    assert st["executables_compiled"] == pins["executables_compiled"]
+    assert st["prefill_compiles"] == 0
+    if pins.get("prefix_blocks_reused"):
+        assert st["prefix_blocks_reused"] > 0   # cache composes
+    if pins.get("spec_tokens_proposed"):
+        assert st["spec_tokens_proposed"] > 0
 
 
 @pytest.mark.skipif(
     __import__("jax").device_count() < 2,
     reason="needs a multi-device mesh")
-def test_ragged_exact_tp2(llama_tiny, monkeypatch):
+def test_ragged_exact_tp2(llama_tiny):
     """TP composes unchanged: the ragged step under tp_degree=2 is
     token-exact vs the single-device ragged engine and still shows
     EXACTLY ONE explicit collective (the logits all_gather)."""
-    monkeypatch.setenv("PADDLE_TPU_RAGGED_BATCH", "1")
     rng = np.random.RandomState(0)
     prompts = [rng.randint(1, 128, (n,)).astype(np.int64)
                for n in (5, 9, 13)]
@@ -446,16 +466,14 @@ def test_ragged_exact_tp2(llama_tiny, monkeypatch):
 
 
 # ------------------------------------------- one-executable steady state
-# + kill switch + telemetry
+# + telemetry
 
 
-def test_ragged_one_executable_with_concurrent_admissions(
-        llama_tiny, monkeypatch):
+def test_ragged_one_executable_with_concurrent_admissions(llama_tiny):
     """The tentpole pin: with admissions landing WHILE other slots
     decode (the mixed regime that used to interleave chunk executables
     between decode launches), the engine still compiles exactly ONE
     executable and never recompiles across waves."""
-    monkeypatch.setenv("PADDLE_TPU_RAGGED_BATCH", "1")
     rng = np.random.RandomState(2)
     eng = ServingEngine(llama_tiny, ServingConfig(
         num_slots=3, block_size=8, max_model_len=64, prefill_chunk=8))
@@ -479,48 +497,19 @@ def test_ragged_one_executable_with_concurrent_admissions(
         -(-n // 8) for n in (4, 9, 23, 2, 17))
 
 
-def test_ragged_kill_switch_restores_zoo(llama_tiny, monkeypatch):
-    """PADDLE_TPU_RAGGED_BATCH=0 (and ServingConfig(ragged_batch=
-    False)) restore the per-width executables with identical greedy
-    tokens."""
-    rng = np.random.RandomState(7)
-    prompts = [rng.randint(1, 128, (n,)) for n in (5, 12, 21)]
-    on, st_on = _serve_waves(llama_tiny, True, monkeypatch, prompts,
-                             max_new=5, waves=1)
-    off, st_off = _serve_waves(llama_tiny, False, monkeypatch, prompts,
-                               max_new=5, waves=1)
-    _assert_equal_streams(on, off, "kill switch")
-    assert st_on["executables_compiled"] == 1
-    # legacy zoo: decode + the chunk prefill executable at minimum
-    assert st_off["executables_compiled"] >= 2
-    assert st_off["prefill_compiles"] >= 1
-    monkeypatch.delenv("PADDLE_TPU_RAGGED_BATCH")
-    eng = ServingEngine(llama_tiny, ServingConfig(
-        num_slots=2, block_size=8, max_model_len=64,
-        ragged_batch=False, prefill_chunk=8))
-    got = eng.serve([prompts[0]], max_new_tokens=5)
-    eng.shutdown()
-    np.testing.assert_array_equal(got[0], on[0])
-    assert eng.stats()["ragged_batch"] is False
-
-
-def test_ragged_stats_keys_and_fallback_counter(llama_tiny,
-                                                monkeypatch, tmp_path):
+def test_ragged_stats_keys_and_fallback_counter(llama_tiny, tmp_path):
     """Satellites: stats() always exposes executables_compiled /
-    ragged_batch / kernel_fallbacks (both paths), and _warn_fallback
+    kernel_fallbacks and the compile counts, and _warn_fallback
     bumps the serving_kernel_fallback monitor counter per occurrence
     (not once per process) + it lands in the JSONL export."""
     import json
     from paddle_tpu.ops.pallas import paged_attention as pa
     rng = np.random.RandomState(1)
-    for ragged in (True, False):
-        _, st = _serve_waves(llama_tiny, ragged, monkeypatch,
-                             [rng.randint(1, 128, (5,))], max_new=2,
-                             waves=1)
-        for k in ("executables_compiled", "ragged_batch",
-                  "kernel_fallbacks", "prefill_compiles",
-                  "decode_compiles"):
-            assert k in st, f"{k} missing (ragged={ragged})"
+    _, st = _serve_waves(llama_tiny, [rng.randint(1, 128, (5,))],
+                         max_new=2, waves=1)
+    for k in ("executables_compiled", "kernel_fallbacks",
+              "prefill_compiles", "decode_compiles"):
+        assert k in st, f"{k} missing"
     c = monitor.counter("serving_kernel_fallback", labels=("path",))
     before = c.labels(path="test_path").value()
     n0 = pa.kernel_fallback_counts().get("test_path", 0)

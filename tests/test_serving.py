@@ -195,8 +195,7 @@ def test_serving_parity_mixed_lengths(llama_tiny):
     prompts = [rng.randint(1, 128, (n,)).astype(np.int64)
                for n in (5, 9, 13, 7, 21, 3)]
     eng = ServingEngine(llama_tiny, ServingConfig(
-        num_slots=3, block_size=8, max_model_len=64, max_new_tokens=6,
-        min_prefill_bucket=8))
+        num_slots=3, block_size=8, max_model_len=64, max_new_tokens=6))
     outs = eng.serve(prompts, max_new_tokens=6)
     for p, got in zip(prompts, outs):
         ref = _dense_ref(llama_tiny, p, 6)
@@ -209,7 +208,7 @@ def test_serving_scheduler_property(llama_tiny):
     tokens, and the block pool drains to empty (no leaks)."""
     rng = np.random.RandomState(1)
     cfg = ServingConfig(num_slots=2, block_size=8, max_model_len=48,
-                        num_blocks=13, min_prefill_bucket=8)
+                        num_blocks=13)
     streamed = {}
     eng = ServingEngine(
         llama_tiny, cfg,
@@ -238,8 +237,7 @@ def test_serving_zero_steadystate_recompiles(llama_tiny):
     keeps growing (fixed-slot static shapes)."""
     rng = np.random.RandomState(2)
     eng = ServingEngine(llama_tiny, ServingConfig(
-        num_slots=2, block_size=8, max_model_len=64,
-        min_prefill_bucket=8))
+        num_slots=2, block_size=8, max_model_len=64))
     eng.serve([rng.randint(1, 128, (n,)) for n in (4, 9)],
               max_new_tokens=4)
     st0 = eng.stats()
@@ -258,7 +256,7 @@ def test_serving_eos_retires_slot(llama_tiny):
     first = int(_dense_ref(llama_tiny, prompt, 1)[0])
     eng = ServingEngine(llama_tiny, ServingConfig(
         num_slots=2, block_size=8, max_model_len=64,
-        eos_token_id=first, min_prefill_bucket=8))
+        eos_token_id=first))
     (out,) = eng.serve([prompt], max_new_tokens=8)
     assert out.tolist() == [first]     # stopped right at EOS
     assert eng.stats()["free_blocks"] == eng._alloc.num_blocks - 1
@@ -275,8 +273,7 @@ def test_serving_gpt_family(llama_tiny):
     prompts = [rng.randint(1, 96, (n,)).astype(np.int64)
                for n in (5, 11, 8)]
     eng = ServingEngine(m, ServingConfig(
-        num_slots=2, block_size=8, max_model_len=64,
-        min_prefill_bucket=8))
+        num_slots=2, block_size=8, max_model_len=64))
     outs = eng.serve(prompts, max_new_tokens=4)
     for p, got in zip(prompts, outs):
         ref = _dense_ref(m, p, 4)
@@ -322,8 +319,7 @@ def test_serving_telemetry_in_jsonl(tmp_path, llama_tiny):
     import json
     rng = np.random.RandomState(6)
     eng = ServingEngine(llama_tiny, ServingConfig(
-        num_slots=2, block_size=8, max_model_len=64,
-        min_prefill_bucket=8))
+        num_slots=2, block_size=8, max_model_len=64))
     eng.serve([rng.randint(1, 128, (n,)) for n in (4, 12, 6)],
               max_new_tokens=4)
     path = monitor.export_jsonl(str(tmp_path / "metrics.jsonl"))
@@ -395,9 +391,34 @@ def test_serving_int8_quantized_model():
     prompts = [rng.randint(1, 128, (n,)).astype(np.int64)
                for n in (6, 10)]
     eng = ServingEngine(m, ServingConfig(
-        num_slots=2, block_size=8, max_model_len=64,
-        min_prefill_bucket=8))
+        num_slots=2, block_size=8, max_model_len=64))
     outs = eng.serve(prompts, max_new_tokens=4)
     for p, got in zip(prompts, outs):
         ref = _dense_ref(m, p, 4)
         np.testing.assert_array_equal(got, ref[:len(got)])
+
+
+def test_no_path_switches(llama_tiny, monkeypatch):
+    """The ragged tick is the engine, not the default of a switch: the
+    options that used to select another engine are unknown fields, and
+    their environment twins are not read — a two-wave serve still ends
+    at ONE executable with every tick on the ragged path."""
+    with pytest.raises(TypeError):
+        ServingConfig(ragged_batch=False)
+    with pytest.raises(TypeError):
+        ServingConfig(chunked_prefill=False)
+    monkeypatch.setenv("PADDLE_TPU_RAGGED_BATCH", "0")
+    monkeypatch.setenv("PADDLE_TPU_CHUNKED_PREFILL", "0")
+    rng = np.random.RandomState(21)
+    eng = ServingEngine(llama_tiny, ServingConfig(
+        num_slots=2, block_size=8, max_model_len=64, prefill_chunk=8))
+    for lens in ((5, 12, 21), (9, 3, 17)):
+        eng.serve([rng.randint(1, 128, (n,)) for n in lens],
+                  max_new_tokens=4)
+    st = eng.stats()
+    ticks = [e["args"] for e in eng.tracer.events()
+             if e["name"] == "tick"]
+    eng.shutdown()
+    assert st["executables_compiled"] == 1
+    assert st["prefill_compiles"] == 0
+    assert ticks and all(t["path"] == "ragged" for t in ticks)

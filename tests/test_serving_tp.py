@@ -235,42 +235,50 @@ def test_tp_sampling_parity(llama_tiny):
 
 
 def test_sharded_step_matches_single_program():
-    """Kernel-layer pin: ``sharded_paged_attention_step`` (shard_map
+    """Kernel-layer pin: ``sharded_ragged_attention_step`` (shard_map
     over mp, per-shard kv_head slice) equals the single-program
-    ``paged_attention_step`` on the same pool/tables at BOTH widths —
-    T=1 decode and T>1 verify/chunk."""
+    ``ragged_attention_step`` on the same pool/tables over a packed
+    batch of every width — a decode row, a verify window, a chunk."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh
     from paddle_tpu.distributed import env as denv
+    from paddle_tpu.ops import paged_cache as pc
     from paddle_tpu.ops.pallas import paged_attention as pa
     rng = np.random.RandomState(0)
-    S, H, Hkv, D, BS, MB = 2, 4, 4, 16, 8, 4
+    S, H, Hkv, D, BS, MB, WN, W = 3, 4, 4, 16, 8, 4, 3, 8
     NB = 1 + S * MB
+    R = S * WN + W
     tables = jnp.asarray(
         (1 + np.arange(S * MB, dtype=np.int32)).reshape(S, MB))
-    lens = jnp.asarray([5, 11], jnp.int32)
+    base = np.asarray([5, 11, 8], np.int64)
+    q_lens = np.asarray([1, 3, 8], np.int64)
+    row_slot, row_pos, row_starts, _ = pc.ragged_row_meta(
+        q_lens, base, R, MB * BS)
     mesh = Mesh(np.array(jax.devices()[:2]), ("mp",))
-    # T=1 (decode) is pinned end-to-end by every TP engine test above;
-    # the multi-query width is the one needing a kernel-level pin
-    for t in (3,):
-        kp = jnp.asarray(rng.randn(NB, BS, Hkv, D), jnp.float32)
-        vp = jnp.asarray(rng.randn(NB, BS, Hkv, D), jnp.float32)
-        qh = jnp.asarray(rng.randn(S, t, H, D), jnp.float32)
-        kh = jnp.asarray(rng.randn(S, t, Hkv, D), jnp.float32)
-        vh = jnp.asarray(rng.randn(S, t, Hkv, D), jnp.float32)
-        ref, rk, rv = pa.paged_attention_step(
-            qh, kh, vh, kp, vp, tables, lens, sm_scale=0.25)
-        denv.set_mesh(mesh)
-        try:
-            out, ok, ov = pa.sharded_paged_attention_step(
-                qh, kh, vh, kp, vp, tables, lens, sm_scale=0.25)
-        finally:
-            denv.set_mesh(None)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-6, atol=1e-6)
-        np.testing.assert_array_equal(np.asarray(ok), np.asarray(rk))
-        np.testing.assert_array_equal(np.asarray(ov), np.asarray(rv))
+    kp = jnp.asarray(rng.randn(NB, BS, Hkv, D), jnp.float32)
+    vp = jnp.asarray(rng.randn(NB, BS, Hkv, D), jnp.float32)
+    qh = jnp.asarray(rng.randn(R, H, D), jnp.float32)
+    kh = jnp.asarray(rng.randn(R, Hkv, D), jnp.float32)
+    vh = jnp.asarray(rng.randn(R, Hkv, D), jnp.float32)
+    args = (qh, kh, vh, kp, vp, tables, jnp.asarray(base),
+            jnp.asarray(q_lens), jnp.asarray(row_starts),
+            jnp.asarray(row_slot), jnp.asarray(row_pos),
+            jnp.arange(WN, dtype=jnp.int32),
+            jnp.arange(W, dtype=jnp.int32))
+    ref, rk, rv = pa.ragged_attention_step(*args, sm_scale=0.25)
+    denv.set_mesh(mesh)
+    try:
+        out, ok, ov = pa.sharded_ragged_attention_step(
+            *args, sm_scale=0.25)
+    finally:
+        denv.set_mesh(None)
+    live = np.asarray(row_pos) < MB * BS
+    np.testing.assert_allclose(np.asarray(out)[live],
+                               np.asarray(ref)[live],
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(ok), np.asarray(rk))
+    np.testing.assert_array_equal(np.asarray(ov), np.asarray(rv))
 
 
 # -------------------------------------------------- switches + errors

@@ -228,7 +228,7 @@ def test_spec_kill_switch(llama_tiny, monkeypatch):
     np.testing.assert_array_equal(ref.numpy(), out.numpy())
     eng = ServingEngine(llama_tiny, ServingConfig(
         num_slots=2, block_size=8, max_model_len=64,
-        num_speculative_tokens=4, min_prefill_bucket=8))
+        num_speculative_tokens=4))
     assert eng._gamma == 0          # engine fell back to plain decode
 
 
@@ -275,7 +275,7 @@ def test_spec_serving_parity_mixed_lengths(llama_tiny):
                for n in (5, 9, 13, 7, 21, 3)]
     eng = ServingEngine(llama_tiny, ServingConfig(
         num_slots=3, block_size=8, max_model_len=64, max_new_tokens=8,
-        min_prefill_bucket=8, num_speculative_tokens=3))
+        num_speculative_tokens=3))
     outs = eng.serve(prompts, max_new_tokens=8)
     for p, got in zip(prompts, outs):
         ref, _ = _ref(llama_tiny, p, 8)
@@ -293,7 +293,7 @@ def test_spec_serving_parity_draft_model(llama_tiny, llama_draft):
     eng = ServingEngine(
         llama_tiny,
         ServingConfig(num_slots=2, block_size=8, max_model_len=64,
-                      min_prefill_bucket=8, num_speculative_tokens=2,
+                      num_speculative_tokens=2,
                       drafter="model"),
         draft_model=llama_draft)
     outs = eng.serve(prompts, max_new_tokens=6)
@@ -310,7 +310,7 @@ def test_spec_serving_zero_steadystate_recompiles(llama_tiny):
     rng = np.random.RandomState(2)
     eng = ServingEngine(llama_tiny, ServingConfig(
         num_slots=2, block_size=8, max_model_len=64,
-        min_prefill_bucket=8, num_speculative_tokens=2))
+        num_speculative_tokens=2))
     eng.serve([rng.randint(1, 128, (n,)) for n in (4, 9)],
               max_new_tokens=4)
     st0 = eng.stats()
@@ -330,7 +330,7 @@ def test_spec_serving_streams_every_token(llama_tiny):
     eng = ServingEngine(
         llama_tiny,
         ServingConfig(num_slots=2, block_size=8, max_model_len=64,
-                      min_prefill_bucket=8, num_speculative_tokens=3),
+                      num_speculative_tokens=3),
         stream_callback=lambda rid, t: streamed.setdefault(rid, [])
         .append(t))
     rids = [eng.submit(rng.randint(1, 128, (n,)), mn)
@@ -352,7 +352,7 @@ def test_spec_serving_gpt(llama_tiny):
                for n in (5, 11, 8)]
     eng = ServingEngine(m, ServingConfig(
         num_slots=2, block_size=8, max_model_len=64,
-        min_prefill_bucket=8, num_speculative_tokens=2))
+        num_speculative_tokens=2))
     outs = eng.serve(prompts, max_new_tokens=4)
     for p, got in zip(prompts, outs):
         ref, _ = _ref(m, p, 4)
@@ -367,7 +367,7 @@ def test_spec_serving_int8(llama_tiny):
                for n in (6, 10)]
     eng = ServingEngine(llama_tiny, ServingConfig(
         num_slots=2, block_size=8, max_model_len=64,
-        min_prefill_bucket=8, num_speculative_tokens=2))
+        num_speculative_tokens=2))
     outs = eng.serve(prompts, max_new_tokens=4)
     for p, got in zip(prompts, outs):
         ref, _ = _ref(llama_tiny, p, 4)
@@ -383,7 +383,7 @@ def test_spec_acceptance_on_repetitive_text(llama_tiny):
     prompts = [np.tile(pattern, 6), np.tile(pattern[::-1], 5)]
     eng = ServingEngine(llama_tiny, ServingConfig(
         num_slots=2, block_size=8, max_model_len=160,
-        min_prefill_bucket=8, num_speculative_tokens=4))
+        num_speculative_tokens=4))
     eng.serve(prompts, max_new_tokens=32)
     st = eng.stats()
     assert st["spec_mean_accepted_len"] > 1.0, st
@@ -410,7 +410,7 @@ def test_spec_rollback_blocks_and_cache_match_fresh_prefill(llama_tiny):
     rng = np.random.RandomState(11)
     eng = ServingEngine(llama_tiny, ServingConfig(
         num_slots=2, block_size=8, max_model_len=64,
-        min_prefill_bucket=8, num_speculative_tokens=3))
+        num_speculative_tokens=3))
     for n, mn in [(5, 9), (12, 7), (3, 8), (9, 5)]:
         eng.submit(rng.randint(1, 128, (n,)), mn)
 
@@ -481,8 +481,7 @@ def test_spec_scheduler_property_interleaved(llama_tiny):
     return to zero."""
     rng = np.random.RandomState(1)
     cfg = ServingConfig(num_slots=2, block_size=8, max_model_len=48,
-                        num_blocks=17, min_prefill_bucket=8,
-                        num_speculative_tokens=2)
+                        num_blocks=17, num_speculative_tokens=2)
     streamed = {}
     eng = ServingEngine(
         llama_tiny, cfg,
@@ -631,7 +630,7 @@ def test_spec_telemetry_in_stats_and_jsonl(tmp_path, llama_tiny):
     rng = np.random.RandomState(6)
     eng = ServingEngine(llama_tiny, ServingConfig(
         num_slots=2, block_size=8, max_model_len=64,
-        min_prefill_bucket=8, num_speculative_tokens=2))
+        num_speculative_tokens=2))
     eng.serve([rng.randint(1, 128, (n,)) for n in (4, 12, 6)],
               max_new_tokens=4)
     st = eng.stats()
