@@ -766,36 +766,43 @@ def main(argv=None):
           f"all {len(rids17) + len(mig17)} streams token-exact")
 
     # ---- 18. async tick pipeline ------------------------------------
-    # async_depth=1 arms depth-1 dispatch-ahead: the tick executable
-    # returns next-tick inputs as device arrays (plus an in-exec done
-    # mask), so tick N+1 launches from device-resident state while
-    # tick N's outputs copy to host and the commit bookkeeping lags
-    # one tick. The contract is exactness: async ON == OFF greedy
-    # token-exact, one executable either way. Kill switch:
-    # PADDLE_TPU_ASYNC_TICK=0 (bit-for-bit).
+    # The default engine launches every tick before the last one's
+    # tokens are fetched: the host packs tick N+1 from committed state
+    # plus what tick N does to it, the decode ids it does not have yet
+    # are read from tick N's output on the device, and tick N's commit
+    # (emit, retire, publish) runs while N+1 executes. Admissions and
+    # chunked prefill ride along; only what reads slot state from
+    # outside the tick (cancel, preemption, migration, a handoff)
+    # drains it. The contract is exactness: the same tokens, the same
+    # schedule and one executable as the blocking loop, which an
+    # explicit async_depth=0 (or PADDLE_TPU_ASYNC_TICK=0) keeps as the
+    # reference.
     rng18 = np.random.RandomState(18)
     prompts18 = [rng18.randint(1, vocab, (n,)).astype(np.int64)
                  for n in (9, 13, 7)]
     outs18, st18 = {}, {}
-    for depth in (0, 1):
+    for name, kw in (("default", {}), ("blocking", {"async_depth": 0})):
         eng18 = ServingEngine(model, ServingConfig(
-            num_slots=2, block_size=8, max_model_len=96,
-            async_depth=depth))
-        outs18[depth] = eng18.serve([p.copy() for p in prompts18],
-                                    max_new_tokens=10)
-        st18[depth] = eng18.stats()
+            num_slots=2, block_size=8, max_model_len=96, **kw))
+        outs18[name] = eng18.serve([p.copy() for p in prompts18],
+                                   max_new_tokens=10)
+        st18[name] = eng18.stats()
         eng18.shutdown()
-    for a, b in zip(outs18[0], outs18[1]):
+    for a, b in zip(outs18["blocking"], outs18["default"]):
         assert a.tolist() == b.tolist(), \
-            "async tick pipeline diverged from the sync loop"
-    assert st18[1]["async_depth"] == 1
-    assert st18[1]["executables_compiled"] == \
-        st18[0]["executables_compiled"] == 1
-    print(f"async tick pipeline: depth-1 overlap token-exact vs sync "
-          f"({st18[1]['decode_steps']} ticks, 1 executable, "
-          f"host gap p50 {st18[1]['host_gap_ms']['p50']:.2f} ms vs "
-          f"sync {st18[0]['host_gap_ms']['p50']:.2f} ms, "
-          f"{st18[1]['pipeline_flushes']} flushes)")
+            "the tick dispatched ahead diverged from the blocking loop"
+    on18, off18 = st18["default"], st18["blocking"]
+    assert on18["async_depth"] == 1 and off18["async_depth"] == 0
+    assert on18["executables_compiled"] == \
+        off18["executables_compiled"] == 1
+    assert on18["decode_steps"] == off18["decode_steps"]
+    assert on18["pipeline_flushes"] == 0
+    print(f"async tick pipeline: the default engine token-exact vs "
+          f"async_depth=0 ({on18['decode_steps']} ticks both, 1 "
+          f"executable, host gap p50 "
+          f"{on18['host_gap_ms']['p50']:.2f} ms vs blocking "
+          f"{off18['host_gap_ms']['p50']:.2f} ms, "
+          f"{on18['pipeline_flushes']} flushes)")
     return n_ok / 12.0, losses
 
 

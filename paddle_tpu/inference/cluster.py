@@ -615,12 +615,10 @@ class EngineCluster:
         self._place_handoffs()
         self._place_migrations()
         # decode replicas tick dispatch-all-then-commit-all: every
-        # async replica's executable is IN FLIGHT before any replica
-        # blocks on its token fetch, so N launches run concurrently
-        # instead of serially. A sync replica (async_depth=0 /
-        # PADDLE_TPU_ASYNC_TICK=0) runs its whole step inside the
-        # dispatch phase and no-ops the commit phase — the loop then
-        # degrades to today's serial ticking bit-for-bit.
+        # replica's executable is IN FLIGHT before any replica blocks
+        # on a token fetch, so N launches run concurrently instead of
+        # serially (``tick_dispatch`` / ``tick_commit`` are the
+        # engine's own two halves of ``step()``)
         stepped = []
         for i in list(self._decode_idx):
             if i in self._failed:
@@ -926,6 +924,11 @@ class EngineCluster:
             if _load(hot) - _load(cold) < 2:
                 break
             eng = self._engines[hot]
+            # the replica's slots are read here: commit the tick it
+            # has in flight first (its tokens stream under the mapping
+            # that is still in place, and a slot it retires is no
+            # candidate)
+            eng._flush_pipe()
             cands = [i for i, s in enumerate(eng._slots)
                      if s is not None and not s.handoff
                      and not (s.pend_pos is not None
@@ -1568,8 +1571,8 @@ class EngineCluster:
     def _safe_phase(self, idx, dispatch: bool):
         """One phase of an overlapped decode tick (same fault domain
         as ``_safe_step``): dispatch launches the replica's next tick,
-        commit drains its lagging host bookkeeping. Sync replicas run
-        their whole step in the dispatch phase."""
+        commit takes in the tick that was in flight before it (a
+        blocking replica: the tick it just launched)."""
         try:
             eng = self._engines[idx]
             if dispatch:

@@ -31,6 +31,17 @@ paged KV layout of *Ragged Paged Attention* (arxiv 2604.15464):
   slots, runs the tick, streams tokens out, and retires slots on
   EOS/max-len — freed blocks and slots are reused by the next
   admission without ever draining the batch.
+- **Ticks dispatched ahead.** Without speculation every tick is
+  launched BEFORE the last one's tokens are fetched: the host packs it
+  from committed state plus what the tick in flight does to it
+  (``_ahead``), a decoding slot's id is read from that tick's output
+  on the device (``src``), and the commit of tick N — fetch, emit,
+  retire, publish, spans — runs while tick N+1 executes. Admission,
+  chunked prefill, growth and eviction spills ride along; what reads
+  slot state from outside the tick (cancel, preemption, migration, a
+  handoff, shutdown) drains the pipeline first. ``async_depth=0`` /
+  ``PADDLE_TPU_ASYNC_TICK=0`` keep the blocking loop as the
+  reference. See docs/OPS.md "Async tick pipeline".
 - **Prefix caching (content-addressed blocks).** The ``BlockAllocator``
   keeps per-block refcounts and a content-hash index (rolling hash
   chains over token ids, seeded by a model/config fingerprint —
@@ -173,7 +184,7 @@ import itertools
 import os
 import time
 import warnings
-from collections import deque
+from collections import deque, namedtuple
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
@@ -434,20 +445,20 @@ class ServingConfig:
     # to the delta weights; ~4x adapters per resident byte)
     lora_quant: bool = False
     # -- async tick pipeline (docs/OPS.md "Async tick pipeline") ------
-    # async_depth=1 arms depth-1 dispatch-ahead:
-    # the tick executable additionally returns next-tick inputs
-    # (per-slot sampled token, advanced lengths, a budget/EOS ``done``
-    # mask) as DEVICE arrays, and on pure steady-state decode ticks
-    # the engine dispatches tick N+1 from that device-resident carry
-    # while tick N's tokens copy to host asynchronously — commit
-    # (emit/retire/stats/tracing) lags one tick. Any slot-composition
-    # event (admission, retirement, preemption, migration, handoff,
-    # cancel) flushes the pipeline, so async ON == OFF stays greedy
-    # token-exact. Env twin
-    # PADDLE_TPU_ASYNC_TICK: 0 = kill switch (beats an explicit depth
-    # — today's dispatch-then-block loop returns bit-for-bit, same
-    # executables), 1 = depth-1 when this field is left None. Only
-    # depth 1 is implemented.
+    # None / 1 (the default): every tick without speculation is
+    # launched BEFORE the last one's tokens are fetched — the host
+    # packs it from committed state plus what the tick in flight does
+    # to it, the decode ids it does not have yet are read from that
+    # tick's output on the device — and the commit of tick N (fetch,
+    # emit, retire, publish, spans: stream callbacks, stats() and
+    # spans lag one tick) runs while tick N+1 executes. Admission,
+    # growth, eviction spills and chunked prefill ride along;
+    # cancel, preemption, migration, a handoff and shutdown drain it
+    # first (stats()["pipeline_flushes"]). Served tokens are the
+    # blocking loop's. 0 = that blocking loop, the reference the
+    # parity tests compare against; env twin PADDLE_TPU_ASYNC_TICK=0
+    # beats an explicit depth. A speculating engine (gamma > 0)
+    # blocks whatever is set here. Only depth 1 is implemented.
     async_depth: Optional[int] = None
 
     def __post_init__(self):
@@ -672,15 +683,19 @@ class _Slot:
         self.pend_pos = pend_pos        # next chunk start; None = done
 
 
+# a slot as the tick about to be packed must see it (``_ahead``)
+_Ahead = namedtuple("_Ahead", "pend_pos cache_len rowless tok")
+
+
 class _Pipe:
-    """One dispatched-but-uncommitted ragged tick (the async
-    pipeline's in-flight record): the executable's output futures plus
-    the host-side row layout the commit half needs. ``pure`` marks a
-    decode-only tick whose ``carry`` (device-resident next-tick packs)
-    may feed a pipelined dispatch."""
+    """One dispatched-but-uncommitted ragged tick: the executable's
+    output futures plus the host-side row layout the commit half
+    needs. ``left`` holds the slots that gave their seat back between
+    this tick's dispatch and its commit (``_release_spent``): the
+    commit still owes each its last token."""
     __slots__ = ("outs", "active", "given", "n_pending", "q_lens",
-                 "rid_of", "pend_pos0", "t_tick", "t_l0", "pure",
-                 "carry", "tick", "dispatch", "attn_grid", "moe")
+                 "rid_of", "pend_pos0", "t_tick", "t_l0", "left",
+                 "tick", "dispatch", "attn_grid", "moe")
 
     def __init__(self, **kw):
         for k in self.__slots__:
@@ -963,22 +978,21 @@ class ServingEngine:
             else int(cfg.num_blocks)
         self._alloc = _pc.BlockAllocator(nb)
         # -- async tick pipeline (docs/OPS.md "Async tick pipeline") --
-        # resolved ONCE at construction: config depth AND the
-        # PADDLE_TPU_ASYNC_TICK env twin (0 = kill switch beating an
-        # explicit depth — today's dispatch-then-block loop returns
-        # bit-for-bit; 1 arms depth-1 when the field is left None)
+        # resolved ONCE at construction: every tick without
+        # speculation is launched before the last one's tokens are
+        # fetched, unless ``async_depth=0`` or the
+        # PADDLE_TPU_ASYNC_TICK=0 kill switch (which beats an explicit
+        # depth) keeps the blocking loop as the reference. A
+        # speculating engine's proposals need the committed history:
+        # it blocks whatever the depth says.
         _ad = getattr(cfg, "async_depth", None)
-        _ae = os.environ.get("PADDLE_TPU_ASYNC_TICK", "")
-        if _ae == "0":
-            _depth = 0
-        elif _ad is None:
-            _depth = 1 if _ae == "1" else 0
-        else:
-            _depth = int(_ad)
-        self._async_on = _depth >= 1
-        self._async_depth = 1 if self._async_on else 0
+        if os.environ.get("PADDLE_TPU_ASYNC_TICK", "") == "0":
+            _ad = 0
+        self._ahead_on = (_ad is None or int(_ad) >= 1) and not gamma
+        self._async_depth = 1 if self._ahead_on else 0
         self._pipe = None               # in-flight (uncommitted) tick
         self._commit_due = None         # commit half of a split tick
+        self._flushed = []              # tokens a drain committed
         self._n_pipe_flushes = 0
         self._last_dispatch_t = None    # host-gap digest anchor
         self._split_t0 = 0.0            # cluster phase-split health
@@ -1029,6 +1043,10 @@ class ServingEngine:
         # sample a different token on every shard)
         self._key = self._dev(jax.random.PRNGKey(int(cfg.seed)))
         self._tables_dev = None         # device mirror of _tables
+        # the last tick's per-slot tokens as it left them on the
+        # device: the next tick's operand (zeros until one has run —
+        # read only where a tick is in flight)
+        self._prev_tok = self._dev(np.zeros(cfg.num_slots, np.int32))
         self._cow_exec = None           # copy-on-write block duplicate
         self._draft_cow_exec = None
         # disaggregated prefill -> decode handoff (role="prefill"
@@ -1390,9 +1408,9 @@ class ServingEngine:
         # serving_spec_accept_len gauge are always present
         self._d_accept = LatencyDigest()
         # dispatch -> dispatch host time, as a P² digest —
-        # unconditional (sync engines observe too: their gap includes
-        # the blocking token fetch + commit bookkeeping, which is
-        # exactly what async_depth=1 moves off the critical path), so
+        # unconditional (blocking engines observe too: their gap
+        # includes the token fetch + commit bookkeeping, which is
+        # exactly what dispatching ahead moves off the critical path), so
         # stats()['host_gap_ms'] and the serving_host_gap_ms gauge are
         # always present
         self._d_host_gap = LatencyDigest()
@@ -1863,11 +1881,8 @@ class ServingEngine:
         t0 = time.monotonic()
         c0 = self._n_exec_compiled
         with self._prof.tick():
-            out = self._step_async() if self._async_on \
-                else self._step_ragged()
-            # a tick that launched nothing has no ``commit`` to take in
-            # what was evicted since the last one
-            self._drain_spills()
+            out = self._tick_dispatch()
+            out.extend(self._tick_commit())
         if self._health is not None:
             self._health_tick(t0, time.monotonic(), c0)
         return out
@@ -1964,38 +1979,90 @@ class ServingEngine:
             self._trim_blocks(i)
         return len(kept)
 
-    def _step_ragged(self) -> List[tuple]:
-        """Ragged mixed-batch tick: pack every live query row — 1 per
-        decoding slot, ``gamma + 1`` per verifying slot, up to the prefill row budget for pending prompts — into
-        ONE launch of the engine's single compiled executable, then
-        commit tokens, prefill progress, speculative accept/reject and
-        retirements host-side. The packed width is static
-        (``num_slots * (gamma+1) + prefill_rows``); slots with no work
-        contribute zero rows, so raggedness lives entirely in the
-        ``q_lens``/``row_starts`` VALUES and steady state runs zero
-        recompiles.
+    # -- the tick: a dispatch half and a commit half -------------------
 
-        The tick is split into a dispatch half (pack + launch) and a
-        commit half (token fetch + host bookkeeping); this sync path
-        runs them back to back, the async pipeline (``_step_async``)
-        lags the commit one tick behind the dispatch."""
-        pipe, emitted = self._ragged_dispatch()
-        if pipe is not None:
-            emitted.extend(self._ragged_commit(pipe))
-        return emitted
+    def _ahead(self, i):
+        """Slot ``i`` as the tick about to be packed must see it: its
+        committed state with what the one uncommitted tick
+        (``self._pipe``, docs/OPS.md "Async tick pipeline") does to it
+        applied. That tick's row counts are the host's own; only the
+        token it samples is not here yet. Returns ``(pend_pos,
+        cache_len, rowless, tok)``: ``rowless`` where the uncommitted
+        tick ends the slot's work here whatever it samples (budget
+        used up, or a prefill-role slot about to park), ``tok`` the id
+        of the slot's next decode row — ``None`` where that id is the
+        uncommitted tick's own output and stays on the device."""
+        s = self._slots[i]
+        pipe = self._pipe
+        if pipe is None or pipe.rid_of.get(i) != s.rid:
+            return _Ahead(s.pend_pos, s.cache_len, False, s.last_token)
+        k = pipe.given.get(i)
+        if k is None:                   # a decode row in flight
+            return _Ahead(None, s.cache_len + 1,
+                          s.n_emitted + 1 >= s.max_new, None)
+        pend = s.pend_pos + k
+        if pend < int(s.prompt.size):
+            return _Ahead(pend, pend, False, None)
+        if s.resume is not None:
+            # a recompute resume completes in flight: the continuation
+            # it restores is the host's own
+            return _Ahead(None, pend, False, int(s.resume[0]))
+        return _Ahead(None, pend,
+                      s.max_new <= 1 or self._role == "prefill", None)
+
+    def _release_spent(self, pipe):
+        """A decode row in flight that uses up its request's budget
+        ends the request whatever it samples, so the seat and the
+        blocks go back NOW, ahead of this dispatch's admission — where
+        the blocking loop's commit would have returned them — and the
+        schedule stays the blocking loop's, tick for tick. The commit
+        still to come delivers the last token (``pipe.left``). What
+        the published blocks' hashes cover is on the host already
+        (position ``cache_len`` holds the row's input token), and
+        launches issue in host order, so whoever maps or spills such a
+        block reads it after that row's write."""
+        for i in pipe.active:
+            s = self._slots[i]
+            if s is not None and self._ahead(i).rowless:
+                s.cache_len += 1
+                self._release_seat(i)
+                pipe.left[i] = s
+
+    def _settle(self) -> bool:
+        """Preemption reads and rewrites slot state, so it is decided
+        on committed state only: commit what is in flight first. True
+        if there was something — the caller then looks again, since
+        the commit may have freed what it was about to take."""
+        if self._pipe is None and self._commit_due is None:
+            return False
+        self._flush_pipe()
+        return True
 
     def _ragged_dispatch(self):
-        """Dispatch half of one ragged tick: admit, pack the row
-        layout, launch the ONE executable. Returns ``(pipe, emitted)``
-        — ``pipe`` holds everything the commit half needs (``None`` on
-        an idle tick), ``emitted`` carries admission-time prefill
-        tokens."""
+        """Dispatch half of one ragged tick: admit, pack every live
+        query row — 1 per decoding slot, ``gamma + 1`` per verifying
+        slot, up to the prefill row budget for pending prompts — and
+        launch the engine's ONE compiled executable. The packed width
+        is static (``num_slots * (gamma+1) + prefill_rows``); slots
+        with no work contribute zero rows, so raggedness lives
+        entirely in the ``q_lens``/``row_starts`` VALUES and steady
+        state runs zero recompiles.
+
+        The rows are packed from committed state plus what the
+        uncommitted tick does to it (``_ahead``); a decoding slot
+        whose last token that tick is still sampling names it by
+        ``src`` and the executable reads it on the device. Returns
+        ``(pipe, emitted)`` — ``pipe`` holds everything the commit
+        half needs (``None`` on an idle tick), ``emitted`` what
+        admission emitted."""
         from ..generation import speculative as _spec
         t_tick = time.monotonic()
         # tick-phase spans (docs/OPS.md "Tick phases"): each carries
         # the ordinal of the tick it belongs to
         tr = self._trace
         tick = self._tick_ord = self._n_decode_steps
+        if self._pipe is not None:
+            self._release_spent(self._pipe)
         ph = tr.phase("admit", tick=tick).begin() \
             if tr is not None else None
         emitted = self._admit()
@@ -2006,25 +2073,36 @@ class ServingEngine:
         cfg = self.config
         g = self._gamma
         n_slots = cfg.num_slots
-        active = [i for i, s in enumerate(self._slots)
-                  if s is not None and s.pend_pos is None
-                  and not s.handoff]
-        pending = [i for i, s in enumerate(self._slots)
-                   if s is not None and s.pend_pos is not None]
-        if not active and not pending:
-            return None, emitted
-        if active:
-            # room for this tick's write positions (the verify window
-            # overhangs by up to gamma speculated slots); growth under
-            # an overcommitted pool may preempt — survivors only
-            n0 = self._n_grown
-            ph = tr.phase("grow", tick=tick).begin() \
-                if tr is not None else None
-            active = self._ensure_blocks(active, horizon=g + 1)
-            if ph is not None:
-                ph.end(blocks=self._n_grown - n0)
+        while True:
+            # the tick still uncommitted; None once anything drained it
+            prev = self._pipe
+            view = {i: self._ahead(i)
+                    for i, s in enumerate(self._slots)
+                    if s is not None and not s.handoff}
+            active = [i for i, v in view.items()
+                      if v.pend_pos is None and not v.rowless]
+            pending = [i for i, v in view.items()
+                       if v.pend_pos is not None]
             if not active and not pending:
                 return None, emitted
+            if active:
+                # room for this tick's write positions (the verify
+                # window overhangs by up to gamma speculated slots);
+                # growth under an overcommitted pool may preempt —
+                # survivors only
+                n0 = self._n_grown
+                ph = tr.phase("grow", tick=tick).begin() \
+                    if tr is not None else None
+                active = self._ensure_blocks(active, horizon=g + 1)
+                if ph is not None:
+                    ph.end(blocks=self._n_grown - n0)
+            if self._pipe is prev:
+                break
+            # growth found the pool dry and preempted, which drained
+            # the pipeline: every view above is of a state that has
+            # moved on — pack again, from what is committed now
+        if not active and not pending:
+            return None, emitted
 
         # -- pack the tick's work into per-slot row counts -------------
         ph = tr.phase("pack", tick=tick).begin() \
@@ -2036,7 +2114,7 @@ class ServingEngine:
         budget = self._prefill_rows
         for i in active:
             q_lens[i] = g + 1
-            base[i] = self._slots[i].cache_len
+            base[i] = view[i].cache_len
         # a growth preemption above may have victimized a pending slot
         pending = [i for i in pending if self._slots[i] is not None]
         if self._preempt_on and len(pending) > 1:
@@ -2054,12 +2132,12 @@ class ServingEngine:
             # two-lane contract; later pending slots still trickle at
             # the narrow (gamma+1) width, so nothing starves
             cap_i = cap if not given else (g + 1)
-            k = min(int(slot.prompt.size) - slot.pend_pos, cap_i,
+            k = min(int(slot.prompt.size) - view[i].pend_pos, cap_i,
                     budget)
             if k <= 0:
                 continue
             q_lens[i] = k
-            base[i] = slot.pend_pos
+            base[i] = view[i].pend_pos
             given[i] = k
             budget -= k
         if not int(q_lens.sum()):
@@ -2069,7 +2147,11 @@ class ServingEngine:
         row_slot, row_pos, row_starts, last_rows = _pc.ragged_row_meta(
             q_lens, base, self._rows, self._overflow)
         if self._tables_dev is None:
-            self._tables_dev = self._dev(self._tables)
+            # a copy: the CPU backend aliases a numpy operand, and the
+            # host rewrites its tables while the tick that reads this
+            # upload is still in flight (likewise the sampling tensor
+            # and the adapter stacks, where they are uploaded)
+            self._tables_dev = self._dev(self._tables.copy())
 
         # -- draft proposals (speculative mode) ------------------------
         toks = None
@@ -2130,22 +2212,31 @@ class ServingEngine:
 
         # -- the ONE mixed-batch launch --------------------------------
         ids = np.full(self._rows, self._pad, np.int32)
+        # row -> the slot whose token the uncommitted tick is sampling
+        # (the executable reads it from that tick's output), -1 where
+        # the host packed the id
+        src = np.full(self._rows, -1, np.int32)
         for i in active:
             s0 = int(row_starts[i])
             if g:
                 ids[s0:s0 + g + 1] = toks[i]
+            elif view[i].tok is None:
+                src[s0] = i
             else:
-                ids[s0] = self._slots[i].last_token
+                ids[s0] = view[i].tok
         for i, k in given.items():
             s0 = int(row_starts[i])
-            slot = self._slots[i]
-            ids[s0:s0 + k] = \
-                slot.prompt[slot.pend_pos:slot.pend_pos + k]
+            p0 = view[i].pend_pos
+            ids[s0:s0 + k] = self._slots[i].prompt[p0:p0 + k]
         sub = self._next_key()
         # TWO packed uploads carry the whole tick's row layout: the
-        # per-row triple (ids, slot, position) and the per-slot quad
-        # (base length, q_lens, row_starts, last_rows)
-        rows_pack = np.stack([ids, row_slot, row_pos]).astype(np.int32)
+        # per-row rows (ids, slot, position; without speculation also
+        # ``src``) and the per-slot quad (base length, q_lens,
+        # row_starts, last_rows)
+        rrows = [ids, row_slot, row_pos]
+        if not g:
+            rrows.append(src)
+        rows_pack = np.stack(rrows).astype(np.int32)
         srows = [base, q_lens, row_starts, last_rows]
         if self._spec_tree is not None:
             # 5th per-slot row: which slots verify a TREE window this
@@ -2160,20 +2251,14 @@ class ServingEngine:
             # tensor — churn changes VALUES at a fixed shape, so no
             # adapter mix ever recompiles the tick
             srows.append(self._slot_adapter)
-        if self._async_on and not g:
-            # LAST row of the async slots pack: each slot's remaining
-            # token budget (max_new - emitted). The executable's done
-            # mask retires rows on device (budget <= 1 or EOS), so a
-            # pipelined tick dispatched from the carry no-ops finished
-            # slots without a host round trip.
-            bud = np.zeros(n_slots, np.int64)
-            for i in active:
-                s = self._slots[i]
-                bud[i] = s.max_new - s.n_emitted
-            srows.append(bud)
         slots_pack = np.stack(srows).astype(np.int32)
         args = [self._params, self._pools, self._tables_dev,
                 self._dev(rows_pack), self._dev(slots_pack)]
+        if not g:
+            # the tick before's tokens, as that tick left them on the
+            # device: read where ``src`` points, and read again by that
+            # tick's commit, so never donated
+            args.append(self._prev_tok)
         if self._lora_on:
             # the stacked A/B weights are a runtime OPERAND (cached on
             # device until the pool version moves), same reasoning
@@ -2188,16 +2273,16 @@ class ServingEngine:
         args.append(sub)
         if self._ragged_exec is None:
             self._ragged_exec = self._compile_ragged_step(tuple(args))
-        # names/positions BEFORE the commit loops retire slots (the
-        # async commit guard also keys on these: a slot reseated with
-        # a DIFFERENT request between dispatch and commit must drop
-        # the stale tick's token)
+        # names/positions BEFORE any commit retires slots (the commit's
+        # guard keys on these: a slot that retired on an EOS the host
+        # could not foresee, and may be seated with ANOTHER request by
+        # the time this tick commits, must drop this tick's token)
         rid_of = {i: self._slots[i].rid
                   for i in active + list(given)}
-        pend_pos0 = {i: int(self._slots[i].pend_pos)
-                     for i in given}
+        pend_pos0 = {i: int(view[i].pend_pos) for i in given}
         # row t of slot s sees base[s] + t + 1 positions
         attn_grid = self._attn_grid(q_lens, base + 1)
+        dispatch = "packed" if prev is None else "carry"
         t_l0 = time.monotonic()
         if ph is not None:
             ph.end(rows=int(q_lens.sum()))
@@ -2209,12 +2294,17 @@ class ServingEngine:
             if tr is None:
                 outs, moe = self._launch_ragged(args)
             else:
-                with tr.phase("launch", tick=tick, dispatch="packed"):
+                with tr.phase("launch", tick=tick, dispatch=dispatch):
                     outs, moe = self._launch_ragged(args)
-        if self._async_on:
-            # the pools advance at DISPATCH (device futures): the next
-            # launch consumes them before this tick's commit runs
-            self._pools = outs[-1]
+        # the pools advance at DISPATCH (device futures): the next
+        # launch, a spill's gather or a COW consumes them before this
+        # tick's commit runs
+        self._pools = outs[-1]
+        if not g:
+            self._prev_tok = outs[0]
+            # the commit wants the tokens on the host as soon as the
+            # tick ends, not a round trip after it asks
+            outs[0].copy_to_host_async()
 
         self._m_steps.inc()
         self._n_decode_steps += 1
@@ -2225,25 +2315,24 @@ class ServingEngine:
         # packed row t of slot s attends base[s] + t + 1 positions
         self._note_kv_read(int((q_lens * base).sum())
                            + int((q_lens * (q_lens + 1) // 2).sum()))
-        pure = (self._async_on and not g and not given and not pending)
         pipe = _Pipe(
             outs=outs, active=list(active), given=given,
             n_pending=len(pending), q_lens=q_lens, rid_of=rid_of,
-            pend_pos0=pend_pos0, t_tick=t_tick, t_l0=t_l0, pure=pure,
-            carry=(outs[2], outs[3]) if pure else None,
-            tick=tick, dispatch="packed", attn_grid=attn_grid,
+            pend_pos0=pend_pos0, t_tick=t_tick, t_l0=t_l0, left={},
+            tick=tick, dispatch=dispatch, attn_grid=attn_grid,
             moe=moe)
         return pipe, emitted
 
     def _ragged_commit(self, pipe, flush=False) -> List[tuple]:
         """Commit half of one ragged tick: fetch tokens, advance
         slots, retire, commit prefill progress, emit trace spans.
-        Under async pipelining this runs one tick AFTER its dispatch —
-        a slot retired, cancelled, preempted or migrated in between is
-        skipped, dropping the speculative extra tick's token exactly
-        (its KV write already null-routed on device via the carry's
-        ``done`` mask, so there is nothing to trim). ``flush`` only
-        labels the ``commit`` phase: the pipeline was drained."""
+        Behind a tick dispatched ahead this runs while the NEXT tick
+        executes. A decode row whose slot is gone by now — retired by
+        the commit before this one on an EOS the host could not
+        foresee — is skipped, dropping its token exactly (the
+        executable parked that row at the overflow position, so there
+        is nothing to trim). ``flush`` only labels the ``commit``
+        phase: the pipeline was drained."""
         outs = pipe.outs
         g = self._gamma
         n_slots = self.config.num_slots
@@ -2252,11 +2341,10 @@ class ServingEngine:
         t_tick, t_l0 = pipe.t_tick, pipe.t_l0
         tr = self._trace
         emitted: List[tuple] = []
-        committed = active
-        if self._async_on:
-            committed = [i for i in active
-                         if self._slots[i] is not None
-                         and self._slots[i].rid == rid_of[i]]
+        committed = [i for i in active
+                     if i in pipe.left
+                     or (self._slots[i] is not None
+                         and self._slots[i].rid == rid_of[i])]
 
         # -- fetch: the host blocks on the device here -----------------
         self._tick_ord = pipe.tick
@@ -2272,30 +2360,36 @@ class ServingEngine:
                 props_next = np.asarray(outs[3])
         if self._health is not None:            # host fetch gated on
             self._nf_last = bool(outs[k])       # the kill switch only
-        if not self._async_on:
-            self._pools = outs[k + 1]
         moe_args = self._commit_moe_share(pipe.moe)
         t_sync = time.monotonic()
         if ph is not None:
             ph.end()
             ph = tr.phase("commit", tick=pipe.tick, flush=flush).begin()
         # the tick has completed, so the spill gathers launched ahead
-        # of it have too: their bytes are taken in without a wait
+        # of it — and of the tick dispatched since — have too: their
+        # bytes are taken in without a wait
         self._drain_spills()
 
         # -- commit decode / verify rows -------------------------------
         acc_lens = {}
         if not g:
             for i in committed:
-                slot = self._slots[i]
+                # a slot whose budget this row used up left its seat
+                # at the dispatch after this one (``_release_spent``)
+                slot = pipe.left.get(i)
+                left = slot is not None
+                if not left:
+                    slot = self._slots[i]
+                    slot.cache_len += 1
                 tok = int(tok_arr[i])
-                slot.cache_len += 1
                 slot.last_token = tok
                 slot.n_emitted += 1
                 slot.history.append(tok)
                 self._emit(slot.rid, tok)
                 emitted.append((slot.rid, tok))
-                if tok == self._eos or slot.n_emitted >= slot.max_new:
+                if left:
+                    self._finish_request(i, slot)
+                elif tok == self._eos or slot.n_emitted >= slot.max_new:
                     self._retire(i)
         else:
             for i in committed:
@@ -2330,10 +2424,11 @@ class ServingEngine:
                 # sampled logits are the request's first token
                 self._finish_prefill(i, int(tok_arr[i]), emitted)
         if tr is not None:
-            # under async the span's [t_l0, t_sync] brackets dispatch
-            # -> commit, i.e. it INCLUDES the one-tick overlap window
-            # (commit-lag semantics, docs/OPS.md "Async tick
-            # pipeline"); dropped (stale-slot) ticks emit no span
+            # behind a tick dispatched ahead the span's [t_l0, t_sync]
+            # brackets dispatch -> commit, i.e. it INCLUDES the
+            # one-tick overlap window (commit-lag semantics, docs/OPS.md
+            # "Async tick pipeline"); dropped (stale-slot) rows emit no
+            # span
             for i in committed:
                 args_i = {"rid": rid_of[i], "rows": int(q_lens[i])}
                 if g:
@@ -2357,234 +2452,80 @@ class ServingEngine:
 
     # -- async tick pipeline (docs/OPS.md "Async tick pipeline") ------
 
-    def _step_async(self) -> List[tuple]:
-        """One engine tick with depth-1 dispatch-ahead: launch tick
-        N+1 (from the device-resident carry when the slot composition
-        is unchanged, sync-shaped otherwise), THEN commit tick N —
-        host bookkeeping overlaps device execution."""
-        out = self._tick_dispatch_async()
-        out.extend(self._tick_commit_async())
+    def _tick_dispatch(self) -> List[tuple]:
+        """First half of one engine tick: launch the next tick. Where
+        the engine dispatches ahead (``_ahead_on``: no speculation,
+        ``async_depth`` not 0) the tick launched before stays
+        uncommitted until ``_tick_commit``, which then runs while this
+        one executes; otherwise this tick is its own commit's."""
+        pipe, emitted = self._ragged_dispatch()
+        out, self._flushed = self._flushed, []
+        out.extend(emitted)
+        if self._ahead_on:
+            # (None where a preemption inside the dispatch drained it)
+            self._commit_due, self._pipe = self._pipe, pipe
+        else:
+            self._commit_due = pipe
         return out
 
-    def _tick_dispatch_async(self) -> List[tuple]:
-        emitted: List[tuple] = []
-        prev = self._pipe
-        if prev is not None and self._pipe_ready(prev):
-            self._pipe = self._dispatch_pipelined(prev)
-            self._commit_due = prev
-            return emitted
-        if prev is not None:
-            # the slot composition wants to change (admission waiting,
-            # a dispatched slot retired/cancelled/preempted/migrated,
-            # prefill rows due, pool dry): drain the pipeline first,
-            # then dispatch sync-shaped
-            self._pipe = None
-            self._n_pipe_flushes += 1
-            emitted.extend(self._ragged_commit(prev, flush=True))
-        pipe, pre = self._ragged_dispatch()
-        emitted.extend(pre)
-        if pipe is None:
-            return emitted
-        if pipe.pure:
-            self._pipe = pipe           # commit lags one tick
-        else:
-            self._commit_due = pipe     # commits this very tick
-        return emitted
-
-    def _tick_commit_async(self) -> List[tuple]:
+    def _tick_commit(self) -> List[tuple]:
+        """Second half: commit the tick that was in flight when
+        ``_tick_dispatch`` launched (the one it launched, on a
+        blocking engine)."""
         due, self._commit_due = self._commit_due, None
-        if due is None:
-            return []
-        return self._ragged_commit(due)
+        out = self._ragged_commit(due) if due is not None else []
+        pipe = self._pipe
+        if pipe is not None and self._eos >= 0 and not any(
+                s is not None and s.rid == pipe.rid_of.get(i)
+                for i, s in enumerate(self._slots)):
+            # every row of the tick in flight belongs to a slot this
+            # commit retired on an EOS: nothing waits for it and the
+            # engine may go idle, so take it in now
+            self._pipe = None
+            out.extend(self._ragged_commit(pipe))
+        if self._pipe is None:
+            # no tick in flight whose ``commit`` would take in what
+            # was evicted since the last one
+            self._drain_spills()
+        return out
 
     def tick_dispatch(self) -> List[tuple]:
         """Dispatch phase of an overlapped CLUSTER tick: launch this
-        engine's next tick and defer the lagging commit to
-        ``tick_commit()``, so N replicas' executables run concurrently
-        instead of serially. Sync engines (async off) run their whole
-        step here — the cluster's dispatch-all-then-commit-all loop
-        then degrades to today's serial ticking bit-for-bit."""
-        if not self._async_on:
-            return self.step()
+        engine's next tick and leave the commit to ``tick_commit()``,
+        so N replicas' executables run concurrently instead of
+        serially. ``step()`` is the two back to back."""
         self._split_t0 = time.monotonic()
         self._split_c0 = self._n_exec_compiled
         with self._prof.tick():
-            return self._tick_dispatch_async()
+            return self._tick_dispatch()
 
     def tick_commit(self) -> List[tuple]:
-        """Commit phase of an overlapped cluster tick (no-op on sync
-        engines — their ``tick_dispatch`` already committed)."""
-        if not self._async_on:
-            return []
-        out = self._tick_commit_async()
+        """Commit phase of an overlapped cluster tick."""
+        out = self._tick_commit()
         if self._health is not None:
             self._health_tick(self._split_t0, time.monotonic(),
                               self._split_c0)
         return out
 
-    def _pipe_ready(self, pipe) -> bool:
-        """May the next tick dispatch straight from the in-flight
-        tick's device carry? Requires an unchanged slot composition
-        (every dispatched slot still seated with the same request,
-        nothing queued, pending or parked) and block headroom for one
-        more position per slot — grown WITHOUT preemption (a
-        mid-pipeline victim would spill stale host state); a dry pool
-        flushes instead and the sync path re-runs growth with
-        preemption armed."""
-        if not pipe.pure or self._handoff_ready:
-            return False
-        for i in pipe.active:
-            s = self._slots[i]
-            if s is None or s.rid != pipe.rid_of[i]:
-                return False
-        if self._queue:
-            # a backed-up queue is safe to pipeline over ONLY when the
-            # in-flight commit provably frees no slot: no EOS
-            # configured and no dispatched slot on its last budgeted
-            # token. Then no admission is possible this tick in the
-            # sync schedule either — composition provably unchanged.
-            # Otherwise flush, so a retirement admits the newcomer on
-            # exactly the tick the blocking loop would have.
-            if self._eos >= 0:
-                return False
-            for i in pipe.active:
-                s = self._slots[i]
-                if s.max_new - s.n_emitted <= 1:
-                    return False
-            if self._preempt_on and any(
-                    q.priority > min(self._slots[i].priority
-                                     for i in pipe.active)
-                    for q in self._queue):
-                # a queued request that outranks a seated slot must
-                # reach the slot-pressure preemption scan NOW, not
-                # after the backlog drains
-                return False
-        if any(s is not None and s.pend_pos is not None
-               for s in self._slots):
-            return False
-        if all(self._slots[i].max_new - self._slots[i].n_emitted <= 1
-               for i in pipe.active):
-            # every slot retires at the in-flight commit (the carry
-            # zeroed all its rows) — a pipelined tick would be a pure
-            # no-op launch
-            return False
-        tr = self._trace
-        tick = self._tick_ord = self._n_decode_steps
-        n0 = self._n_grown
-        ph = tr.phase("grow", tick=tick).begin() \
-            if tr is not None else None
-        ok = self._pipe_grow(pipe)
-        if ph is not None:
-            ph.end(blocks=self._n_grown - n0)
-        return ok
-
-    def _pipe_grow(self, pipe) -> bool:
-        """Grow blocks for the pipelined tick's write positions: the
-        in-flight tick writes position ``cache_len``, the pipelined
-        one ``cache_len + 1``, both uncommitted host-side. No
-        preemption and no COW: decode appends into tail blocks the
-        slot owns privately; a dry pool returns False (caller
-        flushes)."""
-        for i in pipe.active:
-            slot = self._slots[i]
-            if slot.max_new - slot.n_emitted <= 1:
-                # retires at the in-flight commit (its pipelined row
-                # is zeroed on device) — never writes another block
-                continue
-            need = _pc.blocks_for(slot.cache_len + 2, self._bs)
-            while len(slot.blocks) < need:
-                try:
-                    (blk,) = self._alloc.alloc(1)
-                except RuntimeError:
-                    return False
-                self._tables[i, len(slot.blocks)] = blk
-                slot.blocks.append(blk)
-                self._n_grown += 1
-                self._tables_dev = None
-                self._reserved -= 1
-        return True
-
-    def _dispatch_pipelined(self, prev) -> "_Pipe":
-        """Dispatch the next tick straight from the in-flight tick's
-        device-resident carry: no host packing, no token upload, no
-        blocking fetch — the only host work left is the block-table
-        re-upload when growth touched it. Operand count and shapes
-        are EXACTLY the steady-state sync tick's (the carry rows ARE
-        next tick's packs), so pipelining adds zero executables."""
-        t_tick = time.monotonic()
-        tr = self._trace
-        tick = self._tick_ord = self._n_decode_steps
-        ph = tr.phase("pack", tick=tick).begin() \
-            if tr is not None else None
-        carry_rows, carry_slots = prev.carry
-        if self._tables_dev is None:
-            self._tables_dev = self._dev(self._tables)
-        args = [self._params, self._pools, self._tables_dev,
-                carry_rows, carry_slots]
-        if self._lora_on:
-            args.append(self._lora_operand())
-        args.append(self._samp_operand())
-        args.append(self._next_key())
-        t_l0 = time.monotonic()
-        if ph is not None:
-            ph.end(rows=len(prev.active))
-        if self._last_dispatch_t is not None:
-            self._d_host_gap.observe(
-                1000.0 * (t_l0 - self._last_dispatch_t))
-        self._last_dispatch_t = t_l0
-        with _quiet_donation():
-            if tr is None:
-                outs, moe = self._launch_ragged(args)
-            else:
-                with tr.phase("launch", tick=tick, dispatch="carry"):
-                    outs, moe = self._launch_ragged(args)
-        self._pools = outs[-1]
-        self._m_steps.inc()
-        self._n_decode_steps += 1
-        if self._mesh is not None:
-            self._m_tp_bytes.inc(self._tp_step_bytes)
-            self._n_tp_bytes += self._tp_step_bytes
-        n_slots = self.config.num_slots
-        active = list(prev.active)
-        self._m_util.observe(len(active) / n_slots)
-        # committed cache_len lags the device by one tick: the
-        # pipelined row of slot s attends cache_len + 2 positions
-        # (device-retired rows over-count by their window — analytic
-        # gauge, documented)
-        self._note_kv_read(sum(
-            self._slots[i].cache_len + 2 for i in active))
-        q_lens = np.zeros(n_slots, np.int64)
-        ctx = np.zeros(n_slots, np.int64)
-        for i in active:
-            q_lens[i] = 1
-            ctx[i] = self._slots[i].cache_len + 2
-        if tr is not None:
-            tr.instant("pipelined dispatch", tid=0,
-                       args={"active": len(active)})
-        return _Pipe(
-            outs=outs, active=active, given={}, n_pending=0,
-            q_lens=q_lens, rid_of=dict(prev.rid_of), pend_pos0={},
-            t_tick=t_tick, t_l0=t_l0, pure=True,
-            carry=(outs[2], outs[3]), tick=tick, dispatch="carry",
-            attn_grid=self._attn_grid(q_lens, ctx),
-            moe=moe)
-
-    def _flush_pipe(self) -> List[tuple]:
-        """Commit any in-flight pipelined tick NOW. Every
-        slot-composition mutator (cancel, preempt, handoff pop,
-        prefilled/migrated admits, session export/drain, shutdown)
-        calls this before touching slot or queue state, so the
-        pipeline only ever overlaps pure steady-state decode. No-op
-        on sync engines and an idle pipeline."""
-        out: List[tuple] = []
+    def _flush_pipe(self) -> None:
+        """Commit whatever is in flight NOW. Everything that reads or
+        rewrites slot state from outside the tick (cancel, preempt,
+        handoff pop, prefilled/migrated admits, session export/drain,
+        shutdown) calls this first: a host view that lags the device
+        by a tick would be wrong there. The tokens it commits reach
+        their callbacks here and the caller of the next
+        ``tick_dispatch()`` / ``step()`` in its return. No-op on an
+        idle pipeline; each drain of a tick dispatched ahead counts in
+        ``stats()["pipeline_flushes"]``."""
+        tick_ord = self._tick_ord
         due, self._commit_due = self._commit_due, None
         if due is not None:
-            out.extend(self._ragged_commit(due))
+            self._flushed.extend(self._ragged_commit(due))
         pipe, self._pipe = self._pipe, None
         if pipe is not None:
             self._n_pipe_flushes += 1
-            out.extend(self._ragged_commit(pipe, flush=True))
-        return out
+            self._flushed.extend(self._ragged_commit(pipe, flush=True))
+        self._tick_ord = tick_ord
 
     def run(self) -> Dict[int, np.ndarray]:
         """Drive ``step()`` until queue and slots drain; returns (and
@@ -2788,12 +2729,12 @@ class ServingEngine:
             if self._health is not None
             and self._health._incident is not None else 0,
             "nonfinite_logits_ticks": self._nonfinite_ticks,
-            # async-tick-pipeline keys: ALWAYS present (0 depth / 0
-            # flushes under the PADDLE_TPU_ASYNC_TICK=0 kill switch or
-            # async_depth unset; host_gap_ms observes on sync engines
-            # too — their gap includes the blocking fetch the pipeline
-            # removes) so dashboards never KeyError across a mixed or
-            # rolled-back fleet
+            # async-tick-pipeline keys: ALWAYS present. The depth is
+            # the one the engine runs: 1, or 0 / 0 flushes on a
+            # blocking engine (async_depth=0, the
+            # PADDLE_TPU_ASYNC_TICK=0 kill switch, speculation);
+            # host_gap_ms observes there too — its gap includes the
+            # blocking fetch the pipeline removes
             "async_depth": self._async_depth,
             "pipeline_flushes": self._n_pipe_flushes,
             "host_gap_ms": self._d_host_gap.summary(),
@@ -2889,6 +2830,10 @@ class ServingEngine:
         router's affinity probe keys on), and the slot is freed for
         the next admission. The caller (``EngineCluster``) imports the
         payload into a decode replica via ``admit_prefilled()``."""
+        if not self._handoff_ready:
+            # slots park at a commit: nothing to hand over, nothing to
+            # drain the pipeline for
+            return []
         self._flush_pipe()      # commit in-flight ticks before mutating
         out = []
         for i in self._handoff_ready:
@@ -3747,7 +3692,7 @@ class ServingEngine:
         """The [num_slots, 3] per-slot sampling tensor, uploaded only
         after a change (the ``_tables_dev`` pattern)."""
         if self._samp_dev is None:
-            self._samp_dev = self._dev(self._slot_samp)
+            self._samp_dev = self._dev(self._slot_samp.copy())
         return self._samp_dev
 
     def _lora_operand(self):
@@ -3760,7 +3705,7 @@ class ServingEngine:
         if self._lora_dev is None \
                 or self._lora_dev_version != pool.version:
             self._lora_dev = jax.tree_util.tree_map(
-                self._dev, pool.operand())
+                lambda a: self._dev(np.array(a)), pool.operand())
             self._lora_dev_version = pool.version
         return self._lora_dev
 
@@ -3828,6 +3773,8 @@ class ServingEngine:
                 v = self._pick_victim(below=req.priority)
                 if v is None:
                     break
+                if self._settle():
+                    continue
                 self._preempt(v)
                 free = [v]
             if not self._admission_fits(req):
@@ -3961,6 +3908,8 @@ class ServingEngine:
             v = self._pick_victim(below=req.priority)
             if v is None:
                 break
+            if self._settle():
+                continue
             self._preempt(v)
         return self._alloc.free_blocks - need >= target
 
@@ -3996,6 +3945,8 @@ class ServingEngine:
                 v = self._pick_victim(below=below, exclude=exclude)
                 if v is None:
                     break
+                if self._settle():
+                    continue
                 self._preempt(v)
         return self._alloc.alloc(n)
 
@@ -4008,7 +3959,8 @@ class ServingEngine:
         state (cache_len / last_token / n_emitted / history / sampling
         row) — resume is token-exact by construction on either
         path."""
-        self._flush_pipe()      # no-op mid-tick (pipe already drained)
+        self._flush_pipe()      # no-op from inside a tick: its callers
+        #                         ``_settle()`` before they pick a victim
         slot = self._slots[i]
         self._slot_props.pop(i, None)
         samp_row = self._slot_samp[i].copy()
@@ -4350,9 +4302,12 @@ class ServingEngine:
         import, re-publish — and the admission walk continues as if
         the block had never been evicted. Returns the block id (one
         reference, owned by the caller's slot) or None."""
-        if self._host_tier is None:
+        if self._host_tier is None or ("pub", h) not in self._host_tier:
             return None
-        self._drain_spills()    # h may have been evicted this very admit
+        # h may have been evicted this very admit: its bytes must be in
+        # before they are read. (Only then: taking spills in waits for
+        # the tick in flight.)
+        self._drain_spills()
         payload = self._host_tier.get(("pub", h))
         if payload is None:
             return None
@@ -4653,17 +4608,27 @@ class ServingEngine:
             slot = self._slots[i]
             if slot is None:        # preempted as an earlier victim
                 continue
-            need = _pc.blocks_for(slot.cache_len + horizon, self._bs)
             grown = True
-            while len(slot.blocks) < need:
+            # the length the tick about to be packed writes from: the
+            # committed one plus what the uncommitted tick adds. Read
+            # afresh each turn: a preemption below commits that tick
+            # (and may retire this very slot)
+            while self._slots[i] is slot and len(slot.blocks) < \
+                    _pc.blocks_for(self._ahead(i).cache_len + horizon,
+                                   self._bs):
                 try:
                     (blk,) = self._alloc_with_preempt(
                         1, exclude=(i,), below=slot.priority + 1)
                 except RuntimeError:
                     if not self._preempt_on:
                         raise
+                    if self._settle():
+                        continue
                     self._preempt(i)    # self-preempt: out of options
                     grown = False
+                    break
+                if self._slots[i] is not slot:
+                    self._alloc.free([blk])
                     break
                 self._tables[i, len(slot.blocks)] = blk
                 slot.blocks.append(blk)
@@ -4699,29 +4664,15 @@ class ServingEngine:
 
     def _retire(self, i):
         slot = self._slots[i]
+        self._release_seat(i)
+        self._finish_request(i, slot)
+
+    def _release_seat(self, i):
+        """The seat's half of a retirement: publish the sequence's
+        full blocks, free them, and empty slot ``i`` for the next
+        admission."""
+        slot = self._slots[i]
         self._slot_props.pop(i, None)
-        now = time.monotonic()
-        t0 = self._submit_t.pop(slot.rid, None)
-        if t0 is not None:
-            self._d_e2e.observe(1000.0 * (now - t0))
-        if self._health is not None:
-            # burn-rate intake: a retirement that never hit a latency
-            # violation counts as SLO-met (requests retired before the
-            # first token never entered _slo_ok)
-            self._health.on_request(self._slo_ok.pop(slot.rid, True))
-        else:
-            self._slo_ok.pop(slot.rid, None)
-        self._last_emit.pop(slot.rid, None)
-        if self._trace is not None:
-            # the request's whole residency on this slot, admission to
-            # retirement — per-tick decode/verify/prefill spans nest
-            # inside it on the same tid
-            self._trace.emit(
-                f"req{slot.rid}", tid=1 + i, t0=slot.admit_t, t1=now,
-                args={"tokens": slot.n_emitted,
-                      "cache_len": slot.cache_len})
-            self._trace.instant("retired", tid=1 + i,
-                                args={"rid": slot.rid})
         if self._prefix_on and slot.cache_len >= self._bs:
             # publish the retired sequence's FULL blocks into the
             # content index instead of just dropping them: the hash
@@ -4745,12 +4696,38 @@ class ServingEngine:
         self._slots[i] = None
         self._set_slot_samp(i)
         self._lora_release_slot(i, slot)
+        self._m_occupancy.set(self.num_active)
+
+    def _finish_request(self, i, slot):
+        """The request's half of a retirement, once its last token is
+        out: latency records, the residency span, the result."""
+        now = time.monotonic()
+        t0 = self._submit_t.pop(slot.rid, None)
+        if t0 is not None:
+            self._d_e2e.observe(1000.0 * (now - t0))
+        if self._health is not None:
+            # burn-rate intake: a retirement that never hit a latency
+            # violation counts as SLO-met (requests retired before the
+            # first token never entered _slo_ok)
+            self._health.on_request(self._slo_ok.pop(slot.rid, True))
+        else:
+            self._slo_ok.pop(slot.rid, None)
+        self._last_emit.pop(slot.rid, None)
+        if self._trace is not None:
+            # the request's whole residency on this slot, admission to
+            # retirement — per-tick decode/verify/prefill spans nest
+            # inside it on the same tid
+            self._trace.emit(
+                f"req{slot.rid}", tid=1 + i, t0=slot.admit_t, t1=now,
+                args={"tokens": slot.n_emitted,
+                      "cache_len": slot.cache_len})
+            self._trace.instant("retired", tid=1 + i,
+                                args={"rid": slot.rid})
         toks = self._results.pop(slot.rid)
         if self.config.retain_results:
             self._done[slot.rid] = np.asarray(toks, np.int64)
         self._m_completed.inc()
         self._n_completed += 1
-        self._m_occupancy.set(self.num_active)
 
     # -- compiled steps -----------------------------------------------
 
@@ -4790,23 +4767,15 @@ class ServingEngine:
         # shards on the existing GSPMD cut instead (the gmm kernel's
         # scalar-prefetch gather is a single-device layout)
         lora_gmm_ok = self._mesh is None
-        # async tick pipeline: the g=0 executable additionally returns
-        # next-tick inputs as DEVICE arrays (the carry) — per-slot
-        # sampled token, advanced base length, a decremented budget and
-        # an in-executable ``done`` mask (EOS or budget exhausted) that
-        # zeroes a finished slot's next-tick row so a pipelined tick
-        # no-ops it on device (row parks at the overflow position — the
-        # KV write null-routes, exactly like a pad row). Under the
-        # PADDLE_TPU_ASYNC_TICK=0 kill switch this flag is False and
-        # the compiled graph is bit-for-bit today's.
-        async_carry = self._async_on and not g
         eos = self._eos
-        pad = self._pad
         n_slots = self.config.num_slots
         overflow = self._overflow
         share = []      # what expert-parallel shares report, per layer
 
         def ragged(params, pools, tables, rows_pack, slots_pack, *rest):
+            if not g:
+                # the tick before's tokens ride right after the packs
+                prev_tok, rest = rest[0], rest[1:]
             if lora_on:
                 # the stacked adapter weights ride at a FIXED operand
                 # position (right after the packs) — strip them before
@@ -4818,6 +4787,30 @@ class ServingEngine:
             base, q_lens, row_starts, last_rows = (
                 slots_pack[0], slots_pack[1], slots_pack[2],
                 slots_pack[3])
+            if not g:
+                # a decode row whose token the tick before this one
+                # sampled takes it from that tick's own output, still
+                # on the device: ``src`` names the slot, -1 a row whose
+                # id the host packed (docs/OPS.md "Async tick
+                # pipeline"). A slot that tick ended on EOS retires at
+                # its commit, which the host has not run yet: its row
+                # becomes a pad row here (parked at the overflow
+                # position, so the KV write null-routes) and the commit
+                # drops what it samples.
+                src = rows_pack[3]
+                fed = src >= 0
+                fed_tok = jnp.take(prev_tok, jnp.maximum(src, 0))
+                if eos >= 0:
+                    done = fed & (fed_tok == eos)
+                    fed = fed & ~done
+                    row_pos = jnp.where(done, overflow, row_pos)
+                    # a slot's first row names the slot itself exactly
+                    # where the slot has a fed row
+                    sl = jnp.arange(n_slots, dtype=jnp.int32)
+                    q_lens = jnp.where(
+                        (jnp.take(src, row_starts) == sl)
+                        & (prev_tok == eos), 0, q_lens)
+                ids = jnp.where(fed, fed_tok, ids)
             tree_rows = slots_pack[4] if tree is not None else None
             nwin = jnp.arange(g + 1, dtype=jnp.int32)
             win = jnp.arange(self._wmax, dtype=jnp.int32)
@@ -4867,55 +4860,22 @@ class ServingEngine:
                 # of the same executable, never a new one. Always
                 # computed (executable stays bit-identical under
                 # PADDLE_TPU_HEALTH=0); only the host fetch is gated.
-                if not async_carry:
-                    nf = jnp.any(~jnp.isfinite(rows))
-                    _, sel = jax.random.split(key)
-                    tok, _ = self._select_rows(rows, sel, samp)
-                    return tok, nf, pools
-                # pipelined mode masks the probe to LIVE slots: a
-                # device-carried tick packs row i <-> slot i, so a dead
-                # slot's gathered row is an overflow pad row whose
-                # fully-masked attention output is not meaningful
+                # Live slots only: a rowless slot gathers row 0, and a
+                # pad row's fully-masked attention output is not
+                # meaningful.
                 live = q_lens > 0
                 nf = jnp.any(~jnp.isfinite(rows) & live[:, None])
                 _, sel = jax.random.split(key)
                 tok, _ = self._select_rows(rows, sel, samp)
                 tok = tok.astype(jnp.int32)
-                # -- device-resident carry: tick N+1's packs ----------
-                budget = slots_pack[-1]
-                done = live & ((tok == eos) | (budget <= 1))
-                live2 = live & ~done
-                sl = jnp.arange(n_slots, dtype=jnp.int32)
-                nxt_base = jnp.where(live, base + 1, base)
-                nxt_budget = jnp.where(live, budget - 1, budget)
-                tail = r - n_slots      # pad rows past the slot rows
-                ids2 = jnp.concatenate(
-                    [jnp.where(live2, tok, pad),
-                     jnp.full((tail,), pad, jnp.int32)])
-                slot2 = jnp.concatenate(
-                    [sl, jnp.zeros((tail,), jnp.int32)])
-                pos2 = jnp.concatenate(
-                    [jnp.where(live2, nxt_base, overflow)
-                     .astype(jnp.int32),
-                     jnp.full((tail,), overflow, jnp.int32)])
-                carry_rows = jnp.stack([ids2, slot2, pos2])
-                crows = [nxt_base, live2.astype(base.dtype), sl, sl]
-                if lora_on:
-                    crows.append(slots_pack[lora_row])
-                crows.append(nxt_budget)
-                carry_slots = jnp.stack(
-                    [c.astype(jnp.int32) for c in crows])
                 if self._mesh is not None:
-                    # compiled executables are strict about INPUT
-                    # shardings — the carry feeds straight back as
-                    # next tick's packs, so pin it replicated (what
-                    # _dev commits host packs as)
-                    rep = NamedSharding(self._mesh, P(None, None))
-                    carry_rows = jax.lax.with_sharding_constraint(
-                        carry_rows, rep)
-                    carry_slots = jax.lax.with_sharding_constraint(
-                        carry_slots, rep)
-                return tok, nf, carry_rows, carry_slots, pools
+                    # the tokens feed straight back as the next tick's
+                    # operand, and compiled executables are strict
+                    # about INPUT shardings: pin them replicated (what
+                    # _dev commits the host's packs as)
+                    tok = jax.lax.with_sharding_constraint(
+                        tok, NamedSharding(self._mesh, P(None)))
+                return tok, nf, pools
             toks = rest[0]
             if tree is not None:
                 heads = rest[1] if heads_on else None

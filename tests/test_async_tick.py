@@ -1,18 +1,23 @@
-"""Async tick pipeline (ISSUE 20): depth-1 dispatch-ahead with
-device-resident decode state. The contract under test is EXACTNESS —
-``async_depth=1`` must be greedy token-exact vs ``async_depth=0``
-across the whole engine matrix (fp / int8 KV / spec n-gram / spec
-tree / LoRA / TP=2 / GPT / colocated + disaggregated cluster),
-because the pipelined tick consumes the SAME executable's own carry
-outputs instead of a host round-trip. Also pinned here: the
-``PADDLE_TPU_ASYNC_TICK`` kill switch (env "0" beats the config, env
-"1" arms the default), zero steady-state recompiles across waves
-(``executables_compiled`` stays at the ragged baseline of 1),
-pipeline flush correctness on every slot-composition event
-(admission, preemption, migration, cancel), EOS-overrun tokens
-dropped exactly at commit, the non-finite-logits health probe firing
-through the NON-blocking fetch, and the new always-present stats
-keys (``async_depth`` / ``pipeline_flushes`` / ``host_gap_ms``).
+"""Async tick pipeline (ISSUE 20; ISSUE 29: the way the engine ticks).
+Every tick without speculation is launched BEFORE the last one's
+tokens are fetched: the host packs it from committed state plus what
+the tick in flight does to it, and the decode ids it does not have yet
+are read from that tick's output on the device. The contract under
+test is EXACTNESS — the default engine (``async_depth`` unset, or 1)
+must be token-exact against the blocking loop (``async_depth=0``)
+across the whole engine matrix (fp / int8 KV / spec n-gram / spec tree
+/ LoRA / TP=2 / GPT / colocated + disaggregated cluster) AND across
+the mixes that used to drain the pipeline: staggered admissions with a
+waiting queue, a prompt prefilled over several chunks beside decoding
+slots, budget retirements with the queue non-empty, an EOS hit while
+the next tick is in flight, a sampled request. Also pinned here: that
+such a mix launches nearly all of its ticks ahead and compiles one
+executable; the ``PADDLE_TPU_ASYNC_TICK=0`` kill switch; zero
+steady-state recompiles across waves; what still drains the pipeline
+(cancel, preemption, migration, a handoff); EOS-overrun tokens dropped
+exactly at commit; the non-finite-logits health probe through the
+lagging fetch; and the always-present stats keys (``async_depth`` /
+``pipeline_flushes`` / ``host_gap_ms``).
 
 Tier-1 guard: every test here must run in the standard
 ``-m 'not slow'`` sweep — ``test_tier1_no_slow_marker`` pins that.
@@ -78,6 +83,12 @@ def _assert_equal(a, b, tag):
                                       err_msg=f"{tag} request {i}")
 
 
+def _launches(eng):
+    """How each tick of ``eng`` was launched, in order."""
+    return [e["args"]["dispatch"] for e in eng.tracer.events()
+            if e["name"] == "launch" and e["ph"] == "X"]
+
+
 # ------------------------------------------------- parity matrix
 
 
@@ -85,8 +96,10 @@ def _assert_equal(a, b, tag):
                                      "spec_tree"])
 def test_parity_matrix_llama(llama_tiny, variant):
     """async ON == OFF greedy token-exact, per engine variant, with
-    the one-executable collapse intact in BOTH modes (the carry
-    outputs ride the ONE tick executable — they never add one)."""
+    the one-executable collapse intact in BOTH modes (the tokens of
+    the tick before are one more operand of the ONE tick executable).
+    A speculating engine's proposals need the committed history: it
+    keeps the blocking tick and says so (depth 0)."""
     kw = {"fp": {},
           "int8": dict(kv_cache_dtype="int8"),
           "spec_ngram": dict(num_speculative_tokens=2),
@@ -95,16 +108,23 @@ def test_parity_matrix_llama(llama_tiny, variant):
     on, st_on = _serve(llama_tiny, _prompts(), 1, **kw)
     off, st_off = _serve(llama_tiny, _prompts(), 0, **kw)
     _assert_equal(off, on, f"llama {variant} async on/off")
-    assert st_on["async_depth"] == 1 and st_off["async_depth"] == 0
+    ahead = variant in ("fp", "int8")
+    assert st_on["async_depth"] == (1 if ahead else 0)
+    assert st_off["async_depth"] == 0
     assert st_on["executables_compiled"] == \
         st_off["executables_compiled"] == 1
-    if variant == "fp":             # g==0: the pipeline actually ran
+    assert st_on["pipeline_flushes"] == 0
+    if ahead:                       # g==0: the pipeline actually ran
         assert st_on["host_gap_ms"]["count"] > 0
         assert st_on["tokens_total"] == st_off["tokens_total"]
+        # budget retirements free their seat at the dispatch, so the
+        # schedule is the blocking loop's, tick for tick
+        assert st_on["decode_steps"] == st_off["decode_steps"]
+        assert st_on["prefill_chunks"] == st_off["prefill_chunks"]
 
 
 def test_parity_gpt(gpt_tiny):
-    """GPT (LayerNorm + fused QKV + biased MLP): same carry graph,
+    """GPT (LayerNorm + fused QKV + biased MLP): same graph,
     token-exact."""
     on, st_on = _serve(gpt_tiny, _prompts(vocab=96), 1)
     off, _ = _serve(gpt_tiny, _prompts(vocab=96), 0)
@@ -113,8 +133,9 @@ def test_parity_gpt(gpt_tiny):
 
 
 def test_parity_lora(llama_tiny):
-    """Multi-LoRA: the per-slot adapter row travels IN the carry, so
-    a pipelined tick keeps each slot pinned to its adapter."""
+    """Multi-LoRA: the per-slot adapter row rides the slots pack of
+    every tick, so a tick launched ahead keeps each slot pinned to its
+    adapter."""
     names = ("q_proj", "o_proj")    # square on kv_heads=2 tiny
     rng = np.random.RandomState(101)
     w = {n: (rng.normal(0, 0.3, (64, 4)).astype(np.float32),
@@ -131,13 +152,15 @@ def test_parity_lora(llama_tiny):
         outs[depth] = [done[r] for r in rids]
         if depth == 1:
             assert eng.stats()["executables_compiled"] == 1
+            assert _launches(eng).count("carry") > 0
         eng.shutdown()
     _assert_equal(outs[0], outs[1], "lora async on/off")
 
 
 def test_parity_tp2(llama_tiny):
-    """TP=2: carry arrays pinned replicated across the mesh — the
-    pipelined dispatch's input shardings match the AOT signature."""
+    """TP=2: the tokens a tick leaves on the device are pinned
+    replicated across the mesh — as the next tick's operand their
+    sharding matches the AOT signature."""
     on, st_on = _serve(llama_tiny, _prompts(), 1, tp_degree=2)
     off, _ = _serve(llama_tiny, _prompts(), 0, tp_degree=2)
     _assert_equal(off, on, "tp2 async on/off")
@@ -148,8 +171,10 @@ def test_parity_tp2(llama_tiny):
 @pytest.mark.parametrize("disagg", [False, True])
 def test_parity_cluster(llama_tiny, disagg):
     """Cluster dispatch-all-then-commit-all: colocated and
-    prefill/decode-disaggregated fleets stay token-exact vs sync
-    replica ticking, with the fleet stats roll-ups present."""
+    prefill/decode-disaggregated fleets stay token-exact vs blocking
+    replica ticking, with the fleet stats roll-ups present. The
+    disaggregated fleet's handoffs (pop on the prefill replica, seat
+    on the decode replica) drain those engines' pipelines."""
     def run(depth):
         scfg = _scfg(async_depth=depth)
         ccfg = ClusterConfig(num_replicas=2,
@@ -169,13 +194,130 @@ def test_parity_cluster(llama_tiny, disagg):
     assert st_off["pipeline_flushes"] == 0
 
 
+# ------------------------------- the mixes that used to drain it
+
+
+def _mix(name):
+    """``(config, schedule)`` of one mix: ``schedule`` is a list of
+    ``(steps to run first, prompt, max_new, submit kwargs)``."""
+    rng = np.random.RandomState(17)
+    p = lambda n: rng.randint(1, 128, (n,)).astype(np.int64)
+    if name == "staggered_queue":
+        # five arrivals on two slots, two steps apart: there is always
+        # a queue, and admissions land beside decoding slots
+        return {}, [(2 * k, p(n), 6 + k, {})
+                    for k, n in enumerate((9, 5, 12, 7, 10))]
+    if name == "chunked_prompt":
+        # a 40-token prompt rides five 8-row chunks beside a decoding
+        # slot, and a second long prompt trickles behind it
+        return dict(max_model_len=96), [
+            (0, p(6), 14, {}), (3, p(40), 5, {}), (0, p(27), 5, {})]
+    if name == "budget_retire_queue":
+        # short budgets and a deep queue: a seat changes hands every
+        # few ticks
+        return {}, [(0, p(5 + k % 4), 2 + k % 3, {}) for k in range(9)]
+    if name == "eos_mid_pipeline":
+        # (the test picks the EOS from what these streams hold:)
+        # whichever streams hit it retire one tick after the device
+        # has already been given their next row
+        return {}, [(k, p(6 + k), 16, {}) for k in range(5)]
+    if name == "sampled":
+        # temperature > 0: the key is split once a dispatch, in
+        # dispatch order, and the schedule is the blocking loop's
+        return dict(decode_strategy="sampling", temperature=0.9,
+                    top_k=20, seed=5), [
+            (2 * k, p(n), 7, dict(temperature=t))
+            for k, (n, t) in enumerate(((9, 0.7), (5, None), (12, 1.3),
+                                        (7, None)))]
+    raise KeyError(name)
+
+
+def _run_mix(model, name, depth, **over):
+    kw, schedule = _mix(name)
+    eng = ServingEngine(model, _scfg(async_depth=depth, **kw, **over))
+    rids = []
+    for steps, prompt, max_new, skw in schedule:
+        for _ in range(steps):
+            eng.step()
+        rids.append(eng.submit(prompt.copy(), max_new, **skw))
+    done = eng.run()
+    st = eng.stats()
+    how = _launches(eng)
+    assert eng.shutdown()           # the allocator's invariants hold
+    return [done[r] for r in rids], st, how
+
+
+MIXES = ["staggered_queue", "chunked_prompt", "budget_retire_queue",
+         "eos_mid_pipeline", "sampled"]
+
+
+@pytest.mark.parametrize("mix", MIXES)
+def test_parity_mixes(llama_tiny, mix):
+    """ON == OFF token-exact through admissions, chunked prefill,
+    budget retirements, EOS hits and sampling, none of which drains
+    the pipeline any more; one executable either way."""
+    over = {}
+    if mix == "eos_mid_pipeline":
+        free, _, _ = _run_mix(llama_tiny, mix, 0)
+        mids = [int(t) for s in free for t in np.asarray(s)[3:12]]
+        over["eos_token_id"] = max(set(mids), key=mids.count)
+    on, st_on, how = _run_mix(llama_tiny, mix, 1, **over)
+    off, st_off, _ = _run_mix(llama_tiny, mix, 0, **over)
+    _assert_equal(off, on, f"mix {mix} async on/off")
+    assert st_on["pipeline_flushes"] == 0
+    assert st_on["executables_compiled"] == \
+        st_off["executables_compiled"] == 1
+    assert st_on["tokens_total"] == st_off["tokens_total"]
+    assert st_on["requests_completed"] == st_off["requests_completed"]
+    assert how.count("carry") > how.count("packed")
+    if mix == "eos_mid_pipeline":
+        # the mix does hit its EOS, and an EOS costs the device a row
+        # the host could not know was dead — never a token
+        assert any(len(np.asarray(t)) < 16 for t in on)
+    else:
+        assert st_on["decode_steps"] == st_off["decode_steps"]
+        assert st_on["prefill_chunks"] == st_off["prefill_chunks"]
+
+
+def test_mix_launches_ahead_and_compiles_once(llama_tiny):
+    """The new rule, counted: a mix of staggered admissions, chunked
+    prefill and budget retirements under a waiting queue launches
+    over 80% of its ticks with another still uncommitted
+    (``dispatch="carry"``: what ``pipelined_tick_share`` reads), every
+    launch is one of the two kinds, and no second executable is ever
+    compiled."""
+    rng = np.random.RandomState(23)
+    eng = ServingEngine(llama_tiny, _scfg(max_model_len=96))
+    assert eng.stats()["async_depth"] == 1      # the default
+    lens = (9, 30, 5, 12, 26, 7, 10, 6, 18, 8)
+    for k, n in enumerate(lens):
+        eng.submit(rng.randint(1, 128, (n,)), 4 + k % 5)
+        eng.step()
+    eng.run()
+    st = eng.stats()
+    how = _launches(eng)
+    assert len(how) == st["decode_steps"] > 30
+    assert set(how) == {"carry", "packed"}
+    assert how.count("carry") > 0.8 * len(how)
+    assert how[0] == "packed"       # nothing was in flight before it
+    assert st["executables_compiled"] == 1
+    assert st["pipeline_flushes"] == 0
+    assert st["requests_completed"] == len(lens)
+    # each tick's span says how that tick was launched
+    ticks = [e["args"]["dispatch"] for e in eng.tracer.events()
+             if e["name"] == "tick"]
+    assert ticks == how
+    eng.shutdown()
+
+
 # --------------------------------------------- kill switch / arming
 
 
 def test_kill_switch_and_env_arming(llama_tiny, monkeypatch):
     """``PADDLE_TPU_ASYNC_TICK=0`` beats ``async_depth=1`` bit-for-bit
-    (same tokens, same executable census, depth reported 0), and
-    env "1" arms the default (``async_depth=None``) engine."""
+    (same tokens, same executable census, depth reported 0); an engine
+    that leaves the field unset dispatches ahead, with or without the
+    variable's old "1"."""
     off, st_off = _serve(llama_tiny, _prompts(), 0)
     monkeypatch.setenv("PADDLE_TPU_ASYNC_TICK", "0")
     killed, st_k = _serve(llama_tiny, _prompts(), 1)
@@ -183,10 +325,16 @@ def test_kill_switch_and_env_arming(llama_tiny, monkeypatch):
     assert st_k["async_depth"] == 0
     assert st_k["pipeline_flushes"] == 0
     assert st_k["executables_compiled"] == st_off["executables_compiled"]
-    monkeypatch.setenv("PADDLE_TPU_ASYNC_TICK", "1")
-    armed, st_a = _serve(llama_tiny, _prompts(), None)
-    _assert_equal(off, armed, "env-armed vs sync")
-    assert st_a["async_depth"] == 1
+    unset, st_u = _serve(llama_tiny, _prompts(), None)
+    assert st_u["async_depth"] == 0             # the switch is still on
+    for env in ("1", None):
+        if env is None:
+            monkeypatch.delenv("PADDLE_TPU_ASYNC_TICK")
+        else:
+            monkeypatch.setenv("PADDLE_TPU_ASYNC_TICK", env)
+        armed, st_a = _serve(llama_tiny, _prompts(), None)
+        _assert_equal(off, armed, f"default (env {env}) vs sync")
+        assert st_a["async_depth"] == 1
 
 
 def test_async_depth_validation(llama_tiny):
@@ -201,7 +349,7 @@ def test_async_depth_validation(llama_tiny):
 
 def test_zero_steady_state_recompiles_two_waves(llama_tiny):
     """Two waves through one async engine: the executable census is
-    pinned at 1 after wave 1 and STAYS 1 — the pipelined dispatch
+    pinned at 1 after wave 1 and STAYS 1 — a tick launched ahead
     reuses the AOT tick executable, never traces a second one."""
     eng = ServingEngine(llama_tiny, _scfg(async_depth=1))
     eng.serve([p.copy() for p in _prompts()], max_new_tokens=6)
@@ -215,29 +363,34 @@ def test_zero_steady_state_recompiles_two_waves(llama_tiny):
     eng.shutdown()
 
 
-# ------------------------------------------------- flush correctness
+# ------------------------------------------ what drains, what rides
 
 
-def test_flush_on_staggered_admission(llama_tiny):
-    """A request arriving mid-pipeline flushes (commit the in-flight
-    tick) before the admission tick, so the composition every device
-    tick sees — and therefore every greedy token — matches the sync
-    schedule exactly."""
+def test_staggered_admission_rides_the_pipeline(llama_tiny):
+    """A request arriving while a tick is in flight is admitted by the
+    NEXT dispatch, beside that tick: nothing is drained, its prompt
+    rows ride a tick launched ahead, and every greedy token matches
+    the blocking schedule exactly. (Before ISSUE 29 an admission
+    flushed the pipeline; this test pinned that.)"""
     def run(depth):
         eng = ServingEngine(llama_tiny, _scfg(async_depth=depth))
         p0, p1 = _prompts(lens=(9, 7))
         rids = [eng.submit(p0.copy(), 10)]
         for _ in range(4):
             eng.step()
+        n0 = len(_launches(eng))
         rids.append(eng.submit(p1.copy(), 8))
         done = eng.run()
         st = eng.stats()
+        how = _launches(eng)[n0:]
         eng.shutdown()
-        return [done[r] for r in rids], st
-    on, st_on = run(1)
-    off, _ = run(0)
+        return [done[r] for r in rids], st, how
+    on, st_on, how = run(1)
+    off, st_off, _ = run(0)
     _assert_equal(off, on, "staggered admission async on/off")
-    assert st_on["pipeline_flushes"] >= 1
+    assert st_on["pipeline_flushes"] == 0
+    assert how[0] == "carry"        # the admission's own tick
+    assert st_on["decode_steps"] == st_off["decode_steps"]
 
 
 def test_flush_on_preemption_storm(llama_tiny):
@@ -264,6 +417,31 @@ def test_flush_on_preemption_storm(llama_tiny):
     off, st_off = run(0)
     _assert_equal(off, on, "preemption storm async on/off")
     assert st_on["preemptions"] >= 1 and st_off["preemptions"] >= 1
+    assert st_on["pipeline_flushes"] >= 1
+
+
+def test_growth_preemption_inside_a_dispatch(llama_tiny):
+    """An overcommitted pool runs dry while a tick is in flight: the
+    growth that finds it dry commits that tick first, preempts on
+    committed state, and packs the tick again from there — tokens
+    exact, nothing leaked."""
+    def run(depth):
+        eng = ServingEngine(llama_tiny, _scfg(
+            async_depth=depth, num_slots=3, num_blocks=9,
+            enable_preemption=True, admission_watermark_blocks=0,
+            max_model_len=64, enable_prefix_cache=False))
+        rng = np.random.RandomState(9)
+        rids = [eng.submit(rng.randint(1, 128, (n,)), 20)
+                for n in (7, 6, 5)]
+        done = eng.run()
+        st = eng.stats()
+        assert eng.shutdown()
+        return [done[r] for r in rids], st
+    on, st_on = run(1)
+    off, st_off = run(0)
+    _assert_equal(off, on, "growth preemption async on/off")
+    assert st_on["preemptions"] >= 1 and st_off["preemptions"] >= 1
+    assert st_on["pipeline_flushes"] >= 1
 
 
 def test_migration_flushes_and_stays_token_exact(llama_tiny):
@@ -283,6 +461,7 @@ def test_migration_flushes_and_stays_token_exact(llama_tiny):
         src.step()
     rec = src.export_session(0)
     assert src.num_active == 0
+    assert src.stats()["pipeline_flushes"] == 1
     assert dst.admit_migrated(rec) is not None
     dst.run()
     np.testing.assert_array_equal(np.asarray(got),
@@ -316,6 +495,26 @@ def test_cancel_mid_pipeline(llama_tiny):
     assert st_on["requests_cancelled"] == 1
 
 
+def test_step_returns_what_a_drain_committed(llama_tiny):
+    """Tokens a drain commits reach their callback at the drain and
+    the caller of the next ``step()`` in its return: nothing a client
+    is owed goes missing from either stream."""
+    got, ret = [], []
+    eng = ServingEngine(llama_tiny, _scfg(),
+                        stream_callback=lambda r, t: got.append((r, t)))
+    r0 = eng.submit(_prompts(lens=(9,))[0].copy(), 12)
+    r1 = eng.submit(_prompts(lens=(7,))[0].copy(), 12)
+    for _ in range(4):
+        ret.extend(eng.step())
+    assert eng.cancel(r1)
+    while eng.num_active or eng.num_queued:
+        ret.extend(eng.step())
+    assert [(r, int(t)) for r, t in ret] == \
+        [(r, int(t)) for r, t in got]
+    assert sum(r == r0 for r, _ in ret) == 12
+    eng.shutdown()
+
+
 # ------------------------------------------------------ EOS overrun
 
 
@@ -334,15 +533,17 @@ def test_eos_overrun_token_dropped_exactly(llama_tiny):
     _assert_equal(off, on, "eos overrun async on/off")
     assert len(np.asarray(on[0])) < 10      # EOS actually cut it
     assert st_on["tokens_total"] == st_off["tokens_total"]
+    # the row the device was given before the host saw the EOS
+    assert st_on["decode_steps"] == st_off["decode_steps"] + 1
 
 
 # ------------------------------------------------- health under async
 
 
 def test_nonfinite_probe_fires_under_async(llama_tiny):
-    """ISSUE 20 satellite: the non-finite-logits probe now rides the
-    async copy (fetched at COMMIT, off the dispatch path) — NaN
-    params must still trip the page alert under async_depth=1 with
+    """ISSUE 20 satellite: the non-finite-logits probe rides the
+    lagging fetch (taken at COMMIT, off the dispatch path) — NaN
+    params must still trip the page alert on the default engine with
     the executable census unchanged."""
     paddle.seed(0)
     cfg = LlamaConfig.tiny(vocab=128, hidden=64, layers=2, heads=4,
@@ -369,18 +570,22 @@ def test_nonfinite_probe_fires_under_async(llama_tiny):
 
 def test_stats_keys_always_present(llama_tiny):
     """The ISSUE 20 keys are part of the always-present contract: a
-    plain SYNC engine and a 1-replica cluster report them (zeros /
-    empty digest), so dashboards never KeyError across configs."""
+    plain engine, a blocking one and a 1-replica cluster report them
+    (zeros / empty digest), so dashboards never KeyError across
+    configs. The depth is the one the engine runs: 1 by default."""
     eng = ServingEngine(llama_tiny, _scfg())
     st = eng.stats()
-    assert st["async_depth"] == 0
+    assert st["async_depth"] == 1
     assert st["pipeline_flushes"] == 0
     assert st["host_gap_ms"]["count"] >= 0
+    eng.shutdown()
+    eng = ServingEngine(llama_tiny, _scfg(async_depth=0))
+    assert eng.stats()["async_depth"] == 0
     eng.shutdown()
     cl = EngineCluster(llama_tiny, ClusterConfig(num_replicas=1),
                        _scfg())
     cst = cl.stats()
-    assert cst["async_depth"] == 0 and cst["pipeline_flushes"] == 0
+    assert cst["async_depth"] == 1 and cst["pipeline_flushes"] == 0
     cl.shutdown()
 
 

@@ -469,13 +469,21 @@ def test_cluster_watchdog_drains_stuck_replica(tmp_path, monkeypatch):
                              health_watchdog_floor_s=0.05,
                              health_watchdog_mult=1.0))
     eng1 = cl.engines[1]
-    orig = eng1._step_ragged
+    orig = eng1._tick_dispatch
+    calls = []
 
     def slow():
-        time.sleep(0.12)            # > deadline, inside step()'s timer
+        # wedged from its third tick on: the engine dispatches ahead,
+        # so a stall on the host lies inside the launch -> sync time
+        # of the tick in flight and would raise the step EMA that the
+        # deadline is a multiple of, were every tick stalled from the
+        # first
+        calls.append(1)
+        if len(calls) > 2:
+            time.sleep(0.12)        # > deadline, inside the tick's timer
         return orig()
 
-    eng1._step_ragged = slow
+    eng1._tick_dispatch = slow      # both step() and tick_dispatch()
     rng = np.random.RandomState(5)
     rids = [cl.submit(rng.randint(1, 128, (9,)), 4) for _ in range(6)]
     with pytest.warns(UserWarning, match="watchdog"):

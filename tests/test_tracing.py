@@ -256,7 +256,9 @@ def _record_annotations(monkeypatch):
 def test_tick_phases_cover_every_ragged_tick(llama_tiny):
     """A mixed ragged wave: every tick has its pack / launch / fetch /
     commit, every admission lies in an ``admit`` phase, every phase
-    names its tick, and phases only ever overlap by nesting."""
+    names its tick, and phases only ever overlap by nesting. A tick's
+    fetch and commit run after the NEXT tick's launch (the engine
+    dispatches ahead); tick by tick the order is the same."""
     rng = np.random.RandomState(3)
     eng = ServingEngine(llama_tiny, ServingConfig(
         num_slots=2, block_size=8, max_model_len=64, prefill_chunk=16))
@@ -266,7 +268,8 @@ def test_tick_phases_cover_every_ragged_tick(llama_tiny):
     ticks = [e for e in eng.tracer.events() if e["name"] == "tick"]
     n_ticks = eng.stats()["decode_steps"]
     assert len(ticks) == n_ticks > 0
-    assert all(e["args"]["dispatch"] == "packed" for e in ticks)
+    assert [e["args"]["dispatch"] for e in ticks][:2] == \
+        ["packed", "carry"]
     assert all(isinstance(e["args"]["tick"], int) for e in phases)
     by_tick = {}
     for e in phases:
@@ -281,7 +284,14 @@ def test_tick_phases_cover_every_ragged_tick(llama_tiny):
     assert sum(e["args"]["admitted"] for e in admits) == len(prompts)
     assert all(e["args"]["queued"] >= 0 for e in admits)
     launches = [e for e in phases if e["name"] == "launch"]
-    assert all(e["args"]["dispatch"] == "packed" for e in launches)
+    assert [e["args"]["dispatch"] for e in launches] == \
+        [e["args"]["dispatch"] for e in ticks]
+    # the host's order: tick n+1 is launched before tick n is fetched
+    seq = [(e["name"], e["args"]["tick"]) for e in phases
+           if e["name"] in ("launch", "fetch")]
+    for n in range(n_ticks - 1):
+        if launches[n + 1]["args"]["dispatch"] == "carry":
+            assert seq.index(("launch", n + 1)) < seq.index(("fetch", n))
     commits = [e for e in phases if e["name"] == "commit"]
     assert sum(e["args"]["tokens"] for e in commits) == 5 * len(prompts)
     assert not any(e["args"]["flush"] for e in commits)
@@ -350,31 +360,38 @@ def test_spill_phase_per_evicted_block(llama_tiny, tier_bytes, stored):
     eng.shutdown()
 
 
-def test_async_steady_decode_launches_from_the_carry(llama_tiny):
-    """Depth-1 async: steady decode launches from the device-resident
-    carry, and both the launch and its tick say so; the commits that
-    drain the pipeline are marked as flushes."""
+def test_ticks_launched_ahead_say_so(llama_tiny):
+    """The default engine launches every tick but the first with the
+    one before still uncommitted, and both the launch and its tick say
+    so (``dispatch="carry"``; ``"packed"`` where nothing was in
+    flight); only a commit that drains the pipeline — here for a
+    cancel — is marked as a flush."""
     rng = np.random.RandomState(29)
     eng = ServingEngine(llama_tiny, ServingConfig(
-        num_slots=2, block_size=8, max_model_len=64, prefill_chunk=16,
-        async_depth=1))
+        num_slots=2, block_size=8, max_model_len=64, prefill_chunk=16))
     eng.serve([rng.randint(1, 128, (n,)) for n in (6, 9)],
               max_new_tokens=12)
     phases = _phases(eng.tracer)
     how = [e["args"]["dispatch"] for e in phases if e["name"] == "launch"]
-    assert how.count("carry") > 0 and how.count("packed") > 0
+    assert how[0] == "packed" and set(how[1:]) == {"carry"}
     ticks = {e["args"]["tick"]: e["args"]["dispatch"]
              for e in phases if e["name"] == "launch"}
+    assert sorted(ticks) == list(range(eng.stats()["decode_steps"]))
+    spans = {e["args"]["tick"] for e in phases if e["name"] == "commit"}
     for e in eng.tracer.events():
         if e["name"] == "tick":
             assert e["args"]["dispatch"] in ("packed", "carry")
-    assert sorted(ticks) == list(range(eng.stats()["decode_steps"]))
-    n_carry = sum(e["name"] == "pipelined dispatch"
-                  for e in eng.tracer.events())
-    assert n_carry == how.count("carry")
+    assert spans == set(ticks)
     commits = [e for e in phases if e["name"] == "commit"]
     assert len(commits) == len(how)
-    assert any(e["args"]["flush"] for e in commits)
+    assert not any(e["args"]["flush"] for e in commits)
+    rid = eng.submit(rng.randint(1, 128, (7,)), 12)
+    for _ in range(3):
+        eng.step()
+    assert eng.cancel(rid)
+    commits = [e for e in _phases(eng.tracer) if e["name"] == "commit"]
+    assert [e["args"]["flush"] for e in commits[len(how):]] == \
+        [False, False, True]
     eng.shutdown()
 
 
