@@ -67,6 +67,9 @@ EXPECT = {
     "serve.ragged_paged_attention": ("mosaic", ("ragged_paged_attention",)),
     "serve.fused_norm_matmul": ("mosaic", ("fused_norm_matmul",)),
     "serve.fused_matmul_residual": ("mosaic", ("fused_matmul_residual",)),
+    # (a model with slot state beside the paged KV, head size 64)
+    "serve.slot_state.ragged_paged_attention": (
+        "mosaic", ("ragged_paged_attention",)),
     # -- the train step (TrainStep, seq 2048) ------------------------------
     "train.flash_attention": ("mosaic", _FLASH),
     # -- tensor-parallel serving (four-chip phase) -------------------------
@@ -85,6 +88,7 @@ EXPECT = {
     "kernel.paged_verify.int8": ("mosaic", ("paged_verify_attention",)),
     "kernel.ragged_paged.bf16": ("mosaic", ("ragged_paged_attention",)),
     "kernel.ragged_paged.int8": ("mosaic", ("ragged_paged_attention",)),
+    "kernel.ragged_paged.d64": ("mosaic", ("ragged_paged_attention",)),
     "kernel.ragged_latent.bf16": ("mosaic", ("ragged_latent_attention",)),
     "kernel.norm_matmul.qkv_bias": ("mosaic", ("fused_norm_matmul",)),
     "kernel.norm_matmul.gate_up": ("mosaic", ("fused_norm_matmul",)),
@@ -133,6 +137,15 @@ SERVE_FULL = dict(
     engine=dict(),                      # the default ServingConfig
     int8_engine=dict(block_size=32),    # int8 sublane tile (ServingConfig doc)
     n_requests=16, n_int8_requests=8, max_new=(8, 24))
+# one small engine of the family whose conv layers keep slot state
+# (models/lfm2_moe.py): 4 heads x 64 over 2 kv heads, 8 experts top-2,
+# conv / attention / conv / conv / conv — the same on the chip and in the
+# CPU rehearsal
+SLOT_STATE = dict(
+    widths=dict(vocab=512, hidden=256, heads=4, kv_heads=2, dense_ffn=512,
+                moe_ffn=128, experts=8, topk=2),
+    engine=dict(num_slots=4, max_model_len=128, prefill_chunk=16),
+    n_requests=8, max_new=(3, 6))
 SERVE_TINY = dict(
     widths=dict(vocab_size=512, hidden_size=256, intermediate_size=512,
                 num_attention_heads=2, num_key_value_heads=1,
@@ -353,13 +366,19 @@ def kernel_cases(full, interpret=None):
         flashmask_args(rng))))
 
     # -- paged attention: decode / verify / ragged x bf16 / int8 pools -----
-    for tag, quant in (("bf16", False), ("int8", True)):
+    for tag, quant in (("bf16", False), ("int8", True), ("d64", False)):
         # the default engine's shapes (8 slots, 1024-token reach, block 16;
         # int8 pools at their sublane tile, block 32)
         pd = dict(S=8, H=28, Hkv=4, D=128, BS=32 if quant else 16) if full \
             else dict(S=4, H=4, Hkv=2, D=128, BS=32 if quant else 16)
-        pd["MB"] = (1024 if full else 64) // pd["BS"]
         w_narrow, w_max = (4, 128) if full else (2, 8)
+        if tag == "d64":
+            # head size 64, two kv heads to a lane tile: the wide cell's
+            # 128 slots, 32 query / 8 kv heads, one 256-row chunk
+            pd = dict(S=128, H=32, Hkv=8, D=64, BS=16) if full \
+                else dict(S=4, H=8, Hkv=4, D=64, BS=16)
+            w_max = 256 if full else 8
+        pd["MB"] = (1024 if full else 64) // pd["BS"]
         rows = pd["S"] * w_narrow + w_max
 
         def decode_build(rng, pd=pd, quant=quant):
@@ -378,6 +397,12 @@ def kernel_cases(full, interpret=None):
                          rows=rows):
             q, kp, vp, tables, lens = _paged_inputs(
                 rng, pd, quant, (rows, pd["H"], pd["D"]))
+            flat = pd["D"] % 128 != 0
+            if flat:
+                # head size 64: the pool is built flat (a position's kv
+                # heads side by side in one row), the kernel reads two
+                # heads a tile, the mirror plain heads again
+                kp, vp = (p.reshape(*p.shape[:2], -1) for p in (kp, vp))
             # three ticks through the one kernel: a mixed one (one wide
             # prefill chunk, verify windows and decode rows, one idle
             # slot), the commonest one, decode only (a row a slot), and
@@ -412,11 +437,16 @@ def kernel_cases(full, interpret=None):
                     for ql, rs, _sl, live, kw in ticks)
 
             def mirror(q, kp, vp, tables, ctx):
+                if flat:
+                    kp, vp = (pa._unflat(p, pd["D"]) for p in (kp, vp))
                 return tuple(jnp.where(live, pa._xla_ragged_paged(
                     q, kp, vp, tables, ctx, ql, rs, sl, wn, w, **kw), 0)
                     for ql, rs, sl, live, kw in ticks)
             return kern, mirror, (q, kp, vp, tables, ctx)
 
+        if tag == "d64":    # the ragged kernel alone takes this head size
+            cases.append(("kernel.ragged_paged.d64", ragged_build))
+            continue
         cases += [(f"kernel.paged_decode.{tag}", decode_build),
                   (f"kernel.paged_verify.{tag}", verify_build),
                   (f"kernel.ragged_paged.{tag}", ragged_build)]
@@ -758,6 +788,10 @@ def _serve_engine(model, vocab, engine_cfg, prompts, expect, clock,
     t0 = time.monotonic()
     snap = clock.snapshot()
     eng = ServingEngine(model, ServingConfig(**engine_cfg))
+    if eng.stats()["state_bytes"]:
+        # slot state: the snapshot pair a prefix hit needs is built off
+        # the hot path, as a deployment's scale-up warm does
+        eng.warm_migration()
     # warm-up covers every path the wave takes: a multi-chunk prefill
     # beside a decoding slot, a prefix hit, a repeated prompt
     _serve_wave(eng, [prompts[5], prompts[0]], vocab)
@@ -873,6 +907,37 @@ def serve_phase(size, clock, on_chip, observe=None,
     if size["engine"].get("tp_degree", 1) == 1:
         say("serve", logit_gap_max=_check_against_plain_forward(
             model, cfg.vocab_size, samples), tolerance=LOGIT_TOL)
+        del model
+        gc.collect()
+        _serve_slot_state(clock, on_chip)
+
+
+def _serve_slot_state(clock, on_chip):
+    """An engine over two kinds of state: the conv layers' rows a SLOT
+    beside the attention layers' paged KV, through the same waves
+    (chunked prompts, prefix hits cut back to a snapshot, a repeated
+    prompt), checked against the model's plain forward."""
+    import numpy as np
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import ServingConfig
+    from paddle_tpu.models.lfm2_moe import (Lfm2MoeConfig,
+                                            Lfm2MoeForCausalLM)
+    size = SLOT_STATE
+    paddle.seed(SEED)
+    cfg = Lfm2MoeConfig.tiny(dtype="bfloat16", **size["widths"])
+    model = Lfm2MoeForCausalLM(cfg)
+    model.eval()
+    sc = ServingConfig(**size["engine"])
+    prompts = _request_mix(np.random.default_rng(SEED), size["n_requests"],
+                           cfg.vocab_size, sc.prefill_chunk, sc.block_size,
+                           size["max_new"])
+    got = _serve_engine(model, cfg.vocab_size, size["engine"], prompts,
+                        ("serve.slot_state.ragged_paged_attention",),
+                        clock, on_chip, "slot_state")
+    say("serve", model="Lfm2MoeForCausalLM", layer_types=cfg.layer_types,
+        logit_gap_max=_check_against_plain_forward(
+            model, cfg.vocab_size, [("bf16", p, t) for p, t in got]),
+        tolerance=LOGIT_TOL)
 
 
 # ==========================================================================

@@ -816,7 +816,8 @@ def _tap_routing(flat_e, e, top_k, counts):
 
 
 def group_limited_gate(logits, bias, *, n_group, topk_group, top_k,
-                       norm_topk_prob=True, routed_scaling_factor=1.0):
+                       norm_topk_prob=True, routed_scaling_factor=1.0,
+                       eps=1e-20):
     """The sigmoid / bias / group-limited router of the DeepSeek-V3
     lineage (``noaux_tc``), in float32 as published. ``logits``
     ``[s, e]`` are the gate's outputs; the scores are their sigmoid;
@@ -826,8 +827,11 @@ def group_limited_gate(logits, bias, *, n_group, topk_group, top_k,
     choice scores, the best ``topk_group`` groups stay (the others'
     choice scores become 0) and the ``top_k`` largest choice scores
     inside them are the experts. The WEIGHTS are the chosen experts'
-    scores without the bias, divided by their sum (``norm_topk_prob``),
-    times ``routed_scaling_factor``. Ties go to the lower index.
+    scores without the bias, divided by their sum plus ``eps``
+    (``norm_topk_prob``; the DeepSeek-V3 lineage publishes 1e-20, LFM2
+    1e-6), times ``routed_scaling_factor``. With ``n_group =
+    topk_group = 1`` this is the plain sigmoid top-k router with a
+    choice bias. Ties go to the lower index.
     Returns ``(topk_idx [s, k] int32, topk_weight [s, k] f32)``."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     choice = scores + bias.astype(jnp.float32)
@@ -842,7 +846,7 @@ def group_limited_gate(logits, bias, *, n_group, topk_group, top_k,
     _, idx = jax.lax.top_k(choice, top_k)
     w = jnp.take_along_axis(scores, idx, axis=1)
     if norm_topk_prob:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     return idx.astype(jnp.int32), w * jnp.float32(routed_scaling_factor)
 
 

@@ -584,8 +584,10 @@ def as_jax(x):
         return x._data
     if isinstance(x, (jax.Array, jnp.ndarray)) or hasattr(x, "aval"):
         return x
-    if getattr(x, "_is_kv_quant_pool", False):
-        # a quantized KV block pool (ops.paged_cache.QuantKV) is a jax
+    if getattr(x, "_is_kv_quant_pool", False) \
+            or getattr(x, "_is_slot_state", False):
+        # a quantized KV block pool (ops.paged_cache.QuantKV) or a
+        # layer's slot state (ops.paged_cache.SlotState) is a jax
         # pytree of arrays — pass it through, never coerce
         return x
     return _coerce_to_array(x)

@@ -184,7 +184,7 @@ import itertools
 import os
 import time
 import warnings
-from collections import deque, namedtuple
+from collections import OrderedDict, deque, namedtuple
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
@@ -584,7 +584,9 @@ class PrefilledRequest:
     first_token: int
     max_new_tokens: int
     n_blocks: int                       # real (non-pad) blocks
-    payload: list                       # per-layer (k_rows, v_rows)
+    payload: Optional[list]             # per-layer (k_rows, v_rows);
+    #                                     None (a model with slot
+    #                                     state) -> recompute on import
     # the request's per-slot sampling knobs travel with the handoff
     # (the decode replica seats the slot with the SAME values the
     # prefill engine sampled the first token under)
@@ -658,7 +660,8 @@ class _Slot:
     __slots__ = ("rid", "blocks", "worst_blocks", "cache_len",
                  "last_token", "n_emitted", "max_new", "history",
                  "prompt", "pend_pos", "admit_t",
-                 "handoff", "priority", "resume", "adapter_id")
+                 "handoff", "priority", "resume", "adapter_id",
+                 "state_snaps")
 
     def __init__(self, rid, blocks, worst_blocks, cache_len, last_token,
                  max_new, history=None, prompt=None, pend_pos=None):
@@ -668,6 +671,8 @@ class _Slot:
         self.resume = None      # (last_token, n_emitted) to restore
         #                         when a recompute re-prefill completes
         self.adapter_id = None  # pinned LoRA adapter (None = base)
+        self.state_snaps = {}   # block boundary (tokens) -> slot-state
+        #                         snapshot taken where a chunk ended on it
         self.rid = rid
         self.blocks = blocks            # allocated block ids (ordered)
         self.worst_blocks = worst_blocks
@@ -695,7 +700,7 @@ class _Pipe:
     commit still owes each its last token."""
     __slots__ = ("outs", "active", "given", "n_pending", "q_lens",
                  "rid_of", "pend_pos0", "t_tick", "t_l0", "left",
-                 "tick", "dispatch", "attn_grid", "moe")
+                 "tick", "dispatch", "span_args", "moe")
 
     def __init__(self, **kw):
         for k in self.__slots__:
@@ -742,6 +747,16 @@ class ServingEngine:
         if gamma < 0:
             raise ValueError(
                 f"num_speculative_tokens must be >= 0, got {gamma}")
+        # a model whose layers keep slot state (ops/paged_cache
+        # ``SlotState``: a recurrence's state a SLOT, beside the paged
+        # KV) says so (and is handed the engine's slot count when its
+        # caches are built: ``_init_caches``)
+        if gamma and getattr(model, "paged_slot_state", False):
+            raise NotImplementedError(
+                "speculative serving over slot state: a rejected draft "
+                "would have to roll a layer's recurrent state back, and "
+                "only the block tables can be trimmed "
+                f"({type(model).__name__} declares paged_slot_state)")
         if draft_model is not None and \
                 (gamma == 0 or cfg.drafter != "model"):
             # silently drafting via n-gram while the caller handed over
@@ -1015,6 +1030,21 @@ class ServingEngine:
         self._ragged_exec = None
         self._ragged_draft_exec = None
         self._pools = self._init_caches(model, nb)
+        # -- slot state beside the paged KV (docs/OPS.md "Slot state")
+        self._stateful = any(map(_pc.is_slot_state, self._pools))
+        self._state_bytes = _pc.state_bytes(self._pools)
+        # snapshots of a slot's state where a prefill chunk ended on a
+        # block boundary: with the slot until its blocks are published,
+        # then here under the boundary block's chain hash, evicted with
+        # the block (or, past the table's size, oldest first)
+        self._state_snaps = OrderedDict()
+        self._state_snap_cap = max(16, nb * self._bs // self._chunk)
+        self._snap_exec = None          # export_slot_state
+        self._snap_import_exec = None   # import_slot_state
+        self._n_state_started = 0       # seats a tick began from zeros
+        self._n_state_snaps = 0
+        self._n_state_snap_hits = 0
+        self._n_prefix_cut = 0          # hit tokens cut for want of state
         self._attn_geometry = self._ragged_attn_geometry(model)
         self._draft_model = draft_model \
             if gamma and cfg.drafter == "model" else None
@@ -1089,6 +1119,11 @@ class ServingEngine:
             # instead of dying — a later prefix hit restores them
             # through the fixed-width import scatter
             self._alloc.on_evict = self._spill_evicted
+        if self._stateful:
+            # a block of a stateful model cannot be restored without
+            # the state at its boundary: the tier is never offered one,
+            # and the block's snapshot dies with it
+            self._alloc.on_evict = self._evict_stateful
         # the eviction spill's own one-block gather (never crosses
         # engines, so not _mb_xfer wide); built by warm_migration() or
         # the first eviction
@@ -1271,8 +1306,9 @@ class ServingEngine:
         pool_bytes = _pc.pool_bytes(self._pools)
         target_pool_bytes = pool_bytes
         # a latent (MLA) cache is one array a layer
+        paged0 = _pc.first_paged(self._pools)
         self._latent_pool_bytes = pool_bytes \
-            if len(self._pools[0]) == 1 else 0
+            if len(paged0) == 1 else 0
         if self._draft_model is not None:
             pool_bytes += _pc.pool_bytes(self._dpools)
         self._pool_bytes_per_shard = pool_bytes // self._tp
@@ -1282,13 +1318,13 @@ class ServingEngine:
         # keys — fp engines report the fp numbers, consumers never
         # KeyError on a mixed or rolled-back fleet
         self._kv_dtype_name = "int8" if self._kv_dtype == "int8" \
-            else str(jnp.dtype(self._pools[0][0].dtype))
+            else str(jnp.dtype(paged0[0].dtype))
         self._kv_pool_bytes = pool_bytes            # data + scales
         # bytes ONE cached position costs across all target layers
         # (int8: data + scale rows) — the analytic per-step KV read
         # gauge multiplies this by the tick's attended positions
         self._kv_pos_bytes = target_pool_bytes / float(
-            self._pools[0][0].shape[0] * self._bs)
+            paged0[0].shape[0] * self._bs)
         self._kv_step_bytes_last = 0
         monitor.info(
             "serving_kv_cache_dtype",
@@ -1383,7 +1419,9 @@ class ServingEngine:
         self._tid_queue = cfg.num_slots + 1
         self._trace = None
         if _tracing.tracing_enabled():
-            tr = _tracing.Tracer(f"ServingEngine[{self._engine_id}]")
+            # (a span a decoding slot a tick: the ring grows with them)
+            tr = _tracing.Tracer(f"ServingEngine[{self._engine_id}]",
+                                 rows=cfg.num_slots)
             tr.set_thread(0, "engine")
             for i in range(cfg.num_slots):
                 tr.set_thread(1 + i, f"slot {i}")
@@ -1775,7 +1813,8 @@ class ServingEngine:
                         "num_attention_heads", None)
         if not heads:
             return None
-        pool = self._pools[0][0]
+        paged0 = _pc.first_paged(self._pools)
+        pool = paged0[0]
         pool = getattr(pool, "data", pool)      # QuantKV: the int8 half
         dtype = pool.dtype
         if not jnp.issubdtype(dtype, jnp.floating):
@@ -1784,7 +1823,14 @@ class ServingEngine:
                    num_heads=int(heads), num_kv_heads=int(pool.shape[2]),
                    q_dtype=dtype, block_size=self._bs,
                    max_blocks=self._mb)
-        if len(self._pools[0]) == 1:
+        if len(paged0) == 2 and pool.ndim == 3:
+            # a flat pool: its rows hold every kv head; the kernel walks
+            # them a lane tile (one head, or two of 64 lanes) at a time
+            d = int(getattr(model.config, "head_dim", 0)
+                    or model.config.hidden_size // int(heads))
+            geo.update(num_kv_heads=int(pool.shape[2])
+                       // _pa.flat_pool_tile(d))
+        if len(paged0) == 1:
             # a latent (MLA) cache: every head reads the layer's one
             # array, in the latent kernel's own tile
             geo.update(num_kv_heads=1, tile=_pa.LATENT_TILE)
@@ -2300,6 +2346,18 @@ class ServingEngine:
         # launch, a spill's gather or a COW consumes them before this
         # tick's commit runs
         self._pools = outs[-1]
+        state_args = {}
+        if self._stateful:
+            # a seat whose first row is position 0 began from zeros in
+            # the executable; a chunk that ended on a block boundary
+            # leaves a state worth keeping beside that block
+            started = sum(1 for p0 in pend_pos0.values() if p0 == 0)
+            self._n_state_started += started
+            state_args = {"state_seats": len(active) + len(given)}
+            if self._prefix_on:
+                for i, k in given.items():
+                    if (pend_pos0[i] + k) % self._bs == 0:
+                        self._snapshot_state(i, pend_pos0[i] + k)
         if not g:
             self._prev_tok = outs[0]
             # the commit wants the tokens on the host as soon as the
@@ -2319,8 +2377,8 @@ class ServingEngine:
             outs=outs, active=list(active), given=given,
             n_pending=len(pending), q_lens=q_lens, rid_of=rid_of,
             pend_pos0=pend_pos0, t_tick=t_tick, t_l0=t_l0, left={},
-            tick=tick, dispatch=dispatch, attn_grid=attn_grid,
-            moe=moe)
+            tick=tick, dispatch=dispatch,
+            span_args=dict(attn_grid, **state_args), moe=moe)
         return pipe, emitted
 
     def _ragged_commit(self, pipe, flush=False) -> List[tuple]:
@@ -2447,7 +2505,7 @@ class ServingEngine:
                 pending=pipe.n_pending,
                 occupancy=round(
                     (len(active) + pipe.n_pending) / n_slots, 3),
-                dispatch=pipe.dispatch, **pipe.attn_grid, **moe_args)
+                dispatch=pipe.dispatch, **pipe.span_args, **moe_args)
         return emitted
 
     # -- async tick pipeline (docs/OPS.md "Async tick pipeline") ------
@@ -2601,6 +2659,13 @@ class ServingEngine:
             "prefix_cache_enabled": self._prefix_on,
             "prefix_blocks_reused": self._n_prefix_blocks,
             "prefix_tokens_reused": self._n_prefix_tokens,
+            # slot state beside the paged KV (docs/OPS.md "Slot
+            # state"): all 0 for a model without it
+            "prefix_tokens_cut_for_state": self._n_prefix_cut,
+            "state_bytes": self._state_bytes,
+            "state_seats_started": self._n_state_started,
+            "state_snapshots": self._n_state_snaps,
+            "state_snapshot_hits": self._n_state_snap_hits,
             "prefix_hit_rate":
                 self._n_prefix_tokens / self._n_prompt_tokens
                 if self._n_prompt_tokens else 0.0,
@@ -2838,16 +2903,18 @@ class ServingEngine:
         out = []
         for i in self._handoff_ready:
             slot = self._slots[i]
-            ids = np.zeros(self._mb_xfer, np.int32)
-            ids[:len(slot.blocks)] = slot.blocks
-            ids_dev = self._dev(ids)
-            if self._export_exec is None:
-                # pools are NOT donated: the blocks stay live until
-                # _release_handoff publishes + frees them
-                self._export_exec = self._aot_compile(
-                    "export", jax.jit(_pc.export_blocks),
-                    (self._pools, ids_dev))
-            payload = self._export_exec(self._pools, ids_dev)
+            payload = None
+            if not self._stateful:
+                ids = np.zeros(self._mb_xfer, np.int32)
+                ids[:len(slot.blocks)] = slot.blocks
+                ids_dev = self._dev(ids)
+                if self._export_exec is None:
+                    # pools are NOT donated: the blocks stay live until
+                    # _release_handoff publishes + frees them
+                    self._export_exec = self._aot_compile(
+                        "export", jax.jit(_pc.export_blocks),
+                        (self._pools, ids_dev))
+                payload = self._export_exec(self._pools, ids_dev)
             self._n_handoffs += 1
             self._n_blocks_exported += len(slot.blocks)
             samp = self._slot_samp[i]
@@ -2932,6 +2999,9 @@ class ServingEngine:
             self._sync_lora_metrics()
         i = free[0]
         self._slot_adapter[i] = lrow
+        if prefilled.payload is None:
+            return self._seat_prefilled_recompute(i, prefilled, prompt,
+                                                  worst, aid)
         blocks = self._alloc.alloc(init)
         self._reserved += worst - len(blocks)
         ids = np.zeros(self._mb_xfer, np.int32)
@@ -2975,6 +3045,42 @@ class ServingEngine:
                                  args={"rid": rid})
         return rid
 
+    def _seat_prefilled_recompute(self, i, prefilled, prompt, worst,
+                                  aid):
+        """A handoff with ``payload=None`` (a model with slot state:
+        its blocks are no use without the state, which no payload
+        carries): the prompt re-prefills here through the ordinary
+        chunk machinery and ``_finish_prefill`` restores the
+        continuation — the first token already streamed — as a
+        preemption's recompute resume does."""
+        n_real = int(prompt.size)
+        tok = int(prefilled.first_token)
+        blocks, cached = self._map_prefix(prompt, n_real, i)
+        self._reserved += worst - len(blocks)
+        rid = self._next_rid
+        self._next_rid += 1
+        self._results[rid] = []
+        self._tables[i, :] = 0
+        self._tables[i, :len(blocks)] = blocks
+        self._tables_dev = None
+        slot = _Slot(rid, blocks, worst, cached, None,
+                     int(prefilled.max_new_tokens),
+                     history=list(map(int, prompt)) + [tok],
+                     prompt=prompt, pend_pos=cached)
+        slot.resume = (tok, 1)
+        slot.priority = int(getattr(prefilled, "priority", 0) or 0)
+        slot.adapter_id = None if aid is None else int(aid)
+        self._slots[i] = slot
+        self._set_slot_samp(i, prefilled)
+        self._m_occupancy.set(self.num_active)
+        if self._trace is not None:
+            self._trace.instant(
+                "admit_prefilled", tid=1 + i,
+                args={"rid": rid, "blocks": 0, "prompt_tokens": n_real})
+        if self._alloc.is_shared(blocks[cached // self._bs]):
+            self._cow(i, cached // self._bs)
+        return rid
+
     def _release_handoff(self, i):
         """Free a handed-off slot WITHOUT completion accounting — the
         request is still live, on another engine. The prompt's full
@@ -2994,17 +3100,10 @@ class ServingEngine:
             self._trace.instant("handoff", tid=1 + i,
                                 args={"rid": slot.rid,
                                       "blocks": len(slot.blocks)})
-        if self._prefix_on and slot.cache_len >= self._bs:
-            # cache position p holds history[p] for p < cache_len (the
-            # sampled first token is NOT in the cache), so the publish
-            # walk is identical to _retire's
-            n_full = min(len(slot.blocks), slot.cache_len // self._bs)
-            for b, h in zip(slot.blocks[:n_full],
-                            _pc.chain_hashes(
-                                self._fp,
-                                slot.history[:n_full * self._bs],
-                                self._bs)):
-                self._alloc.publish(b, h)
+        # cache position p holds history[p] for p < cache_len (the
+        # sampled first token is NOT in the cache), so the publish
+        # walk is _retire's
+        self._publish_full(slot)
         self._alloc.free(slot.blocks)
         self._reserved -= slot.worst_blocks - len(slot.blocks)
         self._tables[i, :] = 0
@@ -3069,8 +3168,10 @@ class ServingEngine:
         n_ctx = len(slot.history) - 1   # == cache_len for a decoding
         #                                 slot (the pending last_token
         #                                 is not in the cache)
-        payload = None
+        payload = None      # a stateful model's blocks are no use
+        #                     without its slot state: recompute
         if slot.pend_pos is None and slot.blocks \
+                and not self._stateful \
                 and len(slot.blocks) <= self._mb_xfer:
             payload = _pc.payload_rows(
                 self._export_payload(slot.blocks), len(slot.blocks))
@@ -3202,17 +3303,10 @@ class ServingEngine:
             # from the next router probe on (positions < cache_len
             # are committed — decode appends never write a published
             # block, same invariant as _retire's publish-then-free)
-            if self._prefix_on and n_ctx >= self._bs:
-                n_full = min(len(blocks), n_ctx // self._bs)
-                for b, h in zip(blocks[:n_full],
-                                _pc.chain_hashes(
-                                    self._fp,
-                                    history[:n_full * self._bs],
-                                    self._bs)):
-                    self._alloc.publish(b, h)
+            self._publish_full(slot)
             mode = "swap"
         else:
-            blocks, cached = self._map_prefix(ctx, n_ctx)
+            blocks, cached = self._map_prefix(ctx, n_ctx, i)
             self._reserved += worst - len(blocks)
             self._tables[i, :] = 0
             self._tables[i, :len(blocks)] = blocks
@@ -3343,6 +3437,7 @@ class ServingEngine:
         replica no longer serves. Returns the number of index entries
         dropped."""
         n = self._alloc.unpublish_all()
+        self._state_snaps.clear()
         if self._host_tier is not None:
             self._drain_spills()
             n += self._host_tier.purge_published()
@@ -3357,6 +3452,16 @@ class ServingEngine:
         the zero-steady-state-recompile pin holds across scale
         cycles. Where evictions spill, their one-block gather is built
         here too."""
+        if self._stateful:
+            # no payload ever leaves or enters (recompute); what a
+            # prefix hit needs instead is the snapshot pair: seat 0's
+            # state read and written back, which changes nothing
+            if self._prefix_on:
+                self._flush_pipe()
+                hits = self._n_state_snap_hits
+                self._seat_state(0, self._read_state(0))
+                self._n_state_snap_hits = hits
+            return
         payload = _pc.payload_rows(self._export_payload([]), 0)
         if self._role != "prefill":
             self._import_payload([], payload)
@@ -3390,7 +3495,9 @@ class ServingEngine:
 
     def _init_caches(self, mdl, nb):
         """Per-layer paged pools. The ``sharding``/``kv_cache_dtype``
-        kwargs are passed only when needed (TP / int8), so duck-typed
+        kwargs are passed only when needed (TP / int8), and
+        ``num_slots`` only to a model that declares slot state
+        (``paged_slot_state``), so duck-typed
         models implementing the pre-TP two-argument
         ``init_paged_caches(num_blocks, block_size)`` protocol keep
         working on the default path."""
@@ -3399,6 +3506,8 @@ class ServingEngine:
             kw["sharding"] = self._pool_sharding
         if self._kv_dtype is not None:
             kw["kv_cache_dtype"] = self._kv_dtype
+        if getattr(mdl, "paged_slot_state", False):
+            kw["num_slots"] = self.config.num_slots
         return mdl.init_paged_caches(nb, self._bs, **kw)
 
     @staticmethod
@@ -3807,7 +3916,7 @@ class ServingEngine:
                 continue
             n_real = int(req.prompt.size)
             worst = self._worst_for(n_real, req.max_new_tokens)
-            blocks, cached = self._map_prefix(req.prompt, n_real)
+            blocks, cached = self._map_prefix(req.prompt, n_real, i)
             self._reserved += worst - len(blocks)
             self._tables[i, :] = 0
             self._tables[i, :len(blocks)] = blocks
@@ -3982,14 +4091,7 @@ class ServingEngine:
             self._reserved += 1
             self._tables_dev = None
         # 2) publish full blocks (same walk as _retire)
-        if self._prefix_on and slot.cache_len >= self._bs:
-            n_full = min(len(slot.blocks), slot.cache_len // self._bs)
-            for b, h in zip(slot.blocks[:n_full],
-                            _pc.chain_hashes(
-                                self._fp,
-                                slot.history[:n_full * self._bs],
-                                self._bs)):
-                self._alloc.publish(b, h)
+        self._publish_full(slot)
         # 3) spill live bytes to the host tier (swap-resume payload).
         # A MID-PREFILL victim skips the spill: it has streamed
         # nothing, so it requeues as a FRESH request — its published
@@ -3998,7 +4100,7 @@ class ServingEngine:
         key = None
         nbytes = 0
         if slot.pend_pos is None and self._host_tier is not None \
-                and slot.blocks \
+                and slot.blocks and not self._stateful \
                 and len(slot.blocks) <= self._mb_xfer:
             # spill only a fully-valid cache (a decoding victim); a
             # mid-re-prefill victim keeps its continuation but its
@@ -4116,7 +4218,7 @@ class ServingEngine:
                 self._host_tier.pop(r["key"], restore=False)
                 self._m_host_bytes.set(self._host_tier.bytes_used)
             self._n_recompute_resumes += 1
-            blocks, cached = self._map_prefix(ctx, n_ctx)
+            blocks, cached = self._map_prefix(ctx, n_ctx, i)
             self._reserved += int(r["worst_blocks"]) - len(blocks)
             self._tables[i, :] = 0
             self._tables[i, :len(blocks)] = blocks
@@ -4360,7 +4462,7 @@ class ServingEngine:
                 w += 1.0 if s.priority >= priority else 0.25
         return w
 
-    def _map_prefix(self, prompt, n_real):
+    def _map_prefix(self, prompt, n_real, seat):
         """Map the longest cached prefix of ``prompt`` — leading FULL
         blocks whose rolling content hashes hit the allocator's index
         get refcount++'d straight into the slot's block list — then
@@ -4369,7 +4471,11 @@ class ServingEngine:
         a full-prompt hit, where the last prompt token is recomputed
         anyway (admission must produce first-token logits) and its
         shared block is COW-duplicated by the caller before the
-        write."""
+        write. A model with slot state is seated only at a boundary
+        whose state the snapshot table holds: the hit is cut back to
+        the deepest such block short of the prompt's end (to nothing if
+        there is none), ``prefix_tokens_cut_for_state`` counts what was
+        cut, and the snapshot is written into ``seat``."""
         init = _pc.blocks_for(n_real, self._bs)
         matched = []
         if self._prefix_on:
@@ -4391,6 +4497,25 @@ class ServingEngine:
                     break
                 matched.append(rb)
         cached = len(matched) * self._bs
+        if matched and self._stateful:
+            # the state after position n_real - 1 is no use either: the
+            # last prompt row is recomputed, from the state before it
+            keep, snap = 0, None
+            hashes = _pc.chain_hashes(self._fp, prompt[:cached],
+                                      self._bs)
+            for j in range(min(len(matched),
+                               (n_real - 1) // self._bs), 0, -1):
+                snap = self._state_snaps.get(hashes[j - 1])
+                if snap is not None:
+                    keep = j
+                    break
+            self._alloc.free(matched[keep:])
+            del matched[keep:]
+            self._n_prefix_cut += min(cached, n_real - 1) \
+                - keep * self._bs
+            cached = keep * self._bs
+            if snap is not None:
+                self._seat_state(seat, snap)
         if cached >= n_real:                     # full-prompt hit
             cached = n_real - 1
         if matched:
@@ -4667,28 +4792,83 @@ class ServingEngine:
         self._release_seat(i)
         self._finish_request(i, slot)
 
+    def _publish_full(self, slot):
+        """Publish a sequence's FULL blocks into the content index
+        instead of just dropping them: the hash chain runs over the
+        tokens the cache actually holds (prompt + committed
+        continuation — position p holds history[p] for p <
+        cache_len), so a future prompt sharing the prefix maps these
+        blocks instead of re-prefilling. Blocks go to the LRU cached
+        list when their refcount hits 0 and survive until memory
+        pressure evicts them. The slot-state snapshots taken at these
+        blocks' boundaries go into the snapshot table under the same
+        hashes: a later hit is seated only where one exists
+        (``_map_prefix``)."""
+        if not self._prefix_on or slot.cache_len < self._bs:
+            return
+        n_full = min(len(slot.blocks), slot.cache_len // self._bs)
+        hashes = _pc.chain_hashes(
+            self._fp, slot.history[:n_full * self._bs], self._bs)
+        for b, h in zip(slot.blocks[:n_full], hashes):
+            self._alloc.publish(b, h)
+        for end, snap in slot.state_snaps.items():
+            h = hashes[end // self._bs - 1] \
+                if end <= n_full * self._bs else None
+            if h is None or self._alloc.lookup(h) is None:
+                continue
+            self._state_snaps[h] = snap
+            self._state_snaps.move_to_end(h)
+        slot.state_snaps = {}
+        while len(self._state_snaps) > self._state_snap_cap:
+            self._state_snaps.popitem(last=False)
+
+    def _snapshot_state(self, i, end):
+        """Keep slot ``i``'s state as the tick just launched leaves it
+        — its prefill chunk ended on the block boundary ``end`` — until
+        the slot's blocks are published. One small gather on the
+        device, launched behind the tick; nothing comes to the host."""
+        self._slots[i].state_snaps[int(end)] = self._read_state(i)
+        self._n_state_snaps += 1
+
+    def _read_state(self, i):
+        slot_dev = self._dev(np.int32(i))
+        if self._snap_exec is None:
+            self._snap_exec = self._aot_compile(
+                "state_snapshot", jax.jit(_pc.export_slot_state),
+                (self._pools, slot_dev))
+        return self._snap_exec(self._pools, slot_dev)
+
+    def _seat_state(self, i, snap):
+        """Write a snapshot into seat ``i`` before the request mapped
+        past its boundary rides a tick."""
+        slot_dev = self._dev(np.int32(i))
+        if self._snap_import_exec is None:
+            self._snap_import_exec = self._aot_compile(
+                "state_seat",
+                jax.jit(_pc.import_slot_state, donate_argnums=(0,)),
+                (self._pools, slot_dev, snap))
+        with _quiet_donation():
+            self._pools = self._snap_import_exec(self._pools, slot_dev,
+                                                 snap)
+        self._n_state_snap_hits += 1
+
+    def _evict_stateful(self, b, h):
+        """Allocator eviction hook of a model with slot state: the
+        block's snapshot goes with it, and the host tier is not offered
+        a block it could not give back whole."""
+        self._state_snaps.pop(h, None)
+        if self._host_tier is not None and self._trace is not None:
+            self._trace.phase("spill", tick=self._tick_ord,
+                              block=int(b)).begin().end(
+                bytes=0, stored=False, copied=0)
+
     def _release_seat(self, i):
         """The seat's half of a retirement: publish the sequence's
         full blocks, free them, and empty slot ``i`` for the next
         admission."""
         slot = self._slots[i]
         self._slot_props.pop(i, None)
-        if self._prefix_on and slot.cache_len >= self._bs:
-            # publish the retired sequence's FULL blocks into the
-            # content index instead of just dropping them: the hash
-            # chain runs over the tokens the cache actually holds
-            # (prompt + committed continuation — position p holds
-            # history[p] for p < cache_len), so a future prompt sharing
-            # the prefix maps these blocks instead of re-prefilling.
-            # Blocks go to the LRU cached list when their refcount hits
-            # 0 below and survive until memory pressure evicts them.
-            n_full = min(len(slot.blocks), slot.cache_len // self._bs)
-            for b, h in zip(slot.blocks[:n_full],
-                            _pc.chain_hashes(
-                                self._fp,
-                                slot.history[:n_full * self._bs],
-                                self._bs)):
-                self._alloc.publish(b, h)
+        self._publish_full(slot)
         self._alloc.free(slot.blocks)
         self._reserved -= slot.worst_blocks - len(slot.blocks)
         self._tables[i, :] = 0

@@ -12,7 +12,12 @@ Design constraints, in order:
    default 262144 events, env ``PADDLE_TPU_TRACE_EVENTS``): a
    long-lived engine overwrites its oldest spans instead of growing.
    The default holds some minutes of a saturated engine (~19 events a
-   tick at 8 slots; a 16 ms tick fills 65536 in under a minute).
+   tick at 8 slots; a 16 ms tick fills 65536 in under a minute). An
+   owner with many rows says how many (``Tracer(rows=...)``): a
+   serving engine leaves a span a decoding slot a tick, so where the
+   variable is not set the ring is the default or 8192 events a row,
+   whichever is more (128 slots: 1,048,576 events, ~3 minutes of a
+   24 ms tick where the default held 46 s).
 3. **Opt-out kill switch.** ``PADDLE_TPU_TRACE=0`` disables tracing
    entirely; callers are expected to hold ``None`` instead of a Tracer
    and skip every call site (the serving engine does exactly this), so
@@ -92,14 +97,17 @@ def tracing_enabled() -> bool:
 
 
 _CAP_DEFAULT = 262144
+_CAP_PER_ROW = 8192
 
 
-def trace_buffer_capacity() -> int:
-    """Ring-buffer capacity in events (``PADDLE_TPU_TRACE_EVENTS``)."""
+def trace_buffer_capacity(rows: int = 0) -> int:
+    """Ring-buffer capacity in events: ``PADDLE_TPU_TRACE_EVENTS``
+    where it is set, else the default or ``_CAP_PER_ROW`` events for
+    each of the owner's ``rows`` timeline rows, whichever is more."""
     try:
-        return max(16, int(os.environ.get(_CAP_ENV, _CAP_DEFAULT)))
-    except ValueError:
-        return _CAP_DEFAULT
+        return max(16, int(os.environ[_CAP_ENV]))
+    except (KeyError, ValueError):
+        return max(_CAP_DEFAULT, _CAP_PER_ROW * int(rows))
 
 
 class _Phase:
@@ -152,10 +160,10 @@ class Tracer:
     """
 
     def __init__(self, name: str, pid: Optional[int] = None,
-                 capacity: Optional[int] = None):
+                 capacity: Optional[int] = None, rows: int = 0):
         self.name = name
         self.pid = next(_PIDS) if pid is None else int(pid)
-        self.capacity = int(capacity or trace_buffer_capacity())
+        self.capacity = int(capacity or trace_buffer_capacity(rows))
         self._buf: deque = deque(maxlen=self.capacity)
         self._lock = threading.Lock()
         self._threads: Dict[int, str] = {}

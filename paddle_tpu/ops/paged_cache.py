@@ -72,7 +72,10 @@ __all__ = ["NULL_BLOCK", "BlockAllocator", "blocks_for", "init_pool",
            "prompt_block_hashes", "export_blocks", "import_blocks",
            "HostKVTier", "payload_to_host", "payload_nbytes",
            "payload_rows", "payload_pad", "export_stacked",
-           "stacked_layout", "stacked_payload"]
+           "stacked_layout", "stacked_payload", "SlotState",
+           "is_slot_state", "init_slot_state", "first_paged",
+           "state_bytes", "export_slot_state", "import_slot_state",
+           "init_flat_pool"]
 
 # block id 0 is never allocated: inactive slots' tables point here, so
 # their scatter/gather indices stay valid while their data is garbage
@@ -151,6 +154,97 @@ def kv_dequantize(data, scale, dtype=jnp.float32):
     return (data.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
+class SlotState:
+    """A layer's cache that is NOT paged: ``data [num_slots + 1, ...]``
+    holds one row of recurrent state a serving SLOT (a gated short
+    convolution's last ``L - 1`` inputs, ``[S + 1, L - 1, hidden]``),
+    whatever the sequence's length; the last row is the null seat that
+    rows no slot owns read, and nothing writes. The layer's cache entry
+    is the 1-tuple ``(SlotState,)``, and :func:`is_slot_state` is the
+    ONE predicate that tells it from a block-paged entry: every walker
+    that moves BLOCKS (``copy_blocks``, ``export_blocks`` /
+    ``import_blocks``, ``export_stacked``, ``pool_bytes``) passes such
+    a layer through untouched, because it holds no blocks. Registered
+    as a jax pytree like :class:`QuantKV`, so it rides jit arguments
+    and donation unchanged."""
+
+    _is_slot_state = True             # duck-typed marker (framework)
+    __slots__ = ("data",)
+
+    def __init__(self, data):
+        self.data = data
+
+    @property
+    def shape(self):
+        return self.data.shape
+
+    @property
+    def dtype(self):
+        return self.data.dtype
+
+    @property
+    def nbytes(self):
+        return int(self.data.nbytes)
+
+    def __repr__(self):             # pragma: no cover - debugging aid
+        return f"SlotState({self.data.shape} {self.data.dtype})"
+
+
+jax.tree_util.register_pytree_node(
+    SlotState,
+    lambda p: ((p.data,), None),
+    lambda _, children: SlotState(*children))
+
+
+def is_slot_state(layer) -> bool:
+    """Whether a layer's cache entry is slot state (``(SlotState,)``)
+    and not block-paged arrays."""
+    return len(layer) == 1 and isinstance(layer[0], SlotState)
+
+
+def init_slot_state(num_slots: int, depth: int, width: int,
+                    dtype) -> tuple:
+    """Zeroed ``(SlotState,)`` of ``[num_slots + 1, depth, width]``."""
+    return (SlotState(jnp.zeros((int(num_slots) + 1, int(depth),
+                                 int(width)), dtype)),)
+
+
+def first_paged(pools):
+    """The first block-paged layer's cache entry (layer 0 of a model
+    with slot state may hold no blocks)."""
+    for layer in pools:
+        if not is_slot_state(layer):
+            return layer
+    raise ValueError("no block-paged layer among the caches")
+
+
+def state_bytes(pools) -> int:
+    """Total bytes of the slot-state tables among ``pools``."""
+    return sum(layer[0].nbytes for layer in pools if is_slot_state(layer))
+
+
+def export_slot_state(pools, slot):
+    """One slot's row of every slot-state layer, stacked in layer order
+    ``[n, ...]`` (the layers' tables share a shape): the snapshot the
+    engine keeps beside a published block. ``slot`` is a traced int32
+    scalar, so one executable serves every seat."""
+    return jnp.stack([layer[0].data[slot] for layer in pools
+                      if is_slot_state(layer)])
+
+
+def import_slot_state(pools, slot, snap):
+    """Write an :func:`export_slot_state` snapshot back at ``slot``
+    (donate ``pools``); block-paged layers pass through."""
+    out, k = [], 0
+    for layer in pools:
+        if is_slot_state(layer):
+            st = layer[0].data
+            layer = (SlotState(st.at[slot].set(snap[k].astype(st.dtype))),)
+            k += 1
+        out.append(layer)
+    return out
+
+
 def resolve_kv_cache_dtype(requested=None):
     """Resolve the KV-pool quantization request to ``"int8"`` or
     ``None`` (pool in the model dtype — the pre-quantization layout,
@@ -177,8 +271,10 @@ def resolve_kv_cache_dtype(requested=None):
 def pool_bytes(pools) -> int:
     """Total bytes of a per-layer pool list — ``[(k, v), ...]`` pairs
     or a latent cache's ``[(c,), ...]`` — int8 pools count data AND
-    scales (telemetry/bench accounting)."""
-    return sum(int(p.nbytes) for layer in pools for p in layer)
+    scales (telemetry/bench accounting). A slot-state layer holds no
+    block and counts nothing here (``state_bytes``)."""
+    return sum(int(p.nbytes) for layer in pools
+               if not is_slot_state(layer) for p in layer)
 
 
 def _each(fn, *layers):
@@ -478,6 +574,28 @@ def init_pool(num_blocks: int, block_size: int, num_kv_heads: int,
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
+def init_flat_pool(num_blocks: int, block_size: int, num_kv_heads: int,
+                   head_dim: int, dtype) -> tuple:
+    """Zeroed ``(k_pool, v_pool)``, each FLAT: ``[num_blocks,
+    block_size, H_kv * D]``, a position's kv heads side by side in one
+    row. That is the view the ragged kernel copies its K/V tiles out of
+    (``ops/pallas/paged_attention._pool_view``), held as the array
+    itself: on the chip the view of a 4-D pool is a relayout — a copy
+    of the whole pool in every layer of every tick, which a pool of a
+    few hundred blocks hides and one of tens of thousands does not —
+    and a head of 64 lanes is half a lane tile, which the kernel cannot
+    copy at all but reads here two heads a tile. The ragged step
+    (``ragged_attention_step``) writes and reads such a pool; the
+    walkers take it as they take a latent pool. Float pools only."""
+    lanes = int(num_kv_heads) * int(head_dim)
+    if lanes % 128:
+        raise ValueError(
+            f"flat pool: {num_kv_heads} kv heads x {head_dim} is not a "
+            "whole number of 128-lane tiles")
+    shape = (num_blocks, block_size, lanes)
+    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
 @functools.lru_cache(maxsize=32)
 def _sharded_zeros(shape, dtype, sharding):
     """One compiled sharded-zeros program per (shape, dtype, sharding)
@@ -762,7 +880,8 @@ def copy_blocks(pools, src, dst):
                            pool.scale.at[dst].set(pool.scale[src]))
         return pool.at[dst].set(pool[src])
 
-    return [_each(cp, layer) for layer in pools]
+    return [layer if is_slot_state(layer) else _each(cp, layer)
+            for layer in pools]
 
 
 def export_blocks(pools, block_ids):
@@ -786,7 +905,9 @@ def export_blocks(pools, block_ids):
             return QuantKV(pool.data[ids], pool.scale[ids])
         return pool[ids]
 
-    return [_each(gx, layer) for layer in pools]
+    # a slot-state layer holds no blocks: its payload entry is empty
+    return [() if is_slot_state(layer) else _each(gx, layer)
+            for layer in pools]
 
 
 def import_blocks(pools, block_ids, payload):
@@ -820,7 +941,7 @@ def import_blocks(pools, block_ids, payload):
         raise ValueError(
             f"import_blocks: payload has {len(payload)} layers, pool "
             f"has {len(pools)}")
-    return [_each(sx, layer, rows)
+    return [layer if is_slot_state(layer) else _each(sx, layer, rows)
             for layer, rows in zip(pools, payload)]
 
 
@@ -846,7 +967,7 @@ def _stacked_plan(pools):
         return place(pool)
 
     for layer in pools:
-        layout.append(_each(file, layer))
+        layout.append(() if is_slot_state(layer) else _each(file, layer))
     return groups, layout
 
 

@@ -581,6 +581,44 @@ def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tables_ref, lens_ref,
     o_ref[0, 0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
 
 
+def flat_pool_tile(head_dim) -> int:
+    """Lanes of one K/V tile the ragged kernel copies out of a FLAT
+    pool (``paged_cache.init_flat_pool``: ``[NB, BS, H_kv * D]``) for
+    queries of this head size: the head itself where it is whole lane
+    tiles, else — head size 64 — the 128 lanes that hold two
+    neighbouring kv heads (``_pair_queries``)."""
+    return head_dim if head_dim % 128 == 0 else 2 * head_dim
+
+
+def _pair_queries(q, pairs):
+    """Head size 64 as head size 128 over half the kv heads, with no
+    change to the kernel. Over a flat pool pair ``p``'s 128-lane tile
+    holds kv heads ``2p`` (lanes 0-63) and ``2p + 1`` (lanes 64-127): a
+    ``(BS, 64)`` block at a 64-lane offset is what Mosaic refuses. A
+    query head of kv head ``2p`` takes its 64 lanes in the tile's first
+    half and zeros in the second (``2p + 1``: the other way round), so
+    its scores against the 128-lane key rows are exactly its own head's
+    — the other head's lanes meet zeros — and of its output ``[..,
+    128]`` over the paired value rows the half that is its own head's
+    values is kept (``unpair``). The pair's ``2 * rep`` query heads
+    share one K/V tile, so every K/V byte is still read once a query
+    tile; the MXU multiplies twice the lanes, on a walk that waits for
+    HBM. Returns ``(q [R, H, 128], unpair)``."""
+    r, h, d = q.shape
+    second = ((jnp.arange(h) // (h // (2 * pairs))) % 2 == 1)[None, :,
+                                                                None]
+    q2 = jnp.concatenate([jnp.where(second, 0, q),
+                          jnp.where(second, q, 0)], -1)
+    return q2, lambda out: jnp.where(second, out[..., d:], out[..., :d])
+
+
+def _unflat(pool, head_dim):
+    """A flat pool as the ``[NB, BS, H_kv, head_dim]`` the XLA mirror
+    reads (the CPU path: a free reshape there)."""
+    nb, bs, lanes = pool.shape
+    return pool.reshape(nb, bs, lanes // head_dim, head_dim)
+
+
 def _unpack_pools(k_pool, v_pool):
     """(k_view, v_view, [k_scale, v_scale] or [], quantized): each
     pool as its ``_pool_view``; quantized pools split into the int8
@@ -712,15 +750,27 @@ def pallas_ragged_paged_attention(q, k_pool, v_pool, block_tables,
     tile's dead rows never reach a live packed row. Returns [R, H, D];
     rows no slot owns come back zero."""
     r, h, d = q.shape
-    nb, bs, hkv, _ = k_pool.shape
-    kd, vd, scales, quant = _unpack_pools(k_pool, v_pool)
+    scale = np.float32(sm_scale if sm_scale is not None
+                       else 1.0 / math.sqrt(d))
+    unpair = None
+    if k_pool.ndim == 3:
+        # a flat pool IS the view the kernel indexes: no reshape (which
+        # on the chip is a copy of the whole pool, a tick, a layer)
+        nb, bs, lanes = k_pool.shape
+        tile = flat_pool_tile(d)
+        hkv = lanes // tile
+        kd, vd, scales, quant = k_pool, v_pool, [], False
+        if tile != d:
+            q, unpair = _pair_queries(q, hkv)
+            d = tile
+    else:
+        nb, bs, hkv, _ = k_pool.shape
+        kd, vd, scales, quant = _unpack_pools(k_pool, v_pool)
     s, mb = block_tables.shape
     w = int(w_max)
     rep = h // hkv
     rp, tq, kb, n_tiles, n_kv = _ragged_geometry(r, s, rep, q.dtype, bs,
                                                  mb)
-    scale = np.float32(sm_scale if sm_scale is not None
-                       else 1.0 / math.sqrt(d))
     tree_bits = None
     tree_args = []
     if tree_anc is not None:
@@ -784,7 +834,8 @@ def pallas_ragged_paged_attention(q, k_pool, v_pool, block_tables,
                    lens, *tree_args, q4, kd, vd, *scales)
     out = out.reshape(n_tiles, hkv, tq, rp, d)[:, :, :, :rep] \
         .transpose(0, 2, 1, 3, 4).reshape(n_tiles * tq, h, d)
-    return _untile(out, starts, ql, tq, r)
+    out = _untile(out, starts, ql, tq, r)
+    return out if unpair is None else unpair(out)
 
 
 def _untile(out, starts, ql, tq, r):
@@ -1140,26 +1191,36 @@ def _xla_latent_verify(q, pool, block_tables, context_lens, value_dim,
 _MAX_GROUP_ROWS = 1024
 
 
-def _kernel_eligible(num_heads, head_dim, q_dtype, k_pool, window=1):
+def _kernel_eligible(num_heads, head_dim, q_dtype, k_pool, window=1,
+                     flat_ok=False):
     """Exactly the shapes the kernels above lower and compile for
     (cross-lowered for TPU in ``tests/test_tpu_lowering.py``, compiled
     by Mosaic in ``chip_smoke.py``): the ``_pool_view`` K/V block is
     ``(BS, D)`` at a lane offset, so ``D`` must be a whole number of
     128-lane tiles (64 is not) and ``BS`` a whole number of sublane
     tiles of the pool dtype (8 f32, 16 bf16/f16, 32 int8/fp8);
-    ``window`` query tokens per slot must fit ``_MAX_GROUP_ROWS``."""
-    hkv = k_pool.shape[2]
+    ``window`` query tokens per slot must fit ``_MAX_GROUP_ROWS``.
+    Over a FLAT pool (3-D, ``flat_ok``: the ragged kernel alone) the
+    block is ``flat_pool_tile`` lanes wide, which serves head size 64
+    as well: two kv heads a tile."""
     sublanes = 32 // jnp.dtype(k_pool.dtype).itemsize
+    if k_pool.ndim == 3:
+        d = flat_pool_tile(head_dim)
+        if not flat_ok or k_pool.shape[2] % d:
+            return False
+        hkv = k_pool.shape[2] // d
+    else:
+        hkv, d = k_pool.shape[2], head_dim
     if num_heads % hkv:
         return False
     rows = window * _row_pad(num_heads // hkv, q_dtype)
-    return (head_dim % 128 == 0
+    return (d % 128 == 0
             and k_pool.shape[1] % sublanes == 0
             and rows <= _MAX_GROUP_ROWS)
 
 
 def _use_kernel(kind, q_shape, num_heads, head_dim, q_dtype, k_pool,
-                window=1):
+                window=1, flat_ok=False):
     """The ONE routing decision of the three entry points: the Pallas
     kernel on a TPU backend (or under ``PADDLE_TPU_PAGED_KERNEL=
     interpret``) for eligible shapes, the gather fallback otherwise.
@@ -1168,7 +1229,7 @@ def _use_kernel(kind, q_shape, num_heads, head_dim, q_dtype, k_pool,
     to trace or compile is an error."""
     on_tpu = jax.default_backend() == "tpu"
     use = (on_tpu or _force_kernel_routing()) and _kernel_eligible(
-        num_heads, head_dim, q_dtype, k_pool, window)
+        num_heads, head_dim, q_dtype, k_pool, window, flat_ok)
     if on_tpu and not use:
         _warn_fallback(kind, q_shape, k_pool.shape)
     return use
@@ -1213,8 +1274,9 @@ def _warn_fallback(kind, q_shape, pool_shape):
         import warnings
         warnings.warn(
             "%s: shape %s / pool %s not kernel-eligible (head_dim "
-            "must be a 128-multiple, block_size a sublane-tile "
-            "multiple for the pool dtype); using the gather fallback"
+            "must be a 128-multiple — the ragged kernel also takes 64 "
+            "over a flat pool — and block_size a sublane-tile multiple "
+            "for the pool dtype); using the gather fallback"
             % (kind, tuple(q_shape), tuple(pool_shape)))
 
 
@@ -1272,11 +1334,14 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables,
     wn = int(narrow_iota.shape[0])
     w = int(win_iota.shape[0])
     if _use_kernel("ragged_paged_attention", q.shape, q.shape[1],
-                   q.shape[2], q.dtype, k_pool):
+                   q.shape[2], q.dtype, k_pool, flat_ok=True):
         return pallas_ragged_paged_attention(
             q, k_pool, v_pool, block_tables, context_lens, q_lens,
             row_starts, row_slot=row_slot, w_max=w, sm_scale=sm_scale,
             tree_anc=tree_anc, tree_slots=tree_slots)
+    if k_pool.ndim == 3:
+        k_pool = _unflat(k_pool, q.shape[2])
+        v_pool = _unflat(v_pool, q.shape[2])
     return _xla_ragged_paged(q, k_pool, v_pool, block_tables,
                              context_lens, q_lens, row_starts,
                              row_slot, wn, w, sm_scale=sm_scale,
@@ -1309,6 +1374,10 @@ def ragged_attention_step(qh, kh, vh, k_pool, v_pool, block_tables,
             tree_slots = amb_slots if tree_anc is not None else None
     from ..paged_cache import write_rows
     lens = cache_lens.astype(jnp.int32)
+    if k_pool.ndim == 3:
+        # a flat pool: a position's kv heads side by side in one row
+        kh = kh.reshape(kh.shape[0], -1)
+        vh = vh.reshape(vh.shape[0], -1)
     kp2, vp2 = write_rows(k_pool, v_pool, block_tables, row_slot,
                           row_pos, kh, vh)
     out = ragged_paged_attention(qh, kp2, vp2, block_tables, lens + 1,

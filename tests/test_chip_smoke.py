@@ -108,13 +108,17 @@ def test_serve_phase_tiny(clock, capsys):
     chip_smoke.serve_phase(chip_smoke.SERVE_TINY, clock, on_chip=False)
     out = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
     engines = {o["engine"]: o for o in out if "engine" in o}
-    assert set(engines) == {"bf16", "int8"}
-    for o in engines.values():
-        assert o["executables_compiled"] == 1
+    assert set(engines) == {"bf16", "int8", "slot_state"}
+    for tag, o in engines.items():
+        # (slot state: the tick and the snapshot pair of a prefix hit)
+        assert o["executables_compiled"] == (3 if tag == "slot_state"
+                                             else 1)
         assert o["prefix_tokens_reused"] > 0    # the mix hits the cache
         assert o["prefill_chunks"] > o["requests"]      # a multi-chunk one
-    gaps = [o for o in out if "logit_gap_max" in o][0]
-    assert set(gaps["logit_gap_max"]) == {"bf16", "int8"}
+    gaps = [o for o in out if "logit_gap_max" in o]
+    assert set(gaps[0]["logit_gap_max"]) == {"bf16", "int8"}
+    assert gaps[1]["model"] == "Lfm2MoeForCausalLM" \
+        and set(gaps[1]["logit_gap_max"]) == {"bf16"}
 
 
 def test_train_phase_tiny_feeds_from_worker_processes(clock, capsys,

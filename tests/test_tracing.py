@@ -104,8 +104,20 @@ def test_tracer_ring_buffer_bounds_memory():
 def test_tracer_env_capacity(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_TRACE_EVENTS", "64")
     assert Tracer("cap").capacity == 64
+    assert Tracer("cap1", rows=128).capacity == 64   # the variable rules
     monkeypatch.setenv("PADDLE_TPU_TRACE_EVENTS", "bogus")
     assert Tracer("cap2").capacity == 262144
+
+
+@pytest.mark.parametrize("rows, capacity", [
+    (0, 262144), (8, 262144), (32, 262144), (128, 1048576)])
+def test_tracer_capacity_grows_with_its_owner_s_rows(monkeypatch, rows,
+                                                     capacity):
+    """A serving engine leaves a span a decoding slot a tick: at 128
+    slots the default ring held 46 s of a 24 ms tick, less than one
+    benchmark window, and every whole-window reader found it wrapped."""
+    monkeypatch.delenv("PADDLE_TPU_TRACE_EVENTS", raising=False)
+    assert Tracer("rows", rows=rows).capacity == capacity
 
 
 def test_tracer_chrome_schema_nesting_and_ndjson(tmp_path):
