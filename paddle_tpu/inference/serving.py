@@ -1822,30 +1822,34 @@ class ServingEngine:
         geo = dict(rows=self._rows, w_max=self._wmax,
                    num_heads=int(heads), num_kv_heads=int(pool.shape[2]),
                    q_dtype=dtype, block_size=self._bs,
-                   max_blocks=self._mb)
+                   max_blocks=self._mb, head_lanes=int(pool.shape[-1]),
+                   # K and V, and an int8 pool's two scale pools
+                   streams=4 if isinstance(paged0[0], _pc.QuantKV) else 2)
         if len(paged0) == 2 and pool.ndim == 3:
             # a flat pool: its rows hold every kv head; the kernel walks
             # them a lane tile (one head, or two of 64 lanes) at a time
             d = int(getattr(model.config, "head_dim", 0)
                     or model.config.hidden_size // int(heads))
-            geo.update(num_kv_heads=int(pool.shape[2])
-                       // _pa.flat_pool_tile(d))
+            lanes = _pa.flat_pool_tile(d)
+            geo.update(num_kv_heads=int(pool.shape[2]) // lanes,
+                       head_lanes=lanes)
         if len(paged0) == 1:
             # a latent (MLA) cache: every head reads the layer's one
             # array, in the latent kernel's own tile
-            geo.update(num_kv_heads=1, tile=_pa.LATENT_TILE)
+            geo.update(num_kv_heads=1, tile=_pa.LATENT_TILE, streams=1)
         return geo
 
     def _attn_grid(self, q_lens, context_lens):
-        """The ``tick`` span's ``attn_units`` / ``attn_live`` of one
-        layer's ragged attention call this tick (docs/OPS.md "Tick
-        phases"), or nothing where no span would carry them: numpy on
-        ``num_slots`` entries."""
+        """The ``tick`` span's ``attn_units`` / ``attn_live`` /
+        ``attn_copies`` of one layer's ragged attention call this tick
+        (docs/OPS.md "Tick phases"), or nothing where no span would
+        carry them: numpy on ``num_slots`` entries."""
         if self._trace is None or self._attn_geometry is None:
             return {}
-        units, live = _pa.ragged_grid_units(q_lens, context_lens,
-                                            **self._attn_geometry)
-        return {"attn_units": units, "attn_live": live}
+        units, live, copies = _pa.ragged_grid_units(
+            q_lens, context_lens, **self._attn_geometry)
+        return {"attn_units": units, "attn_live": live,
+                "attn_copies": copies}
 
     def _launch_ragged(self, args):
         """Run THE tick executable: ``(outs, share counts or None)``.

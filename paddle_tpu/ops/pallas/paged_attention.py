@@ -60,8 +60,12 @@ tick holds, not what it could hold:
   rows never reach a live packed row; rows no slot owns come back
   zero. The gathers stand OUTSIDE ``kernel_scope``: the scope (and so
   the kernel's roofline share) holds the ``pallas_call`` alone.
-- **the grid** is ``(query tile, kv_head)``, and the scalar-prefetched
-  tile list rides in SMEM. A step holds one tile for one kv head — the
+- **the grid** is ``(query tile, head group)``, and the
+  scalar-prefetched tile list rides in SMEM. A step holds one tile for
+  EVERY kv head of its slot while the pool row's lanes fit
+  ``_GROUP_LANES`` (``_head_group``: 4 heads of 128 lanes, or 8 of 64
+  in pairs, are one group; a wider row walks ``H_kv / group`` steps a
+  tile; a TP shard's one local head is a group of one) — per head the
   ``[tq * rp, D]`` layout of the verify kernel, row ``r`` being window
   token ``row0 + (r >> row_shift)`` with the causal bound ``lens +
   that token`` — against kv tiles of ``kb`` pool blocks = 128 cache
@@ -70,16 +74,25 @@ tick holds, not what it could hold:
   loop of exactly ``ceil((lens + last live row of the tile) / 128)``
   iterations chases ``block_tables[slot]`` with double-buffered async
   copies (tile ``j + 1`` in flight while tile ``j`` is multiplied),
-  each iteration one online-softmax update. A tile past the live count
-  loops zero times and stores zeros. At the default serving shape (8
-  slots, 136 rows, 4 kv heads, a 64-block table) that is 100 grid
-  steps of at most 8 iterations, where the ``(slot, window_row,
-  kv_head, block)`` grid this replaced walked 262,144 steps whatever
-  was live.
+  each iteration one online-softmax update a head. A pool block leaves
+  HBM ONCE a stream an iteration, all the group's heads side by side —
+  where the group is the whole row, one contiguous ``[BS, H_kv * D]``
+  copy (16 KB at both benchmark families), not a strided 128-lane
+  slice a head — and one wait a stream covers the iteration's ``kb``
+  copies; the heads then run as a static loop over the copied
+  buffer's lane tiles, each with its own f32 softmax state, under one
+  mask. A tile past the live count loops zero times and stores zeros.
+  At the default serving shape (8 slots, 136 rows, 4 kv heads, a
+  64-block table) that is 25 grid steps of at most 8 iterations (176
+  at the wide cell's 128 slots and 384 rows), where the ``(slot,
+  window_row, kv_head, block)`` grid this replaced walked 262,144
+  steps whatever was live.
 
 ``ragged_grid_units`` counts the same index space on the host from the
 same rule, for the engine's ``tick`` span (``attn_units`` /
-``attn_live``). The XLA fallback scatters the packed rows into the
+``attn_live``, in (query tile, kv head, kv tile) units whatever the
+group, and ``attn_copies``, the copy descriptors issued). The XLA
+fallback scatters the packed rows into the
 per-slot padded ``[S, W, H, D]`` layout and calls the SAME
 ``_xla_paged_verify`` einsum, so every row is bitwise the per-width
 fallback's output (test-pinned).
@@ -87,8 +100,9 @@ fallback's output (test-pinned).
 QUANTIZED POOLS (``paged_cache.QuantKV`` — int8 data + per-(block,
 position, head) f32 absmax scales): all three kernel variants take
 the scale pools as two extra block-chased operands (the ragged
-kernel's copies fetch them beside the data, their head axis padded to
-a lane tile) and dequantize each K/V tile in VMEM right after its DMA
+kernel's copies fetch them beside the data, a block's scales once for
+all the heads of its group, their head axis padded to a lane tile)
+and dequantize each K/V tile in VMEM right after its DMA
 (int8 -> f32 * scale, kept f32 through the dots — accuracy over MXU
 rate on a bandwidth-bound op), so the HBM stream per decode step
 halves while the softmax math is unchanged. The gather fallbacks read the SAME stored
@@ -370,6 +384,12 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
 _TILE_ROWS = 128
 _TILE_POSITIONS = 128
 
+# lanes of a pool row one ragged grid step copies and holds: the kv
+# heads of one head group (``_head_group``). 512 lanes (4 heads of 128,
+# or 8 of 64 in pairs) keep the step's K/V halves, q/out blocks and
+# softmax state near 2 MB of VMEM and its body at four unrolled heads
+_GROUP_LANES = 512
+
 
 # the latent (MLA) kernel's tile: every head reads the one latent, so a
 # window token brings all its heads (64 rows at the published widths);
@@ -426,18 +446,36 @@ def _ragged_tiles(xp, ql, context_lens, tq, kv_span, n_tiles, n_kv):
     return slot, row0, rows, kv
 
 
+def _head_group(num_kv_heads, head_lanes) -> int:
+    """kv heads (lane tiles of ``head_lanes``) one ragged grid step
+    takes: every one of the pool row while the group stays within
+    ``_GROUP_LANES`` — a pool block then leaves HBM as ONE contiguous
+    copy — else the largest divisor of ``num_kv_heads`` that does, and
+    the step's query tile is walked once a group."""
+    hg = max(1, min(num_kv_heads, _GROUP_LANES // head_lanes))
+    while num_kv_heads % hg:
+        hg -= 1
+    return hg
+
+
 def ragged_grid_units(q_lens, context_lens, *, rows, w_max, num_heads,
                       num_kv_heads, q_dtype, block_size, max_blocks,
-                      tile=(_TILE_ROWS, _TILE_POSITIONS)):
+                      tile=(_TILE_ROWS, _TILE_POSITIONS), head_lanes=128,
+                      streams=2):
     """Host-side count of what ONE ``pallas_ragged_paged_attention``
-    call (or, with ``num_kv_heads=1`` and ``tile=LATENT_TILE``, one
-    ``pallas_ragged_latent_attention`` call) visits for this tick's
-    ``q_lens`` / ``context_lens`` (numpy, no device read):
-    ``(units, live)`` in (query tile, kv head, kv tile) units. A live tile's grid step runs one loop iteration per
-    kv tile of its walk, all live; a tile past the live count is still
-    one launched grid step, predicated off — so ``units - live`` is
-    the dead steps and ``live / units`` the share of the walk that is
-    work."""
+    call (or, with ``num_kv_heads=1``, ``streams=1`` and
+    ``tile=LATENT_TILE``, one ``pallas_ragged_latent_attention`` call)
+    visits for this tick's ``q_lens`` / ``context_lens`` (numpy, no
+    device read): ``(units, live, copies)``. ``units`` and ``live`` are
+    in (query tile, kv head, kv tile) units: a live tile walks one loop
+    iteration per kv tile of its reach for each of its kv heads, all
+    live; a tile past the live count is still launched, predicated off
+    — so ``units - live`` is the dead steps and ``live / units`` the
+    share of the walk that is work. ``copies`` is the async-copy
+    descriptors the call issues: per live (query tile, kv tile) one a
+    pool block (``kb``) a stream (K and V; an int8 pool's two scale
+    pools make ``streams`` 4) a head GROUP (``_head_group`` of
+    ``head_lanes``-wide kv heads) — not one a kv head."""
     ql = np.minimum(np.asarray(q_lens, np.int64), w_max)
     _, tq, kb, n_tiles, n_kv = _ragged_geometry(
         rows, ql.shape[0], num_heads // num_kv_heads, q_dtype,
@@ -445,28 +483,38 @@ def ragged_grid_units(q_lens, context_lens, *, rows, w_max, num_heads,
     _, _, _, kv = _ragged_tiles(
         np, ql, np.asarray(context_lens, np.int64), tq,
         kb * block_size, n_tiles, n_kv)
-    live = int(kv.sum()) * num_kv_heads
-    return live + int((kv == 0).sum()) * num_kv_heads, live
+    walked = int(kv.sum())
+    live = walked * num_kv_heads
+    groups = num_kv_heads // _head_group(num_kv_heads, head_lanes)
+    return (live + int((kv == 0).sum()) * num_kv_heads, live,
+            walked * kb * streams * groups)
 
 
 def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tables_ref, lens_ref,
                    *args, scale, block_size, kv_blocks, max_blocks,
-                   head_dim, row_shift, quantized=False, tree_bits=None):
-    """Ragged mixed-batch body: grid ``(query tile, kv_head)``. A step
-    holds ``tq`` consecutive window rows of ONE slot for one kv head
-    (``[tq * rp, D]``, row ``r`` = window token ``trow_ref[t] +
-    (r >> row_shift)``, causal bound ``lens + that token``) and walks
-    the slot's cache in tiles of ``kv_blocks`` pool blocks, chased
-    through ``tables_ref[slot]`` by double-buffered async copies out of
-    the HBM pools, for exactly ``tkv_ref[t]`` iterations — the tile's
-    own live reach, 0 for a tile past the live count (which stores
-    zeros). ``tree_bits`` (static ancestor bitmasks) adds a SIXTH
+                   head_dim, heads, row_shift, quantized=False,
+                   tree_bits=None):
+    """Ragged mixed-batch body: grid ``(query tile, head group)``. A
+    step holds ``tq`` consecutive window rows of ONE slot for the
+    ``heads`` kv heads of its group (``[heads, tq * rp, D]``, row ``r``
+    = window token ``trow_ref[t] + (r >> row_shift)``, causal bound
+    ``lens + that token``) and walks the slot's cache in tiles of
+    ``kv_blocks`` pool blocks, chased through ``tables_ref[slot]`` by
+    double-buffered async copies out of the HBM pools, for exactly
+    ``tkv_ref[t]`` iterations — the tile's own live reach, 0 for a tile
+    past the live count (which stores zeros). A pool block is copied
+    ONCE a stream a kv tile, all the group's heads side by side (where
+    the group is the pool's whole row, one contiguous block); the
+    heads then run as a static loop over the lane tiles of the copied
+    buffer — ``heads`` independent online-softmax chains an iteration,
+    each with its own f32 state, one mask. ``tree_bits`` (static ancestor bitmasks) adds a SIXTH
     scalar-prefetch operand ``tree_ref`` [S]: slots flagged ``> 0``
     mask their first ``len(tree_bits)`` window rows by ancestor path
     instead of the linear bound — unflagged slots (prefill chunks and
     their narrow trickle rows) keep the linear mask untouched.
-    ``quantized``: the scale pools ride the same copies and each K/V
-    tile dequantizes in VMEM as in ``_decode_kernel``."""
+    ``quantized``: the scale pools ride the same copies (a block's
+    scales once for all its heads) and each K/V tile dequantizes in
+    VMEM as in ``_decode_kernel``."""
     if tree_bits is not None:
         tree_ref, *args = args
     if quantized:
@@ -479,30 +527,38 @@ def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tables_ref, lens_ref,
          m_scr, l_scr, acc_scr) = args
         streams = ((k_hbm, k_buf, True), (v_hbm, v_buf, True))
     t = pl.program_id(0)
-    g = pl.program_id(1)
+    g0 = pl.program_id(1) * heads             # the group's first kv head
     slot = tslot_ref[t]
     row0 = trow_ref[t]
     n_kv = tkv_ref[t]
     lens = lens_ref[slot]
-    bs, kb = block_size, kv_blocks
+    bs, kb, d = block_size, kv_blocks, head_dim
+    whole_rows = k_hbm.shape[2] == heads * d
 
-    def copies(j, buf):
-        """The async copies that bring kv tile ``j`` into buffer half
-        ``buf``: per pool block one ``[BS, D]`` tile of kv head ``g``
-        (and, quantized, its ``[BS, H_kv]`` scale block). Blocks past
-        the table's end re-read its last entry; their columns lie past
-        every row's bound."""
-        out = []
+    def start(j, buf):
+        """Start the async copies that bring kv tile ``j`` into buffer
+        half ``buf``: per pool block the ``[BS, heads * D]`` lanes of
+        the group (and, quantized, its ``[BS, H_kv]`` scale block).
+        Blocks past the table's end re-read its last entry; their
+        columns lie past every row's bound."""
         for i in range(kb):
             blk = tables_ref[slot, jnp.minimum(j * kb + i,
                                                max_blocks - 1)]
             for n, (hbm, vmem, per_head) in enumerate(streams):
-                src = (hbm.at[blk, :, pl.ds(g * head_dim, head_dim)]
-                       if per_head else hbm.at[blk])
-                out.append(pltpu.make_async_copy(
+                src = (hbm.at[blk, :, pl.ds(g0 * d, heads * d)]
+                       if per_head and not whole_rows else hbm.at[blk])
+                pltpu.make_async_copy(
                     src, vmem.at[buf, pl.ds(i * bs, bs), :],
-                    sems.at[n, buf]))
-        return out
+                    sems.at[n, buf]).start()
+
+    def wait(buf):
+        """Wait for buffer half ``buf``'s copies: a wait takes the
+        semaphore and the destination's size alone, so one descriptor
+        a stream the size of the whole half stands for its ``kb``
+        block copies, and no table entry is read again."""
+        for n, (_, vmem, _) in enumerate(streams):
+            pltpu.make_async_copy(vmem.at[buf], vmem.at[buf],
+                                  sems.at[n, buf]).wait()
 
     m_scr[:] = jnp.full_like(m_scr, NEG_INF)
     l_scr[:] = jnp.zeros_like(l_scr)
@@ -510,35 +566,22 @@ def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tables_ref, lens_ref,
 
     @pl.when(n_kv > 0)
     def _first():
-        for c in copies(0, 0):
-            c.start()
+        start(0, 0)
 
     def walk(j, carry):
         buf = jax.lax.rem(j, 2)
 
         @pl.when(j + 1 < n_kv)
         def _next():
-            for c in copies(j + 1, 1 - buf):
-                c.start()
+            start(j + 1, 1 - buf)
 
-        for c in copies(j, buf):
-            c.wait()
-        q = q_ref[0, 0]                       # [tq * rp, D]
-        if quantized:
-            q = q.astype(jnp.float32)         # match the f32 dequant
-            k = _dequant_rows(k_buf[buf], ks_buf[buf], g)
-            v = _dequant_rows(v_buf[buf], vs_buf[buf], g)
-        else:
-            k = k_buf[buf]                    # [kb * BS, D]
-            v = v_buf[buf]
-        sc = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        wait(buf)
+        shape = (q_ref.shape[2], kb * bs)
         cols = j * (kb * bs) + jax.lax.broadcasted_iota(
-            jnp.int32, sc.shape, 1)
+            jnp.int32, shape, 1)
         # row r is window token row0 + (r >> row_shift)
         node = row0 + (jax.lax.broadcasted_iota(
-            jnp.int32, sc.shape, 0) >> row_shift)
+            jnp.int32, shape, 0) >> row_shift)
         linear = cols < lens + node
         if tree_bits is None:
             keep = linear
@@ -548,7 +591,7 @@ def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tables_ref, lens_ref,
             # keeps it iff it is on t's ancestor path. Every tree
             # column satisfies the linear bound, so the walk's end
             # (the last live row's linear reach) stays a superset.
-            bits = jnp.zeros(sc.shape, jnp.int32)
+            bits = jnp.zeros(shape, jnp.int32)
             for i, b in enumerate(tree_bits):
                 bits = jnp.where(node == i, np.int32(b), bits)
             rel = cols - lens
@@ -556,29 +599,45 @@ def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tables_ref, lens_ref,
                 ((bits >> jnp.clip(rel, 0, 31)) & 1) > 0)
             # (boolean algebra, not a select between masks: Mosaic
             # cannot legalize arith.select on i1 vectors)
-            is_tree = (jnp.full(sc.shape, tree_ref[slot]) > 0) & (
+            is_tree = (jnp.full(shape, tree_ref[slot]) > 0) & (
                 node < len(tree_bits))
             keep = (is_tree & ok_tree) | (
                 jnp.logical_not(is_tree) & linear)
-        sc = jnp.where(keep, sc, NEG_INF)
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        p = jnp.exp(sc - m_cur)
-        alpha = jnp.exp(m_prev - m_cur)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_scr[:] = alpha * acc_scr[:] + pv
-        m_scr[:] = jnp.broadcast_to(m_cur, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        for h in range(heads):
+            lanes = pl.ds(h * d, d)
+            q = q_ref[0, h]                   # [tq * rp, D]
+            if quantized:
+                q = q.astype(jnp.float32)     # match the f32 dequant
+                k = _dequant_rows(k_buf[buf, :, lanes], ks_buf[buf],
+                                  g0 + h)
+                v = _dequant_rows(v_buf[buf, :, lanes], vs_buf[buf],
+                                  g0 + h)
+            else:
+                k = k_buf[buf, :, lanes]      # [kb * BS, D]
+                v = v_buf[buf, :, lanes]
+            sc = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            sc = jnp.where(keep, sc, NEG_INF)
+            m_prev = m_scr[h, :, :1]
+            l_prev = l_scr[h, :, :1]
+            m_cur = jnp.maximum(m_prev,
+                                jnp.max(sc, axis=-1, keepdims=True))
+            p = jnp.exp(sc - m_cur)
+            alpha = jnp.exp(m_prev - m_cur)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc_scr[h] = alpha * acc_scr[h] + pv
+            m_scr[h] = jnp.broadcast_to(m_cur, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
         return carry
 
     jax.lax.fori_loop(0, n_kv, walk, 0)
-    l = l_scr[:, :1]
+    l = l_scr[:, :, :1]
     safe_l = jnp.where(l == 0.0, np.float32(1.0), l)
-    o_ref[0, 0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
+    o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
 
 
 def flat_pool_tile(head_dim) -> int:
@@ -630,12 +689,12 @@ def _unpack_pools(k_pool, v_pool):
     return _pool_view(k_pool), _pool_view(v_pool), [], False
 
 
-def _softmax_scratch(rows, d):
+def _softmax_scratch(rows, d, lead=()):
     """Online-softmax state (running max, denominator, weighted
-    values) for ``rows`` query rows."""
-    return [pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.float32)]
+    values) for ``rows`` query rows (``lead``: a leading head axis)."""
+    return [pltpu.VMEM((*lead, rows, 128), jnp.float32),
+            pltpu.VMEM((*lead, rows, 128), jnp.float32),
+            pltpu.VMEM((*lead, rows, d), jnp.float32)]
 
 
 def pallas_paged_attention(q, k_pool, v_pool, block_tables,
@@ -797,10 +856,12 @@ def pallas_ragged_paged_attention(q, k_pool, v_pool, block_tables,
     # anyway — a minor dim under 128 lanes is stored padded to them)
     scales = [jnp.pad(sc, ((0, 0), (0, 0), (0, -hkv % 128)))
               for sc in scales]
+    hg = _head_group(hkv, d)
     kernel = functools.partial(
         _ragged_kernel, scale=scale, block_size=bs, kv_blocks=kb,
-        max_blocks=mb, head_dim=d, row_shift=rp.bit_length() - 1,
-        quantized=quant, tree_bits=tree_bits)
+        max_blocks=mb, head_dim=d, heads=hg,
+        row_shift=rp.bit_length() - 1, quantized=quant,
+        tree_bits=tree_bits)
 
     def q_block(t, g, *prefetch):
         return (t, g, 0, 0)
@@ -809,23 +870,23 @@ def pallas_ragged_paged_attention(q, k_pool, v_pool, block_tables,
     n_streams = 2 + len(scales)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5 + len(tree_args),
-        grid=(n_tiles, hkv),
-        in_specs=[pl.BlockSpec((1, 1, rows, d), q_block)]
+        grid=(n_tiles, hkv // hg),
+        in_specs=[pl.BlockSpec((1, hg, rows, d), q_block)]
         + [hbm] * n_streams,
-        out_specs=pl.BlockSpec((1, 1, rows, d), q_block),
-        scratch_shapes=[pltpu.VMEM((2, kb * bs, d), kd.dtype)] * 2
+        out_specs=pl.BlockSpec((1, hg, rows, d), q_block),
+        scratch_shapes=[pltpu.VMEM((2, kb * bs, hg * d), kd.dtype)] * 2
         + [pltpu.VMEM((2, kb * bs, sc.shape[2]), jnp.float32)
            for sc in scales]
         + [pltpu.SemaphoreType.DMA((n_streams, 2))]
-        + _softmax_scratch(rows, d),
+        + _softmax_scratch(rows, d, (hg,)),
     )
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_tiles, hkv, rows, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            # no state crosses a step: every (tile, kv head) starts and
-            # waits its own copies and writes its own output block
+            # no state crosses a step: every (tile, head group) starts
+            # and waits its own copies and writes its own output block
             dimension_semantics=("parallel", "parallel")),
         interpret=_interpret() if interpret is None else interpret,
     )

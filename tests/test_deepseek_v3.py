@@ -208,8 +208,10 @@ def test_latent_grid_units_follow_the_kernels_tiles():
     q_lens = np.zeros(32, np.int64)
     ctx = np.zeros(32, np.int64)
     q_lens[:3], ctx[:3] = (1, 512, 1), (1000, 257, 4096)
-    units, live = pa.ragged_grid_units(q_lens, ctx, **geo)
+    units, live, copies = pa.ragged_grid_units(q_lens, ctx, streams=1,
+                                               **geo)
     tq, span = pa.LATENT_TILE[0] // 64, pa.LATENT_TILE[1]
+    assert copies == live * (span // 16)    # a whole block, one array
     chunk = sum(-(-(257 + r0 + tq - 1) // span)
                 for r0 in range(0, 512, tq))
     assert live == -(-1000 // span) + chunk + 4096 // span

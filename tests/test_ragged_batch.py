@@ -220,6 +220,33 @@ _KERNEL_MIXES = {
 }
 
 
+def _block_tables(q_lens, base, MB, NB, BS):
+    """``[S, MB]`` tables with each slot's blocks allocated up to its
+    length after the tick, null past the allocation."""
+    from paddle_tpu.ops import paged_cache as pc
+    tables = np.zeros((len(q_lens), MB), np.int32)
+    alloc = pc.BlockAllocator(NB)
+    for s in range(len(q_lens)):
+        n = pc.blocks_for(int(base[s] + q_lens[s]), BS)
+        if n:
+            tables[s, :n] = alloc.alloc(n)
+    return tables
+
+
+def _assert_live_rows_match(out, ref, q_lens, row_starts, tol):
+    """Every live row of ``out`` agrees with ``ref``; every packed row
+    no slot owns is zero."""
+    owned = np.zeros(len(out), bool)
+    for s, n in enumerate(map(int, q_lens)):
+        s0 = int(row_starts[s])
+        owned[s0:s0 + n] = True
+        np.testing.assert_allclose(
+            out[s0:s0 + n], ref[s0:s0 + n], rtol=tol, atol=tol,
+            err_msg=f"slot {s} rows diverged")
+    assert np.isfinite(out).all()
+    assert not out[~owned].any(), "a row past a slot's q_lens is written"
+
+
 @pytest.mark.parametrize("mix", list(_KERNEL_MIXES))
 def test_ragged_kernel_matches_fallback_interpret(mix):
     """The ragged Pallas kernel (interpret mode on CPU) agrees with the
@@ -241,12 +268,7 @@ def test_ragged_kernel_matches_fallback_interpret(mix):
         return pc.QuantKV(*pc.kv_quantize(x)) if quant else x
 
     kp, vp = pool(), pool()
-    tables = np.zeros((S, MB), np.int32)    # null past the allocation
-    alloc = pc.BlockAllocator(NB)
-    for s in range(S):
-        n = pc.blocks_for(int(base[s] + q_lens[s]), BS)
-        if n:
-            tables[s, :n] = alloc.alloc(n)
+    tables = _block_tables(q_lens, base, MB, NB, BS)
     row_slot, _, row_starts, _ = pc.ragged_row_meta(q_lens, base, R,
                                                     MB * BS)
     q = jnp.asarray(rng.randn(R, H, D), jnp.float32)
@@ -258,15 +280,8 @@ def test_ragged_kernel_matches_fallback_interpret(mix):
     ref = pa._xla_ragged_paged(*args, jnp.asarray(row_slot), wn, W, **kw)
     out = np.asarray(pa.pallas_ragged_paged_attention(
         *args, w_max=W, interpret=True, **kw))
-    owned = np.zeros(R, bool)
-    for s, n in enumerate(map(int, q_lens)):
-        s0 = int(row_starts[s])
-        owned[s0:s0 + n] = True
-        np.testing.assert_allclose(
-            out[s0:s0 + n], np.asarray(ref[s0:s0 + n]),
-            rtol=1e-5, atol=1e-5, err_msg=f"slot {s} rows diverged")
-    assert np.isfinite(out).all()
-    assert not out[~owned].any(), "a row past a slot's q_lens is written"
+    _assert_live_rows_match(out, np.asarray(ref), q_lens, row_starts,
+                            1e-5)
 
 
 def test_ragged_kernel_mixed_batch_interpret():
@@ -288,6 +303,62 @@ def test_ragged_kernel_mixed_batch_interpret():
             err_msg=f"slot {s} rows diverged")
 
 
+# One mix for the head-group cases: a decode row, a 3-row verify window
+# (tree-flagged where the case masks by tree), a dead slot, a 20-row
+# chunk, a decode row whose context ends on a kv tile's edge, a dead slot
+_GROUP_MIX = ([1, 3, 0, 20, 1, 0], [5, 130, 0, 24, 127, 77],
+              [0, 1, 0, 0, 0, 0])
+
+
+@pytest.mark.parametrize("pool", ["bf16_4d", "flat_d64", "int8", "tree"])
+@pytest.mark.parametrize("tiles,hg", [(1, 1), (2, 2), (4, 4), (8, 4)],
+                         ids=["hg1", "hg2", "hg4", "hg4_two_groups"])
+def test_ragged_kernel_head_groups_match_fallback_interpret(pool, tiles,
+                                                            hg):
+    """A grid step takes ``hg`` kv heads (128-lane tiles of the pool
+    row) — one (the TP shard's kernel), all of a narrow row, or a
+    capped group of a row wider than ``_GROUP_LANES`` — and every live
+    row agrees with the gather fallback whatever the pool: 4-D bf16,
+    flat with head size 64 in pairs, int8 with its scale pools, f32
+    under the tree mask."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import paged_cache as pc
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    assert pa._head_group(tiles, 128) == hg
+    q_lens, base, tree = (np.asarray(a, np.int64) for a in _GROUP_MIX)
+    flat, quant = pool == "flat_d64", pool == "int8"
+    dt = jnp.float32 if pool in ("int8", "tree") else jnp.bfloat16
+    D = 64 if flat else 128
+    Hkv = tiles * 128 // D
+    S, H, BS, W, wn = len(q_lens), 2 * Hkv, 32 if quant else 16, 24, 3
+    MB = 192 // BS
+    R, NB = S * wn + W, 1 + S * MB
+    rng = np.random.RandomState(3)
+
+    def make():
+        x = jnp.asarray(rng.randn(NB, BS, Hkv, D), jnp.float32)
+        return pc.QuantKV(*pc.kv_quantize(x)) if quant else x.astype(dt)
+
+    kp, vp = make(), make()
+    tables = _block_tables(q_lens, base, MB, NB, BS)
+    row_slot, _, row_starts, _ = pc.ragged_row_meta(q_lens, base, R,
+                                                    MB * BS)
+    q = jnp.asarray(rng.randn(R, H, D), dt)
+    rest = (jnp.asarray(tables), jnp.asarray(base + 1),
+            jnp.asarray(q_lens), jnp.asarray(row_starts))
+    kw = {}
+    if pool == "tree":
+        kw = dict(tree_anc=(0, 0), tree_slots=jnp.asarray(tree))
+    ref = np.asarray(pa._xla_ragged_paged(
+        q, kp, vp, *rest, jnp.asarray(row_slot), wn, W, **kw), np.float32)
+    if flat:
+        kp, vp = (x.reshape(NB, BS, Hkv * D) for x in (kp, vp))
+    out = np.asarray(pa.pallas_ragged_paged_attention(
+        q, kp, vp, *rest, w_max=W, interpret=True, **kw), np.float32)
+    _assert_live_rows_match(out, ref, q_lens, row_starts,
+                            1e-5 if dt == jnp.float32 else 2e-2)
+
+
 def _direct_grid_count(q_lens, ctx, tq, span, heads, n_tiles):
     """(units, live) by walking slots, tiles and kv tiles one at a
     time: what ``ragged_grid_units`` must equal."""
@@ -298,6 +369,39 @@ def _direct_grid_count(q_lens, ctx, tq, span, heads, n_tiles):
             live += -(-(int(c) + last) // span)     # sees c + last cols
             tiles += 1
     return (live + (n_tiles - tiles)) * heads, live * heads
+
+
+@pytest.mark.parametrize("hkv,rep,lanes,bs,streams,groups", [
+    (4, 7, 128, 16, 2, 1),  # the Qwen cells: a 4-D bf16 pool
+    (4, 8, 128, 16, 2, 1),  # the wide cell: 8 heads of 64 in 4 pairs
+    (4, 7, 128, 32, 4, 1),  # an int8 pool: the scale pools ride along
+    (1, 7, 128, 16, 2, 1),  # a TP shard's one local kv head
+    (8, 7, 128, 16, 2, 2),  # a row wider than the lane budget
+    (2, 7, 256, 16, 2, 1),  # head size 256: two heads fill the budget
+], ids=["bf16_4d", "flat_d64_pairs", "int8", "tp_shard", "two_groups",
+        "d256"])
+def test_grid_units_keep_their_rule_and_count_the_copies(
+        hkv, rep, lanes, bs, streams, groups):
+    """``ragged_grid_units`` on a fixed tick (decode rows, a 40-row
+    chunk, dead slots): ``(units, live)`` stay the (query tile, kv
+    head, kv tile) count the kernel had with one head a grid step — a
+    slot-by-slot walk — and ``copies`` is a descriptor a pool block a
+    stream a HEAD GROUP of every live (query tile, kv tile)."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    import jax.numpy as jnp
+    q_lens = [1, 1, 0, 40, 1, 0, 3, 1]
+    ctx = [6, 128, 0, 25, 129, 78, 500, 1024]
+    geo = dict(rows=136, w_max=128, num_heads=hkv * rep, num_kv_heads=hkv,
+               q_dtype=jnp.bfloat16, block_size=bs, max_blocks=1024 // bs)
+    tq, span, n_tiles = 8, 128, 8 + 136 // 8
+    units, live, copies = pa.ragged_grid_units(
+        q_lens, ctx, head_lanes=lanes, streams=streams, **geo)
+    assert (units, live) == _direct_grid_count(q_lens, ctx, tq, span, hkv,
+                                               n_tiles)
+    kv_tiles, kb = live // hkv, span // bs
+    assert copies == kv_tiles * kb * streams * groups
+    # a descriptor a kv head, the kernel before: ``hg`` times as many
+    assert copies * pa._head_group(hkv, lanes) == live * kb * streams
 
 
 def test_tick_span_counts_the_attention_grid(llama_tiny):
@@ -329,6 +433,10 @@ def test_tick_span_counts_the_attention_grid(llama_tiny):
             [1, chunk], [5 + k, 8 * k + 1], tq, span, heads, n_tiles))
     got = [(a["attn_units"], a["attn_live"]) for a in spans]
     assert got[:20] == want
+    # 2 kv heads of 16 lanes are ONE head group: a copy a block (16 a
+    # kv tile) a stream (K, V) of every live (query tile, kv tile)
+    assert [a["attn_copies"] for a in spans[:20]] == [
+        live // heads * 16 * 2 for _, live in want]
     # the chunk's walk grows past one kv tile at position 128
     assert want[15] == (8, 4) and want[17] == (10, 6)
 

@@ -185,13 +185,19 @@ def test_paged_eligibility_is_what_compiles(hkv, d, quant):
 # the default ServingConfig at the Qwen2-7B widths: 8 slots, 8 decode
 # rows + one 128-row chunk, 7 query heads a kv head, a 1024-token table
 _SERVING = dict(s=8, r=136, w=128, rep=7, d=128, reach=1024)
+# chat-wide-sat's (LFM2-24B-A2B): 128 slots, 128 decode rows + one
+# 256-row chunk, 32 query heads over 8 kv heads of 64 lanes side by
+# side in a FLAT pool row (4 paired lane tiles), a 4096-token table
+_WIDE = dict(s=128, r=384, w=256, rep=4, d=64, reach=4096)
 
 
-def _serving_ragged_call(hkv, quant, tree):
-    """``(fn, args)``: one ragged attention call at the serving shape."""
-    c = _SERVING
+def _serving_ragged_call(hkv, quant, tree, c=_SERVING, flat=False):
+    """``(fn, args)``: one ragged attention call at the serving shape
+    ``c`` (``flat``: over a flat ``[NB, BS, H_kv * D]`` pool)."""
     bs = 32 if quant else 16
     pool = _pools(hkv, c["d"], bs, quant)
+    if flat:
+        pool = pool.reshape(*pool.shape[:2], -1)
     tables = jnp.zeros((c["s"], c["reach"] // bs), jnp.int32)
     lens = jnp.ones((c["s"],), jnp.int32)
     q = jnp.zeros((c["r"], hkv * c["rep"], c["d"]), jnp.bfloat16)
@@ -202,26 +208,28 @@ def _serving_ragged_call(hkv, quant, tree):
 
 @pytest.mark.parametrize("tree", [False, True], ids=["linear", "tree"])
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("hkv", [4, 1], ids=["hkv4", "tp4_shard"])
+@pytest.mark.parametrize("hkv", [4, 1, 8],
+                         ids=["hkv4", "tp4_shard", "hkv8_two_groups"])
 def test_ragged_serving_shape_compiles(hkv, quant, tree):
-    """The shape both benchmark cells run in every layer of every tick
-    (and its int8-pool, tree-verify and one-kv-head TP=4 shard
-    variants) compiles to Mosaic: the async copies out of the HBM
-    pools, the dynamic walk and the tile metadata included."""
+    """The shape the Qwen benchmark cells run in every layer of every
+    tick (and its int8-pool, tree-verify, one-kv-head TP=4 shard and
+    wider-than-``_GROUP_LANES`` variants: 8 kv heads walk two head
+    groups a query tile) compiles to Mosaic: the async copies out of
+    the HBM pools, the dynamic walk and the tile metadata included."""
     fn, args = _serving_ragged_call(hkv, quant, tree)
     assert _lowers_to_mosaic(fn, *args)
 
 
-def test_ragged_launch_stays_far_below_the_old_grid():
-    """One call at the default config launches ``(query tile, kv
-    head)`` grid steps of at most ``n_kv`` loop iterations each: under
-    1/64 of the ``slot x window_row x kv_head x block`` walk (262,144
-    steps) this kernel replaced, so that walk cannot grow back
-    unnoticed."""
-    c = _SERVING
-    hkv, bs = 4, 16
-    fn, args = _serving_ragged_call(hkv, False, False)
+@pytest.mark.parametrize("tree", [False, True], ids=["linear", "tree"])
+def test_ragged_wide_cell_shape_compiles(tree):
+    """chat-wide-sat's call: head size 64 in pairs over a flat pool
+    ``[NB, 16, 512]``, 128 slots, 384 rows — one head group of 4 lane
+    tiles, a pool block one contiguous copy."""
+    fn, args = _serving_ragged_call(8, False, tree, _WIDE, flat=True)
+    assert _lowers_to_mosaic(fn, *args)
 
+
+def _launched_grid(fn, args):
     def pallas_calls(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
@@ -230,13 +238,39 @@ def test_ragged_launch_stays_far_below_the_old_grid():
                 yield from pallas_calls(sub)
 
     (call,) = pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)
-    grid = tuple(call.params["grid_mapping"].grid)
+    return tuple(call.params["grid_mapping"].grid)
+
+
+@pytest.mark.parametrize("hkv,groups", [(4, 1), (1, 1), (8, 2), (32, 8)])
+def test_ragged_launch_stays_far_below_the_old_grid(hkv, groups):
+    """One call at the default config launches ``(query tile, head
+    group)`` grid steps — a step takes every kv head of its slot's
+    tile while they fit ``_GROUP_LANES``, so ``hkv`` heads are
+    ``groups`` steps a tile — of at most ``n_kv`` loop iterations
+    each: under 1/64 of the ``slot x window_row x kv_head x block``
+    walk (262,144 steps at 4 kv heads) this kernel replaced, so that
+    walk cannot grow back unnoticed."""
+    c = _SERVING
+    bs = 16
+    grid = _launched_grid(*_serving_ragged_call(hkv, False, False))
     mb = c["reach"] // bs
     _, tq, kb, n_tiles, n_kv = pa._ragged_geometry(
         c["r"], c["s"], c["rep"], jnp.bfloat16, bs, mb)
-    assert grid == (n_tiles, hkv) == (c["s"] + -(-c["r"] // tq), hkv)
+    assert pa._head_group(hkv, c["d"]) == hkv // groups
+    assert grid == (n_tiles, groups) == (c["s"] + -(-c["r"] // tq),
+                                         groups)
     assert (tq, kb * bs, n_kv) == (8, 128, 8)
     assert int(np.prod(grid)) * n_kv < c["s"] * c["w"] * hkv * mb // 64
+
+
+def test_ragged_wide_cell_launches_one_step_a_query_tile():
+    """chat-wide-sat's call is 176 grid steps (128 slots + 384 / 8
+    query tiles, one head group of the 4 paired lane tiles), where a
+    step a kv head made 704."""
+    c = _WIDE
+    grid = _launched_grid(*_serving_ragged_call(8, False, False, c,
+                                                flat=True))
+    assert grid == (c["s"] + c["r"] // 8, 1) == (176, 1)
 
 
 def test_paged_eligibility_wants_whole_sublane_tiles():
