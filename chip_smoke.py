@@ -70,6 +70,9 @@ EXPECT = {
     # (a model with slot state beside the paged KV, head size 64)
     "serve.slot_state.ragged_paged_attention": (
         "mosaic", ("ragged_paged_attention",)),
+    # (a model whose slot state is a matrix a head, advanced by a scan)
+    "serve.scan_state.delta_rule": (
+        "mosaic", ("kda_recurrent", "kda_chunk", "ragged_paged_attention")),
     # -- the train step (TrainStep, seq 2048) ------------------------------
     "train.flash_attention": ("mosaic", _FLASH),
     # -- tensor-parallel serving (four-chip phase) -------------------------
@@ -90,6 +93,8 @@ EXPECT = {
     "kernel.ragged_paged.int8": ("mosaic", ("ragged_paged_attention",)),
     "kernel.ragged_paged.d64": ("mosaic", ("ragged_paged_attention",)),
     "kernel.ragged_latent.bf16": ("mosaic", ("ragged_latent_attention",)),
+    "kernel.kda_recurrent": ("mosaic", ("kda_recurrent",)),
+    "kernel.kda_chunk": ("mosaic", ("kda_chunk",)),
     "kernel.norm_matmul.qkv_bias": ("mosaic", ("fused_norm_matmul",)),
     "kernel.norm_matmul.gate_up": ("mosaic", ("fused_norm_matmul",)),
     "kernel.matmul_residual.o_proj": ("mosaic", ("fused_matmul_residual",)),
@@ -145,6 +150,19 @@ SLOT_STATE = dict(
     widths=dict(vocab=512, hidden=256, heads=4, kv_heads=2, dense_ffn=512,
                 moe_ffn=128, experts=8, topk=2),
     engine=dict(num_slots=4, max_model_len=128, prefill_chunk=16),
+    n_requests=8, max_new=(3, 6))
+# one small engine of the family whose kda layers keep a matrix state a
+# head (models/solar_open2.py) at the kernels' head size: gqa, kda, kda,
+# kda; 2 kda heads x 128, attention 4 heads x 64 over 2 kv heads, 8
+# experts top-2 all held and a shared one; chunks of 80 rows (a whole
+# sub-chunk of the chunkwise form and a part of one); a pool large enough
+# that an eighth of its bytes holds a few 0.4 MB snapshots of the state
+SCAN_STATE = dict(
+    widths=dict(vocab=512, hidden=256, layers=4, heads=4, kv_heads=2,
+                head_dim=64, kda_heads=2, kda_head_dim=128, moe_ffn=128,
+                experts=8, topk=2),
+    engine=dict(num_slots=4, max_model_len=256, prefill_chunk=80,
+                num_blocks=4096),
     n_requests=8, max_new=(3, 6))
 SERVE_TINY = dict(
     widths=dict(vocab_size=512, hidden_size=256, intermediate_size=512,
@@ -628,6 +646,51 @@ def kernel_cases(full, interpret=None):
         cases += [("kernel.megablox_gmm", megablox_build),
                   ("kernel.megablox_gmm.share", share_build)]
 
+    # -- the delta rule over slot state: one-row seats and a chunk -----------
+    # (the wide cell's tick: 96 seats of 64 heads x 128 x 128 float32,
+    # one of them carrying a 256-row chunk, the others a row each)
+    kd = dict(S=96, H=64, W=256) if full else dict(S=4, H=2, W=80)
+
+    def kda_args(rng):
+        from paddle_tpu.ops import paged_cache as pc_
+        s_, h_, w_, d_ = kd["S"], kd["H"], kd["W"], 128
+        rows = s_ + w_
+        unit = lambda x: x / np.sqrt((x * x).sum(-1, keepdims=True))
+        q = unit(rng.standard_normal((rows, h_, d_))) * d_ ** -0.5
+        k = unit(rng.standard_normal((rows, h_, d_)))
+        v = rng.standard_normal((rows, h_, d_))
+        g = -rng.uniform(1, 16, (1, h_, 1)) \
+            * 10 ** rng.uniform(-3, -1, (rows, h_, d_))
+        beta = rng.uniform(0, 2, (rows, h_))
+        state = jnp.asarray(rng.standard_normal((s_ + 1, h_, d_, d_)),
+                            jnp.float32).at[s_].set(0)
+        # seat 1 carries the chunk (from a held state), seat 2 is idle,
+        # seat 3 starts a request, the others decode
+        q_lens = np.ones(s_, np.int64)
+        q_lens[1], q_lens[2] = w_ - 3, 0
+        base = np.full(s_, 70, np.int64)
+        base[3] = 0
+        sl, pos, rs, _ = pc_.ragged_row_meta(q_lens, base, rows, 10 ** 6)
+        ops = [jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta)]
+        meta = [jnp.asarray(x, jnp.int32) for x in (q_lens, rs, sl, pos)]
+        return (*ops, state, *meta,
+                jnp.arange(1, dtype=jnp.int32),
+                jnp.arange(w_, dtype=jnp.int32))
+
+    def kda_case(kernel, mirror):
+        def run(fn):
+            return lambda q, k, v, g, beta, state, *meta: fn(
+                q, k, v, g, beta, state, meta)
+        return lambda rng: (
+            run(lambda *a: kernel(*a, interpret=interpret)), run(mirror),
+            kda_args(rng))
+
+    from paddle_tpu.ops.pallas import delta_rule as dr
+    cases += [
+        ("kernel.kda_recurrent", kda_case(dr.pallas_kda_recurrent,
+                                          dr._xla_recurrent)),
+        ("kernel.kda_chunk", kda_case(dr.pallas_kda_chunk, dr._xla_chunk))]
+
     # -- the LoRA grouped-matmul route (rank on 128 lanes) -------------------
     n_ad, rank = (9, 128) if full else (3, 128)
 
@@ -910,34 +973,55 @@ def serve_phase(size, clock, on_chip, observe=None,
         del model
         gc.collect()
         _serve_slot_state(clock, on_chip)
+        _serve_scan_state(clock, on_chip)
+
+
+def _serve_stateful(clock, on_chip, size, model, expect, tag, **fields):
+    """An engine over a model with slot state beside the paged KV,
+    through the same waves (chunked prompts, prefix hits cut back to a
+    snapshot, a repeated prompt), checked against the model's plain
+    forward."""
+    import numpy as np
+    from paddle_tpu.inference import ServingConfig
+    model.eval()
+    vocab = model.config.vocab_size
+    sc = ServingConfig(**size["engine"])
+    prompts = _request_mix(np.random.default_rng(SEED), size["n_requests"],
+                           vocab, sc.prefill_chunk, sc.block_size,
+                           size["max_new"])
+    got = _serve_engine(model, vocab, size["engine"], prompts, (expect,),
+                        clock, on_chip, tag)
+    say("serve", model=type(model).__name__,
+        logit_gap_max=_check_against_plain_forward(
+            model, vocab, [("bf16", p, t) for p, t in got]),
+        tolerance=LOGIT_TOL, **fields)
+
+
+def _serve_scan_state(clock, on_chip):
+    """Slot state that is a matrix a head, advanced by the delta rule's
+    two kernels (one-row seats; a chunk through the chunkwise form);
+    the plain forward it is checked against is a scan over tokens."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.solar_open2 import (SolarOpen2Config,
+                                               SolarOpen2ForCausalLM)
+    paddle.seed(SEED)
+    cfg = SolarOpen2Config.tiny(dtype="bfloat16", **SCAN_STATE["widths"])
+    _serve_stateful(clock, on_chip, SCAN_STATE, SolarOpen2ForCausalLM(cfg),
+                    "serve.scan_state.delta_rule", "scan_state",
+                    gqa_layers=cfg.gqa_layers)
 
 
 def _serve_slot_state(clock, on_chip):
-    """An engine over two kinds of state: the conv layers' rows a SLOT
-    beside the attention layers' paged KV, through the same waves
-    (chunked prompts, prefix hits cut back to a snapshot, a repeated
-    prompt), checked against the model's plain forward."""
-    import numpy as np
+    """Two kinds of state: the conv layers' rows a SLOT beside the
+    attention layers' paged KV."""
     import paddle_tpu as paddle
-    from paddle_tpu.inference import ServingConfig
     from paddle_tpu.models.lfm2_moe import (Lfm2MoeConfig,
                                             Lfm2MoeForCausalLM)
-    size = SLOT_STATE
     paddle.seed(SEED)
-    cfg = Lfm2MoeConfig.tiny(dtype="bfloat16", **size["widths"])
-    model = Lfm2MoeForCausalLM(cfg)
-    model.eval()
-    sc = ServingConfig(**size["engine"])
-    prompts = _request_mix(np.random.default_rng(SEED), size["n_requests"],
-                           cfg.vocab_size, sc.prefill_chunk, sc.block_size,
-                           size["max_new"])
-    got = _serve_engine(model, cfg.vocab_size, size["engine"], prompts,
-                        ("serve.slot_state.ragged_paged_attention",),
-                        clock, on_chip, "slot_state")
-    say("serve", model="Lfm2MoeForCausalLM", layer_types=cfg.layer_types,
-        logit_gap_max=_check_against_plain_forward(
-            model, cfg.vocab_size, [("bf16", p, t) for p, t in got]),
-        tolerance=LOGIT_TOL)
+    cfg = Lfm2MoeConfig.tiny(dtype="bfloat16", **SLOT_STATE["widths"])
+    _serve_stateful(clock, on_chip, SLOT_STATE, Lfm2MoeForCausalLM(cfg),
+                    "serve.slot_state.ragged_paged_attention", "slot_state",
+                    layer_types=cfg.layer_types)
 
 
 # ==========================================================================

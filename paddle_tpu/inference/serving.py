@@ -1036,9 +1036,19 @@ class ServingEngine:
         # snapshots of a slot's state where a prefill chunk ended on a
         # block boundary: with the slot until its blocks are published,
         # then here under the boundary block's chain hash, evicted with
-        # the block (or, past the table's size, oldest first)
+        # the block. The table and the slots' unpublished snapshots
+        # together are budgeted in BYTES, an eighth of the KV pool's: a
+        # snapshot may be a few KB (a short convolution's rows) or tens
+        # of MB (a matrix state a head). Short of budget a slot keeps
+        # its deepest boundary only and the table's oldest entry goes
+        # first; a snapshot larger than the budget is never taken
         self._state_snaps = OrderedDict()
-        self._state_snap_cap = max(16, nb * self._bs // self._chunk)
+        self._snap_nbytes = _pc.snapshot_nbytes(self._pools)
+        self._state_snap_budget = _pc.pool_bytes(self._pools) // 8
+        self._n_state_snaps_dropped = 0
+        # what the tick span calls a scanned state's one-row seats and
+        # chunked rows (the model's ``paged_scan_state``), if it has one
+        self._scan_state = getattr(model, "paged_scan_state", None)
         self._snap_exec = None          # export_slot_state
         self._snap_import_exec = None   # import_slot_state
         self._n_state_started = 0       # seats a tick began from zeros
@@ -2358,6 +2368,11 @@ class ServingEngine:
             started = sum(1 for p0 in pend_pos0.values() if p0 == 0)
             self._n_state_started += started
             state_args = {"state_seats": len(active) + len(given)}
+            if self._scan_state:
+                state_args[self._scan_state + "_seats"] = int(
+                    (q_lens == 1).sum())
+                state_args[self._scan_state + "_chunk_rows"] = int(
+                    q_lens[q_lens > 1].sum())
             if self._prefix_on:
                 for i, k in given.items():
                     if (pend_pos0[i] + k) % self._bs == 0:
@@ -2670,6 +2685,9 @@ class ServingEngine:
             "state_seats_started": self._n_state_started,
             "state_snapshots": self._n_state_snaps,
             "state_snapshot_hits": self._n_state_snap_hits,
+            "state_snapshot_bytes":
+                self._snaps_held() * self._snap_nbytes,
+            "state_snapshots_dropped": self._n_state_snaps_dropped,
             "prefix_hit_rate":
                 self._n_prefix_tokens / self._n_prompt_tokens
                 if self._n_prompt_tokens else 0.0,
@@ -4823,15 +4841,40 @@ class ServingEngine:
             self._state_snaps[h] = snap
             self._state_snaps.move_to_end(h)
         slot.state_snaps = {}
-        while len(self._state_snaps) > self._state_snap_cap:
-            self._state_snaps.popitem(last=False)
+
+    def _snaps_held(self):
+        """Snapshots held now: the table's and the live slots'
+        unpublished ones."""
+        return len(self._state_snaps) + sum(
+            len(s.state_snaps) for s in self._slots if s is not None)
+
+    def _room_for_snapshot(self, slot):
+        """Make room in the byte budget for one more snapshot of
+        ``slot``: its own shallower boundaries go first, then the
+        table's oldest entries. False where the budget holds not even
+        one, or other slots' unpublished snapshots fill it."""
+        cap = self._state_snap_budget // max(1, self._snap_nbytes)
+        while self._snaps_held() >= cap:
+            if slot.state_snaps:
+                del slot.state_snaps[min(slot.state_snaps)]
+            elif self._state_snaps:
+                self._state_snaps.popitem(last=False)
+            else:
+                return False
+            self._n_state_snaps_dropped += 1
+        return True
 
     def _snapshot_state(self, i, end):
         """Keep slot ``i``'s state as the tick just launched leaves it
         — its prefill chunk ended on the block boundary ``end`` — until
-        the slot's blocks are published. One small gather on the
-        device, launched behind the tick; nothing comes to the host."""
-        self._slots[i].state_snaps[int(end)] = self._read_state(i)
+        the slot's blocks are published, if the byte budget has room
+        for it. One gather of the seat's rows on the device, launched
+        behind the tick; nothing comes to the host."""
+        slot = self._slots[i]
+        if not self._room_for_snapshot(slot):
+            self._n_state_snaps_dropped += 1
+            return
+        slot.state_snaps[int(end)] = self._read_state(i)
         self._n_state_snaps += 1
 
     def _read_state(self, i):

@@ -54,6 +54,7 @@ from ..nn import functional as F
 from ..nn.layer.container import LayerList
 from ..nn.layer.layers import Layer
 from ..generation import GenerationMixin
+from ..ops.short_conv import causal_taps, ragged_causal_taps
 from .deepseek_v3 import _linear, _norm, _param, _rms, _swiglu
 from .llama import (LlamaPretrainingCriterion, _rope_rotate,
                     ragged_paged_attention_decode)
@@ -140,16 +141,6 @@ def _rope(x, pos, theta):
                         jnp.sin(ang)[:, None, :])
 
 
-def _taps(w, rows):
-    """``sum_j w[:, j] * rows[j]`` in float32: ``rows`` are the ``L``
-    shifted copies of ``g`` (oldest first), each ``[..., hidden]``."""
-    w32 = w.astype(jnp.float32)
-    acc = rows[0].astype(jnp.float32) * w32[:, 0]
-    for j in range(1, len(rows)):
-        acc = acc + rows[j].astype(jnp.float32) * w32[:, j]
-    return acc
-
-
 # -- layers --------------------------------------------------------------------
 
 class _Filter(Layer):
@@ -183,7 +174,7 @@ class Lfm2ShortConv(Layer):
             g = b_ * z
             t = g.shape[1]
             gp = jnp.pad(g, ((0, 0), (taps - 1, 0), (0, 0)))
-            conv = _taps(w, [gp[:, j:j + t] for j in range(taps)])
+            conv = causal_taps(w, [gp[:, j:j + t] for j in range(taps)])
             return (c_ * conv.astype(x_a.dtype)) @ w_out
 
         return apply_jax("short_conv", f, x, *self._weights())
@@ -191,65 +182,21 @@ class Lfm2ShortConv(Layer):
     def forward_paged(self, x, cache, ragged_meta):
         """Over the ragged tick's packed rows ``x [1, R, hidden]``;
         ``cache`` is the layer's ``(SlotState,)``: ``state[s]`` holds
-        the last ``L - 1`` rows of ``g`` that slot ``s`` has seen.
-
-        Row ``r`` of slot ``s`` at offset ``o = r - row_starts[s]``:
-        tap ``j`` reads ``g[r - (L - 1) + j]`` where the tick carries
-        it (``o >= L - 1 - j``), else ``state[s, o + j]``. A slot whose
-        first row is at position 0 reads zeros: a NEW request's seat
-        never sees its last occupant's state, and needs no reset
-        executable. Afterwards ``state[s]`` is the last ``L - 1`` of
-        the slot's old state followed by its rows of this tick. A row
-        no slot owns — past the packed total, or retired inside the
-        executable by the ``done`` mask, whose ``q_lens`` is 0 — reads
-        the null seat (the table's last row, never written) and writes
-        nothing. The gating, the taps and the state's gather and
-        scatter run under the scope ``short_conv``; the two projections
-        stay outside it. Returns ``(out, cache)``."""
+        the last ``L - 1`` rows of ``g`` that slot ``s`` has seen. The
+        taps over the packed rows and the state's gather and scatter
+        are ``ops/short_conv.ragged_causal_taps`` (which rows read the
+        state, which zeros, which the null seat); they and the gating
+        run under the scope ``short_conv``, the two projections stay
+        outside it. Returns ``(out, cache)``."""
         from ..ops.paged_cache import SlotState
-        taps = self.config.conv_L_cache
-        keep = taps - 1
 
         def f(x_a, w_in, w, w_out, state, ql, rs, sl, pos):
-            state = state.data
-            r = x_a.shape[1]
-            n_slots = ql.shape[0]
             b_, c_, z = jnp.split(x_a[0] @ w_in, 3, axis=-1)
             with jax.named_scope("short_conv"):
-                ql = ql.astype(jnp.int32)
-                rs = rs.astype(jnp.int32)
-                row = jnp.arange(r, dtype=jnp.int32)
-                off = row - rs[sl]
-                live = (off >= 0) & (off < ql[sl])
-                seat = jnp.where(live, sl.astype(jnp.int32), n_slots)
-                # a slot's state as its rows see it: zeros where the
-                # slot's first row is position 0
-                first = pos.astype(jnp.int32)[jnp.minimum(rs, r - 1)]
-                fresh = (ql > 0) & (first == 0)
-                old = jnp.where(fresh[:, None, None], 0, state[:n_slots])
-                old = jnp.concatenate([old, state[n_slots:]])
-                g = b_ * z                                  # [R, hidden]
-                rows = []
-                for j in range(keep):
-                    back = keep - j
-                    prev = jnp.pad(g, ((back, 0), (0, 0)))[:r]
-                    kept = old[seat, jnp.clip(off + j, 0, keep - 1)]
-                    rows.append(jnp.where((off >= back)[:, None], prev,
-                                          kept))
-                rows.append(g)
-                conv = _taps(w, rows).astype(x_a.dtype)
-                # the slot's last L - 1 entries of (old state ++ rows)
-                n = ql[:, None] + jnp.arange(keep, dtype=jnp.int32)[None]
-                from_g = g[jnp.clip(rs[:, None] + n - keep, 0, r - 1)]
-                from_old = jnp.take_along_axis(
-                    old[:n_slots], jnp.clip(n, 0, keep - 1)[..., None],
-                    axis=1)
-                new = jnp.where((n >= keep)[..., None], from_g, from_old)
-                new = jnp.where((ql > 0)[:, None, None], new,
-                                state[:n_slots])
-                state = state.at[:n_slots].set(new.astype(state.dtype))
-                y = c_ * conv
-            return (y @ w_out)[None], state
+                conv, new = ragged_causal_taps(
+                    b_ * z, state.data, w, (ql, rs, sl, pos))
+                y = c_ * conv.astype(x_a.dtype)
+            return (y @ w_out)[None], new
 
         ql, rs, sl, pos = ragged_meta[:4]
         out, state = apply_jax(
@@ -529,8 +476,8 @@ class Lfm2MoeForCausalLM(Layer, GenerationMixin):
         return [init_flat_pool(num_blocks, block_size,
                                c.num_key_value_heads, c.head_dim, dtype)
                 if kind == "full_attention"
-                else init_slot_state(num_slots, c.conv_L_cache - 1,
-                                     c.hidden_size, dtype)
+                else init_slot_state(
+                    num_slots, (c.conv_L_cache - 1, c.hidden_size), dtype)
                 for kind in c.layer_types]
 
     def forward(self, input_ids, labels=None, attention_mask=None,

@@ -75,6 +75,7 @@ __all__ = ["NULL_BLOCK", "BlockAllocator", "blocks_for", "init_pool",
            "stacked_layout", "stacked_payload", "SlotState",
            "is_slot_state", "init_slot_state", "first_paged",
            "state_bytes", "export_slot_state", "import_slot_state",
+           "snapshot_nbytes",
            "init_flat_pool"]
 
 # block id 0 is never allocated: inactive slots' tables point here, so
@@ -157,16 +158,19 @@ def kv_dequantize(data, scale, dtype=jnp.float32):
 class SlotState:
     """A layer's cache that is NOT paged: ``data [num_slots + 1, ...]``
     holds one row of recurrent state a serving SLOT (a gated short
-    convolution's last ``L - 1`` inputs, ``[S + 1, L - 1, hidden]``),
+    convolution's last ``L - 1`` inputs, ``[S + 1, L - 1, hidden]``; a
+    delta-rule mixer's matrix a head, ``[S + 1, heads, d_v, d_k]``),
     whatever the sequence's length; the last row is the null seat that
     rows no slot owns read, and nothing writes. The layer's cache entry
-    is the 1-tuple ``(SlotState,)``, and :func:`is_slot_state` is the
-    ONE predicate that tells it from a block-paged entry: every walker
-    that moves BLOCKS (``copy_blocks``, ``export_blocks`` /
-    ``import_blocks``, ``export_stacked``, ``pool_bytes``) passes such
-    a layer through untouched, because it holds no blocks. Registered
-    as a jax pytree like :class:`QuantKV`, so it rides jit arguments
-    and donation unchanged."""
+    is a tuple of NOTHING BUT such tables — one (``(SlotState,)``) or,
+    where a mixer keeps state of two kinds, several of different shape
+    and dtype — and :func:`is_slot_state` is the ONE predicate that
+    tells it from a block-paged entry: every walker that moves BLOCKS
+    (``copy_blocks``, ``export_blocks`` / ``import_blocks``,
+    ``export_stacked``, ``pool_bytes``) passes such a layer through
+    untouched, because it holds no blocks. Registered as a jax pytree
+    like :class:`QuantKV`, so it rides jit arguments and donation
+    unchanged."""
 
     _is_slot_state = True             # duck-typed marker (framework)
     __slots__ = ("data",)
@@ -197,16 +201,16 @@ jax.tree_util.register_pytree_node(
 
 
 def is_slot_state(layer) -> bool:
-    """Whether a layer's cache entry is slot state (``(SlotState,)``)
-    and not block-paged arrays."""
-    return len(layer) == 1 and isinstance(layer[0], SlotState)
+    """Whether a layer's cache entry is slot state (a tuple of
+    ``SlotState`` tables) and not block-paged arrays."""
+    return len(layer) > 0 and all(isinstance(t, SlotState) for t in layer)
 
 
-def init_slot_state(num_slots: int, depth: int, width: int,
-                    dtype) -> tuple:
-    """Zeroed ``(SlotState,)`` of ``[num_slots + 1, depth, width]``."""
-    return (SlotState(jnp.zeros((int(num_slots) + 1, int(depth),
-                                 int(width)), dtype)),)
+def init_slot_state(num_slots: int, shape, dtype) -> tuple:
+    """Zeroed ``(SlotState,)`` of ``[num_slots + 1, *shape]``; a layer
+    with tables of two kinds adds two such tuples."""
+    return (SlotState(jnp.zeros(
+        (int(num_slots) + 1, *(int(n) for n in shape)), dtype)),)
 
 
 def first_paged(pools):
@@ -220,16 +224,24 @@ def first_paged(pools):
 
 def state_bytes(pools) -> int:
     """Total bytes of the slot-state tables among ``pools``."""
-    return sum(layer[0].nbytes for layer in pools if is_slot_state(layer))
+    return sum(t.nbytes for layer in pools if is_slot_state(layer)
+               for t in layer)
 
 
 def export_slot_state(pools, slot):
-    """One slot's row of every slot-state layer, stacked in layer order
-    ``[n, ...]`` (the layers' tables share a shape): the snapshot the
-    engine keeps beside a published block. ``slot`` is a traced int32
-    scalar, so one executable serves every seat."""
-    return jnp.stack([layer[0].data[slot] for layer in pools
-                      if is_slot_state(layer)])
+    """One slot's row of every slot-state table: the snapshot the
+    engine keeps beside a published block, a PYTREE — a list over the
+    slot-state layers of a tuple over the layer's tables — or, where
+    every such layer holds ONE table and the tables share a shape and a
+    dtype, those rows stacked in layer order ``[n, ...]`` (one leaf).
+    ``slot`` is a traced int32 scalar, so one executable serves every
+    seat."""
+    rows = [tuple(t.data[slot] for t in layer) for layer in pools
+            if is_slot_state(layer)]
+    if all(len(r) == 1 for r in rows) and len(
+            {(r[0].shape, r[0].dtype) for r in rows}) == 1:
+        return jnp.stack([r[0] for r in rows])
+    return rows
 
 
 def import_slot_state(pools, slot, snap):
@@ -238,11 +250,20 @@ def import_slot_state(pools, slot, snap):
     out, k = [], 0
     for layer in pools:
         if is_slot_state(layer):
-            st = layer[0].data
-            layer = (SlotState(st.at[slot].set(snap[k].astype(st.dtype))),)
+            rows = snap[k] if isinstance(snap[k], tuple) else (snap[k],)
+            layer = tuple(
+                SlotState(t.data.at[slot].set(row.astype(t.dtype)))
+                for t, row in zip(layer, rows))
             k += 1
         out.append(layer)
     return out
+
+
+def snapshot_nbytes(pools) -> int:
+    """Bytes of one :func:`export_slot_state` snapshot: one seat's row
+    of every slot-state table."""
+    return sum(t.nbytes // t.shape[0] for layer in pools
+               if is_slot_state(layer) for t in layer)
 
 
 def resolve_kv_cache_dtype(requested=None):

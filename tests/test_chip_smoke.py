@@ -108,17 +108,19 @@ def test_serve_phase_tiny(clock, capsys):
     chip_smoke.serve_phase(chip_smoke.SERVE_TINY, clock, on_chip=False)
     out = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
     engines = {o["engine"]: o for o in out if "engine" in o}
-    assert set(engines) == {"bf16", "int8", "slot_state"}
+    assert set(engines) == {"bf16", "int8", "slot_state", "scan_state"}
     for tag, o in engines.items():
         # (slot state: the tick and the snapshot pair of a prefix hit)
-        assert o["executables_compiled"] == (3 if tag == "slot_state"
-                                             else 1)
+        assert o["executables_compiled"] == (
+            3 if tag in ("slot_state", "scan_state") else 1)
         assert o["prefix_tokens_reused"] > 0    # the mix hits the cache
         assert o["prefill_chunks"] > o["requests"]      # a multi-chunk one
     gaps = [o for o in out if "logit_gap_max" in o]
     assert set(gaps[0]["logit_gap_max"]) == {"bf16", "int8"}
     assert gaps[1]["model"] == "Lfm2MoeForCausalLM" \
         and set(gaps[1]["logit_gap_max"]) == {"bf16"}
+    assert gaps[2]["model"] == "SolarOpen2ForCausalLM" \
+        and gaps[2]["gqa_layers"] == [0]
 
 
 def test_train_phase_tiny_feeds_from_worker_processes(clock, capsys,
