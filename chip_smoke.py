@@ -69,10 +69,11 @@ EXPECT = {
     "serve.fused_matmul_residual": ("mosaic", ("fused_matmul_residual",)),
     # (a model with slot state beside the paged KV, head size 64)
     "serve.slot_state.ragged_paged_attention": (
-        "mosaic", ("ragged_paged_attention",)),
+        "mosaic", ("ragged_paged_attention", "short_conv_taps")),
     # (a model whose slot state is a matrix a head, advanced by a scan)
     "serve.scan_state.delta_rule": (
-        "mosaic", ("kda_recurrent", "kda_chunk", "ragged_paged_attention")),
+        "mosaic", ("kda_recurrent", "kda_chunk", "short_conv_taps",
+                   "ragged_paged_attention")),
     # -- the train step (TrainStep, seq 2048) ------------------------------
     "train.flash_attention": ("mosaic", _FLASH),
     # -- tensor-parallel serving (four-chip phase) -------------------------
@@ -95,6 +96,8 @@ EXPECT = {
     "kernel.ragged_latent.bf16": ("mosaic", ("ragged_latent_attention",)),
     "kernel.kda_recurrent": ("mosaic", ("kda_recurrent",)),
     "kernel.kda_chunk": ("mosaic", ("kda_chunk",)),
+    "kernel.short_conv_taps": ("mosaic", ("short_conv_taps",)),
+    "kernel.short_conv_taps.3taps": ("mosaic", ("short_conv_taps",)),
     "kernel.norm_matmul.qkv_bias": ("mosaic", ("fused_norm_matmul",)),
     "kernel.norm_matmul.gate_up": ("mosaic", ("fused_norm_matmul",)),
     "kernel.matmul_residual.o_proj": ("mosaic", ("fused_matmul_residual",)),
@@ -119,6 +122,9 @@ EXPECT = {
 # rounding, far below what a wrong block, mask or head routing produces
 # (errors of the order of the scale itself).
 KERNEL_TOL = 5e-2
+# ... except where a kernel only MOVES values and sums them in its
+# mirror's order (ops/short_conv.py): nothing may differ
+KERNEL_EXACT = ("kernel.short_conv_taps", "kernel.short_conv_taps.3taps")
 
 # Greedy-token check of the serve phase: each served token's logit under
 # the plain forward must be within this of that position's max logit.
@@ -651,8 +657,20 @@ def kernel_cases(full, interpret=None):
     # one of them carrying a 256-row chunk, the others a row each)
     kd = dict(S=96, H=64, W=256) if full else dict(S=4, H=2, W=80)
 
+    def tick_meta(seats, wide):
+        """``q_lens, row_starts, row_slot, row_pos`` of a tick of
+        ``seats + wide`` packed rows: seat 1 carries the chunk (from a
+        held state), seat 2 is idle, seat 3 starts a request, the
+        others decode."""
+        q_lens = np.ones(seats, np.int64)
+        q_lens[1], q_lens[2] = wide - 3, 0
+        base = np.full(seats, 70, np.int64)
+        base[3] = 0
+        sl, pos, rs, _ = pc.ragged_row_meta(q_lens, base, seats + wide,
+                                            10 ** 6)
+        return [jnp.asarray(x, jnp.int32) for x in (q_lens, rs, sl, pos)]
+
     def kda_args(rng):
-        from paddle_tpu.ops import paged_cache as pc_
         s_, h_, w_, d_ = kd["S"], kd["H"], kd["W"], 128
         rows = s_ + w_
         unit = lambda x: x / np.sqrt((x * x).sum(-1, keepdims=True))
@@ -664,16 +682,8 @@ def kernel_cases(full, interpret=None):
         beta = rng.uniform(0, 2, (rows, h_))
         state = jnp.asarray(rng.standard_normal((s_ + 1, h_, d_, d_)),
                             jnp.float32).at[s_].set(0)
-        # seat 1 carries the chunk (from a held state), seat 2 is idle,
-        # seat 3 starts a request, the others decode
-        q_lens = np.ones(s_, np.int64)
-        q_lens[1], q_lens[2] = w_ - 3, 0
-        base = np.full(s_, 70, np.int64)
-        base[3] = 0
-        sl, pos, rs, _ = pc_.ragged_row_meta(q_lens, base, rows, 10 ** 6)
         ops = [jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta)]
-        meta = [jnp.asarray(x, jnp.int32) for x in (q_lens, rs, sl, pos)]
-        return (*ops, state, *meta,
+        return (*ops, state, *tick_meta(s_, w_),
                 jnp.arange(1, dtype=jnp.int32),
                 jnp.arange(w_, dtype=jnp.int32))
 
@@ -690,6 +700,27 @@ def kernel_cases(full, interpret=None):
         ("kernel.kda_recurrent", kda_case(dr.pallas_kda_recurrent,
                                           dr._xla_recurrent)),
         ("kernel.kda_chunk", kda_case(dr.pallas_kda_chunk, dr._xla_chunk))]
+
+    # -- the short convolution's tap reader over the same kind of tick -------
+    # (the reasoning cell's: 97 seats x 3 stored taps x 24,576 channels,
+    # 352 rows; the wide cell's: 129 x 2 x 2,048, 384 rows)
+    def taps_case(seats, wide, channels, taps):
+        def build(rng):
+            from paddle_tpu.ops import short_conv as sc
+            g = mat(rng, seats + wide, channels)
+            state = mat(rng, seats + 1, taps - 1, channels).at[seats].set(0)
+            return (lambda g_, s_, w_, *m: sc.pallas_ragged_taps(
+                        g_, s_, w_, m, interpret=interpret),
+                    lambda g_, s_, w_, *m: sc._xla_ragged_taps(g_, s_, w_, m),
+                    (g, state, mat(rng, channels, taps),
+                     *tick_meta(seats, wide)))
+        return build
+
+    cases += [
+        ("kernel.short_conv_taps",
+         taps_case(*((96, 256, 24576, 4) if full else (4, 12, 256, 4)))),
+        ("kernel.short_conv_taps.3taps",
+         taps_case(*((128, 256, 2048, 3) if full else (5, 15, 128, 3))))]
 
     # -- the LoRA grouped-matmul route (rank on 128 lanes) -------------------
     n_ad, rank = (9, 128) if full else (3, 128)
@@ -751,17 +782,17 @@ def kernel_phase(full, clock, on_chip):
             run_ms = 1e3 * (time.monotonic() - t0)
             ref = jax.block_until_ready(jax.jit(mirror)(*args))
             err = rel_err(got, ref)
+            tol = 0.0 if name in KERNEL_EXACT else KERNEL_TOL
             # run_ms: one warm call, host clock — an observation of
             # scale, not a benchmark
-            say("kernels", entry=name, err=round(err, 5), tol=KERNEL_TOL,
+            say("kernels", entry=name, err=round(err, 5), tol=tol,
                 run_ms=round(run_ms, 2),
                 mosaic_kernels=census["hlo_mosaic_kernels"],
                 **clock.since(snap))
-            if not err <= KERNEL_TOL:
+            if not err <= tol:
                 raise AssertionError(
                     f"{name}: kernel differs from its XLA mirror by "
-                    f"{err:.4f} of the mirror's scale (tolerance "
-                    f"{KERNEL_TOL})")
+                    f"{err:.4g} of the mirror's scale (tolerance {tol})")
             if EXPECT[name][0] == "mosaic":
                 check_expected(name, census["hlo_mosaic_kernels"], on_chip)
         except Exception as exc:    # keep going: name EVERY broken kernel
