@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..framework.core import Tensor, apply_jax, as_jax, _wrap_out
+from ..framework.core import (Tensor, apply_jax, as_jax, component,
+                              _wrap_out)
 from ..nn import functional as F
 from ..nn.layer.layers import Layer
 from .shard_utils import annotate_param, constraint, mesh_axis_size
@@ -833,21 +834,22 @@ def group_limited_gate(logits, bias, *, n_group, topk_group, top_k,
     topk_group = 1`` this is the plain sigmoid top-k router with a
     choice bias. Ties go to the lower index.
     Returns ``(topk_idx [s, k] int32, topk_weight [s, k] f32)``."""
-    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
-    choice = scores + bias.astype(jnp.float32)
-    s, e = scores.shape
-    per = e // n_group
-    group_score = jnp.sum(
-        jax.lax.top_k(choice.reshape(s, n_group, per), 2)[0], axis=-1)
-    _, gidx = jax.lax.top_k(group_score, topk_group)
-    kept = jnp.zeros((s, n_group), bool).at[
-        jnp.arange(s)[:, None], gidx].set(True)
-    choice = jnp.where(jnp.repeat(kept, per, axis=1), choice, 0.0)
-    _, idx = jax.lax.top_k(choice, top_k)
-    w = jnp.take_along_axis(scores, idx, axis=1)
-    if norm_topk_prob:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
-    return idx.astype(jnp.int32), w * jnp.float32(routed_scaling_factor)
+    with component("moe.gate"):
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+        choice = scores + bias.astype(jnp.float32)
+        s, e = scores.shape
+        per = e // n_group
+        group_score = jnp.sum(
+            jax.lax.top_k(choice.reshape(s, n_group, per), 2)[0], axis=-1)
+        _, gidx = jax.lax.top_k(group_score, topk_group)
+        kept = jnp.zeros((s, n_group), bool).at[
+            jnp.arange(s)[:, None], gidx].set(True)
+        choice = jnp.where(jnp.repeat(kept, per, axis=1), choice, 0.0)
+        _, idx = jax.lax.top_k(choice, top_k)
+        w = jnp.take_along_axis(scores, idx, axis=1)
+        if norm_topk_prob:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
+        return idx.astype(jnp.int32), w * jnp.float32(routed_scaling_factor)
 
 
 def moe_share_dispatch_combine(x, topk_idx, topk_weight, gate_up, down,
@@ -871,37 +873,39 @@ def moe_share_dispatch_combine(x, topk_idx, topk_weight, gate_up, down,
     s, d = x.shape
     k = topk_idx.shape[1]
     count = gate_up.shape[0]
-    local = topk_idx.astype(jnp.int32) - jnp.int32(first)
-    held = (local >= 0) & (local < count)
     mask = getattr(_SERVING_TAP, "rows_mask", None)
-    live = mask if mask is not None and mask.shape[0] == s \
-        else jnp.ones((s,), bool)
-    held = held & live[:, None]
     # the grouped kernel walks whole row tiles: the pair buffer is
     # s * k rounded up to one (the extra pairs are absent ones)
     m = -(-s * k // _SHARE_TM) * _SHARE_TM
     pad = m - s * k
-    flat_e = jnp.pad(local.reshape(-1), (0, pad))
-    flat_ok = jnp.pad(held.reshape(-1), (0, pad))
-    order, rank, counts = _sort_pairs(flat_e, count, valid=flat_ok)
+    with component("moe.gate"):
+        local = topk_idx.astype(jnp.int32) - jnp.int32(first)
+        held = (local >= 0) & (local < count)
+        live = mask if mask is not None and mask.shape[0] == s \
+            else jnp.ones((s,), bool)
+        held = held & live[:, None]
+        flat_e = jnp.pad(local.reshape(-1), (0, pad))
+        flat_ok = jnp.pad(held.reshape(-1), (0, pad))
+        order, rank, counts = _sort_pairs(flat_e, count, valid=flat_ok)
     sink = getattr(_SERVING_TAP, "share_counts", None)
     if sink is not None:
-        sink.append(jnp.concatenate(
-            [counts, jnp.sum(live, dtype=jnp.int32)[None]]))
+        with component("tick.io"):
+            sink.append(jnp.concatenate(
+                [counts, jnp.sum(live, dtype=jnp.int32)[None]]))
     from ..ops.pallas.paged_attention import serving_tp_active
     from ..profiler import RecordEvent
-    with RecordEvent("moe:dispatch"):
+    with RecordEvent("moe:dispatch"), component("moe.dispatch"):
         xs = x[jnp.minimum(order // k, s - 1)]              # [m, d]
     # a group here is an expert's few rows of one tick (rows * k /
     # num_expert on average): row tiles of 128 keep its matmuls under
     # the time its weights take to arrive
     tm = 512 if s * k // num_expert >= 512 else _SHARE_TM
-    with RecordEvent("moe:expert_mm"):
+    with RecordEvent("moe:expert_mm"), component("moe.experts"):
         ys = _expert_swiglu_grouped(
             xs, gate_up, down, counts, x.dtype,
             allow_pallas=not serving_tp_active(),
             tiling=(tm, 1024, 1024))
-    with RecordEvent("moe:combine"):
+    with RecordEvent("moe:combine"), component("moe.combine"):
         picked = ys[rank[:s * k]].reshape(s, k, d)
         # rows of the buffer's tail were never computed: select, do not
         # multiply by a zero weight
@@ -909,7 +913,7 @@ def moe_share_dispatch_combine(x, topk_idx, topk_weight, gate_up, down,
             held[..., None],
             picked.astype(jnp.float32) * topk_weight[..., None], 0.0),
             axis=1)
-    return y.astype(x.dtype)
+        return y.astype(x.dtype)
 
 
 def moe_dispatch_combine_dropless(x, gate_logits, num_expert, top_k,

@@ -34,6 +34,7 @@ from .place import Place, _get_default_place
 
 __all__ = [
     "Tensor", "Parameter", "GradNode", "to_tensor", "as_jax", "apply_jax",
+    "component", "executable_scopes",
     "no_grad", "enable_grad", "is_grad_enabled", "set_grad_enabled",
     "run_backward", "calc_gradients",
 ]
@@ -631,6 +632,42 @@ def _check_nan_inf(op_name: str, outputs):
                     f"FLAGS_check_nan_inf: op {op_name!r} produced "
                     f"{bad} non-finite value(s) in output shape "
                     f"{tuple(o.shape)} dtype {o.dtype}")
+
+
+# How many serving engines are tracing an executable right now
+# (``executable_scopes``). While one is, ``component(name)`` enters its
+# scope, so every instruction of the compiled program carries the path
+# of the model code it came from (``monitor.accounting.component_map``
+# reads it back out of ``compiled.as_text()``). A scope changes an
+# instruction's metadata and nothing else; everywhere else it is not
+# entered at all. (``apply_jax``'s op names are NOT entered: in the
+# paged paths they are one name a module, ``kda_paged``, which says
+# what the layer's kind and the component already say.)
+_executable_scopes = 0
+_scopes_lock = threading.Lock()
+
+
+@contextlib.contextmanager
+def executable_scopes():
+    """Armed by ``ServingEngine._trace_ctx`` around every trace."""
+    global _executable_scopes
+    with _scopes_lock:
+        _executable_scopes += 1
+    try:
+        yield
+    finally:
+        with _scopes_lock:
+            _executable_scopes -= 1
+
+
+def component(name: str):
+    """``jax.named_scope(name)`` while an engine traces an executable,
+    else a context that does nothing. ``name`` is one of
+    ``monitor.accounting.COMPONENTS``, or the outer segment of one
+    decoder layer, ``L<index>.<kind>`` (``L3.kda``)."""
+    if _executable_scopes:
+        return jax.named_scope(name)
+    return contextlib.nullcontext()
 
 
 def apply_jax(op_name: str, fn: Callable, *inputs, n_outputs: int = 1,

@@ -195,6 +195,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import monitor
 from ..distributed import moe as _moe
+from ..framework.core import component, executable_scopes
 from ..monitor import health as _health
 from ..monitor import tracing as _tracing
 from ..monitor.digest import LatencyDigest
@@ -970,6 +971,15 @@ class ServingEngine:
             # honest on TP engines
             self._fused_mode = None
         self._kcensus = {}          # exec name -> kernel census rows
+        self._cmap = {}             # exec name -> component map rows
+        # JAX's persistent compile cache leaves op metadata out of its
+        # key, so a cache another version filled (other scope names, the
+        # same program: a shared JAX_COMPILATION_CACHE_DIR) would hand
+        # back ITS text and the map would name scopes this program never
+        # entered. Counted into the key, set once and left on; the
+        # compiled program is the same either way
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", True)
 
         self._bs = int(cfg.block_size)
         # +gamma: the speculative verify window may overhang the last
@@ -3662,9 +3672,13 @@ class ServingEngine:
         un-gather the lm_head so logits leave the model vocab-sharded
         — ``_gather_logits`` is then the step's ONE explicit logits
         collective instead of a gather/re-shard pair. Everything is
-        restored on exit, so nothing leaks into other code."""
+        restored on exit, so nothing leaks into other code. Every
+        trace also runs under ``executable_scopes()``: the model's
+        ``component`` scopes are entered only here, and
+        ``component_map()`` reads them back."""
         if self._mesh is None:
-            with self._df.fused_decode_scope(self._fused_mode):
+            with self._df.fused_decode_scope(self._fused_mode), \
+                    executable_scopes():
                 yield
             return
         from ..distributed import env as _denv
@@ -3684,7 +3698,8 @@ class ServingEngine:
             # folds into fused_decode_mode(), which reports "off" there
             # (an opaque pallas_call cannot be GSPMD-partitioned)
             with serving_tp_scope(), \
-                    self._df.fused_decode_scope(self._fused_mode):
+                    self._df.fused_decode_scope(self._fused_mode), \
+                    executable_scopes():
                 yield
         finally:
             _denv.set_mesh(prev)
@@ -3713,6 +3728,14 @@ class ServingEngine:
                         traced.jaxpr)
                 kc = monitor.kernel_census(compiled=exec_,
                                            jaxpr=traced.jaxpr)
+                # which component of the model each instruction came
+                # from: kept beside the census, and with the tracer
+                # (outside its ring), so it outlives the engine with
+                # the spans and stands in dump_trace()'s file
+                self._cmap[name] = kc.pop("hlo_components", [])
+                if self._trace is not None:
+                    self._trace.annotate("component_map",
+                                         {name: self._cmap[name]})
                 self._kcensus[name] = kc
                 # roofline static half: the executable's cost-model
                 # FLOPs + HBM bytes (per-tick MFU / bandwidth
@@ -3763,6 +3786,16 @@ class ServingEngine:
         decode layer down") is read off the ``decode``/``verify``
         row — measured on every engine, every compile."""
         return dict(self._kcensus)
+
+    def component_map(self) -> dict:
+        """Per-executable component map (``monitor.component_map``,
+        from the same read of the compiled text as the census):
+        ``{exec_name: [{name, opcode, shape, bytes, component, layer,
+        also}, ...]}`` — which part of the model each instruction of
+        the executable came from, so a device trace's events (named by
+        instruction) can be summed by component. Built at compile
+        time, with tracing on or off."""
+        return dict(self._cmap)
 
     def _tp_census_bytes(self, name) -> int:
         """Explicit per-shard ``mp`` collective payload of one
@@ -4999,16 +5032,11 @@ class ServingEngine:
         overflow = self._overflow
         share = []      # what expert-parallel shares report, per layer
 
-        def ragged(params, pools, tables, rows_pack, slots_pack, *rest):
-            if not g:
-                # the tick before's tokens ride right after the packs
-                prev_tok, rest = rest[0], rest[1:]
-            if lora_on:
-                # the stacked adapter weights ride at a FIXED operand
-                # position (right after the packs) — strip them before
-                # the g/heads/dq parsing below, which indexes rest
-                # from both ends
-                lora_ops, rest = rest[0], rest[1:]
+        def unpack(rows_pack, slots_pack, prev_tok):
+            """The packs' rows as the model's operands: ``(ids,
+            row_slot, row_pos, base, q_lens, row_starts, last_rows)``,
+            a decode row's id taken from the tick before's output
+            where the host packed none."""
             ids, row_slot, row_pos = (rows_pack[0], rows_pack[1],
                                       rows_pack[2])
             base, q_lens, row_starts, last_rows = (
@@ -5038,10 +5066,30 @@ class ServingEngine:
                         (jnp.take(src, row_starts) == sl)
                         & (prev_tok == eos), 0, q_lens)
                 ids = jnp.where(fed, fed_tok, ids)
-            tree_rows = slots_pack[4] if tree is not None else None
-            nwin = jnp.arange(g + 1, dtype=jnp.int32)
-            win = jnp.arange(self._wmax, dtype=jnp.int32)
-            meta = (q_lens, row_starts, row_slot, row_pos, nwin, win)
+            return (ids, row_slot, row_pos, base, q_lens, row_starts,
+                    last_rows)
+
+        def ragged(params, pools, tables, rows_pack, slots_pack, *rest):
+            prev_tok = None
+            if not g:
+                # the tick before's tokens ride right after the packs
+                prev_tok, rest = rest[0], rest[1:]
+            if lora_on:
+                # the stacked adapter weights ride at a FIXED operand
+                # position (right after the packs) — strip them before
+                # the g/heads/dq parsing below, which indexes rest
+                # from both ends
+                lora_ops, rest = rest[0], rest[1:]
+            # the component scopes (monitor.COMPONENTS): what the tick
+            # does around the model's forward is ``tick.io`` (carry,
+            # packs, outputs) and ``sample``; the model names its own
+            with component("tick.io"):
+                (ids, row_slot, row_pos, base, q_lens, row_starts,
+                 last_rows) = unpack(rows_pack, slots_pack, prev_tok)
+                tree_rows = slots_pack[4] if tree is not None else None
+                nwin = jnp.arange(g + 1, dtype=jnp.int32)
+                win = jnp.arange(self._wmax, dtype=jnp.int32)
+                meta = (q_lens, row_starts, row_slot, row_pos, nwin, win)
             # pad rows park at the overflow position — exclude them
             # from the MoE routing telemetry (they'd read as
             # hot-expert skew on lightly loaded ticks)
@@ -5062,13 +5110,15 @@ class ServingEngine:
                     # and contribute nothing downstream. The scope
                     # arms the tagged q/k/v/o projections' ragged
                     # grouped-matmul delta inside the SAME executable.
-                    row_adapter = jnp.take(slots_pack[lora_row],
-                                           row_slot)
+                    with component("tick.io"):
+                        row_adapter = jnp.take(slots_pack[lora_row],
+                                               row_slot)
                     ctx.enter_context(_lora.serving_lora_scope(
                         lora_ops, row_adapter, lora_scaling,
                         gmm_ok=lora_gmm_ok))
-                ctx.enter_context(
-                    _moe.serving_rows_mask(row_pos < self._overflow))
+                with component("tick.io"):
+                    live_rows = row_pos < self._overflow
+                ctx.enter_context(_moe.serving_rows_mask(live_rows))
                 ctx.enter_context(_moe.serving_share_counts(share))
                 logits, pools = step(
                     params, ids[None, :], pools, None,
@@ -5076,33 +5126,45 @@ class ServingEngine:
                     ragged_meta=meta)
             if heads_on:
                 logits, hid = logits
-            lg = logits[0]                          # [R, V(/tp)]
-            if not g:
-                samp, key = rest
-                rows = jnp.take(lg, last_rows.astype(jnp.int32),
-                                axis=0)
-                rows = self._gather_logits(rows)    # the ONE collective
-                # health probe: one any(~isfinite) reduction over the
-                # rows already gathered for sampling — a scalar OUTPUT
-                # of the same executable, never a new one. Always
-                # computed (executable stays bit-identical under
-                # PADDLE_TPU_HEALTH=0); only the host fetch is gated.
-                # Live slots only: a rowless slot gathers row 0, and a
-                # pad row's fully-masked attention output is not
-                # meaningful.
-                live = q_lens > 0
-                nf = jnp.any(~jnp.isfinite(rows) & live[:, None])
-                _, sel = jax.random.split(key)
-                tok, _ = self._select_rows(rows, sel, samp)
-                tok = tok.astype(jnp.int32)
-                if self._mesh is not None:
-                    # the tokens feed straight back as the next tick's
-                    # operand, and compiled executables are strict
-                    # about INPUT shardings: pin them replicated (what
-                    # _dev commits the host's packs as)
-                    tok = jax.lax.with_sharding_constraint(
-                        tok, NamedSharding(self._mesh, P(None)))
-                return tok, nf, pools
+            with component("sample"):
+                lg = logits[0]                      # [R, V(/tp)]
+                if not g:
+                    return sample_rows(lg, last_rows, q_lens, rest,
+                                       pools)
+                return verify_rows(lg, last_rows, row_starts, tables,
+                                   base, tree_rows,
+                                   hid if heads_on else None, rest,
+                                   pools)
+
+        def sample_rows(lg, last_rows, q_lens, rest, pools):
+            samp, key = rest
+            rows = jnp.take(lg, last_rows.astype(jnp.int32),
+                            axis=0)
+            rows = self._gather_logits(rows)    # the ONE collective
+            # health probe: one any(~isfinite) reduction over the
+            # rows already gathered for sampling — a scalar OUTPUT
+            # of the same executable, never a new one. Always
+            # computed (executable stays bit-identical under
+            # PADDLE_TPU_HEALTH=0); only the host fetch is gated.
+            # Live slots only: a rowless slot gathers row 0, and a
+            # pad row's fully-masked attention output is not
+            # meaningful.
+            live = q_lens > 0
+            nf = jnp.any(~jnp.isfinite(rows) & live[:, None])
+            _, sel = jax.random.split(key)
+            tok, _ = self._select_rows(rows, sel, samp)
+            tok = tok.astype(jnp.int32)
+            if self._mesh is not None:
+                # the tokens feed straight back as the next tick's
+                # operand, and compiled executables are strict
+                # about INPUT shardings: pin them replicated (what
+                # _dev commits the host's packs as)
+                tok = jax.lax.with_sharding_constraint(
+                    tok, NamedSharding(self._mesh, P(None)))
+            return tok, nf, pools
+
+        def verify_rows(lg, last_rows, row_starts, tables, base,
+                        tree_rows, hid, rest, pools):
             toks = rest[0]
             if tree is not None:
                 heads = rest[1] if heads_on else None
@@ -5174,7 +5236,8 @@ class ServingEngine:
             # pairs an expert held here, then live rows; _launch_ragged
             # takes it off again
             self._moe_share_out = True
-            return tuple(outs) + (jnp.stack(share),)
+            with component("tick.io"):
+                return tuple(outs) + (jnp.stack(share),)
 
         jitted = jax.jit(ragged_tick, donate_argnums=(1,))
         name = "verify" if g else "decode"

@@ -55,7 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..framework import random as _random
-from ..framework.core import apply_jax
+from ..framework.core import apply_jax, component
 from ..framework.dtype import to_np
 from ..nn import functional as F
 from ..nn.layer.container import LayerList
@@ -280,14 +280,22 @@ class DeepseekV3Attention(Layer):
         k_pe [R, dr])``, rope applied."""
         c = self.config
         eps = c.rms_norm_eps
-        q = (_rms(x @ wqa, qa_ln, eps) @ wqb).reshape(
-            x.shape[0], c.num_attention_heads, self.q_head_dim)
-        q_nope = q[..., :c.qk_nope_head_dim]
-        ckv = x @ wkva
-        c_kv = _rms(ckv[:, :c.kv_lora_rank], kva_ln, eps)
-        rope = (pos, self._inv_freq, self._rope_factor)
-        q_pe = _rope_lanes(q[..., c.qk_nope_head_dim:], *rope)
-        k_pe = _rope_lanes(ckv[:, c.kv_lora_rank:], *rope)
+        with component("mixer.in"):
+            q_a = x @ wqa
+        with component("mixer.glue"):
+            q_a = _rms(q_a, qa_ln, eps)
+        with component("mixer.in"):
+            q = (q_a @ wqb).reshape(
+                x.shape[0], c.num_attention_heads, self.q_head_dim)
+        with component("mixer.glue"):
+            q_nope = q[..., :c.qk_nope_head_dim]
+        with component("mixer.in"):
+            ckv = x @ wkva
+        with component("mixer.glue"):
+            c_kv = _rms(ckv[:, :c.kv_lora_rank], kva_ln, eps)
+            rope = (pos, self._inv_freq, self._rope_factor)
+            q_pe = _rope_lanes(q[..., c.qk_nope_head_dim:], *rope)
+            k_pe = _rope_lanes(ckv[:, c.kv_lora_rank:], *rope)
         return q_nope, q_pe, c_kv, k_pe
 
     def forward(self, x):
@@ -338,33 +346,40 @@ class DeepseekV3Attention(Layer):
 
         def f(x_a, wqa, qa_ln, wqb, wkva, kva_ln, wkvb, wo, pool,
               tables, lens, *meta):
-            lens = lens.astype(jnp.int32)
-            if uniform:
-                ql = jnp.full((b,), l, jnp.int32)
-                rs = jnp.arange(b, dtype=jnp.int32) * l
-                sl = jnp.repeat(jnp.arange(b, dtype=jnp.int32), l)
-                pos_r = (lens[:, None] + jnp.arange(
-                    l, dtype=jnp.int32)[None]).reshape(-1)
-                nwin = win = jnp.arange(l, dtype=jnp.int32)
-            else:
-                ql, rs, sl, pos_r, nwin, win = meta
-            pos = jnp.clip(pos_r.astype(jnp.int32), 0,
-                           c.max_position_embeddings - 1)
+            with component("mixer.glue"):
+                lens = lens.astype(jnp.int32)
+                if uniform:
+                    ql = jnp.full((b,), l, jnp.int32)
+                    rs = jnp.arange(b, dtype=jnp.int32) * l
+                    sl = jnp.repeat(jnp.arange(b, dtype=jnp.int32), l)
+                    pos_r = (lens[:, None] + jnp.arange(
+                        l, dtype=jnp.int32)[None]).reshape(-1)
+                    nwin = win = jnp.arange(l, dtype=jnp.int32)
+                else:
+                    ql, rs, sl, pos_r, nwin, win = meta
+                pos = jnp.clip(pos_r.astype(jnp.int32), 0,
+                               c.max_position_embeddings - 1)
             q_nope, q_pe, c_kv, k_pe = self._project(
                 x_a.reshape(r, -1), pos, wqa, qa_ln, wqb, wkva, kva_ln)
             w3 = wkvb.reshape(rank, h, dn + dv)
-            q_abs = jnp.einsum("rhn,chn->rhc", q_nope, w3[..., :dn])
-            lanes = pool.shape[-1]
-            pad = lanes - rank - c.qk_rope_head_dim
-            q_cat = jnp.concatenate(
-                [q_abs, q_pe, jnp.zeros((r, h, pad), q_abs.dtype)], -1)
-            c_new = jnp.concatenate(
-                [c_kv, k_pe, jnp.zeros((r, pad), c_kv.dtype)], -1)
-            u, (pool2,) = ragged_latent_attention_step(
-                q_cat, c_new, (pool,), tables, lens, ql, rs, sl, pos_r,
-                nwin, win, rank, self._scale)
-            o = jnp.einsum("rhc,chv->rhv", u, w3[..., dn:])
-            return (o.reshape(b, l, h * dv) @ wo), pool2
+            with component("mixer.in"):
+                # the absorbed form: q against kv_b_proj's key half
+                q_abs = jnp.einsum("rhn,chn->rhc", q_nope, w3[..., :dn])
+            with component("mixer.glue"):
+                lanes = pool.shape[-1]
+                pad = lanes - rank - c.qk_rope_head_dim
+                q_cat = jnp.concatenate(
+                    [q_abs, q_pe, jnp.zeros((r, h, pad), q_abs.dtype)],
+                    -1)
+                c_new = jnp.concatenate(
+                    [c_kv, k_pe, jnp.zeros((r, pad), c_kv.dtype)], -1)
+                u, (pool2,) = ragged_latent_attention_step(
+                    q_cat, c_new, (pool,), tables, lens, ql, rs, sl,
+                    pos_r, nwin, win, rank, self._scale)
+            with component("mixer.out"):
+                # kv_b_proj's value half, then o_proj
+                o = jnp.einsum("rhc,chv->rhv", u, w3[..., dn:])
+                return (o.reshape(b, l, h * dv) @ wo), pool2
 
         meta = () if uniform else tuple(ragged_meta)
         out, pool = apply_jax(
@@ -382,8 +397,10 @@ class DeepseekV3MLP(Layer):
         self.down_proj = _linear(config, width, hidden)
 
     def forward(self, x):
-        return apply_jax("swiglu_mlp", _swiglu, x, self.gate_proj.weight,
-                         self.up_proj.weight, self.down_proj.weight)
+        with component("ffn"):
+            return apply_jax("swiglu_mlp", _swiglu, x,
+                             self.gate_proj.weight, self.up_proj.weight,
+                             self.down_proj.weight)
 
 
 class DeepseekV3Gate(Layer):
@@ -431,9 +448,10 @@ class DeepseekV3MoE(Layer):
             x2 = x_a.reshape(-1, x_a.shape[-1])
             # the gate runs in float32 as published: on a TPU that
             # takes the highest matmul precision, not bf16 passes
-            logits = jnp.matmul(x2.astype(jnp.float32),
-                                wg.astype(jnp.float32),
-                                precision=jax.lax.Precision.HIGHEST)
+            with component("moe.gate"):
+                logits = jnp.matmul(x2.astype(jnp.float32),
+                                    wg.astype(jnp.float32),
+                                    precision=jax.lax.Precision.HIGHEST)
             idx, w = group_limited_gate(
                 logits, bias, n_group=c.n_group,
                 topk_group=c.topk_group, top_k=c.num_experts_per_tok,
@@ -442,7 +460,8 @@ class DeepseekV3MoE(Layer):
             y = moe_share_dispatch_combine(
                 x2, idx, w, gate_up, down, first=c.expert_first,
                 num_expert=c.n_routed_experts)
-            return (y + _swiglu(x2, sg, su, sd)).reshape(x_a.shape)
+            with component("ffn"):      # the shared expert
+                return (y + _swiglu(x2, sg, su, sd)).reshape(x_a.shape)
 
         sh = self.shared_experts
         return apply_jax(
@@ -465,15 +484,20 @@ class DeepseekV3DecoderLayer(Layer):
 
     def forward(self, h, cache=None, block_tables=None, cache_lens=None,
                 ragged_meta=None):
-        a = F.rms_norm(h, self.input_layernorm.weight, self._eps)
+        with component("norm"):
+            a = F.rms_norm(h, self.input_layernorm.weight, self._eps)
         if cache is None:
             a = self.self_attn(a)
         else:
             a, cache = self.self_attn.forward_paged(
                 a, cache, block_tables, cache_lens, ragged_meta)
-        h = h + a
-        h = h + self.mlp(F.rms_norm(
-            h, self.post_attention_layernorm.weight, self._eps))
+        with component("norm"):     # the residual stream, then its norm
+            h = h + a
+            a = F.rms_norm(h, self.post_attention_layernorm.weight,
+                           self._eps)
+        a = self.mlp(a)
+        with component("norm"):
+            h = h + a
         return h if cache is None else (h, cache)
 
 
@@ -493,17 +517,20 @@ class DeepseekV3Model(Layer):
 
     def forward(self, input_ids, caches=None, block_tables=None,
                 cache_lens=None, ragged_meta=None):
-        h = F.embedding(input_ids, self.embed_tokens.weight)
+        with component("embed"):
+            h = F.embedding(input_ids, self.embed_tokens.weight)
         if caches is None:
             for layer in self.layers:
                 h = layer(h)
             return self._final_norm(h)
         new_caches = []
-        for layer, cache in zip(self.layers, caches):
-            h, cache = layer(h, cache, block_tables, cache_lens,
-                             ragged_meta)
+        for i, (layer, cache) in enumerate(zip(self.layers, caches)):
+            with component(f"L{i}.mla"):
+                h, cache = layer(h, cache, block_tables, cache_lens,
+                                 ragged_meta)
             new_caches.append(cache)
-        return self._final_norm(h), new_caches
+        with component("norm"):
+            return self._final_norm(h), new_caches
 
 
 class DeepseekV3ForCausalLM(Layer, GenerationMixin):
@@ -561,7 +588,8 @@ class DeepseekV3ForCausalLM(Layer, GenerationMixin):
             h, new_caches = self.model(
                 input_ids, caches=caches, block_tables=block_tables,
                 cache_lens=cache_lens, ragged_meta=ragged_meta)
-            return self._logits(h), new_caches
+            with component("head"):
+                return self._logits(h), new_caches
         logits = self._logits(self.model(input_ids))
         return logits if labels is None \
             else self.criterion(logits, labels)

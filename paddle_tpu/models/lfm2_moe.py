@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..framework.core import apply_jax, as_jax
+from ..framework.core import apply_jax, as_jax, component
 from ..nn import functional as F
 from ..nn.layer.container import LayerList
 from ..nn.layer.layers import Layer
@@ -185,18 +185,22 @@ class Lfm2ShortConv(Layer):
         the last ``L - 1`` rows of ``g`` that slot ``s`` has seen. The
         taps over the packed rows and the state's gather and scatter
         are ``ops/short_conv.ragged_causal_taps`` (which rows read the
-        state, which zeros, which the null seat); they and the gating
-        run under the scope ``short_conv``, the two projections stay
-        outside it. Returns ``(out, cache)``."""
+        state, which zeros, which the null seat): they and the gating
+        are the component ``mixer.glue`` (the table's relayouts
+        ``cache``), the two projections ``mixer.in`` and
+        ``mixer.out``. Returns ``(out, cache)``."""
         from ..ops.paged_cache import SlotState
 
         def f(x_a, w_in, w, w_out, state, ql, rs, sl, pos):
-            b_, c_, z = jnp.split(x_a[0] @ w_in, 3, axis=-1)
-            with jax.named_scope("short_conv"):
+            with component("mixer.in"):
+                bcz = x_a[0] @ w_in
+            with component("mixer.glue"):
+                b_, c_, z = jnp.split(bcz, 3, axis=-1)
                 conv, new = ragged_causal_taps(
                     b_ * z, state.data, w, (ql, rs, sl, pos))
                 y = c_ * conv.astype(x_a.dtype)
-            return (y @ w_out)[None], new
+            with component("mixer.out"):
+                return (y @ w_out)[None], new
 
         ql, rs, sl, pos = ragged_meta[:4]
         out, state = apply_jax(
@@ -228,13 +232,21 @@ class Lfm2Attention(Layer):
         and then rotated."""
         c = self.config
         r, d = x.shape[0], c.head_dim
-        q = _rms((x @ wq).reshape(r, c.num_attention_heads, d), qn,
-                 c.norm_eps)
-        k = _rms((x @ wk).reshape(r, c.num_key_value_heads, d), kn,
-                 c.norm_eps)
-        v = (x @ wv).reshape(r, c.num_key_value_heads, d)
-        return (_rope(q, pos, c.rope_theta), _rope(k, pos, c.rope_theta),
-                v)
+        with component("mixer.in"):
+            q = x @ wq
+        with component("mixer.glue"):
+            q = _rms(q.reshape(r, c.num_attention_heads, d), qn,
+                     c.norm_eps)
+        with component("mixer.in"):
+            k = x @ wk
+        with component("mixer.glue"):
+            k = _rms(k.reshape(r, c.num_key_value_heads, d), kn,
+                     c.norm_eps)
+        with component("mixer.in"):
+            v = (x @ wv).reshape(r, c.num_key_value_heads, d)
+        with component("mixer.glue"):
+            return (_rope(q, pos, c.rope_theta),
+                    _rope(k, pos, c.rope_theta), v)
 
     def forward(self, x):
         """No cache: plain causal softmax over ``x [B, T, hidden]``."""
@@ -270,13 +282,16 @@ class Lfm2Attention(Layer):
 
         def f(x_a, wq, wk, wv, wo, qn, kn, kp, vp, tables, lens, ql, rs,
               sl, pos_r, nwin, win):
-            pos = jnp.clip(pos_r.astype(jnp.int32), 0,
-                           c.max_position_embeddings - 1)
+            with component("mixer.glue"):
+                pos = jnp.clip(pos_r.astype(jnp.int32), 0,
+                               c.max_position_embeddings - 1)
             q, k, v = self._project(x_a[0], pos, wq, wk, wv, qn, kn)
-            o, kp2, vp2 = ragged_paged_attention_decode(
-                q, k, v, kp, vp, tables, lens, ql, rs, sl, pos_r, nwin,
-                win, c.head_dim)
-            return (o.reshape(1, r, -1) @ wo), kp2, vp2
+            with component("mixer.glue"):
+                o, kp2, vp2 = ragged_paged_attention_decode(
+                    q, k, v, kp, vp, tables, lens, ql, rs, sl, pos_r,
+                    nwin, win, c.head_dim)
+            with component("mixer.out"):
+                return (o.reshape(1, r, -1) @ wo), kp2, vp2
 
         out, kp, vp = apply_jax(
             "lfm2_attention_paged", f, x, *self._weights(), cache[0],
@@ -297,8 +312,9 @@ class Lfm2MLP(Layer):
         self.w2 = _linear(config, f, h)
 
     def forward(self, x):
-        return apply_jax("swiglu_mlp", _swiglu, x, self.w1.weight,
-                         self.w3.weight, self.w2.weight)
+        with component("ffn"):
+            return apply_jax("swiglu_mlp", _swiglu, x, self.w1.weight,
+                             self.w3.weight, self.w2.weight)
 
 
 class _Router(Layer):
@@ -343,11 +359,12 @@ class Lfm2MoeSparseBlock(Layer):
             x2 = x_a.reshape(-1, x_a.shape[-1])
             # float32 as the GigaChat configuration's gate: on a TPU
             # that takes the highest matmul precision, not bf16 passes
-            logits = jnp.matmul(x2.astype(jnp.float32),
-                                wg.astype(jnp.float32),
-                                precision=jax.lax.Precision.HIGHEST)
-            if not c.use_expert_bias:
-                bias = jnp.zeros_like(bias)
+            with component("moe.gate"):
+                logits = jnp.matmul(x2.astype(jnp.float32),
+                                    wg.astype(jnp.float32),
+                                    precision=jax.lax.Precision.HIGHEST)
+                if not c.use_expert_bias:
+                    bias = jnp.zeros_like(bias)
             idx, w = group_limited_gate(
                 logits, bias, n_group=1, topk_group=1,
                 top_k=c.num_experts_per_tok,
@@ -382,7 +399,8 @@ class Lfm2MoeDecoderLayer(Layer):
 
     def forward(self, h, cache=None, block_tables=None, cache_lens=None,
                 ragged_meta=None):
-        a = F.rms_norm(h, self.operator_norm.weight, self._eps)
+        with component("norm"):
+            a = F.rms_norm(h, self.operator_norm.weight, self._eps)
         if cache is None:
             a = self.self_attn(a) if self.is_attention else self.conv(a)
         elif self.is_attention:
@@ -390,9 +408,12 @@ class Lfm2MoeDecoderLayer(Layer):
                 a, cache, block_tables, cache_lens, ragged_meta)
         else:
             a, cache = self.conv.forward_paged(a, cache, ragged_meta)
-        h = h + a
-        h = h + self.feed_forward(
-            F.rms_norm(h, self.ffn_norm.weight, self._eps))
+        with component("norm"):     # the residual stream, then its norm
+            h = h + a
+            a = F.rms_norm(h, self.ffn_norm.weight, self._eps)
+        a = self.feed_forward(a)
+        with component("norm"):
+            h = h + a
         return h if cache is None else (h, cache)
 
 
@@ -409,17 +430,20 @@ class Lfm2MoeModel(Layer):
 
     def forward(self, input_ids, caches=None, block_tables=None,
                 cache_lens=None, ragged_meta=None):
-        h = F.embedding(input_ids, self.embed_tokens.weight)
+        with component("embed"):
+            h = F.embedding(input_ids, self.embed_tokens.weight)
         new_caches = []
         for i, layer in enumerate(self.layers):
             if caches is None:
                 h = layer(h)
                 continue
-            h, cache = layer(h, caches[i], block_tables, cache_lens,
-                             ragged_meta)
+            with component(f"L{i}.{self.config.layer_types[i]}"):
+                h, cache = layer(h, caches[i], block_tables, cache_lens,
+                                 ragged_meta)
             new_caches.append(cache)
-        h = F.rms_norm(h, self.embedding_norm.weight,
-                       self.config.norm_eps)
+        with component("norm"):
+            h = F.rms_norm(h, self.embedding_norm.weight,
+                           self.config.norm_eps)
         return h if caches is None else (h, new_caches)
 
 
@@ -494,7 +518,8 @@ class Lfm2MoeForCausalLM(Layer, GenerationMixin):
             h, new_caches = self.model(
                 input_ids, caches=caches, block_tables=block_tables,
                 cache_lens=cache_lens, ragged_meta=ragged_meta)
-            return self._logits(h), new_caches
+            with component("head"):
+                return self._logits(h), new_caches
         logits = self._logits(self.model(input_ids))
         return logits if labels is None \
             else self.criterion(logits, labels)

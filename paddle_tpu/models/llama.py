@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..framework.core import Tensor, apply_jax, as_jax, _wrap_out
+from ..framework.core import (Tensor, apply_jax, as_jax, component,
+                              _wrap_out)
 from ..nn import functional as F
 from ..nn.initializer import Normal
 from ..nn.layer.layers import Layer
@@ -250,9 +251,10 @@ class LlamaAttention(Layer):
                 position_ids=None, block_tables=None, cache_lens=None,
                 ragged_meta=None):
         b, l, _ = hidden_states.shape
-        q = self.q_proj(hidden_states)
-        k = self.k_proj(hidden_states)
-        v = self.v_proj(hidden_states)
+        with component("mixer.in"):
+            q = self.q_proj(hidden_states)
+            k = self.k_proj(hidden_states)
+            v = self.v_proj(hidden_states)
 
         if kv_cache is not None and block_tables is not None \
                 and ragged_meta is not None:
@@ -312,8 +314,9 @@ class LlamaAttention(Layer):
         ctx, kp2, vp2 = self._attend_paged(q, k, v, rope_cos, rope_sin,
                                            kv_cache, block_tables,
                                            cache_lens, b, l)
-        ctx = constraint(ctx, None, None, "mp")
-        return self.o_proj(ctx), (kp2, vp2)
+        with component("mixer.out"):
+            ctx = constraint(ctx, None, None, "mp")
+            return self.o_proj(ctx), (kp2, vp2)
 
     def _attend_paged(self, q, k, v, rope_cos, rope_sin, kv_cache,
                       block_tables, cache_lens, b, l):
@@ -338,10 +341,11 @@ class LlamaAttention(Layer):
             return (out.reshape(b, l, self.num_heads * self.head_dim),
                     kp2, vp2)
 
-        return apply_jax(
-            "llama_attention_paged", attn_p, q, k, v, rope_cos, rope_sin,
-            kv_cache[0], kv_cache[1], block_tables, cache_lens,
-            n_outputs=3)
+        with component("mixer.glue"):
+            return apply_jax(
+                "llama_attention_paged", attn_p, q, k, v, rope_cos,
+                rope_sin, kv_cache[0], kv_cache[1], block_tables,
+                cache_lens, n_outputs=3)
 
     def _forward_ragged(self, q, k, v, rope_cos, rope_sin, kv_cache,
                         block_tables, cache_lens, ragged_meta, b, l):
@@ -356,8 +360,9 @@ class LlamaAttention(Layer):
                                             rope_sin, kv_cache,
                                             block_tables, cache_lens,
                                             ragged_meta, b, l)
-        ctx = constraint(ctx, None, None, "mp")
-        return self.o_proj(ctx), (kp2, vp2)
+        with component("mixer.out"):
+            ctx = constraint(ctx, None, None, "mp")
+            return self.o_proj(ctx), (kp2, vp2)
 
     def _attend_ragged(self, q, k, v, rope_cos, rope_sin, kv_cache,
                        block_tables, cache_lens, ragged_meta, b, l):
@@ -386,11 +391,12 @@ class LlamaAttention(Layer):
             return (out.reshape(b, l, self.num_heads * self.head_dim),
                     kp2, vp2)
 
-        return apply_jax(
-            "llama_attention_ragged", attn_r, q, k, v, rope_cos,
-            rope_sin, kv_cache[0], kv_cache[1], block_tables,
-            cache_lens, q_lens, row_starts, row_slot, row_pos,
-            narrow_iota, win_iota, n_outputs=3)
+        with component("mixer.glue"):
+            return apply_jax(
+                "llama_attention_ragged", attn_r, q, k, v, rope_cos,
+                rope_sin, kv_cache[0], kv_cache[1], block_tables,
+                cache_lens, q_lens, row_starts, row_slot, row_pos,
+                narrow_iota, win_iota, n_outputs=3)
 
     def _forward_cached(self, q, k, v, rope_cos, rope_sin, kv_cache,
                         offset, b, l, attention_mask=None,
@@ -460,7 +466,9 @@ class LlamaMLP(Layer):
             input_is_parallel=True)
 
     def forward(self, x):
-        return self.down_proj(swiglu(self.gate_proj(x), self.up_proj(x)))
+        with component("ffn"):
+            return self.down_proj(swiglu(self.gate_proj(x),
+                                         self.up_proj(x)))
 
 
 class LlamaDecoderLayer(Layer):
@@ -512,23 +520,25 @@ class LlamaDecoderLayer(Layer):
         attn = self.self_attn
         b, l, _ = hidden_states.shape
         eps = self.input_layernorm._epsilon
-        q, k, v = _df.norm_matmul(
-            hidden_states, self.input_layernorm.weight, None,
-            [attn.q_proj.weight, attn.k_proj.weight,
-             attn.v_proj.weight],
-            [attn.q_proj.bias, attn.k_proj.bias, attn.v_proj.bias],
-            eps=eps, kind="rms")
-        if _lora.armed(attn.q_proj) or _lora.armed(attn.k_proj) \
-                or _lora.armed(attn.v_proj):
-            # multi-LoRA serving composes per MODULE: the fused
-            # prologue stays; the armed projections add their ragged
-            # grouped-matmul delta off the recomputed norm (bitwise
-            # the norm the unfused module path feeds them, so fused
-            # ON==OFF stays token-exact under adapters too)
-            hn = self.input_layernorm(hidden_states)
-            q = _lora.apply(attn.q_proj, hn, q)
-            k = _lora.apply(attn.k_proj, hn, k)
-            v = _lora.apply(attn.v_proj, hn, v)
+        with component("mixer.in"):
+            q, k, v = _df.norm_matmul(
+                hidden_states, self.input_layernorm.weight, None,
+                [attn.q_proj.weight, attn.k_proj.weight,
+                 attn.v_proj.weight],
+                [attn.q_proj.bias, attn.k_proj.bias, attn.v_proj.bias],
+                eps=eps, kind="rms")
+            if _lora.armed(attn.q_proj) or _lora.armed(attn.k_proj) \
+                    or _lora.armed(attn.v_proj):
+                # multi-LoRA serving composes per MODULE: the fused
+                # prologue stays; the armed projections add their
+                # ragged grouped-matmul delta off the recomputed norm
+                # (bitwise the norm the unfused module path feeds
+                # them, so fused ON==OFF stays token-exact under
+                # adapters too)
+                hn = self.input_layernorm(hidden_states)
+                q = _lora.apply(attn.q_proj, hn, q)
+                k = _lora.apply(attn.k_proj, hn, k)
+                v = _lora.apply(attn.v_proj, hn, v)
         if ragged_meta is not None:
             ctx, kp2, vp2 = attn._attend_ragged(
                 q, k, v, rope_cos, rope_sin, kv_cache, block_tables,
@@ -537,29 +547,31 @@ class LlamaDecoderLayer(Layer):
             ctx, kp2, vp2 = attn._attend_paged(
                 q, k, v, rope_cos, rope_sin, kv_cache, block_tables,
                 cache_lens, b, l)
-        if _lora.armed(attn.o_proj):
-            # an armed epilogue falls back to module call + residual
-            # add (the unfused ordering — module forward applies the
-            # delta), keeping the prologue fusions above
-            h = hidden_states + attn.o_proj(ctx)
-        else:
-            h = _df.matmul_residual([ctx], attn.o_proj.weight,
-                                    attn.o_proj.bias, hidden_states)
+        with component("mixer.out"):
+            if _lora.armed(attn.o_proj):
+                # an armed epilogue falls back to module call +
+                # residual add (the unfused ordering — module forward
+                # applies the delta), keeping the prologue fusions above
+                h = hidden_states + attn.o_proj(ctx)
+            else:
+                h = _df.matmul_residual([ctx], attn.o_proj.weight,
+                                        attn.o_proj.bias, hidden_states)
         mlp = self.mlp
-        g, u = _df.norm_matmul(
-            h, self.post_attention_layernorm.weight, None,
-            [mlp.gate_proj.weight, mlp.up_proj.weight], [None, None],
-            eps=self.post_attention_layernorm._epsilon, kind="rms")
-        if _lora.armed(mlp.gate_proj) or _lora.armed(mlp.up_proj):
-            hn2 = self.post_attention_layernorm(h)
-            g = _lora.apply(mlp.gate_proj, hn2, g)
-            u = _lora.apply(mlp.up_proj, hn2, u)
-        if _lora.armed(mlp.down_proj):
-            out = h + mlp.down_proj(swiglu(g, u))
-        else:
-            out = _df.matmul_residual([g, u], mlp.down_proj.weight,
-                                      mlp.down_proj.bias, h,
-                                      act="swiglu")
+        with component("ffn"):
+            g, u = _df.norm_matmul(
+                h, self.post_attention_layernorm.weight, None,
+                [mlp.gate_proj.weight, mlp.up_proj.weight], [None, None],
+                eps=self.post_attention_layernorm._epsilon, kind="rms")
+            if _lora.armed(mlp.gate_proj) or _lora.armed(mlp.up_proj):
+                hn2 = self.post_attention_layernorm(h)
+                g = _lora.apply(mlp.gate_proj, hn2, g)
+                u = _lora.apply(mlp.up_proj, hn2, u)
+            if _lora.armed(mlp.down_proj):
+                out = h + mlp.down_proj(swiglu(g, u))
+            else:
+                out = _df.matmul_residual([g, u], mlp.down_proj.weight,
+                                          mlp.down_proj.bias, h,
+                                          act="swiglu")
         return out, (kp2, vp2)
 
     def forward(self, hidden_states, rope_cos, rope_sin,
@@ -572,7 +584,8 @@ class LlamaDecoderLayer(Layer):
                 hidden_states, rope_cos, rope_sin, kv_cache,
                 block_tables, cache_lens, ragged_meta)
         residual = hidden_states
-        h = self.input_layernorm(hidden_states)
+        with component("norm"):
+            h = self.input_layernorm(hidden_states)
         new_cache = None
         if kv_cache is not None:
             h, new_cache = self.self_attn(h, rope_cos, rope_sin,
@@ -588,11 +601,13 @@ class LlamaDecoderLayer(Layer):
             from jax.ad_checkpoint import checkpoint_name
             h = apply_jax("attn_out_tag",
                           lambda a: checkpoint_name(a, "attn_out"), h)
-        h = residual + h
-        residual = h
-        h2 = self.post_attention_layernorm(h)
+        with component("norm"):     # the residual stream, then its norm
+            h = residual + h
+            residual = h
+            h2 = self.post_attention_layernorm(h)
         h2 = self.mlp(h2)
-        out = residual + h2
+        with component("norm"):
+            out = residual + h2
         if kv_cache is not None:
             return out, new_cache
         return out
@@ -619,7 +634,8 @@ class LlamaModel(Layer):
                 caches=None, offset=None, block_tables=None,
                 cache_lens=None, ragged_meta=None):
         input_ids = batch_shard(input_ids)
-        h = self.embed_tokens(input_ids)
+        with component("embed"):
+            h = self.embed_tokens(input_ids)
         if caches is not None:
             # decode path: full rope tables + per-layer kv caches
             # (dense [B, S, H, D] pairs, or — with block_tables — the
@@ -627,15 +643,17 @@ class LlamaModel(Layer):
             # ragged_meta, ONE packed mixed-batch row buffer)
             cos, sin = self._rope_cos, self._rope_sin
             new_caches = []
-            for layer, kv in zip(self.layers, caches):
-                h, kv2 = layer(h, cos, sin, attention_mask,
-                               kv_cache=kv, offset=offset,
-                               position_ids=position_ids,
-                               block_tables=block_tables,
-                               cache_lens=cache_lens,
-                               ragged_meta=ragged_meta)
+            for i, (layer, kv) in enumerate(zip(self.layers, caches)):
+                with component(f"L{i}.attn"):
+                    h, kv2 = layer(h, cos, sin, attention_mask,
+                                   kv_cache=kv, offset=offset,
+                                   position_ids=position_ids,
+                                   block_tables=block_tables,
+                                   cache_lens=cache_lens,
+                                   ragged_meta=ragged_meta)
                 new_caches.append(kv2)
-            return self.norm(h), new_caches
+            with component("norm"):
+                return self.norm(h), new_caches
         l = h.shape[1]
         cos = _wrap_out(as_jax(self._rope_cos)[:l])
         sin = _wrap_out(as_jax(self._rope_sin)[:l])
@@ -708,9 +726,9 @@ class LlamaForCausalLM(Layer, GenerationMixin):
                                        block_tables=block_tables,
                                        cache_lens=cache_lens,
                                        ragged_meta=ragged_meta)
-            if return_hidden:
-                return (self._head_and_loss(h, None), h), new_caches
-            return self._head_and_loss(h, None), new_caches
+            with component("head"):
+                logits = self._head_and_loss(h, None)
+            return ((logits, h) if return_hidden else logits), new_caches
         h = self.llama(input_ids, attention_mask, position_ids)
         return self._head_and_loss(h, labels)
 

@@ -58,7 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..framework.core import apply_jax, as_jax
+from ..framework.core import apply_jax, as_jax, component
 from ..nn import functional as F
 from ..nn.layer.container import LayerList
 from ..nn.layer.layers import Layer
@@ -223,7 +223,8 @@ class KimiDeltaAttention(Layer):
 
     def _inputs(self, u, wq, wk, wv):
         """The convolution's input: ``u W_q | u W_k | u W_v``."""
-        return jnp.concatenate([u @ wq, u @ wk, u @ wv], axis=-1)
+        with component("mixer.in"):
+            return jnp.concatenate([u @ wq, u @ wk, u @ wv], axis=-1)
 
     def _operands(self, u, conv, wfa, wfb, wb, a_log, dt_bias):
         """``(q, k, v, g, beta)`` of rows ``u [..., hidden]`` from the
@@ -232,25 +233,36 @@ class KimiDeltaAttention(Layer):
         c = self.config
         heads, d = c.kda_heads, c.kda_head_dim
         lead = u.shape[:-1]
-        q, k, v = (x.reshape(lead + (heads, d)) for x in jnp.split(
-            jax.nn.silu(conv), 3, axis=-1))
-        q = _l2norm(q) * np.float32(d ** -0.5)
-        k = _l2norm(k)
-        dt = ((u @ wfa) @ wfb).astype(jnp.float32) \
-            + dt_bias.astype(jnp.float32)
-        g = -jnp.exp(a_log.astype(jnp.float32))[:, None] \
-            * jax.nn.softplus(dt).reshape(lead + (heads, d))
-        beta = jax.nn.sigmoid((u @ wb).astype(jnp.float32))
-        if c.kda_allow_neg_eigval:
-            beta = beta * np.float32(2.0)
-        return q, k, v, g, beta
+        with component("mixer.glue"):
+            q, k, v = (x.reshape(lead + (heads, d)) for x in jnp.split(
+                jax.nn.silu(conv), 3, axis=-1))
+            q = _l2norm(q) * np.float32(d ** -0.5)
+            k = _l2norm(k)
+        with component("mixer.in"):     # the decay's low-rank pair
+            dt = (u @ wfa) @ wfb
+        with component("mixer.glue"):
+            dt = dt.astype(jnp.float32) + dt_bias.astype(jnp.float32)
+            g = -jnp.exp(a_log.astype(jnp.float32))[:, None] \
+                * jax.nn.softplus(dt).reshape(lead + (heads, d))
+        with component("mixer.in"):
+            beta = u @ wb
+        with component("mixer.glue"):
+            beta = jax.nn.sigmoid(beta.astype(jnp.float32))
+            if c.kda_allow_neg_eigval:
+                beta = beta * np.float32(2.0)
+            return q, k, v, g, beta
 
     def _output(self, u, o, wga, wgb, o_norm, wo):
         """``(rms_head(o) * sigmoid(u W_g1 W_g2)) W_o``."""
-        gate = jax.nn.sigmoid(((u @ wga) @ wgb).astype(jnp.float32))
-        o = _rms(o, o_norm.astype(jnp.float32), self.config.rms_norm_eps)
-        y = o.reshape(gate.shape) * gate
-        return y.astype(u.dtype) @ wo
+        with component("mixer.in"):     # the gate's low-rank pair
+            gate = (u @ wga) @ wgb
+        with component("mixer.glue"):
+            gate = jax.nn.sigmoid(gate.astype(jnp.float32))
+            o = _rms(o, o_norm.astype(jnp.float32),
+                     self.config.rms_norm_eps)
+            y = (o.reshape(gate.shape) * gate).astype(u.dtype)
+        with component("mixer.out"):
+            return y @ wo
 
     def forward(self, x):
         """No cache: the whole sequence ``x [B, T, hidden]``, the
@@ -296,12 +308,16 @@ class KimiDeltaAttention(Layer):
         def f(x_a, wq, wk, wv, cq, ck, cv, wfa, wfb, wga, wgb, wb, a_log,
               dt_bias, o_norm, wo, taps_state, rec_state, *meta):
             u = x_a[0]
-            conv, taps_new = ragged_causal_taps(
-                self._inputs(u, wq, wk, wv), taps_state.data,
-                jnp.concatenate([cq, ck, cv]), meta)
+            pre = self._inputs(u, wq, wk, wv)
+            with component("mixer.glue"):
+                conv, taps_new = ragged_causal_taps(
+                    pre, taps_state.data, jnp.concatenate([cq, ck, cv]),
+                    meta)
             q, k, v, g, beta = self._operands(u, conv, wfa, wfb, wb,
                                               a_log, dt_bias)
-            o, rec_new = kda_step(q, k, v, g, beta, rec_state.data, meta)
+            with component("mixer.glue"):
+                o, rec_new = kda_step(q, k, v, g, beta, rec_state.data,
+                                      meta)
             return (self._output(u, o, wga, wgb, o_norm, wo)[None],
                     taps_new, rec_new)
 
@@ -332,9 +348,13 @@ class SolarGatedAttention(Layer):
         plain ``attn W_o``."""
         o = o.reshape(u.shape[:-1] + (-1,))
         if self.config.use_gqa_gate:
-            gate = jax.nn.sigmoid((u @ wg).astype(jnp.float32))
-            o = (o.astype(jnp.float32) * gate).astype(u.dtype)
-        return o @ wo
+            with component("mixer.in"):
+                gate = u @ wg
+            with component("mixer.glue"):
+                gate = jax.nn.sigmoid(gate.astype(jnp.float32))
+                o = (o.astype(jnp.float32) * gate).astype(u.dtype)
+        with component("mixer.out"):
+            return o @ wo
 
     def forward(self, x):
         """No cache: plain causal softmax over ``x [B, T, hidden]``, no
@@ -368,12 +388,14 @@ class SolarGatedAttention(Layer):
         def f(x_a, wq, wk, wv, wg, wo, kp, vp, tables, lens, ql, rs, sl,
               pos_r, nwin, win):
             u = x_a[0]
-            q = (u @ wq).reshape(r, c.num_attention_heads, d)
-            k = (u @ wk).reshape(r, c.num_key_value_heads, d)
-            v = (u @ wv).reshape(r, c.num_key_value_heads, d)
-            o, kp2, vp2 = ragged_paged_attention_decode(
-                q, k, v, kp, vp, tables, lens, ql, rs, sl, pos_r, nwin,
-                win, d)
+            with component("mixer.in"):
+                q = (u @ wq).reshape(r, c.num_attention_heads, d)
+                k = (u @ wk).reshape(r, c.num_key_value_heads, d)
+                v = (u @ wv).reshape(r, c.num_key_value_heads, d)
+            with component("mixer.glue"):
+                o, kp2, vp2 = ragged_paged_attention_decode(
+                    q, k, v, kp, vp, tables, lens, ql, rs, sl, pos_r,
+                    nwin, win, d)
             return self._gated(u, o, wg, wo)[None], kp2, vp2
 
         out, kp, vp = apply_jax(
@@ -400,7 +422,8 @@ class SolarOpen2DecoderLayer(Layer):
 
     def forward(self, h, cache=None, block_tables=None, cache_lens=None,
                 ragged_meta=None):
-        a = F.rms_norm(h, self.input_layernorm.weight, self._eps)
+        with component("norm"):
+            a = F.rms_norm(h, self.input_layernorm.weight, self._eps)
         if cache is None:
             a = self.self_attn(a) if self.is_gqa else self.linear_attn(a)
         elif self.is_gqa:
@@ -409,9 +432,13 @@ class SolarOpen2DecoderLayer(Layer):
         else:
             a, cache = self.linear_attn.forward_paged(a, cache,
                                                       ragged_meta)
-        h = h + a
-        h = h + self.mlp(F.rms_norm(
-            h, self.post_attention_layernorm.weight, self._eps))
+        with component("norm"):     # the residual stream, then its norm
+            h = h + a
+            a = F.rms_norm(h, self.post_attention_layernorm.weight,
+                           self._eps)
+        a = self.mlp(a)
+        with component("norm"):
+            h = h + a
         return h if cache is None else (h, cache)
 
 
@@ -428,16 +455,19 @@ class SolarOpen2Model(Layer):
 
     def forward(self, input_ids, caches=None, block_tables=None,
                 cache_lens=None, ragged_meta=None):
-        h = F.embedding(input_ids, self.embed_tokens.weight)
+        with component("embed"):
+            h = F.embedding(input_ids, self.embed_tokens.weight)
         new_caches = []
         for i, layer in enumerate(self.layers):
             if caches is None:
                 h = layer(h)
                 continue
-            h, cache = layer(h, caches[i], block_tables, cache_lens,
-                             ragged_meta)
+            with component(f"L{i}.{'gqa' if layer.is_gqa else 'kda'}"):
+                h, cache = layer(h, caches[i], block_tables, cache_lens,
+                                 ragged_meta)
             new_caches.append(cache)
-        h = F.rms_norm(h, self.norm.weight, self.config.rms_norm_eps)
+        with component("norm"):
+            h = F.rms_norm(h, self.norm.weight, self.config.rms_norm_eps)
         return h if caches is None else (h, new_caches)
 
 
@@ -512,7 +542,8 @@ class SolarOpen2ForCausalLM(Layer, GenerationMixin):
             h, new_caches = self.model(
                 input_ids, caches=caches, block_tables=block_tables,
                 cache_lens=cache_lens, ragged_meta=ragged_meta)
-            return self.lm_head(h), new_caches
+            with component("head"):
+                return self.lm_head(h), new_caches
         logits = self.lm_head(self.model(input_ids))
         return logits if labels is None \
             else self.criterion(logits, labels)

@@ -34,8 +34,9 @@ import sys
 from .registry import (Counter, Gauge, Histogram, Info, Registry,
                        get_registry, metrics_dir, metrics_enabled,
                        prometheus_path)
-from .accounting import (DEVICE_PEAKS, analytic_mfu, collective_census,
-                         device_peaks, executable_cost, kernel_census,
+from .accounting import (COMPONENTS, DEVICE_PEAKS, analytic_mfu,
+                         collective_census, component_map, device_peaks,
+                         executable_cost, kernel_census,
                          record_compiled_step, sample_device_memory,
                          step_report, step_reports)
 from .digest import LatencyDigest, P2Quantile
@@ -54,6 +55,7 @@ __all__ = [
     "LatencyDigest", "P2Quantile", "Tracer", "tracing_enabled",
     "ProfilerWindow", "next_flow_id",
     "record_compiled_step", "collective_census", "kernel_census",
+    "component_map", "COMPONENTS",
     "step_report", "step_reports", "sample_device_memory",
     "analytic_mfu", "DEVICE_PEAKS", "device_peaks", "executable_cost",
     "ALERT_SEVERITY", "BurnRateMonitor", "CollapseDetector",

@@ -30,7 +30,8 @@ import numpy as np
 from .registry import get_registry
 
 __all__ = ["record_compiled_step", "collective_census",
-           "kernel_census", "step_report", "step_reports",
+           "kernel_census", "component_map", "COMPONENTS",
+           "step_report", "step_reports",
            "sample_device_memory", "analytic_mfu",
            "DEVICE_PEAKS", "device_peaks", "executable_cost"]
 
@@ -145,6 +146,19 @@ _HLO_OP_NAME_RE = _re.compile(r'op_name="([^"]+)"')
 _HLO_TRANSFORM_RE = _re.compile(r"\w+\((.*)\)")   # jvp(...), transpose(...)
 
 
+def _kernel_scope(op_name: str) -> str:
+    """The ``kernel_scope`` a Mosaic call was invoked under: the path
+    segment before its ``pallas_call``, with the transformations it was
+    traced under taken off (``transpose(jvp(<scope>))`` for a backward
+    kernel); ``?`` where there is none."""
+    parts = op_name.split("/")
+    calls = [i for i, p in enumerate(parts) if p.startswith("pallas_call")]
+    name = parts[calls[-1] - 1] if calls and calls[-1] > 0 else ""
+    while (w := _HLO_TRANSFORM_RE.fullmatch(name)) is not None:
+        name = w.group(1)
+    return name or "?"
+
+
 def _mosaic_kernels(hlo_text: str) -> Dict[str, int]:
     """The Mosaic (Pallas) kernels a COMPILED program holds, in every
     computation (loop and shard_map bodies included): ``{name: count}``
@@ -157,35 +171,282 @@ def _mosaic_kernels(hlo_text: str) -> Dict[str, int]:
     with no scope of its own counts under the enclosing component."""
     names: Dict[str, int] = {}
     for line in hlo_text.splitlines():
-        if 'custom_call_target="tpu_custom_call"' not in line:
+        if _MOSAIC_TARGET not in line:
             continue
         m = _HLO_OP_NAME_RE.search(line)
-        parts = m.group(1).split("/") if m else []
-        calls = [i for i, p in enumerate(parts)
-                 if p.startswith("pallas_call")]
-        name = parts[calls[-1] - 1] if calls and calls[-1] > 0 else ""
-        while (w := _HLO_TRANSFORM_RE.fullmatch(name)) is not None:
-            name = w.group(1)
-        name = name or "?"
+        name = _kernel_scope(m.group(1) if m else "")
         names[name] = names.get(name, 0) + 1
     return dict(sorted(names.items()))
+
+
+# -- which part of the model an instruction came from -------------------------
+#
+# The taxonomy of components, defined here ONCE (tabled in docs/OPS.md
+# "Components of the tick executable"). A model's paged path enters them
+# as ``framework.core.component(name)`` scopes while an engine traces an
+# executable; ``component_map`` reads them back out of the compiled
+# program's op metadata, so a device event (named by its instruction)
+# joins the part of the model it belongs to.
+COMPONENTS = (
+    "embed",         # token ids -> rows of the embedding table
+    "norm",          # the layer norms on the residual stream, final norm
+    "mixer.in",      # q | k | v, in_proj, low-rank decay and gate products
+    "mixer.glue",    # rope, QK-norm, gating, silu and split, chunk slabs,
+    #                  the query tiles around an attention kernel
+    "mixer.out",     # o_proj / out_proj (and what XLA fuses into it)
+    "ffn",           # dense FFN and shared expert
+    "moe.gate",      # router logits, sort, top-k, pair ranks
+    "moe.dispatch",  # rows gathered into the expert-major pair buffer
+    "moe.experts",   # the grouped matmuls where no kernel takes them,
+    #                  and the activation between the two
+    "moe.combine",   # pairs gathered back, weighted and summed
+    "cache",         # pool and slot-state writes, relayouts, table gathers
+    "head",          # the LM head
+    "sample",        # last-row gather, filtering, token choice, health probe
+    "tick.io",       # carry, packed operands, outputs, routing counters
+)
+_LAYER_RE = _re.compile(r"L(\d+)\.(\w+)")
+_MOSAIC_TARGET = 'custom_call_target="tpu_custom_call"'
+_HLO_COMMENT_RE = _re.compile(r"/\*.*?\*/")
+_HLO_LAYOUT_RE = _re.compile(r"\{[^{}]*\}")
+_HLO_NAME_RE = _re.compile(r"\s+(ROOT\s+)?%?([\w\.\-]+) = ")
+_HLO_OPCODE_RE = _re.compile(r" ?([a-zA-Z][\w\-]*)\(")
+_HLO_CALLEE_RE = _re.compile(
+    r"(?:calls|to_apply|body|condition)=%?([\w\.\-]+)"
+    r"|(?:branch_computations|called_computations)=\{([^}]*)\}")
+_HLO_ARRAY_RE = _re.compile(r"([a-z]+\d*)\[([\d,]*)\]")
+# data movement the compiler inserts with no metadata of its own: named
+# ``copy`` where it carries no scope, never ``unnamed``
+_HLO_MOVE_OPS = {"copy", "bitcast-convert", "slice", "dynamic-slice",
+                 "transpose", "reshape", "concatenate", "pad", "broadcast",
+                 "iota"}
+# ... and the custom calls that only re-assemble such movement (async
+# slices of one array joined again)
+_HLO_MOVE_TARGETS = ('custom_call_target="ConcatBitcast"',)
+_HLO_CONTROL_OPS = {"while", "conditional", "call"}
+_ASYNC_SUFFIXES = ("-start", "-done", "-update")
+
+
+def _parse_instruction(line: str):
+    """``(name, is_root, result shape, opcode, rest of the line)`` of one
+    HLO instruction line, or None. The result shape is the type as
+    printed without layouts, comments or spaces; a tuple type may hold
+    ``/*index=5*/`` comments and nested parentheses."""
+    m = _HLO_NAME_RE.match(line)
+    if m is None:
+        return None
+    at = m.end()
+    if line.startswith("(", at):
+        depth, end = 0, at
+        for end in range(at, len(line)):
+            c = line[end]
+            if c == "(":
+                depth += 1
+            elif c == ")":
+                depth -= 1
+                if not depth:
+                    break
+        end += 1
+    else:
+        end = line.find(" ", at)
+        if end < 0:
+            return None
+    om = _HLO_OPCODE_RE.match(line, end)
+    if om is None:
+        return None
+    shape = _HLO_LAYOUT_RE.sub("", _HLO_COMMENT_RE.sub("", line[at:end]))
+    return (m.group(2), m.group(1) is not None, shape.replace(" ", ""),
+            om.group(1), line[om.end():])
+
+
+def _shape_bytes(shape: str) -> int:
+    """Bytes of every array in a result shape (``bf16[352,4096]``)."""
+    total = 0
+    for dtype, dims in _HLO_ARRAY_RE.findall(shape):
+        bits = "".join(c for c in dtype if c.isdigit())
+        n = 1
+        for d in dims.split(","):
+            n *= int(d) if d else 1
+        total += n * (max(int(bits), 8) if bits else 8) // 8
+    return total
+
+
+def _path_component(op_name: str):
+    """``(component, layer)`` of one op-metadata path: its INNERMOST
+    taxonomy segment and its innermost ``L<n>.<kind>`` segment, each
+    None where the path holds none."""
+    comp = layer = None
+    for seg in op_name.split("/"):
+        while (w := _HLO_TRANSFORM_RE.fullmatch(seg)) is not None:
+            seg = w.group(1)
+        if seg in COMPONENTS:
+            comp = seg
+        elif _LAYER_RE.fullmatch(seg):
+            layer = seg
+    return comp, layer
+
+
+def _base_opcode(op: str) -> str:
+    for suffix in _ASYNC_SUFFIXES:
+        if op.endswith(suffix):
+            return op[:-len(suffix)]
+    return op
+
+
+def component_map(hlo_text: str) -> List[dict]:
+    """Which component of the model each instruction of a compiled
+    program's ENTRY computation came from: one ``{name, opcode, shape,
+    bytes, component, layer, also}`` for every instruction that is not
+    bookkeeping (``_HLO_SKIP_OPS``), in program order; after them the
+    instructions of the computations ENTRY runs through control flow
+    (``while``, ``conditional``, ``call``), which a device executes as
+    events of their own.
+
+    - ``name``: the instruction's name as a device trace spells it (no
+      ``%``); ``shape``: its result type without layouts; ``bytes``: of
+      that result; ``opcode``: an async pair (``copy-start`` /
+      ``copy-done``) under its one base name.
+    - ``component``: from the scope path (op metadata ``op_name``) of
+      the instruction's HEAVIEST member — the ``convolution`` / ``dot``
+      of a fused (or otherwise called) computation where it holds one,
+      else the called computation's root, else the instruction itself,
+      else the ONE component its members carry where they all agree; a
+      Mosaic call is ``kernel:<kernel_scope>``; a ``-done`` takes its
+      ``-start``'s; bare data movement with no scope is ``copy``; and
+      an instruction whose path holds no taxonomy segment, or whose
+      members disagree, is ``unnamed`` — nothing is chosen among
+      candidates (``also`` still says what its body holds).
+    - ``layer``: the innermost ``L<n>.<kind>`` segment of the same path
+      (``L3.kda``), or None.
+    - ``also``: the OTHER components found in the bodies of the
+      computations the instruction calls (what else XLA fused in)."""
+    comps: Dict[str, list] = {}     # computation -> parsed instructions
+    entry = current = None
+    for line in hlo_text.splitlines():
+        if not line:
+            continue
+        if not line[0].isspace():
+            if line.endswith("{"):
+                head = line.split(None, 2)
+                is_entry = head[0] == "ENTRY"
+                current = head[1 if is_entry else 0].lstrip("%")
+                comps[current] = []
+                if is_entry:
+                    entry = current
+            continue
+        if current is None:
+            continue
+        ins = _parse_instruction(line)
+        if ins is not None:
+            comps[current].append(ins)
+    if entry is None:
+        return []
+
+    def op_name(rest):
+        m = _HLO_OP_NAME_RE.search(rest)
+        return m.group(1) if m else ""
+
+    def callees(rest):
+        out = []
+        for one, many in _HLO_CALLEE_RE.findall(rest):
+            out += [one] if one else \
+                [c.strip().lstrip("%") for c in many.split(",")]
+        return [c for c in out if c in comps]
+
+    members_of: Dict[str, list] = {}
+
+    def members(comp):
+        """Every instruction of ``comp`` and of what it calls."""
+        if comp not in members_of:
+            members_of[comp] = out = []
+            for ins in comps[comp]:
+                out.append(ins)
+                for c in callees(ins[4]):
+                    out += members(c)
+        return members_of[comp]
+
+    rows, by_name = [], {}
+    # the ENTRY computation, then what it runs through control flow (a
+    # ``while``'s body and condition, a ``conditional``'s branches, a
+    # ``call``): their instructions are device events of their own
+    todo, seen = [entry], {entry}
+    program = []
+    while todo:
+        comp_name = todo.pop(0)
+        for ins in comps[comp_name]:
+            program.append(ins)
+            if ins[3] in _HLO_CONTROL_OPS:
+                for c in callees(ins[4]):
+                    if c not in seen:
+                        seen.add(c)
+                        todo.append(c)
+    for name, _root, shape, op, rest in program:
+        if op in _HLO_SKIP_OPS:
+            continue
+        called = [] if op in _HLO_CONTROL_OPS else callees(rest)
+        inner = [m for c in called for m in members(c)]
+        heavy = next((m for m in inner
+                      if m[3] in ("convolution", "dot")), None)
+        if heavy is None and called and op == "fusion":
+            heavy = next((m for m in comps[called[0]] if m[1]), None)
+        comp = layer = None
+        for path in ((op_name(heavy[4]),) if heavy else ()) \
+                + (op_name(rest),):
+            c, l = _path_component(path)
+            comp, layer = comp or c, layer or l
+        if _MOSAIC_TARGET in rest:
+            comp = "kernel:" + _kernel_scope(op_name(rest))
+        base = _base_opcode(op)
+        if comp is None and base != op and op.endswith("-done"):
+            first = rest.split(")", 1)[0].split(",")[0].strip().lstrip("%")
+            start = by_name.get(first)
+            if start is not None:
+                comp, layer = start["component"], layer or start["layer"]
+        paths = {_path_component(op_name(m[4])) for m in inner}
+        also = {c for c, _l in paths if c is not None}
+        if comp is None and len(also) == 1:
+            # its root and its own path name nothing (a multi-output
+            # fusion's tuple; a rewrite that kept no metadata), and the
+            # members that carry a component all carry this one
+            (comp,) = also
+            inner_layers = {l for c, l in paths if c is not None}
+            if layer is None and len(inner_layers) == 1:
+                (layer,) = inner_layers
+        if comp is None:
+            # (a fusion or an ``async-start`` whose body only moves data
+            # is movement too)
+            moves = base in _HLO_MOVE_OPS \
+                or any(t in rest for t in _HLO_MOVE_TARGETS) \
+                or (inner and all(m[3] in _HLO_MOVE_OPS
+                                  or m[3] in _HLO_SKIP_OPS for m in inner))
+            comp = "copy" if moves else "unnamed"
+        row = {"name": name, "opcode": base, "shape": shape,
+               "bytes": _shape_bytes(shape), "component": comp,
+               "layer": layer, "also": sorted(also - {comp})}
+        rows.append(row)
+        by_name[name] = row
+    return rows
 
 
 def kernel_census(compiled=None, jaxpr=None) -> dict:
     """Kernel-count census of one executable (ISSUE 13 — the
     machinery behind ``ServingEngine.stats()['kernels_per_tick']`` and
     the ``serving_kernels_per_tick`` gauge, so "kernel count per
-    decode layer down" is measured, not asserted). Two views:
+    decode layer down" is measured, not asserted). Three views, the
+    first two from ONE read of ``compiled.as_text()``:
 
     - ``hlo_kernels`` (+ ``hlo_fusions``/``hlo_custom_calls``/
       ``hlo_by_op``): instructions of the optimized HLO ENTRY
-      computation (``compiled.as_text()``), excluding pure
-      bookkeeping — each is approximately one kernel thunk on the
-      compiling backend. The truth on real TPU hardware.
+      computation, excluding pure bookkeeping — each is approximately
+      one kernel thunk on the compiling backend.
       ``hlo_mosaic_kernels`` counts the Mosaic custom calls of EVERY
       computation by kernel scope name — on a TPU, the Pallas kernels
       that actually compiled in (empty for an interpreted or
       XLA-fallback graph).
+    - ``hlo_components`` (``component_map``): for each of those ENTRY
+      instructions, the component of the model it came from
+      (``COMPONENTS``), its layer, and what else XLA fused into it —
+      the join from a device trace's events to the model.
     - ``launch_proxy`` (+ ``launch_by_op``): a jaxpr walk (the PR 2
       collective-census machinery, same recursion through
       pjit/scan/while/shard_map bodies) counting launch-rooted
@@ -194,7 +455,13 @@ def kernel_census(compiled=None, jaxpr=None) -> dict:
       interpreter, so a CPU census of the fused decode tick shows the
       same collapse the TPU compile gets.
 
-    Either input may be omitted; its view is then absent."""
+    A TPU run should trust the first two: they describe the program
+    the chip executes, instruction for instruction, and
+    ``hlo_components`` names every event of its trace. On the CPU
+    backend they describe the CPU's program (other fusions, no Mosaic
+    call): good for counts and for the taxonomy's coverage, not for
+    what a chip will run. Either input may be omitted; its view is
+    then absent."""
     out = {}
     if jaxpr is not None:
         n = [0]
@@ -242,6 +509,7 @@ def kernel_census(compiled=None, jaxpr=None) -> dict:
         out["hlo_custom_calls"] = by.get("custom-call", 0)
         out["hlo_by_op"] = dict(sorted(by.items()))
         out["hlo_mosaic_kernels"] = _mosaic_kernels(txt)
+        out["hlo_components"] = component_map(txt)
     return out
 
 

@@ -167,6 +167,7 @@ class Tracer:
         self._buf: deque = deque(maxlen=self.capacity)
         self._lock = threading.Lock()
         self._threads: Dict[int, str] = {}
+        self._notes: Dict[str, Any] = {}     # annotate(): not in the ring
         self._n_dropped = 0          # events the ring overwrote
         # satellite (ISSUE 15): ring wrap-around is OBSERVABLE — the
         # process-wide counter makes silent truncation a metric, the
@@ -184,6 +185,26 @@ class Tracer:
         """Name one timeline row (Perfetto track label)."""
         with self._lock:
             self._threads[int(tid)] = str(name)
+
+    def annotate(self, key: str, value: Any):
+        """Attach one named, JSON-able fact about the owner to the
+        trace, OUTSIDE the ring (it is never overwritten and costs the
+        hot path nothing): ``chrome_events()`` writes it as a metadata
+        record named ``key``. A dict value is merged into what ``key``
+        already holds — the serving engine adds each executable's
+        ``component_map`` as it compiles it."""
+        with self._lock:
+            held = self._notes.get(key)
+            if isinstance(held, dict) and isinstance(value, dict):
+                held.update(value)
+            else:
+                self._notes[key] = dict(value) \
+                    if isinstance(value, dict) else value
+
+    def annotations(self) -> Dict[str, Any]:
+        """What ``annotate`` was given, by key."""
+        with self._lock:
+            return dict(self._notes)
 
     def _append(self, rec):
         with self._lock:
@@ -292,15 +313,21 @@ class Tracer:
 
     def chrome_events(self) -> List[dict]:
         """This tracer's events in Chrome trace-event form: metadata
-        rows first (process/thread names), then one ``"X"`` (complete)
+        rows first (process/thread names, then what ``annotate`` was
+        given), then one ``"X"`` (complete)
         or ``"i"`` (instant) event per record, ``ts``/``dur`` in
         integer microseconds."""
         with self._lock:
             items = list(self._buf)
             threads = dict(self._threads)
+            notes = dict(self._notes)
         out: List[dict] = [{
             "ph": "M", "pid": self.pid, "tid": 0,
             "name": "process_name", "args": {"name": self.name}}]
+        for key, value in notes.items():
+            out.append({"ph": "M", "pid": self.pid, "tid": 0,
+                        "name": key, "args": value
+                        if isinstance(value, dict) else {"value": value}})
         for tid in sorted(threads):
             out.append({"ph": "M", "pid": self.pid, "tid": tid,
                         "name": "thread_name",

@@ -61,6 +61,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..framework.core import component
+
 __all__ = ["NULL_BLOCK", "BlockAllocator", "blocks_for", "init_pool",
            "write_prefill", "write_decode", "write_tokens",
            "write_rows", "scatter_rows", "init_latent_pool",
@@ -81,6 +83,18 @@ __all__ = ["NULL_BLOCK", "BlockAllocator", "blocks_for", "init_pool",
 # block id 0 is never allocated: inactive slots' tables point here, so
 # their scatter/gather indices stay valid while their data is garbage
 NULL_BLOCK = 0
+
+
+def _cache_component(fn):
+    """Run ``fn`` under the component scope ``cache``
+    (``monitor.accounting.COMPONENTS``): what moves pool blocks and
+    slot state inside an engine's executables is named so in their
+    component maps."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with component("cache"):
+            return fn(*args, **kwargs)
+    return scoped
 
 
 def blocks_for(n_tokens: int, block_size: int) -> int:
@@ -228,6 +242,7 @@ def state_bytes(pools) -> int:
                for t in layer)
 
 
+@_cache_component
 def export_slot_state(pools, slot):
     """One slot's row of every slot-state table: the snapshot the
     engine keeps beside a published block, a PYTREE — a list over the
@@ -244,6 +259,7 @@ def export_slot_state(pools, slot):
     return rows
 
 
+@_cache_component
 def import_slot_state(pools, slot, snap):
     """Write an :func:`export_slot_state` snapshot back at ``slot``
     (donate ``pools``); block-paged layers pass through."""
@@ -768,6 +784,7 @@ def write_tokens(k_pool, v_pool, block_tables, cache_lens, k_new, v_new):
     return _store(k_pool, bi, off, k_new), _store(v_pool, bi, off, v_new)
 
 
+@_cache_component
 def scatter_rows(layer, block_tables, row_slot, row_pos, news):
     """Append a RAGGED mixed batch to one layer's cache, whatever its
     arity: row ``r`` of each array of ``news`` (``[R, ...]``, the
@@ -792,6 +809,7 @@ def scatter_rows(layer, block_tables, row_slot, row_pos, news):
                  tuple(layer), tuple(news))
 
 
+@_cache_component
 def write_rows(k_pool, v_pool, block_tables, row_slot, row_pos,
                k_new, v_new):
     """``scatter_rows`` on a ``(k_pool, v_pool)`` pair: ``k_new/v_new``
@@ -800,6 +818,7 @@ def write_rows(k_pool, v_pool, block_tables, row_slot, row_pos,
                         row_pos, (k_new, v_new))
 
 
+@_cache_component
 def permute_window(k_pool, v_pool, block_tables, cache_lens, perm,
                    n_keep):
     """Tree-acceptance K/V compaction: after a tree-speculative verify
@@ -885,6 +904,7 @@ def ragged_row_meta(q_lens, base_lens, total_rows, overflow_pos):
     return row_slot, row_pos, row_starts, last_rows
 
 
+@_cache_component
 def copy_blocks(pools, src, dst):
     """Copy-on-write device op: duplicate block ``src`` into ``dst``
     across every array of every layer's cache (a pair, or a latent
@@ -905,6 +925,7 @@ def copy_blocks(pools, src, dst):
             for layer in pools]
 
 
+@_cache_component
 def export_blocks(pools, block_ids):
     """Disaggregated prefill->decode transfer, read side: gather the
     SELF-CONTAINED bytes of ``block_ids`` ([M] int32, padded with the
@@ -931,6 +952,7 @@ def export_blocks(pools, block_ids):
             for layer in pools]
 
 
+@_cache_component
 def import_blocks(pools, block_ids, payload):
     """Disaggregated prefill->decode transfer, write side: scatter an
     :func:`export_blocks` payload into THIS pool at ``block_ids``
@@ -992,6 +1014,7 @@ def _stacked_plan(pools):
     return groups, layout
 
 
+@_cache_component
 def export_stacked(pools, block_ids):
     """Eviction spill, read side: the bytes of ``block_ids`` ([n]
     int32 — the evicted blocks, no padding) as ONE array per dtype, so

@@ -61,6 +61,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas.delta_rule import _seats
+from ..framework.core import component
 from .pallas.flash_attention_kernel import kernel_scope
 from .pallas.paged_attention import (_force_kernel_routing, _interpret,
                                      count_fallback)
@@ -99,8 +100,9 @@ def _xla_ragged_taps(g, state, w, ragged_meta):
     seat = jnp.where(live, sl, n_slots)
     # a slot's state as its rows see it: zeros where the slot's first
     # row is position 0
-    old = jnp.where(fresh[:, None, None], 0, state[:n_slots])
-    old = jnp.concatenate([old, state[n_slots:]])
+    with component("cache"):
+        old = jnp.where(fresh[:, None, None], 0, state[:n_slots])
+        old = jnp.concatenate([old, state[n_slots:]])
     rows = []
     for j in range(keep):
         back = keep - j
@@ -114,9 +116,10 @@ def _xla_ragged_taps(g, state, w, ragged_meta):
     from_g = g[jnp.clip(rs[:, None] + n - keep, 0, r - 1)]
     from_old = jnp.take_along_axis(
         old[:n_slots], jnp.clip(n, 0, keep - 1)[..., None], axis=1)
-    new = jnp.where((n >= keep)[..., None], from_g, from_old)
-    new = jnp.where((ql > 0)[:, None, None], new, state[:n_slots])
-    return conv, state.at[:n_slots].set(new.astype(state.dtype))
+    with component("cache"):
+        new = jnp.where((n >= keep)[..., None], from_g, from_old)
+        new = jnp.where((ql > 0)[:, None, None], new, state[:n_slots])
+        return conv, state.at[:n_slots].set(new.astype(state.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -231,10 +234,12 @@ def pallas_ragged_taps(g, state, w, ragged_meta, interpret=None):
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret() if interpret is None else interpret,
     )
+    with component("cache"):
+        table_in = jnp.swapaxes(state, 0, 1)
     with kernel_scope("short_conv_taps"):
-        new, conv = call(per_row, per_seat, w_t, g,
-                         jnp.swapaxes(state, 0, 1))
-    return conv, jnp.swapaxes(new, 0, 1)
+        new, conv = call(per_row, per_seat, w_t, g, table_in)
+    with component("cache"):
+        return conv, jnp.swapaxes(new, 0, 1)
 
 
 # ---------------------------------------------------------------------------

@@ -332,3 +332,120 @@ def test_fused_decode_eligibility_refuses_what_cannot_fit():
     assert not df._eligible(
         [16384, 128], 1024, True,
         df._norm_mm_vmem(1024, 16384, [128], isz))
+
+
+# -- the component map of a whole tick, compiled for the v5e -------------------
+
+def _tick_components(model, engine_kw, prompt_len):
+    """The ``component_map`` rows of the ragged tick of an engine over
+    ``model``, compiled for the described v5e (XLA:TPU and Mosaic)
+    instead of this host: what ``ServingEngine._aot_compile`` would
+    read on the chip."""
+    from unittest import mock
+    from paddle_tpu.inference import ServingConfig, ServingEngine
+    from paddle_tpu.monitor import accounting
+    placement = _v5e_placement()
+    engine = ServingEngine(model, ServingConfig(**engine_kw))
+    got = {}
+
+    class Compiled(Exception):
+        pass
+
+    def aot(name, jitted, args):
+        abstract = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                           sharding=placement), args)
+        with engine._trace_ctx(), \
+                mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            text = jitted.lower(*abstract).compile().as_text()
+        got[name] = accounting.component_map(text)
+        raise Compiled
+
+    engine._aot_compile = aot
+    engine.submit(np.arange(prompt_len) % 1000 + 1, max_new_tokens=2)
+    with pytest.raises(Compiled):
+        engine.run()
+    return got["decode"]
+
+
+def _cell_config(name):
+    import json
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", name)) as f:
+        return json.load(f)
+
+
+def _reasoning_tick():
+    """``reason-wide-sat``'s widths and engine at depth 2 (a gqa and a
+    kda layer), two held experts of the 320-wide gate, 2,048 rows of
+    vocabulary; zeros for weights (nothing is run)."""
+    from benchmark.models import solar_open2 as fam
+    from paddle_tpu.models.solar_open2 import (SolarOpen2Config,
+                                               SolarOpen2ForCausalLM)
+    cfg = _cell_config("configs/solar-open2-250b.ep16.d8.json")
+    keys = dict({k: cfg[k] for k in fam.CFG_KEYS}, num_hidden_layers=2,
+                vocab_size=2048)
+    model = SolarOpen2ForCausalLM(SolarOpen2Config(
+        dtype="bfloat16", initializer_range=0.0, n_routed_experts=320,
+        expert_first=0, expert_count=2, gqa_layers=(0,),
+        kda_low_rank=fam.low_rank(cfg), **keys))
+    cell = _cell_config(
+        "workloads/reason-wide-sat.solar-open2-250b.ep16.d8.json")
+    return model, dict(cell["engine"], num_blocks=512), {
+        "kernel:ragged_paged_attention", "kernel:gmm",
+        "kernel:short_conv_taps", "kernel:kda_chunk",
+        "kernel:kda_recurrent"}, {"gqa", "kda"}
+
+
+def _latent_tick():
+    """``longprompt-sat``'s widths and engine at depth 2 (the dense and
+    an expert layer), two held experts of the 256-wide gate, 2,048 rows
+    of vocabulary."""
+    from benchmark.models import deepseek_v3 as fam
+    from paddle_tpu.models.deepseek_v3 import (DeepseekV3Config,
+                                               DeepseekV3ForCausalLM)
+    cfg = _cell_config("configs/gigachat3.1-702b.ep16.d5.json")
+    keys = dict({k: cfg[k] for k in fam.CFG_KEYS}, num_hidden_layers=2,
+                vocab_size=2048)
+    model = DeepseekV3ForCausalLM(DeepseekV3Config(
+        dtype="bfloat16", initializer_range=0.0, n_routed_experts=256,
+        expert_first=0, expert_count=2, **keys))
+    cell = _cell_config(
+        "workloads/longprompt-sat.gigachat3.1-702b.ep16.d5.json")
+    return model, dict(cell["engine"], num_blocks=512), {
+        "kernel:ragged_latent_attention", "kernel:gmm"}, {"mla"}
+
+
+@pytest.mark.parametrize("tick", [_reasoning_tick, _latent_tick])
+def test_tick_compiled_for_tpu_is_named_by_component(tick):
+    """The cell's tick at a cut-down depth and the cell's widths,
+    through the TPU compiler: the Mosaic kernels are ``kernel:<scope>``
+    rows, the weight products and the glue carry their components, and
+    ``unnamed`` covers under 5% of the instructions' result bytes (and
+    of the instructions)."""
+    if _v5e_placement() is None:
+        pytest.skip("needs the TPU compiler (libtpu topology)")
+    from paddle_tpu.monitor import accounting
+    import paddle_tpu as paddle
+    paddle.seed(0)
+    model, engine_kw, kernels, kinds = tick()
+    model.eval()
+    rows = _tick_components(model, engine_kw, 600)
+    names = {r["component"] for r in rows}
+    assert kernels <= names
+    assert {"embed", "norm", "mixer.in", "mixer.glue", "mixer.out", "ffn",
+            "moe.gate", "moe.dispatch", "moe.combine", "cache", "head",
+            "sample", "tick.io"} <= names
+    assert names <= set(accounting.COMPONENTS) | kernels \
+        | {"copy", "unnamed"}
+    assert {r["layer"].partition(".")[2] for r in rows if r["layer"]} \
+        == kinds
+    unnamed = [r for r in rows if r["component"] == "unnamed"]
+    assert sum(r["bytes"] for r in unnamed) \
+        < 0.05 * sum(r["bytes"] for r in rows)
+    assert len(unnamed) < 0.05 * len(rows)
+    # the output projection's fusion says what else XLA fused into it
+    out = [r for r in rows if r["component"] == "mixer.out"
+           and r["shape"].startswith("(f32[")]
+    assert out and all("norm" in r["also"] for r in out)
