@@ -494,6 +494,13 @@ def kernel_cases(full, interpret=None):
         q = jnp.asarray(rng.standard_normal((rows, ld["H"], lanes)),
                         bf16).at[..., ld["W"]:].set(0)
         lens = rng.integers(1, mb * bs - w, s_)
+        # one-token slots (the kernel's narrow rung) whose context ends
+        # a position short of a kv tile, on its edge, a position past
+        # it and inside a block of the third, where the table holds them
+        span = pa.LATENT_TILE[1]
+        pins = [n for n in (span - 2, span - 1, span, 3 * span - 77)
+                if n < mb * bs - w]
+        lens[1:1 + len(pins)] = pins
         ctx = jnp.asarray(lens + 1, jnp.int32)
         # a mixed tick (one chunk, decode rows, one idle slot) and the
         # decode-only tick

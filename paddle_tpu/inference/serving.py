@@ -1856,20 +1856,22 @@ class ServingEngine:
         if len(paged0) == 1:
             # a latent (MLA) cache: every head reads the layer's one
             # array, in the latent kernel's own tile
-            geo.update(num_kv_heads=1, tile=_pa.LATENT_TILE, streams=1)
+            geo.update(num_kv_heads=1, tile=_pa.LATENT_TILE, streams=1,
+                       narrow=True)
         return geo
 
     def _attn_grid(self, q_lens, context_lens):
         """The ``tick`` span's ``attn_units`` / ``attn_live`` /
-        ``attn_copies`` of one layer's ragged attention call this tick
-        (docs/OPS.md "Tick phases"), or nothing where no span would
-        carry them: numpy on ``num_slots`` entries."""
+        ``attn_copies`` (and, over a latent pool, ``attn_narrow``) of
+        one layer's ragged attention call this tick (docs/OPS.md "Tick
+        phases"), or nothing where no span would carry them: numpy on
+        ``num_slots`` entries."""
         if self._trace is None or self._attn_geometry is None:
             return {}
-        units, live, copies = _pa.ragged_grid_units(
-            q_lens, context_lens, **self._attn_geometry)
-        return {"attn_units": units, "attn_live": live,
-                "attn_copies": copies}
+        return dict(zip(
+            ("attn_units", "attn_live", "attn_copies", "attn_narrow"),
+            _pa.ragged_grid_units(q_lens, context_lens,
+                                  **self._attn_geometry)))
 
     def _launch_ragged(self, args):
         """Run THE tick executable: ``(outs, share counts or None)``.

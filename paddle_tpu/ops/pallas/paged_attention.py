@@ -397,8 +397,19 @@ _GROUP_LANES = 512
 # and the online softmax's rescaling well under a tile's two products.
 # On one v5e, a 512-row chunk at context 2k beside 20 decode rows, 64
 # heads: 2.32 ms a call against 2.96 at (256, 256), 3.66 at (128, 256),
-# 3.20 at (1024, 256); a decode-only tick pays 0.98 against 0.88 for
-# the taller tile's dead rows (chip run, PR 26)
+# 3.20 at (1024, 256) (chip run, PR 26). What the tall tile costs a
+# slot that brings ONE token (a decode row, a prompt trickling a row a
+# tick) is no longer its dead rows: such a tile walks at the token's
+# own rows (``_latent_rungs``), a kv tile in ~1.5 us where the full
+# tile takes 4.5 — about its 655 KB copy and the products' stationary
+# operands, which are pushed whatever the row count — and what is left
+# of the tile's height there is the ``[512, W]`` query block in and
+# the ``[512, value_dim]`` block out a grid step (1.2 MB, a live tile
+# or not). At the long-prompt cell's shapes (32 slots, 544 rows, 64
+# heads): that chunk beside 20 decode rows 2.09 -> 1.66 ms a call, a
+# decode-only call of 32 slots at 800-7,000 positions 1.12 -> 0.43, of
+# 20 slots 0.70 -> 0.29 (chip run, PR 35; the kernel alone, from a
+# profiler trace)
 LATENT_TILE = (512, 512)
 
 
@@ -461,7 +472,7 @@ def _head_group(num_kv_heads, head_lanes) -> int:
 def ragged_grid_units(q_lens, context_lens, *, rows, w_max, num_heads,
                       num_kv_heads, q_dtype, block_size, max_blocks,
                       tile=(_TILE_ROWS, _TILE_POSITIONS), head_lanes=128,
-                      streams=2):
+                      streams=2, narrow=False):
     """Host-side count of what ONE ``pallas_ragged_paged_attention``
     call (or, with ``num_kv_heads=1``, ``streams=1`` and
     ``tile=LATENT_TILE``, one ``pallas_ragged_latent_attention`` call)
@@ -475,19 +486,27 @@ def ragged_grid_units(q_lens, context_lens, *, rows, w_max, num_heads,
     descriptors the call issues: per live (query tile, kv tile) one a
     pool block (``kb``) a stream (K and V; an int8 pool's two scale
     pools make ``streams`` 4) a head GROUP (``_head_group`` of
-    ``head_lanes``-wide kv heads) — not one a kv head."""
+    ``head_lanes``-wide kv heads) — not one a kv head. ``narrow=True``
+    (the latent call's geometry) appends a fourth value: the live
+    units that the latent kernel walks at its narrow rung (a tile
+    that holds one window token, ``_latent_rungs``: rows of one
+    token's heads, not of ``tq`` tokens')."""
     ql = np.minimum(np.asarray(q_lens, np.int64), w_max)
     _, tq, kb, n_tiles, n_kv = _ragged_geometry(
         rows, ql.shape[0], num_heads // num_kv_heads, q_dtype,
         block_size, max_blocks, tile)
-    _, _, _, kv = _ragged_tiles(
+    _, _, held, kv = _ragged_tiles(
         np, ql, np.asarray(context_lens, np.int64), tq,
         kb * block_size, n_tiles, n_kv)
     walked = int(kv.sum())
     live = walked * num_kv_heads
     groups = num_kv_heads // _head_group(num_kv_heads, head_lanes)
-    return (live + int((kv == 0).sum()) * num_kv_heads, live,
-            walked * kb * streams * groups)
+    counts = (live + int((kv == 0).sum()) * num_kv_heads, live,
+              walked * kb * streams * groups)
+    if not narrow:
+        return counts
+    return counts + (
+        int(kv[held <= _latent_rungs(tq)[0]].sum()) * num_kv_heads,)
 
 
 def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tables_ref, lens_ref,
@@ -915,10 +934,10 @@ def _untile(out, starts, ql, tq, r):
                      out[jnp.where(has, back, 0)], 0)
 
 
-def _latent_kernel(tslot_ref, trow_ref, tkv_ref, tables_ref, lens_ref,
-                   q_ref, c_hbm, o_ref, c_buf, sems, m_scr, l_scr,
-                   acc_scr, *, scale, block_size, kv_blocks, max_blocks,
-                   value_dim, row_shift):
+def _latent_kernel(tslot_ref, trow_ref, tkv_ref, tlive_ref, tables_ref,
+                   lens_ref, q_ref, c_hbm, o_ref, c_buf, sems, m_scr,
+                   l_scr, acc_scr, *, scale, block_size, kv_blocks,
+                   max_blocks, value_dim, row_shift, rungs):
     """Latent (MLA) ragged body: grid ``(query tile,)``. Every head of
     a window token reads the SAME cached row — the compressed
     ``c_kv`` and the shared rotary key ``k_pe`` side by side, ``W``
@@ -928,74 +947,114 @@ def _latent_kernel(tslot_ref, trow_ref, tkv_ref, tables_ref, lens_ref,
     W_UK`` beside ``q_pe``) and walks the slot's cache as
     ``_ragged_kernel`` does: ``tkv_ref[t]`` tiles of ``kv_blocks``
     pool blocks, chased through ``tables_ref[slot]`` by double-buffered
-    async copies out of the HBM pool. One copied tile serves both
-    products: the scores contract all ``W`` lanes, the values are the
-    tile's first ``value_dim`` lanes."""
+    async copies out of the HBM pool, one wait a kv tile. One copied
+    tile serves both products: the scores contract all ``W`` lanes, the
+    values are the tile's first ``value_dim`` lanes.
+
+    A step computes over the rows its tile HOLDS: ``tlive_ref[t]`` is
+    the tile's live window tokens and ``rungs`` the static token
+    counts a walk is compiled at, ascending (``_latent_rungs``); the
+    step takes the smallest rung that covers its tile — a decoding
+    slot's and a trickling prompt's one token walk ``rp`` rows of
+    query, scores and softmax state a kv tile, a chunk's tiles all
+    ``tq * rp`` — and stores zeros in the rows of its output block
+    above the rung. Every rung is the one body at another static row
+    count: a live row's scores, softmax and weighted sum do not depend
+    on the other rows of its tile."""
     t = pl.program_id(0)
     slot = tslot_ref[t]
     row0 = trow_ref[t]
     n_kv = tkv_ref[t]
+    live = tlive_ref[t]
     lens = lens_ref[slot]
     bs, kb = block_size, kv_blocks
 
-    def copies(j, buf):
-        out = []
+    def start(j, buf):
+        """Start the ``kb`` block copies that bring kv tile ``j`` into
+        buffer half ``buf``. Blocks past the table's end re-read its
+        last entry; their columns lie past every row's bound."""
         for i in range(kb):
             blk = tables_ref[slot, jnp.minimum(j * kb + i,
                                                max_blocks - 1)]
-            out.append(pltpu.make_async_copy(
+            pltpu.make_async_copy(
                 c_hbm.at[blk], c_buf.at[buf, pl.ds(i * bs, bs), :],
-                sems.at[buf]))
-        return out
+                sems.at[buf]).start()
 
-    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[:] = jnp.zeros_like(l_scr)
-    acc_scr[:] = jnp.zeros_like(acc_scr)
+    def wait(buf):
+        """One descriptor the size of the buffer half stands for its
+        ``kb`` block copies (``_ragged_kernel.wait``): no table entry
+        is read a second time."""
+        pltpu.make_async_copy(c_buf.at[buf], c_buf.at[buf],
+                              sems.at[buf]).wait()
 
-    @pl.when(n_kv > 0)
-    def _first():
-        for c in copies(0, 0):
-            c.start()
+    def walk_rows(n):
+        """The whole step over the tile's first ``n`` rows (static)."""
+        m_scr[:n] = jnp.full((n, m_scr.shape[1]), NEG_INF, m_scr.dtype)
+        l_scr[:n] = jnp.zeros((n, l_scr.shape[1]), l_scr.dtype)
+        acc_scr[:n] = jnp.zeros((n, value_dim), acc_scr.dtype)
 
-    def walk(j, carry):
-        buf = jax.lax.rem(j, 2)
+        @pl.when(n_kv > 0)
+        def _first():
+            start(0, 0)
 
-        @pl.when(j + 1 < n_kv)
-        def _next():
-            for c in copies(j + 1, 1 - buf):
-                c.start()
+        def walk(j, carry):
+            buf = jax.lax.rem(j, 2)
 
-        for c in copies(j, buf):
-            c.wait()
-        q = q_ref[0]                          # [tq * rp, W]
-        c_tile = c_buf[buf]                   # [kb * BS, W]
-        sc = jax.lax.dot_general(
-            q, c_tile, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        cols = j * (kb * bs) + jax.lax.broadcasted_iota(
-            jnp.int32, sc.shape, 1)
-        node = row0 + (jax.lax.broadcasted_iota(
-            jnp.int32, sc.shape, 0) >> row_shift)
-        sc = jnp.where(cols < lens + node, sc, NEG_INF)
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        p = jnp.exp(sc - m_cur)
-        alpha = jnp.exp(m_prev - m_cur)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(c_tile.dtype), c_tile[:, :value_dim],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_scr[:] = alpha * acc_scr[:] + pv
-        m_scr[:] = jnp.broadcast_to(m_cur, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-        return carry
+            @pl.when(j + 1 < n_kv)
+            def _next():
+                start(j + 1, 1 - buf)
 
-    jax.lax.fori_loop(0, n_kv, walk, 0)
-    l = l_scr[:, :1]
-    safe_l = jnp.where(l == 0.0, np.float32(1.0), l)
-    o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
+            wait(buf)
+            q = q_ref[0, :n]                      # [n, W]
+            c_tile = c_buf[buf]                   # [kb * BS, W]
+            sc = jax.lax.dot_general(
+                q, c_tile, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            cols = j * (kb * bs) + jax.lax.broadcasted_iota(
+                jnp.int32, sc.shape, 1)
+            node = row0 + (jax.lax.broadcasted_iota(
+                jnp.int32, sc.shape, 0) >> row_shift)
+            sc = jnp.where(cols < lens + node, sc, NEG_INF)
+            m_prev = m_scr[:n, :1]
+            l_prev = l_scr[:n, :1]
+            m_cur = jnp.maximum(m_prev,
+                                jnp.max(sc, axis=-1, keepdims=True))
+            p = jnp.exp(sc - m_cur)
+            alpha = jnp.exp(m_prev - m_cur)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(c_tile.dtype), c_tile[:, :value_dim],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc_scr[:n] = alpha * acc_scr[:n] + pv
+            m_scr[:n] = jnp.broadcast_to(m_cur, (n, m_scr.shape[1]))
+            l_scr[:n] = jnp.broadcast_to(l_new, (n, l_scr.shape[1]))
+            return carry
+
+        jax.lax.fori_loop(0, n_kv, walk, 0)
+        l = l_scr[:n, :1]
+        safe_l = jnp.where(l == 0.0, np.float32(1.0), l)
+        o_ref[0, :n] = (acc_scr[:n] / safe_l).astype(o_ref.dtype)
+        dead = o_ref.shape[1] - n
+        if dead:
+            o_ref[0, n:] = jnp.zeros((dead, value_dim), o_ref.dtype)
+
+    # the smallest rung that covers the tile's live tokens; a tile past
+    # the live count (0 tokens, 0 kv tiles) takes the first
+    rung = sum((live > tokens).astype(jnp.int32) for tokens in rungs[:-1])
+    for i, tokens in enumerate(rungs):
+        pl.when(rung == i)(
+            functools.partial(walk_rows, tokens << row_shift))
+
+
+def _latent_rungs(tq):
+    """Window tokens of a tile the latent kernel's walk is compiled
+    at, ascending; a grid step takes the smallest that covers its
+    tile's live tokens. Two rungs: ONE token — a decoding slot, a
+    pending prompt trickling a row a tick: a third of the long-prompt
+    cell's walk, at an eighth of the full tile's rows — and the whole
+    tile."""
+    return (1, tq) if tq > 1 else (tq,)
 
 
 def pallas_ragged_latent_attention(q, pool, block_tables, context_lens,
@@ -1009,7 +1068,11 @@ def pallas_ragged_latent_attention(q, pool, block_tables, context_lens,
     tiles; the slot partition (``q_lens`` / ``row_starts`` /
     ``context_lens`` / ``w_max``) as in
     ``pallas_ragged_paged_attention``, whose tiling (``_ragged_tiles``)
-    this shares, with all ``H`` heads of a token in one tile. Returns
+    this shares, with all ``H`` heads of a token in one tile. Each
+    tile's live token count rides beside its slot, first row and kv
+    reach, and a grid step computes over that many rows' rung
+    (``_latent_rungs``; ``ragged_grid_units(..., narrow=True)`` counts
+    the same choice on the host). Returns
     ``[R, H, value_dim]``: per head the softmax-weighted sum of the
     cached rows' first ``value_dim`` lanes (``u_h``, still to be
     expanded by ``W_UV``); rows no slot owns come back zero."""
@@ -1021,8 +1084,9 @@ def pallas_ragged_latent_attention(q, pool, block_tables, context_lens,
     ql = jnp.minimum(q_lens.astype(jnp.int32), int(w_max))
     starts = row_starts.astype(jnp.int32)
     lens = context_lens.astype(jnp.int32)
-    tslot, trow, _, tkv = (a.astype(jnp.int32) for a in _ragged_tiles(
-        jnp, ql, lens, tq, kb * bs, n_tiles, n_kv))
+    tslot, trow, tlive, tkv = (
+        a.astype(jnp.int32) for a in _ragged_tiles(
+            jnp, ql, lens, tq, kb * bs, n_tiles, n_kv))
     src = jnp.clip((starts[tslot] + trow)[:, None]
                    + jnp.arange(tq, dtype=jnp.int32)[None, :], 0, r - 1)
     rows = tq * rp
@@ -1030,13 +1094,13 @@ def pallas_ragged_latent_attention(q, pool, block_tables, context_lens,
     kernel = functools.partial(
         _latent_kernel, scale=np.float32(sm_scale), block_size=bs,
         kv_blocks=kb, max_blocks=mb, value_dim=value_dim,
-        row_shift=rp.bit_length() - 1)
+        row_shift=rp.bit_length() - 1, rungs=_latent_rungs(tq))
 
     def q_block(t, *prefetch):
         return (t, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=(n_tiles,),
         in_specs=[pl.BlockSpec((1, rows, wd), q_block),
                   pl.BlockSpec(memory_space=pltpu.HBM)],
@@ -1055,8 +1119,8 @@ def pallas_ragged_latent_attention(q, pool, block_tables, context_lens,
         interpret=_interpret() if interpret is None else interpret,
     )
     with kernel_scope("ragged_latent_attention"):
-        out = call(tslot, trow, tkv, block_tables.astype(jnp.int32),
-                   lens, q3, pool)
+        out = call(tslot, trow, tkv, tlive,
+                   block_tables.astype(jnp.int32), lens, q3, pool)
     out = out.reshape(n_tiles, tq, rp, value_dim)[:, :, :h] \
         .reshape(n_tiles * tq, h, value_dim)
     return _untile(out, starts, ql, tq, r)

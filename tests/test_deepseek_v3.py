@@ -159,15 +159,50 @@ def test_absorbed_attention_equals_non_absorbed(built):
     assert np.abs(pool[1:, :, :40]).min() > 0 and not pool[..., 40:].any()
 
 
+def _latent_tq(heads, dtype):
+    """Window tokens a latent query tile holds at this head count."""
+    return pa._ragged_geometry(1, 1, heads, dtype, 16, 1,
+                               pa.LATENT_TILE)[1]
+
+
+# (q_lens, context of each slot's first row) as functions of ``tq``,
+# the window tokens a tile holds (8 at 64 bf16 heads, 64 at 8 float32)
+_LATENT_TICKS = {
+    # a decode row, a chunk, an idle slot, a long context
+    "mixed": lambda tq: ([1, 37, 0, 1], [100, 40, 0, 333]),
+    # one-token slots (the narrow rung) whose context ends inside the
+    # first block, a position short of a kv tile, on its edge, a
+    # position past it and three tiles on inside a block; an idle slot
+    # between them
+    "one_token": lambda tq: ([1, 1, 0, 1, 1, 1],
+                             [1, 511, 0, 512, 513, 1500]),
+    # a chunk of k * tq + 1 rows (the mirror takes ONE wide slot a
+    # tick, as the engine schedules): its LAST tile holds one token at
+    # ``row0 > 0``, so the narrow rung's causal bound is the tile's
+    # first row's; an idle slot and a decode row past a kv tile beside
+    "chunk_tail": lambda tq: ([1, 2 * tq + 1, 0, 1], [513, 509, 0, 77]),
+    # a chunk of a few rows: a partial tile, on the wide rung
+    "chunk_partial": lambda tq: ([1, 0, min(5, tq - 1), 1],
+                                 [1500, 0, 700, 512]),
+}
+
+
+@pytest.mark.parametrize("tick", sorted(_LATENT_TICKS))
 @pytest.mark.parametrize("dtype,heads,tol", [(jnp.float32, 8, 1e-5),
                                              (jnp.bfloat16, 64, 2e-2)])
-def test_latent_kernel_matches_mirror_at_key_width_576(dtype, heads, tol):
+def test_latent_kernel_matches_mirror_at_key_width_576(dtype, heads, tol,
+                                                       tick):
     """The Pallas kernel under the interpreter against its XLA mirror:
     key width 576 (640 lanes in the pool), value = the first 512,
-    ragged lengths (a decode row, a chunk, an idle slot, a long
-    context)."""
+    ragged lengths; tiles that hold one token take the kernel's narrow
+    rung, the others its full tile."""
     rng = np.random.default_rng(3)
-    s, mb, bs, width, vdim, r = 4, 24, 16, 576, 512, 64
+    tq = _latent_tq(heads, dtype)
+    q_lens, ctx = (np.asarray(a) for a in _LATENT_TICKS[tick](tq))
+    s, bs, width, vdim = len(q_lens), 16, 576, 512
+    w = int(max(q_lens.max(), 40))
+    mb = -(-int((ctx + q_lens).max()) // bs) + 1
+    r = -(-int(q_lens.sum() + 8) // 8) * 8
     nb = 1 + s * mb
     (pool,) = pc.init_latent_pool(nb, bs, width, dtype)
     lanes = pool.shape[2]
@@ -176,7 +211,6 @@ def test_latent_kernel_matches_mirror_at_key_width_576(dtype, heads, tol):
                        dtype).at[..., width:].set(0)
     tables = jnp.asarray(rng.permutation(np.arange(1, nb))
                          .reshape(s, mb), jnp.int32)
-    q_lens = np.array([1, 37, 0, 1])
     starts = np.concatenate([[0], np.cumsum(q_lens)[:-1]])
     row_slot = np.zeros(r, np.int32)
     live = np.zeros(r, bool)
@@ -184,18 +218,54 @@ def test_latent_kernel_matches_mirror_at_key_width_576(dtype, heads, tol):
         row_slot[a:a + n], live[a:a + n] = i, True
     q = jnp.asarray(rng.standard_normal((r, heads, lanes)), dtype) \
         .at[..., width:].set(0)
-    ctx = jnp.asarray([100, 40, 0, 333], jnp.int32)
-    args = (tables, ctx, jnp.asarray(q_lens, jnp.int32),
-            jnp.asarray(starts, jnp.int32))
-    got = pa.pallas_ragged_latent_attention(q, pool, *args, 40, vdim, 0.1,
+    args = (tables, jnp.asarray(ctx, jnp.int32),
+            jnp.asarray(q_lens, jnp.int32), jnp.asarray(starts, jnp.int32))
+    got = pa.pallas_ragged_latent_attention(q, pool, *args, w, vdim, 0.1,
                                             interpret=True)
     want = pa.ragged_latent_attention(
         q, pool, *args, jnp.asarray(row_slot), jnp.arange(1),
-        jnp.arange(40), vdim, 0.1)
+        jnp.arange(w), vdim, 0.1)
     assert got.shape == (r, heads, vdim)
     np.testing.assert_allclose(np.asarray(got, np.float32)[live],
                                np.asarray(want, np.float32)[live], atol=tol)
     assert not np.asarray(got, np.float32)[~live].any()
+
+
+def test_latent_narrow_count_is_the_kernels_own_choice():
+    """``ragged_grid_units(..., narrow=True)``'s fourth value, tile by
+    tile against what the wrapper's own ``_ragged_tiles`` call hands
+    the kernel (a tile whose live tokens fit the first rung walks its
+    kv tiles narrow); ``units`` / ``live`` / ``copies`` are the same
+    with and without it, and a non-latent geometry's are untouched."""
+    geo = dict(rows=544, w_max=512, num_heads=64, num_kv_heads=1,
+               q_dtype=jnp.bfloat16, block_size=16, max_blocks=512,
+               tile=pa.LATENT_TILE, streams=1)
+    q_lens = np.zeros(32, np.int64)
+    ctx = np.zeros(32, np.int64)
+    q_lens[:6] = (1, 17, 1, 0, 3, 1)
+    ctx[:6] = (1000, 257, 4096, 0, 600, 512)
+    three = pa.ragged_grid_units(q_lens, ctx, **geo)
+    units, live, copies, narrow = pa.ragged_grid_units(
+        q_lens, ctx, narrow=True, **geo)
+    assert (units, live, copies) == three
+    _, tq, kb, n_tiles, n_kv = pa._ragged_geometry(
+        544, 32, 64, jnp.bfloat16, 16, 512, pa.LATENT_TILE)
+    rungs = pa._latent_rungs(tq)
+    assert rungs == (1, 8)
+    _, _, held, kv = (np.asarray(a) for a in pa._ragged_tiles(
+        jnp, jnp.asarray(q_lens, jnp.int32), jnp.asarray(ctx, jnp.int32),
+        tq, kb * 16, n_tiles, n_kv))
+    assert narrow == sum(int(k) for h, k in zip(held, kv) if h <= rungs[0])
+    # slots 0, 2, 5 and the 17-row chunk's last tile (row 16: one token)
+    assert narrow == 2 + 8 + 1 + -(-(257 + 16) // 512)
+    assert live == narrow + 2 * 1 + -(-(600 + 2) // 512)
+    # the other kernel's geometry: the three values the parent of this
+    # count gave (computed there)
+    gqa = dict(rows=136, w_max=128, num_heads=28, num_kv_heads=4,
+               q_dtype=jnp.bfloat16, block_size=16, max_blocks=64)
+    q8, c8 = np.array([1, 128, 0, 1, 1, 0, 1, 1]), \
+        np.array([100, 300, 0, 1024, 5, 0, 77, 600])
+    assert pa.ragged_grid_units(q8, c8, **gqa) == (296, 280, 1120)
 
 
 def test_latent_grid_units_follow_the_kernels_tiles():

@@ -340,7 +340,7 @@ def _tick_components(model, engine_kw, prompt_len):
     """The ``component_map`` rows of the ragged tick of an engine over
     ``model``, compiled for the described v5e (XLA:TPU and Mosaic)
     instead of this host: what ``ServingEngine._aot_compile`` would
-    read on the chip."""
+    read on the chip; and the compiled text they were read from."""
     from unittest import mock
     from paddle_tpu.inference import ServingConfig, ServingEngine
     from paddle_tpu.monitor import accounting
@@ -358,7 +358,7 @@ def _tick_components(model, engine_kw, prompt_len):
         with engine._trace_ctx(), \
                 mock.patch.object(jax, "default_backend", lambda: "tpu"):
             text = jitted.lower(*abstract).compile().as_text()
-        got[name] = accounting.component_map(text)
+        got[name] = accounting.component_map(text), text
         raise Compiled
 
     engine._aot_compile = aot
@@ -395,7 +395,7 @@ def _reasoning_tick():
     return model, dict(cell["engine"], num_blocks=512), {
         "kernel:ragged_paged_attention", "kernel:gmm",
         "kernel:short_conv_taps", "kernel:kda_chunk",
-        "kernel:kda_recurrent"}, {"gqa", "kda"}
+        "kernel:kda_recurrent"}, {"gqa", "kda"}, {}
 
 
 def _latent_tick():
@@ -413,8 +413,12 @@ def _latent_tick():
         expert_first=0, expert_count=2, **keys))
     cell = _cell_config(
         "workloads/longprompt-sat.gigachat3.1-702b.ep16.d5.json")
+    # the latent kernel's operands: six scalar-prefetched arrays (a
+    # tile's slot, first row, kv reach and LIVE tokens, which choose
+    # the step's rung; the tables; the lengths), the query tiles, the pool
     return model, dict(cell["engine"], num_blocks=512), {
-        "kernel:ragged_latent_attention", "kernel:gmm"}, {"mla"}
+        "kernel:ragged_latent_attention", "kernel:gmm"}, {"mla"}, {
+        "ragged_latent_attention": 8}
 
 
 @pytest.mark.parametrize("tick", [_reasoning_tick, _latent_tick])
@@ -423,17 +427,25 @@ def test_tick_compiled_for_tpu_is_named_by_component(tick):
     through the TPU compiler: the Mosaic kernels are ``kernel:<scope>``
     rows, the weight products and the glue carry their components, and
     ``unnamed`` covers under 5% of the instructions' result bytes (and
-    of the instructions)."""
+    of the instructions); a kernel the tick names with an operand count
+    takes that many (the latent kernel's live-token list reached it)."""
     if _v5e_placement() is None:
         pytest.skip("needs the TPU compiler (libtpu topology)")
     from paddle_tpu.monitor import accounting
     import paddle_tpu as paddle
     paddle.seed(0)
-    model, engine_kw, kernels, kinds = tick()
+    model, engine_kw, kernels, kinds, operands = tick()
     model.eval()
-    rows = _tick_components(model, engine_kw, 600)
+    rows, text = _tick_components(model, engine_kw, 600)
     names = {r["component"] for r in rows}
     assert kernels <= names
+    for scope, count in operands.items():
+        calls = [line for line in text.splitlines()
+                 if "custom-call(" in line and scope in line]
+        assert calls and all(
+            line.split("custom-call(", 1)[1]
+            .split("custom_call_target", 1)[0].count("%") == count
+            for line in calls), scope
     assert {"embed", "norm", "mixer.in", "mixer.glue", "mixer.out", "ffn",
             "moe.gate", "moe.dispatch", "moe.combine", "cache", "head",
             "sample", "tick.io"} <= names
