@@ -33,6 +33,7 @@ and prints no result.
 from __future__ import annotations
 
 import argparse
+import functools
 import gc
 import json
 import math
@@ -98,6 +99,10 @@ EXPECT = {
     "kernel.kda_chunk": ("mosaic", ("kda_chunk",)),
     "kernel.short_conv_taps": ("mosaic", ("short_conv_taps",)),
     "kernel.short_conv_taps.3taps": ("mosaic", ("short_conv_taps",)),
+    # (a residual of four streams: one connection boundary, and both ends)
+    "kernel.hc_mix": ("mosaic", ("hc_mix",)),
+    "kernel.hc_mix.first": ("mosaic", ("hc_mix",)),
+    "kernel.hc_mix.last": ("mosaic", ("hc_mix",)),
     "kernel.norm_matmul.qkv_bias": ("mosaic", ("fused_norm_matmul",)),
     "kernel.norm_matmul.gate_up": ("mosaic", ("fused_norm_matmul",)),
     "kernel.matmul_residual.o_proj": ("mosaic", ("fused_matmul_residual",)),
@@ -728,6 +733,40 @@ def kernel_cases(full, interpret=None):
          taps_case(*((96, 256, 24576, 4) if full else (4, 12, 256, 4)))),
         ("kernel.short_conv_taps.3taps",
          taps_case(*((128, 256, 2048, 3) if full else (5, 15, 128, 3))))]
+
+    # -- the hyper-connection boundary over four streams ---------------------
+    # (the Xing cell's tick: 1,056 rows of 4 x 3,584, a partial last tile)
+    hr, hw = (1056, 3584) if full else (200, 256)
+
+    def hc_case(call):
+        def build(rng):
+            from paddle_tpu.ops.pallas import hyper_connection as hc
+            n, k = 4, hc.n_coef(4)
+            kw = dict(n=n, first=call == "first", last=call == "last",
+                      iters=20, eps=1e-6, clamp=(-30.0, 30.0),
+                      norm_eps=1e-6)
+            ops = {"y": mat(rng, hr, hw)}
+            if call != "first":
+                ops["x"] = mat(rng, hr, n * hw)
+                ops["coef"] = jnp.asarray(rng.uniform(0, 1, (hr, k - n)),
+                                          jnp.float32)
+            if call != "last":
+                ops.update(fn=mat(rng, k, n * hw, std=0.02),
+                           base=mat(rng, k), scale=jnp.ones((3,), bf16))
+            names = sorted(ops)
+
+            def run(f):
+                return lambda *a: f(**dict(
+                    dict.fromkeys(("x", "coef", "fn", "base", "scale")),
+                    **dict(zip(names, a))), **kw)
+            return (run(functools.partial(hc.pallas_hc_mix,
+                                          interpret=interpret)),
+                    run(hc._xla_hc_mix), [ops[k_] for k_ in names])
+        return build
+
+    cases += [("kernel.hc_mix", hc_case("middle")),
+              ("kernel.hc_mix.first", hc_case("first")),
+              ("kernel.hc_mix.last", hc_case("last"))]
 
     # -- the LoRA grouped-matmul route (rank on 128 lanes) -------------------
     n_ad, rank = (9, 128) if full else (3, 128)

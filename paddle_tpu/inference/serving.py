@@ -1066,6 +1066,10 @@ class ServingEngine:
         self._n_state_snap_hits = 0
         self._n_prefix_cut = 0          # hit tokens cut for want of state
         self._attn_geometry = self._ragged_attn_geometry(model)
+        # connections a row passes a tick where the residual is several
+        # streams (the model's ``hc_connections``): the tick span's
+        # ``hc_rows`` is that many a live row
+        self._hc_connections = int(getattr(model, "hc_connections", 0))
         self._draft_model = draft_model \
             if gamma and cfg.drafter == "model" else None
         if self._draft_model is not None:
@@ -2354,6 +2358,8 @@ class ServingEngine:
         pend_pos0 = {i: int(view[i].pend_pos) for i in given}
         # row t of slot s sees base[s] + t + 1 positions
         attn_grid = self._attn_grid(q_lens, base + 1)
+        if self._hc_connections:
+            attn_grid["hc_rows"] = int(q_lens.sum()) * self._hc_connections
         dispatch = "packed" if prev is None else "carry"
         t_l0 = time.monotonic()
         if ph is not None:

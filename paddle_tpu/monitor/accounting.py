@@ -190,6 +190,9 @@ def _mosaic_kernels(hlo_text: str) -> Dict[str, int]:
 COMPONENTS = (
     "embed",         # token ids -> rows of the embedding table
     "norm",          # the layer norms on the residual stream, final norm
+    "residual.hc",   # a multi-stream residual's connection boundary
+    #                  outside kernel:hc_mix (its leaves' casts; on the
+    #                  CPU the whole mirror)
     "mixer.in",      # q | k | v, in_proj, low-rank decay and gate products
     "mixer.glue",    # rope, QK-norm, gating, silu and split, chunk slabs,
     #                  the query tiles around an attention kernel

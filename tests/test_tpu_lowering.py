@@ -461,3 +461,43 @@ def test_tick_compiled_for_tpu_is_named_by_component(tick):
     out = [r for r in rows if r["component"] == "mixer.out"
            and r["shape"].startswith("(f32[")]
     assert out and all("norm" in r["also"] for r in out)
+
+
+def test_xing_tick_compiled_for_tpu_runs_the_connection_kernel():
+    """``longprompt-sat.xing4.0``'s widths and engine at depth 2 (the
+    dense and an expert layer), two held experts of the 64-wide gate,
+    2,048 rows of vocabulary, through the TPU compiler: every connection
+    boundary is ONE ``hc_mix`` custom call (2 x 2 + 1 of them), beside
+    the latent kernel and the grouped matmuls, and the layers are
+    ``mla``."""
+    if _v5e_placement() is None:
+        pytest.skip("needs the TPU compiler (libtpu topology)")
+    from benchmark.models import xing4_0 as fam
+    from paddle_tpu.monitor import accounting
+    from paddle_tpu.models.xing4_0 import Xing40Config, Xing40ForCausalLM
+    import paddle_tpu as paddle
+    paddle.seed(0)
+    cfg = _cell_config("configs/xing4.0-29b-a4b.d6.json")
+    model = Xing40ForCausalLM(Xing40Config(
+        dtype="bfloat16", initializer_range=0.0, n_routed_experts=64,
+        expert_first=0, expert_count=2,
+        **dict({k: cfg[k] for k in fam.CFG_KEYS}, num_hidden_layers=2,
+               vocab_size=2048)))
+    model.eval()
+    cell = _cell_config("workloads/longprompt-sat.xing4.0-29b-a4b.d6.json")
+    rows, text = _tick_components(
+        model, dict(cell["engine"], num_blocks=512), 1200)
+    names = {r["component"] for r in rows}
+    assert {"kernel:hc_mix", "kernel:ragged_latent_attention",
+            "kernel:gmm"} <= names
+    assert names <= set(accounting.COMPONENTS) | {
+        "kernel:hc_mix", "kernel:ragged_latent_attention", "kernel:gmm",
+        "copy", "unnamed"}
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "hc_mix" in line]
+    assert len(calls) == 5
+    assert {r["layer"].partition(".")[2] for r in rows if r["layer"]} \
+        == {"mla"}
+    unnamed = [r for r in rows if r["component"] == "unnamed"]
+    assert sum(r["bytes"] for r in unnamed) \
+        < 0.05 * sum(r["bytes"] for r in rows)
