@@ -231,7 +231,7 @@ class GenerationMixin:
     def _build_model_step(self, binder, buffers, want_hidden=False):
         def model_step(params_a, tok_ids, caches, off, mask=None,
                       pos=None, block_tables=None, cache_lens=None,
-                      ragged_meta=None):
+                      ragged_meta=None, logit_rows=None):
             # a layer's cache is a tuple of arrays: a (k, v) pair, or
             # the one array of a latent (MLA) cache
             t_caches = [tuple(_wrap_out(c) for c in layer)
@@ -253,6 +253,11 @@ class GenerationMixin:
                 # packed row buffer
                 kwargs["ragged_meta"] = tuple(
                     _wrap_out(x) for x in ragged_meta)
+            if logit_rows is not None:
+                # [N] int32 packed rows: the head runs over these alone
+                # (the logits come back [1, N, V]); the hidden state a
+                # draft head reads stays whole
+                kwargs["logit_rows"] = _wrap_out(logit_rows)
             if want_hidden:
                 # draft-head speculation needs the final hidden state
                 # alongside the logits

@@ -1030,6 +1030,10 @@ class ServingEngine:
         # static packed width: every active slot's decode/verify rows
         # plus one tick's prefill row budget always fit
         self._rows = cfg.num_slots * (gamma + 1) + self._prefill_rows
+        # the rows the LM head runs over: the ones the tick samples
+        # from (a slot's continuation row, and speculating its verify
+        # window), gathered from the hidden state before the head
+        self._head_rows = cfg.num_slots * (gamma + 2 if gamma else 1)
         # static per-slot row ceiling (the rows of a slot the ragged
         # kernel attends)
         self._wmax = max(gamma + 1,
@@ -2358,6 +2362,7 @@ class ServingEngine:
         pend_pos0 = {i: int(view[i].pend_pos) for i in given}
         # row t of slot s sees base[s] + t + 1 positions
         attn_grid = self._attn_grid(q_lens, base + 1)
+        attn_grid["head_rows"] = self._head_rows
         if self._hc_connections:
             attn_grid["hc_rows"] = int(q_lens.sum()) * self._hc_connections
         dispatch = "packed" if prev is None else "carry"
@@ -5016,6 +5021,8 @@ class ServingEngine:
         continuation row from ``last_rows`` — decode rows sample their
         only row, completing prefills their final prompt row, verify
         windows run the shared acceptance core on their gamma+1 rows.
+        Those rows are taken from the hidden state BEFORE the LM head,
+        which runs over them alone (``logit_rows``: ``_head_rows``).
         ONE logits gather serves all of it (under TP: still exactly
         one explicit all_gather per step). Its census name is
         ``verify`` on a speculating engine, else ``decode``."""
@@ -5098,6 +5105,16 @@ class ServingEngine:
                 nwin = jnp.arange(g + 1, dtype=jnp.int32)
                 win = jnp.arange(self._wmax, dtype=jnp.int32)
                 meta = (q_lens, row_starts, row_slot, row_pos, nwin, win)
+            with component("sample"):
+                # the rows the tick samples from: each slot's
+                # continuation row, then (verifying) its window — ONE
+                # index set, gathered before the head
+                take = last_rows.astype(jnp.int32)
+                if g:
+                    idx = row_starts.astype(jnp.int32)[:, None] \
+                        + jnp.arange(g + 1, dtype=jnp.int32)[None, :]
+                    take = jnp.clip(jnp.concatenate(
+                        [take[:, None], idx], axis=1), 0, r - 1).reshape(-1)
             # pad rows park at the overflow position — exclude them
             # from the MoE routing telemetry (they'd read as
             # hot-expert skew on lightly loaded ticks)
@@ -5131,24 +5148,21 @@ class ServingEngine:
                 logits, pools = step(
                     params, ids[None, :], pools, None,
                     block_tables=tables, cache_lens=base,
-                    ragged_meta=meta)
+                    ragged_meta=meta, logit_rows=take)
             if heads_on:
                 logits, hid = logits
             with component("sample"):
-                lg = logits[0]                      # [R, V(/tp)]
+                lg = logits[0]              # [len(take), V(/tp)]
                 if not g:
-                    return sample_rows(lg, last_rows, q_lens, rest,
-                                       pools)
-                return verify_rows(lg, last_rows, row_starts, tables,
-                                   base, tree_rows,
+                    return sample_rows(lg, q_lens, rest, pools)
+                return verify_rows(lg, row_starts, tables, base,
+                                   tree_rows,
                                    hid if heads_on else None, rest,
                                    pools)
 
-        def sample_rows(lg, last_rows, q_lens, rest, pools):
+        def sample_rows(lg, q_lens, rest, pools):
             samp, key = rest
-            rows = jnp.take(lg, last_rows.astype(jnp.int32),
-                            axis=0)
-            rows = self._gather_logits(rows)    # the ONE collective
+            rows = self._gather_logits(lg)      # the ONE collective
             # health probe: one any(~isfinite) reduction over the
             # rows already gathered for sampling — a scalar OUTPUT
             # of the same executable, never a new one. Always
@@ -5171,8 +5185,8 @@ class ServingEngine:
                     tok, NamedSharding(self._mesh, P(None)))
             return tok, nf, pools
 
-        def verify_rows(lg, last_rows, row_starts, tables, base,
-                        tree_rows, hid, rest, pools):
+        def verify_rows(lg, row_starts, tables, base, tree_rows, hid,
+                        rest, pools):
             toks = rest[0]
             if tree is not None:
                 heads = rest[1] if heads_on else None
@@ -5181,15 +5195,9 @@ class ServingEngine:
                 dq = rest[1] if len(rest) == 4 else None
             samp = rest[-2]
             key = rest[-1]
-            # one take + ONE gather covers the per-slot continuation
-            # rows AND the verify windows
-            idx = row_starts.astype(jnp.int32)[:, None] \
-                + jnp.arange(g + 1, dtype=jnp.int32)[None, :]
-            take = jnp.concatenate(
-                [last_rows.astype(jnp.int32)[:, None], idx], axis=1)
-            rows = jnp.take(lg, jnp.clip(take, 0, r - 1).reshape(-1),
-                            axis=0)
-            rows = self._gather_logits(rows)
+            # ONE gather covers the per-slot continuation rows AND the
+            # verify windows
+            rows = self._gather_logits(lg)
             nf = jnp.any(~jnp.isfinite(rows))   # health probe (see g=0)
             rows = rows.reshape(toks.shape[0], g + 2, -1)
             sel_key, acc_key = jax.random.split(key)

@@ -18,7 +18,8 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-from ..framework.core import Tensor, apply_jax, as_jax, _wrap_out
+from ..framework.core import Tensor, apply_jax, as_jax, component, \
+    _wrap_out
 from ..nn.layer.layers import Layer
 from ..nn.layer.norm import RMSNorm
 from ..distributed.fleet.mp_layers import (ColumnParallelLinear,
@@ -26,7 +27,7 @@ from ..distributed.fleet.mp_layers import (ColumnParallelLinear,
 from ..distributed.shard_utils import batch_shard
 from ..generation import GenerationMixin
 from .llama import (LlamaAttention, LlamaPretrainingCriterion,
-                    _rope_tables)
+                    _rope_tables, head_rows)
 from .qwen2_moe import StackedExpertsMLP, _DenseMLP
 
 __all__ = ["DeepseekMoeConfig", "DeepseekMoeModel",
@@ -279,7 +280,8 @@ class DeepseekMoeForCausalLM(Layer, GenerationMixin):
 
     def forward(self, input_ids, labels=None, attention_mask=None,
                 caches=None, offset=None, position_ids=None,
-                block_tables=None, cache_lens=None, ragged_meta=None):
+                block_tables=None, cache_lens=None, ragged_meta=None,
+                logit_rows=None):
         if caches is not None:
             h, _, new_caches = self.deepseek(input_ids, attention_mask,
                                              caches=caches, offset=offset,
@@ -287,7 +289,8 @@ class DeepseekMoeForCausalLM(Layer, GenerationMixin):
                                              block_tables=block_tables,
                                              cache_lens=cache_lens,
                                              ragged_meta=ragged_meta)
-            return self._logits(h), new_caches
+            with component("head"):
+                return self._logits(head_rows(h, logit_rows)), new_caches
         h, aux_total = self.deepseek(input_ids, attention_mask)
         logits = self._logits(h)
         if labels is None:

@@ -61,7 +61,7 @@ from ..nn import functional as F
 from ..nn.layer.container import LayerList
 from ..nn.layer.layers import Layer, create_parameter
 from ..generation import GenerationMixin
-from .llama import LlamaPretrainingCriterion, _rope_rotate
+from .llama import LlamaPretrainingCriterion, _rope_rotate, head_rows
 
 __all__ = ["DeepseekV3Config", "DeepseekV3Model", "DeepseekV3ForCausalLM",
            "yarn_inv_freq", "yarn_mscale", "yarn_softmax_scale"]
@@ -578,7 +578,8 @@ class DeepseekV3ForCausalLM(Layer, GenerationMixin):
 
     def forward(self, input_ids, labels=None, attention_mask=None,
                 caches=None, offset=None, position_ids=None,
-                block_tables=None, cache_lens=None, ragged_meta=None):
+                block_tables=None, cache_lens=None, ragged_meta=None,
+                logit_rows=None):
         if attention_mask is not None or position_ids is not None:
             raise NotImplementedError(
                 "padded batches (attention_mask / position_ids)")
@@ -589,7 +590,7 @@ class DeepseekV3ForCausalLM(Layer, GenerationMixin):
                 input_ids, caches=caches, block_tables=block_tables,
                 cache_lens=cache_lens, ragged_meta=ragged_meta)
             with component("head"):
-                return self._logits(h), new_caches
+                return self._logits(head_rows(h, logit_rows)), new_caches
         logits = self._logits(self.model(input_ids))
         return logits if labels is None \
             else self.criterion(logits, labels)

@@ -7,7 +7,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from ..framework.core import Tensor, apply_jax, as_jax, _wrap_out
+from ..framework.core import Tensor, apply_jax, as_jax, component, \
+    _wrap_out
 from ..nn import functional as F
 from ..nn.layer.common import Dropout, Embedding
 from ..nn.layer.container import LayerList
@@ -18,6 +19,7 @@ from ..distributed.fleet.mp_layers import (ColumnParallelLinear,
                                            VocabParallelEmbedding)
 from ..distributed.shard_utils import batch_shard, constraint
 from ..generation import GenerationMixin
+from .llama import head_rows
 
 __all__ = ["GPTConfig", "GPTModel", "GPTForCausalLM",
            "GPTPretrainingCriterion"]
@@ -361,7 +363,7 @@ class GPTForCausalLM(Layer, GenerationMixin):
 
     def forward(self, input_ids, labels=None, caches=None, offset=None,
                 block_tables=None, cache_lens=None, ragged_meta=None,
-                return_hidden=False):
+                logit_rows=None, return_hidden=False):
         from ..ops.linalg import matmul
         if caches is not None:
             h, new_caches = self.gpt(input_ids, caches=caches,
@@ -369,8 +371,10 @@ class GPTForCausalLM(Layer, GenerationMixin):
                                      block_tables=block_tables,
                                      cache_lens=cache_lens,
                                      ragged_meta=ragged_meta)
-            logits = matmul(h, self.gpt.embeddings.weight,
-                            transpose_y=True)
+            with component("head"):
+                logits = matmul(head_rows(h, logit_rows),
+                                self.gpt.embeddings.weight,
+                                transpose_y=True)
             if return_hidden:
                 return (logits, h), new_caches
             return logits, new_caches

@@ -56,7 +56,7 @@ from ..nn.layer.layers import Layer
 from ..generation import GenerationMixin
 from ..ops.short_conv import causal_taps, ragged_causal_taps
 from .deepseek_v3 import _linear, _norm, _param, _rms, _swiglu
-from .llama import (LlamaPretrainingCriterion, _rope_rotate,
+from .llama import (LlamaPretrainingCriterion, _rope_rotate, head_rows,
                     ragged_paged_attention_decode)
 
 __all__ = ["Lfm2MoeConfig", "Lfm2MoeModel", "Lfm2MoeForCausalLM"]
@@ -506,7 +506,8 @@ class Lfm2MoeForCausalLM(Layer, GenerationMixin):
 
     def forward(self, input_ids, labels=None, attention_mask=None,
                 caches=None, offset=None, position_ids=None,
-                block_tables=None, cache_lens=None, ragged_meta=None):
+                block_tables=None, cache_lens=None, ragged_meta=None,
+                logit_rows=None):
         if attention_mask is not None or position_ids is not None:
             raise NotImplementedError(
                 "padded batches (attention_mask / position_ids)")
@@ -519,7 +520,7 @@ class Lfm2MoeForCausalLM(Layer, GenerationMixin):
                 input_ids, caches=caches, block_tables=block_tables,
                 cache_lens=cache_lens, ragged_meta=ragged_meta)
             with component("head"):
-                return self._logits(h), new_caches
+                return self._logits(head_rows(h, logit_rows)), new_caches
         logits = self._logits(self.model(input_ids))
         return logits if labels is None \
             else self.criterion(logits, labels)

@@ -85,6 +85,16 @@ class LlamaConfig:
             num_key_value_heads=kv_heads, max_position_embeddings=2048)
 
 
+def head_rows(h, logit_rows):
+    """What the LM head runs over: the packed hidden rows ``h [1, R,
+    hidden]`` taken at ``logit_rows`` (``[N]`` int32, the rows a serving
+    tick samples from), or ``h`` whole where ``logit_rows`` is None."""
+    if logit_rows is None:
+        return h
+    return apply_jax("head_rows", lambda a, i: jnp.take(a, i, axis=1),
+                     h, logit_rows)
+
+
 def _rope_tables(seq_len, head_dim, theta):
     pos = np.arange(seq_len, dtype=np.float32)
     inv = theta ** (-np.arange(0, head_dim, 2,
@@ -718,7 +728,7 @@ class LlamaForCausalLM(Layer, GenerationMixin):
     def forward(self, input_ids, labels=None, attention_mask=None,
                 position_ids=None, caches=None, offset=None,
                 block_tables=None, cache_lens=None, ragged_meta=None,
-                return_hidden=False):
+                logit_rows=None, return_hidden=False):
         if caches is not None:
             h, new_caches = self.llama(input_ids, attention_mask,
                                        position_ids, caches=caches,
@@ -727,7 +737,8 @@ class LlamaForCausalLM(Layer, GenerationMixin):
                                        cache_lens=cache_lens,
                                        ragged_meta=ragged_meta)
             with component("head"):
-                logits = self._head_and_loss(h, None)
+                logits = self._head_and_loss(head_rows(h, logit_rows),
+                                             None)
             return ((logits, h) if return_hidden else logits), new_caches
         h = self.llama(input_ids, attention_mask, position_ids)
         return self._head_and_loss(h, labels)

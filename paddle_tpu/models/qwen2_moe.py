@@ -15,7 +15,8 @@ from dataclasses import dataclass
 
 import jax.numpy as jnp
 
-from ..framework.core import Tensor, apply_jax, as_jax, _wrap_out
+from ..framework.core import Tensor, apply_jax, as_jax, component, \
+    _wrap_out
 from ..nn.initializer import Normal
 from ..nn.layer.layers import Layer
 from ..nn.layer.norm import RMSNorm
@@ -26,7 +27,7 @@ from ..distributed.shard_utils import batch_shard
 from ..generation import GenerationMixin
 from ..incubate.nn.functional import swiglu
 from .llama import (LlamaAttention, LlamaPretrainingCriterion,
-                    _rope_tables)
+                    _rope_tables, head_rows)
 
 __all__ = ["Qwen2MoeConfig", "Qwen2MoeModel", "Qwen2MoeForCausalLM",
            "StackedExpertsMLP"]
@@ -364,7 +365,8 @@ class Qwen2MoeForCausalLM(Layer, GenerationMixin):
 
     def forward(self, input_ids, labels=None, attention_mask=None,
                 caches=None, offset=None, position_ids=None,
-                block_tables=None, cache_lens=None, ragged_meta=None):
+                block_tables=None, cache_lens=None, ragged_meta=None,
+                logit_rows=None):
         if caches is not None:
             h, _, new_caches = self.qwen2_moe(input_ids, attention_mask,
                                               caches=caches, offset=offset,
@@ -372,7 +374,8 @@ class Qwen2MoeForCausalLM(Layer, GenerationMixin):
                                               block_tables=block_tables,
                                               cache_lens=cache_lens,
                                               ragged_meta=ragged_meta)
-            return self._logits(h), new_caches
+            with component("head"):
+                return self._logits(head_rows(h, logit_rows)), new_caches
         h, aux_total = self.qwen2_moe(input_ids, attention_mask)
         logits = self._logits(h)
         if labels is None:

@@ -67,7 +67,8 @@ from ..ops.pallas.delta_rule import kda_step
 from ..ops.short_conv import causal_taps, ragged_causal_taps
 from .deepseek_v3 import (DeepseekV3MoE, _Leaf, _linear, _norm, _param,
                           _rms)
-from .llama import LlamaPretrainingCriterion, ragged_paged_attention_decode
+from .llama import (LlamaPretrainingCriterion, head_rows,
+                    ragged_paged_attention_decode)
 
 __all__ = ["SolarOpen2Config", "SolarOpen2Model", "SolarOpen2ForCausalLM"]
 
@@ -530,7 +531,8 @@ class SolarOpen2ForCausalLM(Layer, GenerationMixin):
 
     def forward(self, input_ids, labels=None, attention_mask=None,
                 caches=None, offset=None, position_ids=None,
-                block_tables=None, cache_lens=None, ragged_meta=None):
+                block_tables=None, cache_lens=None, ragged_meta=None,
+                logit_rows=None):
         if attention_mask is not None or position_ids is not None:
             raise NotImplementedError(
                 "padded batches (attention_mask / position_ids)")
@@ -543,7 +545,7 @@ class SolarOpen2ForCausalLM(Layer, GenerationMixin):
                 input_ids, caches=caches, block_tables=block_tables,
                 cache_lens=cache_lens, ragged_meta=ragged_meta)
             with component("head"):
-                return self.lm_head(h), new_caches
+                return self.lm_head(head_rows(h, logit_rows)), new_caches
         logits = self.lm_head(self.model(input_ids))
         return logits if labels is None \
             else self.criterion(logits, labels)

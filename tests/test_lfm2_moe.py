@@ -82,8 +82,10 @@ def _ref_logits(cfg, w, seq, pad_to=128):
 def _engine(model, **kw):
     """An engine whose tick also hands every row's logits to the test
     (``engine.rows``: per tick the rows' input ids, slots, positions
-    and logits), so that what is compared is every logit the engine
-    computed, not only which token came first."""
+    and logits), so that what is compared is every row's logits, not
+    only which token came first. Those rows come from a second pass of
+    the step with the head over every row; the tick serves from its
+    own pass, the head over the rows it samples (``logit_rows``)."""
     base = dict(num_slots=4, max_model_len=128, block_size=16,
                 prefill_chunk=16, host_kv_tier_bytes=0)
     base.update(kw)
@@ -91,13 +93,13 @@ def _engine(model, **kw):
     engine.rows = []
     inner = engine._model_step
 
-    def step(params, ids, *a, **kw):
-        logits, pools = inner(params, ids, *a, **kw)
+    def step(params, ids, *a, logit_rows=None, **kw):
+        every, _pools = inner(params, ids, *a, **kw)
         _ql, _rs, slot, pos = kw["ragged_meta"][:4]
         jax.debug.callback(
             lambda *x: engine.rows.append(tuple(map(np.asarray, x))),
-            ids[0], slot, pos, logits[0])
-        return logits, pools
+            ids[0], slot, pos, every[0])
+        return inner(params, ids, *a, logit_rows=logit_rows, **kw)
 
     engine._model_step = step
     return engine

@@ -104,13 +104,13 @@ def _engine(model, **kw):
     engine.rows = []
     inner = engine._model_step
 
-    def step(params, ids, *a, **kw):
-        logits, pools = inner(params, ids, *a, **kw)
+    def step(params, ids, *a, logit_rows=None, **kw):
+        every, _pools = inner(params, ids, *a, **kw)
         _ql, _rs, slot, pos = kw["ragged_meta"][:4]
         jax.debug.callback(
             lambda *x: engine.rows.append(tuple(map(np.asarray, x))),
-            ids[0], slot, pos, logits[0])
-        return logits, pools
+            ids[0], slot, pos, every[0])
+        return inner(params, ids, *a, logit_rows=logit_rows, **kw)
 
     engine._model_step = step
     return engine
