@@ -99,6 +99,10 @@ EXPECT = {
     "kernel.kda_chunk": ("mosaic", ("kda_chunk",)),
     "kernel.short_conv_taps": ("mosaic", ("short_conv_taps",)),
     "kernel.short_conv_taps.3taps": ("mosaic", ("short_conv_taps",)),
+    # (Mamba's scan and the window rings of the SambaY decoder)
+    "kernel.ssm_recurrent": ("mosaic", ("ssm_recurrent",)),
+    "kernel.ssm_chunk": ("mosaic", ("ssm_chunk",)),
+    "kernel.window_attention": ("mosaic", ("window_attention",)),
     # (a residual of four streams: one connection boundary, and both ends)
     "kernel.hc_mix": ("mosaic", ("hc_mix",)),
     "kernel.hc_mix.first": ("mosaic", ("hc_mix",)),
@@ -712,6 +716,61 @@ def kernel_cases(full, interpret=None):
         ("kernel.kda_recurrent", kda_case(dr.pallas_kda_recurrent,
                                           dr._xla_recurrent)),
         ("kernel.kda_chunk", kda_case(dr.pallas_kda_chunk, dr._xla_chunk))]
+
+    # -- Mamba's selective scan and the window rings over the same tick -------
+    # (the long-generation cell's: 96 seats of 16 x 5,120 float32 scan
+    # state and 512-position rings of 1,280 lanes, 40 paired query heads)
+    sd = dict(S=96, E=5120, W=256, ring=512, lanes=1280, H=40) if full \
+        else dict(S=4, E=256, W=80, ring=32, lanes=256, H=8)
+
+    def ssm_case(kernel, mirror):
+        def build(rng):
+            s_, e_, w_ = sd["S"], sd["E"], sd["W"]
+            rows = s_ + w_
+            ops = [jnp.asarray(x, jnp.float32) for x in (
+                rng.uniform(1e-3, 0.1, (rows, e_)),
+                rng.standard_normal((rows, e_)),
+                rng.standard_normal((rows, 16)),
+                rng.standard_normal((rows, 16)),
+                -rng.uniform(1, 16, (16, e_)))]
+            state = jnp.asarray(rng.standard_normal((s_ + 1, 16, e_)),
+                                jnp.float32).at[s_].set(0)
+            meta = tick_meta(s_, w_) + [jnp.arange(1, dtype=jnp.int32),
+                                        jnp.arange(w_, dtype=jnp.int32)]
+
+            def run(fn):
+                return lambda dt, x, b, c, a, st, *m: fn(dt, x, b, c, a,
+                                                         st, m)
+            return (run(lambda *a_: kernel(*a_, interpret=interpret)),
+                    run(mirror), (*ops, state, *meta))
+        return build
+
+    def window_build(rng):
+        from paddle_tpu.ops.pallas import window_attention as wa
+        s_, w_, ring, lanes = sd["S"], sd["W"], sd["ring"], sd["lanes"]
+        rows = s_ + w_
+        q = mat(rng, rows, sd["H"], 128)
+        k, v = mat(rng, rows, lanes), mat(rng, rows, lanes)
+        rk, rv = (mat(rng, s_ + 1, ring, lanes) for _ in "kv")
+        meta = tick_meta(s_, w_) + [jnp.arange(1, dtype=jnp.int32),
+                                    jnp.arange(w_, dtype=jnp.int32)]
+        base = jnp.full((s_,), 70, jnp.int32).at[3].set(0)
+
+        def kern(q_, k_, v_, rk_, rv_, b_, *m):
+            return sum(f(q_, k_, v_, rk_, rv_, b_, m, ring, 0.125,
+                         interpret=interpret)
+                       for f in (wa.pallas_window_decode,
+                                 wa.pallas_window_chunk))
+        return (kern, lambda q_, k_, v_, rk_, rv_, b_, *m: wa._xla_window(
+                    q_, k_, v_, rk_, rv_, b_, m, ring, 0.125),
+                (q, k, v, rk, rv, base, *meta))
+
+    from paddle_tpu.ops.pallas import selective_scan as ss
+    cases += [
+        ("kernel.ssm_recurrent", ssm_case(ss.pallas_ssm_recurrent,
+                                          ss._xla_recurrent)),
+        ("kernel.ssm_chunk", ssm_case(ss.pallas_ssm_chunk, ss._xla_chunk)),
+        ("kernel.window_attention", window_build)]
 
     # -- the short convolution's tap reader over the same kind of tick -------
     # (the reasoning cell's: 97 seats x 3 stored taps x 24,576 channels,

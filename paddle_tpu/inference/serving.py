@@ -1074,6 +1074,9 @@ class ServingEngine:
         # streams (the model's ``hc_connections``): the tick span's
         # ``hc_rows`` is that many a live row
         self._hc_connections = int(getattr(model, "hc_connections", 0))
+        # what else the model counts on the tick span, from the slots'
+        # ``q_lens`` and base lengths (the model's ``tick_counts``)
+        self._tick_counts = getattr(model, "tick_counts", None)
         self._draft_model = draft_model \
             if gamma and cfg.drafter == "model" else None
         if self._draft_model is not None:
@@ -2365,6 +2368,8 @@ class ServingEngine:
         attn_grid["head_rows"] = self._head_rows
         if self._hc_connections:
             attn_grid["hc_rows"] = int(q_lens.sum()) * self._hc_connections
+        if self._tick_counts is not None:
+            attn_grid.update(self._tick_counts(q_lens, base))
         dispatch = "packed" if prev is None else "carry"
         t_l0 = time.monotonic()
         if ph is not None:

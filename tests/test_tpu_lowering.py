@@ -501,3 +501,51 @@ def test_xing_tick_compiled_for_tpu_runs_the_connection_kernel():
     unnamed = [r for r in rows if r["component"] == "unnamed"]
     assert sum(r["bytes"] for r in unnamed) \
         < 0.05 * sum(r["bytes"] for r in rows)
+
+
+def test_phi4flash_tick_compiled_for_tpu_runs_its_kernels():
+    """``reason-long-sat.phi4-mini-flash``'s widths and engine at depth 8
+    (every layer kind once or more: ``mamba, swa, mamba, swa, mamba
+    (memory), full, gmu, cross``), 2,048 rows of vocabulary and an FFN
+    of 2,560 (neither reaches a kernel), through the TPU compiler: the
+    scan's two kernels and the short conv's once a Mamba layer, the
+    window kernel's two calls a ``swa`` layer, the ragged kernel once in
+    the ``full`` layer and once a ``cross`` layer, and the layers are
+    named by their kinds."""
+    if _v5e_placement() is None:
+        pytest.skip("needs the TPU compiler (libtpu topology)")
+    from benchmark.models import phi4flash as fam
+    from paddle_tpu.monitor import accounting
+    from paddle_tpu.models.phi4flash import (Phi4FlashConfig,
+                                             Phi4FlashForCausalLM)
+    import paddle_tpu as paddle
+    paddle.seed(0)
+    cfg = _cell_config("configs/phi4-mini-flash-3.8b.d32.json")
+    model = Phi4FlashForCausalLM(Phi4FlashConfig(
+        dtype="bfloat16", initializer_range=0.0,
+        **dict({k: cfg[k] for k in fam.CFG_KEYS}, num_hidden_layers=8,
+               vocab_size=2048, intermediate_size=2560)))
+    model.eval()
+    cell = _cell_config(
+        "workloads/reason-long-sat.phi4-mini-flash-3.8b.d32.json")
+    rows, text = _tick_components(
+        model, dict(cell["engine"], num_blocks=512), 600)
+    kernels = {"kernel:ssm_recurrent", "kernel:ssm_chunk",
+               "kernel:short_conv_taps", "kernel:window_attention",
+               "kernel:ragged_paged_attention"}
+    names = {r["component"] for r in rows}
+    assert kernels <= names
+    assert names <= set(accounting.COMPONENTS) | kernels | {"copy",
+                                                            "unnamed"}
+    for scope, count in (("ssm_recurrent", 3), ("ssm_chunk", 3),
+                         ("window_attention", 4),
+                         ("ragged_paged_attention", 2)):
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line
+                 and scope in line]
+        assert len(calls) == count, scope
+    assert {r["layer"].partition(".")[2] for r in rows if r["layer"]} \
+        == {"mamba", "swa", "full", "gmu", "cross"}
+    unnamed = [r for r in rows if r["component"] == "unnamed"]
+    assert sum(r["bytes"] for r in unnamed) \
+        < 0.05 * sum(r["bytes"] for r in rows)
