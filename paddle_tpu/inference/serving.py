@@ -1855,7 +1855,8 @@ class ServingEngine:
                    q_dtype=dtype, block_size=self._bs,
                    max_blocks=self._mb, head_lanes=int(pool.shape[-1]),
                    # K and V, and an int8 pool's two scale pools
-                   streams=4 if isinstance(paged0[0], _pc.QuantKV) else 2)
+                   streams=4 if isinstance(paged0[0], _pc.QuantKV) else 2,
+                   narrow=True)
         if len(paged0) == 2 and pool.ndim == 3:
             # a flat pool: its rows hold every kv head; the kernel walks
             # them a lane tile (one head, or two of 64 lanes) at a time
@@ -1867,16 +1868,15 @@ class ServingEngine:
         if len(paged0) == 1:
             # a latent (MLA) cache: every head reads the layer's one
             # array, in the latent kernel's own tile
-            geo.update(num_kv_heads=1, tile=_pa.LATENT_TILE, streams=1,
-                       narrow=True)
+            geo.update(num_kv_heads=1, latent=True, streams=1)
         return geo
 
     def _attn_grid(self, q_lens, context_lens):
         """The ``tick`` span's ``attn_units`` / ``attn_live`` /
-        ``attn_copies`` (and, over a latent pool, ``attn_narrow``) of
-        one layer's ragged attention call this tick (docs/OPS.md "Tick
-        phases"), or nothing where no span would carry them: numpy on
-        ``num_slots`` entries."""
+        ``attn_copies`` / ``attn_narrow`` of one layer's ragged
+        attention call this tick (docs/OPS.md "Tick phases"), or
+        nothing where no span would carry them: numpy on ``num_slots``
+        entries."""
         if self._trace is None or self._attn_geometry is None:
             return {}
         return dict(zip(

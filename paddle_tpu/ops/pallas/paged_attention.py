@@ -82,6 +82,15 @@ tick holds, not what it could hold:
   copies; the heads then run as a static loop over the copied
   buffer's lane tiles, each with its own f32 softmax state, under one
   mask. A tile past the live count loops zero times and stores zeros.
+- **a one-token tile walks narrow.** A tile whose live rows are ONE
+  window token (a decoding slot, a prompt trickling a row a tick,
+  every tile of a one-row-a-slot call) computes at that token's
+  ``rp`` rows against kv tiles of ``_NARROW_POSITIONS`` positions and
+  copies no block past its reach; the tile's live-token count rides
+  in SMEM beside the rest of the tile list and picks the walk
+  (``_tile_rungs``, the latent kernel's rule). A call of one row a
+  slot takes the whole pool row (up to ``_ONE_ROW_LANES``) as its
+  head group.
   At the default serving shape (8 slots, 136 rows, 4 kv heads, a
   64-block table) that is 25 grid steps of at most 8 iterations (176
   at the wide cell's 128 slots and 384 rows), where the ``(slot,
@@ -384,11 +393,40 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
 _TILE_ROWS = 128
 _TILE_POSITIONS = 128
 
+# cache positions one kv tile of the ragged kernel's NARROW walk spans:
+# a query tile that holds one window token (a decoding slot, a prompt
+# trickling a row a tick, every tile of a one-row-a-slot call) computes
+# at that token's ``rp`` rows, and at 16 rows a [16, N] score tile is
+# the element-wise work of the full body's [128, 128], so one softmax
+# update covers N positions where the full body's covers 128. The
+# kernel alone on one v5e, ms a call at N = 128 / 256 / 512 / 1024
+# against the 128-row body before (``benchmark/tools/ragged_probe.py``,
+# seed 2147539001; chip run): the cross layers' call (96 one-row slots
+# at a mean 1,852 positions, 10 paired lane tiles) 7.08 -> 5.68 / 5.03
+# / 3.96 / 3.51 in 5 head groups, 3.06 / 2.68 / 1.80 / 1.75 as one
+# (``_ONE_ROW_LANES``); Solar's gqa call (95 decode rows at 1,623
+# beside a 256-row chunk, 8 kv heads in 2 groups) 5.19 -> 3.38 / 3.15
+# / 2.43 / 2.20; LFM2's (127 decode rows at 715, 4 pairs) 1.73 -> 1.18
+# / 1.13 / 0.95 / 0.93
+_NARROW_POSITIONS = 1024
+
 # lanes of a pool row one ragged grid step copies and holds: the kv
 # heads of one head group (``_head_group``). 512 lanes (4 heads of 128,
 # or 8 of 64 in pairs) keep the step's K/V halves, q/out blocks and
 # softmax state near 2 MB of VMEM and its body at four unrolled heads
 _GROUP_LANES = 512
+# the same for a call of ONE row a slot, where every tile walks narrow
+# (``_NARROW_POSITIONS``): a pool row of up to 2,048 lanes is one group,
+# so a block leaves HBM as one contiguous copy a stream. At the cross
+# layers' call (10 paired lane tiles, 96 one-row slots at ~1.85k
+# positions) five groups of 256 lanes — 16 strided 512-byte rows a
+# block copy — take 3.51 ms a call, the whole 1,280-lane row 1.75
+# (chip run, the probe of ``_NARROW_POSITIONS``)
+_ONE_ROW_LANES = 2048
+# VMEM the ragged kernel asks Mosaic for (its default grant is 16 MB of
+# the v5e core's 128 MB): the narrow walk's K/V halves of a one-row
+# call's whole pool row are 10 MB at 1,280 lanes of bf16
+_VMEM_LIMIT = 48 << 20
 
 
 # the latent (MLA) kernel's tile: every head reads the one latent, so a
@@ -400,7 +438,7 @@ _GROUP_LANES = 512
 # 3.20 at (1024, 256) (chip run, PR 26). What the tall tile costs a
 # slot that brings ONE token (a decode row, a prompt trickling a row a
 # tick) is no longer its dead rows: such a tile walks at the token's
-# own rows (``_latent_rungs``), a kv tile in ~1.5 us where the full
+# own rows (``_tile_rungs``), a kv tile in ~1.5 us where the full
 # tile takes 4.5 — about its 655 KB copy and the products' stationary
 # operands, which are pushed whatever the row count — and what is left
 # of the tile's height there is the ``[512, W]`` query block in and
@@ -430,6 +468,21 @@ def _ragged_geometry(rows, slots, rep, q_dtype, block_size, max_blocks,
     return (rp, tq, kb, slots + -(-rows // tq), -(-max_blocks // kb))
 
 
+def _narrow_blocks(block_size, max_blocks) -> int:
+    """Pool blocks one kv tile of the ragged kernel's narrow walk
+    chases: ``_NARROW_POSITIONS`` of cache, within the table."""
+    return max(1, min(_NARROW_POSITIONS // block_size, max_blocks))
+
+
+def _tile_rungs(tq):
+    """Window tokens of a tile the ragged and latent kernels' walks are
+    compiled at, ascending; a grid step takes the smallest that covers
+    its tile's live tokens, and a tile of the FIRST walks narrow. Two
+    rungs: ONE token — a decoding slot, a pending prompt trickling a
+    row a tick — and the whole tile."""
+    return (1, tq) if tq > 1 else (tq,)
+
+
 def _ragged_tiles(xp, ql, context_lens, tq, kv_span, n_tiles, n_kv):
     """The query tiles of one ragged call, ``[n_tiles]`` each: ``slot``
     the tile belongs to, ``row0`` its first window row, ``rows`` live
@@ -457,13 +510,15 @@ def _ragged_tiles(xp, ql, context_lens, tq, kv_span, n_tiles, n_kv):
     return slot, row0, rows, kv
 
 
-def _head_group(num_kv_heads, head_lanes) -> int:
+def _head_group(num_kv_heads, head_lanes, one_row=False) -> int:
     """kv heads (lane tiles of ``head_lanes``) one ragged grid step
     takes: every one of the pool row while the group stays within
-    ``_GROUP_LANES`` — a pool block then leaves HBM as ONE contiguous
-    copy — else the largest divisor of ``num_kv_heads`` that does, and
-    the step's query tile is walked once a group."""
-    hg = max(1, min(num_kv_heads, _GROUP_LANES // head_lanes))
+    ``_GROUP_LANES`` (``_ONE_ROW_LANES`` in a call of ``one_row`` a
+    slot) — a pool block then leaves HBM as ONE contiguous copy — else
+    the largest divisor of ``num_kv_heads`` that does, and the step's
+    query tile is walked once a group."""
+    lanes = _ONE_ROW_LANES if one_row else _GROUP_LANES
+    hg = max(1, min(num_kv_heads, lanes // head_lanes))
     while num_kv_heads % hg:
         hg -= 1
     return hg
@@ -471,69 +526,93 @@ def _head_group(num_kv_heads, head_lanes) -> int:
 
 def ragged_grid_units(q_lens, context_lens, *, rows, w_max, num_heads,
                       num_kv_heads, q_dtype, block_size, max_blocks,
-                      tile=(_TILE_ROWS, _TILE_POSITIONS), head_lanes=128,
-                      streams=2, narrow=False):
+                      latent=False, head_lanes=128, streams=2,
+                      narrow=False):
     """Host-side count of what ONE ``pallas_ragged_paged_attention``
-    call (or, with ``num_kv_heads=1``, ``streams=1`` and
-    ``tile=LATENT_TILE``, one ``pallas_ragged_latent_attention`` call)
+    call (or, ``latent=True`` with ``num_kv_heads=1`` and ``streams=1``,
+    one ``pallas_ragged_latent_attention`` call, in ``LATENT_TILE``)
     visits for this tick's ``q_lens`` / ``context_lens`` (numpy, no
     device read): ``(units, live, copies)``. ``units`` and ``live`` are
-    in (query tile, kv head, kv tile) units: a live tile walks one loop
-    iteration per kv tile of its reach for each of its kv heads, all
-    live; a tile past the live count is still launched, predicated off
-    — so ``units - live`` is the dead steps and ``live / units`` the
-    share of the walk that is work. ``copies`` is the async-copy
-    descriptors the call issues: per live (query tile, kv tile) one a
-    pool block (``kb``) a stream (K and V; an int8 pool's two scale
-    pools make ``streams`` 4) a head GROUP (``_head_group`` of
-    ``head_lanes``-wide kv heads) — not one a kv head. ``narrow=True``
-    (the latent call's geometry) appends a fourth value: the live
-    units that the latent kernel walks at its narrow rung (a tile
-    that holds one window token, ``_latent_rungs``: rows of one
-    token's heads, not of ``tq`` tokens')."""
+    in (query tile, kv head, kv tile) units, a kv tile being the full
+    walk's: a live tile counts one per kv tile of its reach for each of
+    its kv heads, all live; a tile past the live count is still
+    launched, predicated off — so ``units - live`` is the dead steps
+    and ``live / units`` the share of the walk that is work. ``copies``
+    is the async-copy descriptors the call issues, one a pool block a
+    stream (K and V; an int8 pool's two scale pools make ``streams`` 4)
+    a head GROUP (``_head_group`` of ``head_lanes``-wide kv heads), not
+    one a kv head: ``kb`` blocks a live (query tile, kv tile) of the
+    full walk, and for a tile the ragged kernel walks narrow the blocks
+    its token's reach covers, none past it. ``narrow=True`` appends a
+    fourth value: the live units of tiles that hold one window token,
+    which either kernel walks at its narrow rung (``_tile_rungs``)."""
     ql = np.minimum(np.asarray(q_lens, np.int64), w_max)
+    ctx = np.asarray(context_lens, np.int64)
     _, tq, kb, n_tiles, n_kv = _ragged_geometry(
         rows, ql.shape[0], num_heads // num_kv_heads, q_dtype,
-        block_size, max_blocks, tile)
-    _, _, held, kv = _ragged_tiles(
-        np, ql, np.asarray(context_lens, np.int64), tq,
-        kb * block_size, n_tiles, n_kv)
+        block_size, max_blocks,
+        LATENT_TILE if latent else (_TILE_ROWS, _TILE_POSITIONS))
+    slot, row0, held, kv = _ragged_tiles(np, ql, ctx, tq, kb * block_size,
+                                         n_tiles, n_kv)
+    one = held <= _tile_rungs(tq)[0]
     walked = int(kv.sum())
     live = walked * num_kv_heads
-    groups = num_kv_heads // _head_group(num_kv_heads, head_lanes)
+    groups = num_kv_heads // _head_group(num_kv_heads, head_lanes,
+                                         w_max == 1)
+    blocks = kv * kb
+    if not latent:
+        reach = ctx[slot] + row0 + held - 1
+        blocks = np.where(one & (held > 0),
+                          np.minimum(-(-reach // block_size), max_blocks),
+                          blocks)
     counts = (live + int((kv == 0).sum()) * num_kv_heads, live,
-              walked * kb * streams * groups)
+              int(blocks.sum()) * streams * groups)
     if not narrow:
         return counts
-    return counts + (
-        int(kv[held <= _latent_rungs(tq)[0]].sum()) * num_kv_heads,)
+    return counts + (int(kv[one].sum()) * num_kv_heads,)
 
 
-def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tables_ref, lens_ref,
-                   *args, scale, block_size, kv_blocks, max_blocks,
-                   head_dim, heads, row_shift, quantized=False,
-                   tree_bits=None):
+def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tlive_ref, tables_ref,
+                   lens_ref, *args, scale, block_size, kv_blocks,
+                   narrow_blocks, max_blocks, head_dim, heads, row_shift,
+                   rungs, quantized=False, tree_bits=None):
     """Ragged mixed-batch body: grid ``(query tile, head group)``. A
     step holds ``tq`` consecutive window rows of ONE slot for the
     ``heads`` kv heads of its group (``[heads, tq * rp, D]``, row ``r``
     = window token ``trow_ref[t] + (r >> row_shift)``, causal bound
-    ``lens + that token``) and walks the slot's cache in tiles of
-    ``kv_blocks`` pool blocks, chased through ``tables_ref[slot]`` by
-    double-buffered async copies out of the HBM pools, for exactly
-    ``tkv_ref[t]`` iterations — the tile's own live reach, 0 for a tile
-    past the live count (which stores zeros). A pool block is copied
-    ONCE a stream a kv tile, all the group's heads side by side (where
-    the group is the pool's whole row, one contiguous block); the
-    heads then run as a static loop over the lane tiles of the copied
-    buffer — ``heads`` independent online-softmax chains an iteration,
-    each with its own f32 state, one mask. ``tree_bits`` (static ancestor bitmasks) adds a SIXTH
+    ``lens + that token``) and walks the slot's cache in kv tiles of
+    pool blocks, chased through ``tables_ref[slot]`` by
+    double-buffered async copies out of the HBM pools, to the tile's
+    own live reach — none for a tile past the live count (which stores
+    zeros). A pool block is copied ONCE a stream a kv tile, all the
+    group's heads side by side (where the group is the pool's whole
+    row, one contiguous block); the heads then run as a static loop
+    over the lane tiles of the copied buffer — ``heads`` independent
+    online-softmax chains an iteration, each with its own f32 state,
+    one mask.
+
+    A step computes over the rows its tile HOLDS, as
+    ``_latent_kernel`` does: ``tlive_ref[t]`` is the tile's live
+    window tokens and ``rungs`` the static token counts a walk is
+    compiled at (``_tile_rungs``). A tile of the whole rung walks
+    ``tq * rp`` rows against kv tiles of ``kv_blocks`` blocks, exactly
+    ``tkv_ref[t]`` of them (blocks past the table's end re-read its
+    last entry). A tile of ONE token — the narrow rung — walks its
+    ``rp`` rows against kv tiles of ``narrow_blocks`` blocks
+    (``_NARROW_POSITIONS``), copies only the blocks its token's reach
+    covers, waits for exactly those, and stores zeros in the rows of
+    its output block above ``rp``: a live row's scores, softmax and
+    weighted sum do not depend on the other rows of its tile. Both
+    rungs are the one body at another static geometry.
+
+    ``tree_bits`` (static ancestor bitmasks) adds a SEVENTH
     scalar-prefetch operand ``tree_ref`` [S]: slots flagged ``> 0``
     mask their first ``len(tree_bits)`` window rows by ancestor path
     instead of the linear bound — unflagged slots (prefill chunks and
     their narrow trickle rows) keep the linear mask untouched.
-    ``quantized``: the scale pools ride the same copies (a block's
-    scales once for all its heads) and each K/V tile dequantizes in
-    VMEM as in ``_decode_kernel``."""
+    ``quantized``: the scale pools ride the same copies at the same
+    span, either rung (a block's scales once for all its heads), and
+    each K/V tile dequantizes in VMEM as in ``_decode_kernel``."""
     if tree_bits is not None:
         tree_ref, *args = args
     if quantized:
@@ -551,112 +630,197 @@ def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tables_ref, lens_ref,
     row0 = trow_ref[t]
     n_kv = tkv_ref[t]
     lens = lens_ref[slot]
-    bs, kb, d = block_size, kv_blocks, head_dim
+    bs, d = block_size, head_dim
     whole_rows = k_hbm.shape[2] == heads * d
 
-    def start(j, buf):
-        """Start the async copies that bring kv tile ``j`` into buffer
-        half ``buf``: per pool block the ``[BS, heads * D]`` lanes of
-        the group (and, quantized, its ``[BS, H_kv]`` scale block).
-        Blocks past the table's end re-read its last entry; their
-        columns lie past every row's bound."""
-        for i in range(kb):
-            blk = tables_ref[slot, jnp.minimum(j * kb + i,
-                                               max_blocks - 1)]
-            for n, (hbm, vmem, per_head) in enumerate(streams):
-                src = (hbm.at[blk, :, pl.ds(g0 * d, heads * d)]
-                       if per_head and not whole_rows else hbm.at[blk])
-                pltpu.make_async_copy(
-                    src, vmem.at[buf, pl.ds(i * bs, bs), :],
-                    sems.at[n, buf]).start()
+    def copy(i, blk, buf):
+        """Start the copies of pool block ``blk`` into block ``i`` of
+        buffer half ``buf``, a stream each: the ``[BS, heads * D]``
+        lanes of the group (and, quantized, its ``[BS, H_kv]`` scale
+        block)."""
+        dst = pl.ds(pl.multiple_of(i * bs, bs), bs)
+        for n, (hbm, vmem, per_head) in enumerate(streams):
+            src = (hbm.at[blk, :, pl.ds(g0 * d, heads * d)]
+                   if per_head and not whole_rows else hbm.at[blk])
+            pltpu.make_async_copy(src, vmem.at[buf, dst, :],
+                                  sems.at[n, buf]).start()
 
-    def wait(buf):
-        """Wait for buffer half ``buf``'s copies: a wait takes the
-        semaphore and the destination's size alone, so one descriptor
-        a stream the size of the whole half stands for its ``kb``
-        block copies, and no table entry is read again."""
+    def wait(buf, blocks):
+        """Wait for ``blocks`` (static) block copies into buffer half
+        ``buf``: a wait takes the semaphore and the destination's size
+        alone, so one descriptor a stream the size of those blocks
+        stands for them, and no table entry is read again."""
         for n, (_, vmem, _) in enumerate(streams):
-            pltpu.make_async_copy(vmem.at[buf], vmem.at[buf],
-                                  sems.at[n, buf]).wait()
+            part = vmem.at[buf, pl.ds(0, blocks * bs)]
+            pltpu.make_async_copy(part, part, sems.at[n, buf]).wait()
 
-    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[:] = jnp.zeros_like(l_scr)
-    acc_scr[:] = jnp.zeros_like(acc_scr)
+    def walk_rows(tokens):
+        """The whole step at the rung of ``tokens`` window tokens."""
+        rows = tokens << row_shift
+        narrow = tokens == 1
+        kb = narrow_blocks if narrow else kv_blocks
+        span = kb * bs
+        if narrow:
+            # the token sees lens + row0 positions: the blocks that hold
+            # them, and kv tiles to cover those
+            blocks = jnp.where(n_kv > 0, jnp.minimum(
+                (lens + row0 + (bs - 1)) // bs, max_blocks), 0)
+            n_it = (blocks + (kb - 1)) // kb
 
-    @pl.when(n_kv > 0)
-    def _first():
-        start(0, 0)
-
-    def walk(j, carry):
-        buf = jax.lax.rem(j, 2)
-
-        @pl.when(j + 1 < n_kv)
-        def _next():
-            start(j + 1, 1 - buf)
-
-        wait(buf)
-        shape = (q_ref.shape[2], kb * bs)
-        cols = j * (kb * bs) + jax.lax.broadcasted_iota(
-            jnp.int32, shape, 1)
-        # row r is window token row0 + (r >> row_shift)
-        node = row0 + (jax.lax.broadcasted_iota(
-            jnp.int32, shape, 0) >> row_shift)
-        linear = cols < lens + node
-        if tree_bits is None:
-            keep = linear
+            def count(j):
+                """Block copies of kv tile ``j``."""
+                return jnp.minimum(blocks - j * kb, kb)
         else:
-            # tree slots: rel = cols - lens names the window node this
-            # column holds (rel < 0 = committed prefix + root); row t
-            # keeps it iff it is on t's ancestor path. Every tree
-            # column satisfies the linear bound, so the walk's end
-            # (the last live row's linear reach) stays a superset.
-            bits = jnp.zeros(shape, jnp.int32)
-            for i, b in enumerate(tree_bits):
-                bits = jnp.where(node == i, np.int32(b), bits)
-            rel = cols - lens
-            ok_tree = (rel < 0) | (
-                ((bits >> jnp.clip(rel, 0, 31)) & 1) > 0)
-            # (boolean algebra, not a select between masks: Mosaic
-            # cannot legalize arith.select on i1 vectors)
-            is_tree = (jnp.full(shape, tree_ref[slot]) > 0) & (
-                node < len(tree_bits))
-            keep = (is_tree & ok_tree) | (
-                jnp.logical_not(is_tree) & linear)
-        for h in range(heads):
-            lanes = pl.ds(h * d, d)
-            q = q_ref[0, h]                   # [tq * rp, D]
-            if quantized:
-                q = q.astype(jnp.float32)     # match the f32 dequant
-                k = _dequant_rows(k_buf[buf, :, lanes], ks_buf[buf],
-                                  g0 + h)
-                v = _dequant_rows(v_buf[buf, :, lanes], vs_buf[buf],
-                                  g0 + h)
-            else:
-                k = k_buf[buf, :, lanes]      # [kb * BS, D]
-                v = v_buf[buf, :, lanes]
-            sc = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            sc = jnp.where(keep, sc, NEG_INF)
-            m_prev = m_scr[h, :, :1]
-            l_prev = l_scr[h, :, :1]
-            m_cur = jnp.maximum(m_prev,
-                                jnp.max(sc, axis=-1, keepdims=True))
-            p = jnp.exp(sc - m_cur)
-            alpha = jnp.exp(m_prev - m_cur)
-            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-            pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            acc_scr[h] = alpha * acc_scr[h] + pv
-            m_scr[h] = jnp.broadcast_to(m_cur, m_scr.shape[1:])
-            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
-        return carry
+            n_it = n_kv
 
-    jax.lax.fori_loop(0, n_kv, walk, 0)
-    l = l_scr[:, :, :1]
-    safe_l = jnp.where(l == 0.0, np.float32(1.0), l)
-    o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
+        def start(j, buf):
+            """Start the copies that bring kv tile ``j`` into buffer
+            half ``buf``."""
+            if narrow:
+                def one(i, carry):
+                    copy(i, tables_ref[slot, j * kb + i], buf)
+                    return carry
+
+                jax.lax.fori_loop(0, count(j), one, 0)
+                return
+            # past the table's end: its last entry, whose columns lie
+            # past every row's bound
+            for i in range(kb):
+                copy(i, tables_ref[slot, jnp.minimum(j * kb + i,
+                                                     max_blocks - 1)],
+                     buf)
+
+        def arrive(j, buf):
+            """Wait for kv tile ``j``'s copies into half ``buf``."""
+            if not narrow:
+                wait(buf, kb)
+                return
+            got = count(j)
+
+            @pl.when(got == kb)
+            def _whole():
+                wait(buf, kb)
+
+            @pl.when(got < kb)
+            def _part():
+                # the tile's blocks as a sum of powers of two: a wait a
+                # set bit, each the size of its part
+                p = 1
+                while p < kb:
+                    pl.when((got & p) != 0)(
+                        functools.partial(wait, buf, p))
+                    p *= 2
+
+                # past the reach the half holds what an earlier walk
+                # left, or what no copy ever wrote: a weight of 0 times
+                # a NaN there would still be NaN, so zeros
+                def zero(i, carry):
+                    rows_i = pl.ds(pl.multiple_of(i * bs, bs), bs)
+                    for _, vmem, _ in streams:
+                        vmem[buf, rows_i, :] = jnp.zeros(
+                            (bs, vmem.shape[2]), vmem.dtype)
+                    return carry
+
+                jax.lax.fori_loop(got, kb, zero, 0)
+
+        m_scr[:, :rows] = jnp.full((heads, rows, m_scr.shape[2]), NEG_INF,
+                                   m_scr.dtype)
+        l_scr[:, :rows] = jnp.zeros((heads, rows, l_scr.shape[2]),
+                                    l_scr.dtype)
+        acc_scr[:, :rows] = jnp.zeros((heads, rows, d), acc_scr.dtype)
+
+        @pl.when(n_it > 0)
+        def _first():
+            start(0, 0)
+
+        def walk(j, carry):
+            buf = jax.lax.rem(j, 2)
+
+            @pl.when(j + 1 < n_it)
+            def _next():
+                start(j + 1, 1 - buf)
+
+            arrive(j, buf)
+            shape = (rows, span)
+            cols = j * span + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            # row r is window token row0 + (r >> row_shift)
+            node = row0 + (jax.lax.broadcasted_iota(
+                jnp.int32, shape, 0) >> row_shift)
+            linear = cols < lens + node
+            if tree_bits is None:
+                keep = linear
+            else:
+                # tree slots: rel = cols - lens names the window node
+                # this column holds (rel < 0 = committed prefix + root);
+                # row t keeps it iff it is on t's ancestor path. Every
+                # tree column satisfies the linear bound, so the walk's
+                # end (the last live row's linear reach) stays a
+                # superset.
+                bits = jnp.zeros(shape, jnp.int32)
+                for i, b in enumerate(tree_bits):
+                    bits = jnp.where(node == i, np.int32(b), bits)
+                rel = cols - lens
+                ok_tree = (rel < 0) | (
+                    ((bits >> jnp.clip(rel, 0, 31)) & 1) > 0)
+                # (boolean algebra, not a select between masks: Mosaic
+                # cannot legalize arith.select on i1 vectors)
+                is_tree = (jnp.full(shape, tree_ref[slot]) > 0) & (
+                    node < len(tree_bits))
+                keep = (is_tree & ok_tree) | (
+                    jnp.logical_not(is_tree) & linear)
+            kv_rows = pl.ds(0, span)
+            for h in range(heads):
+                lanes = pl.ds(h * d, d)
+                q = q_ref[0, h, :rows]                # [rows, D]
+                if quantized:
+                    q = q.astype(jnp.float32)     # match the f32 dequant
+                    k = _dequant_rows(k_buf[buf, kv_rows, lanes],
+                                      ks_buf[buf, kv_rows], g0 + h)
+                    v = _dequant_rows(v_buf[buf, kv_rows, lanes],
+                                      vs_buf[buf, kv_rows], g0 + h)
+                else:
+                    k = k_buf[buf, kv_rows, lanes]    # [span, D]
+                    v = v_buf[buf, kv_rows, lanes]
+                sc = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                sc = jnp.where(keep, sc, NEG_INF)
+                m_prev = m_scr[h, :rows, :1]
+                l_prev = l_scr[h, :rows, :1]
+                m_cur = jnp.maximum(m_prev,
+                                    jnp.max(sc, axis=-1, keepdims=True))
+                p = jnp.exp(sc - m_cur)
+                alpha = jnp.exp(m_prev - m_cur)
+                l_new = alpha * l_prev + jnp.sum(p, axis=-1,
+                                                 keepdims=True)
+                pv = jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                acc_scr[h, :rows] = alpha * acc_scr[h, :rows] + pv
+                m_scr[h, :rows] = jnp.broadcast_to(
+                    m_cur, (rows, m_scr.shape[2]))
+                l_scr[h, :rows] = jnp.broadcast_to(
+                    l_new, (rows, l_scr.shape[2]))
+            return carry
+
+        jax.lax.fori_loop(0, n_it, walk, 0)
+        l = l_scr[:, :rows, :1]
+        safe_l = jnp.where(l == 0.0, np.float32(1.0), l)
+        o_ref[0, :, :rows] = (acc_scr[:, :rows] / safe_l).astype(
+            o_ref.dtype)
+        dead = o_ref.shape[2] - rows
+        if dead:
+            o_ref[0, :, rows:] = jnp.zeros((heads, dead, d), o_ref.dtype)
+
+    if len(rungs) == 1:
+        walk_rows(rungs[0])
+        return
+    # the smallest rung that covers the tile's live tokens; a tile past
+    # the live count (0 tokens, no kv tiles) takes the first
+    live = tlive_ref[t]
+    rung = sum((live > tokens).astype(jnp.int32) for tokens in rungs[:-1])
+    for i, tokens in enumerate(rungs):
+        pl.when(rung == i)(functools.partial(walk_rows, tokens))
 
 
 def flat_pool_tile(head_dim) -> int:
@@ -859,7 +1023,7 @@ def pallas_ragged_paged_attention(q, k_pool, v_pool, block_tables,
     ql = jnp.minimum(q_lens.astype(jnp.int32), w)
     starts = row_starts.astype(jnp.int32)
     lens = context_lens.astype(jnp.int32)
-    tslot, trow, _, tkv = (a.astype(jnp.int32) for a in _ragged_tiles(
+    tslot, trow, tlive, tkv = (a.astype(jnp.int32) for a in _ragged_tiles(
         jnp, ql, lens, tq, kb * bs, n_tiles, n_kv))
     # tile t's rows in the packed buffer (clipped: a dead row reads
     # some live row's q and its output is never gathered back)
@@ -875,12 +1039,16 @@ def pallas_ragged_paged_attention(q, k_pool, v_pool, block_tables,
     # anyway — a minor dim under 128 lanes is stored padded to them)
     scales = [jnp.pad(sc, ((0, 0), (0, 0), (0, -hkv % 128)))
               for sc in scales]
-    hg = _head_group(hkv, d)
+    hg = _head_group(hkv, d, w == 1)
+    nkb = _narrow_blocks(bs, mb)
     kernel = functools.partial(
         _ragged_kernel, scale=scale, block_size=bs, kv_blocks=kb,
-        max_blocks=mb, head_dim=d, heads=hg,
-        row_shift=rp.bit_length() - 1, quantized=quant,
+        narrow_blocks=nkb, max_blocks=mb, head_dim=d, heads=hg,
+        row_shift=rp.bit_length() - 1,
+        # a call of one row a slot holds no tile of more than one token
+        rungs=_tile_rungs(tq) if w > 1 else (1,), quantized=quant,
         tree_bits=tree_bits)
+    span = max(kb, nkb) * bs                  # positions a buffer half
 
     def q_block(t, g, *prefetch):
         return (t, g, 0, 0)
@@ -888,13 +1056,13 @@ def pallas_ragged_paged_attention(q, k_pool, v_pool, block_tables,
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     n_streams = 2 + len(scales)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5 + len(tree_args),
+        num_scalar_prefetch=6 + len(tree_args),
         grid=(n_tiles, hkv // hg),
         in_specs=[pl.BlockSpec((1, hg, rows, d), q_block)]
         + [hbm] * n_streams,
         out_specs=pl.BlockSpec((1, hg, rows, d), q_block),
-        scratch_shapes=[pltpu.VMEM((2, kb * bs, hg * d), kd.dtype)] * 2
-        + [pltpu.VMEM((2, kb * bs, sc.shape[2]), jnp.float32)
+        scratch_shapes=[pltpu.VMEM((2, span, hg * d), kd.dtype)] * 2
+        + [pltpu.VMEM((2, span, sc.shape[2]), jnp.float32)
            for sc in scales]
         + [pltpu.SemaphoreType.DMA((n_streams, 2))]
         + _softmax_scratch(rows, d, (hg,)),
@@ -906,11 +1074,12 @@ def pallas_ragged_paged_attention(q, k_pool, v_pool, block_tables,
         compiler_params=pltpu.CompilerParams(
             # no state crosses a step: every (tile, head group) starts
             # and waits its own copies and writes its own output block
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret() if interpret is None else interpret,
     )
     with kernel_scope("ragged_paged_attention"):
-        out = call(tslot, trow, tkv, block_tables.astype(jnp.int32),
+        out = call(tslot, trow, tkv, tlive, block_tables.astype(jnp.int32),
                    lens, *tree_args, q4, kd, vd, *scales)
     out = out.reshape(n_tiles, hkv, tq, rp, d)[:, :, :, :rep] \
         .transpose(0, 2, 1, 3, 4).reshape(n_tiles * tq, h, d)
@@ -953,7 +1122,7 @@ def _latent_kernel(tslot_ref, trow_ref, tkv_ref, tlive_ref, tables_ref,
 
     A step computes over the rows its tile HOLDS: ``tlive_ref[t]`` is
     the tile's live window tokens and ``rungs`` the static token
-    counts a walk is compiled at, ascending (``_latent_rungs``); the
+    counts a walk is compiled at, ascending (``_tile_rungs``); the
     step takes the smallest rung that covers its tile — a decoding
     slot's and a trickling prompt's one token walk ``rp`` rows of
     query, scores and softmax state a kv tile, a chunk's tiles all
@@ -1047,16 +1216,6 @@ def _latent_kernel(tslot_ref, trow_ref, tkv_ref, tlive_ref, tables_ref,
             functools.partial(walk_rows, tokens << row_shift))
 
 
-def _latent_rungs(tq):
-    """Window tokens of a tile the latent kernel's walk is compiled
-    at, ascending; a grid step takes the smallest that covers its
-    tile's live tokens. Two rungs: ONE token — a decoding slot, a
-    pending prompt trickling a row a tick: a third of the long-prompt
-    cell's walk, at an eighth of the full tile's rows — and the whole
-    tile."""
-    return (1, tq) if tq > 1 else (tq,)
-
-
 def pallas_ragged_latent_attention(q, pool, block_tables, context_lens,
                                    q_lens, row_starts, w_max, value_dim,
                                    sm_scale, interpret=None):
@@ -1071,7 +1230,7 @@ def pallas_ragged_latent_attention(q, pool, block_tables, context_lens,
     this shares, with all ``H`` heads of a token in one tile. Each
     tile's live token count rides beside its slot, first row and kv
     reach, and a grid step computes over that many rows' rung
-    (``_latent_rungs``; ``ragged_grid_units(..., narrow=True)`` counts
+    (``_tile_rungs``; ``ragged_grid_units(..., narrow=True)`` counts
     the same choice on the host). Returns
     ``[R, H, value_dim]``: per head the softmax-weighted sum of the
     cached rows' first ``value_dim`` lanes (``u_h``, still to be
@@ -1094,7 +1253,7 @@ def pallas_ragged_latent_attention(q, pool, block_tables, context_lens,
     kernel = functools.partial(
         _latent_kernel, scale=np.float32(sm_scale), block_size=bs,
         kv_blocks=kb, max_blocks=mb, value_dim=value_dim,
-        row_shift=rp.bit_length() - 1, rungs=_latent_rungs(tq))
+        row_shift=rp.bit_length() - 1, rungs=_tile_rungs(tq))
 
     def q_block(t, *prefetch):
         return (t, 0, 0)

@@ -239,7 +239,7 @@ def test_latent_narrow_count_is_the_kernels_own_choice():
     with and without it, and a non-latent geometry's are untouched."""
     geo = dict(rows=544, w_max=512, num_heads=64, num_kv_heads=1,
                q_dtype=jnp.bfloat16, block_size=16, max_blocks=512,
-               tile=pa.LATENT_TILE, streams=1)
+               latent=True, streams=1)
     q_lens = np.zeros(32, np.int64)
     ctx = np.zeros(32, np.int64)
     q_lens[:6] = (1, 17, 1, 0, 3, 1)
@@ -250,7 +250,7 @@ def test_latent_narrow_count_is_the_kernels_own_choice():
     assert (units, live, copies) == three
     _, tq, kb, n_tiles, n_kv = pa._ragged_geometry(
         544, 32, 64, jnp.bfloat16, 16, 512, pa.LATENT_TILE)
-    rungs = pa._latent_rungs(tq)
+    rungs = pa._tile_rungs(tq)
     assert rungs == (1, 8)
     _, _, held, kv = (np.asarray(a) for a in pa._ragged_tiles(
         jnp, jnp.asarray(q_lens, jnp.int32), jnp.asarray(ctx, jnp.int32),
@@ -259,13 +259,82 @@ def test_latent_narrow_count_is_the_kernels_own_choice():
     # slots 0, 2, 5 and the 17-row chunk's last tile (row 16: one token)
     assert narrow == 2 + 8 + 1 + -(-(257 + 16) // 512)
     assert live == narrow + 2 * 1 + -(-(600 + 2) // 512)
-    # the other kernel's geometry: the three values the parent of this
-    # count gave (computed there)
+    # the other kernel's geometry: units and live are the values the
+    # parent of this count gave (296, 280, computed there); its copies
+    # (1120 there) lose what the five decode rows' narrow walks no
+    # longer read past their reach: 1 + 0 + 7 + 3 + 2 blocks, K and V
     gqa = dict(rows=136, w_max=128, num_heads=28, num_kv_heads=4,
                q_dtype=jnp.bfloat16, block_size=16, max_blocks=64)
     q8, c8 = np.array([1, 128, 0, 1, 1, 0, 1, 1]), \
         np.array([100, 300, 0, 1024, 5, 0, 77, 600])
-    assert pa.ragged_grid_units(q8, c8, **gqa) == (296, 280, 1120)
+    assert pa.ragged_grid_units(q8, c8, **gqa) == (296, 280,
+                                                   1120 - 13 * 2)
+
+
+def _narrow_walk_copies(table, seen, bs, nkb):
+    """The pool blocks ``_ragged_kernel``'s narrow walk starts copies of
+    for one tile a stream, kv tile by kv tile as its loops start them,
+    for a token that sees ``seen`` positions through ``table``."""
+    blocks = min(-(-seen // bs), len(table))
+    return [table[j * nkb + i] for j in range(-(-blocks // nkb))
+            for i in range(min(blocks - j * nkb, nkb))]
+
+
+@pytest.mark.parametrize("geo,q_lens,ctx", [
+    # the Qwen cells' call: decode rows (one at the table's last
+    # entry), a 40-row chunk whose last tile holds one token, dead slots
+    (dict(rows=136, w_max=128, num_heads=28, num_kv_heads=4),
+     [1, 1, 0, 40, 1, 0, 3, 1], [6, 512, 0, 25, 1024, 78, 500, 1000]),
+    # the cross layers' call: one row a slot, 10 paired lane tiles in
+    # one head group, every tile walked narrow
+    (dict(rows=96, w_max=1, num_heads=40, num_kv_heads=10),
+     [1] * 95 + [0], [1, 15, 16, 17, 511, 512, 513, 1541] * 11 + [900] * 7
+     + [0]),
+], ids=["qwen2", "phi4_cross"])
+def test_ragged_narrow_count_is_the_kernels_own_choice(geo, q_lens, ctx):
+    """The paged geometry's ``ragged_grid_units(..., narrow=True)``:
+    ``attn_narrow`` is the live units of the tiles the wrapper's own
+    ``_ragged_tiles`` call hands the kernel with one live token (the
+    rung ``_tile_rungs`` chooses), and ``attn_copies`` the block copies
+    the narrow walk starts — the blocks of the token's reach, none past
+    it — beside whole kv tiles for every other tile, a stream a head
+    group."""
+    geo = dict(geo, q_dtype=jnp.bfloat16, block_size=16, max_blocks=64)
+    q_lens, ctx = np.asarray(q_lens), np.asarray(ctx)
+    units, live, copies, narrow = pa.ragged_grid_units(
+        q_lens, ctx, narrow=True, **geo)
+    assert (units, live, copies) == pa.ragged_grid_units(q_lens, ctx,
+                                                         **geo)
+    hkv, bs, mb = geo["num_kv_heads"], 16, 64
+    _, tq, kb, n_tiles, n_kv = pa._ragged_geometry(
+        geo["rows"], len(q_lens), geo["num_heads"] // hkv, jnp.bfloat16,
+        bs, mb)
+    slot, row0, held, kv = (np.asarray(a) for a in pa._ragged_tiles(
+        jnp, jnp.asarray(np.minimum(q_lens, geo["w_max"]), jnp.int32),
+        jnp.asarray(ctx, jnp.int32), tq, kb * bs, n_tiles, n_kv))
+    one = held <= pa._tile_rungs(tq)[0]
+    assert narrow == int(kv[one].sum()) * hkv
+    table = list(range(mb))
+    nkb = pa._narrow_blocks(bs, mb)
+    blocks = 0
+    for s_, r0, h_, k_ in zip(slot, row0, held, kv):
+        if not h_:
+            continue
+        if h_ == 1:
+            seen = int(ctx[s_] + r0)
+            walked = _narrow_walk_copies(table, seen, bs, nkb)
+            assert max(walked) == min(-(-seen // bs), mb) - 1  # its reach
+            blocks += len(walked)
+        else:
+            blocks += int(k_) * kb
+    one_row = geo["w_max"] == 1
+    groups = hkv // pa._head_group(hkv, 128, one_row)
+    assert copies == blocks * 2 * groups
+    # a one-row call: every tile narrow, the whole pool row one group
+    # (5 groups of 2 lane tiles in a call that may hold a chunk)
+    assert groups == (1 if one_row else hkv // 4)
+    if one_row:
+        assert live == narrow and hkv // pa._head_group(hkv, 128) == 5
 
 
 def test_latent_grid_units_follow_the_kernels_tiles():
@@ -274,7 +343,7 @@ def test_latent_grid_units_follow_the_kernels_tiles():
     ``LATENT_TILE[1]`` positions that end at the slot's length."""
     geo = dict(rows=544, w_max=512, num_heads=64, num_kv_heads=1,
                q_dtype=jnp.bfloat16, block_size=16, max_blocks=512,
-               tile=pa.LATENT_TILE)
+               latent=True)
     q_lens = np.zeros(32, np.int64)
     ctx = np.zeros(32, np.int64)
     q_lens[:3], ctx[:3] = (1, 512, 1), (1000, 257, 4096)

@@ -383,14 +383,26 @@ def test_ring_write_keeps_each_slot_s_last_w_positions():
     assert float(ring[2].max()) == -1.0         # no rows: untouched
 
 
-def test_differential_mode_of_the_ragged_kernel_is_two_softmaxes():
+_N = pa._NARROW_POSITIONS
+
+
+@pytest.mark.parametrize("q_lens,base,w_max", [
+    ([1, 20, 1], [30, 7, 0], 20),
+    # the cross layers' call: one row a slot, every tile walked narrow,
+    # the token seeing 1, 16, 17, N, N + 1 and 3N + 5 positions
+    ([1, 1, 1, 1, 1, 1, 0], [0, 15, 16, _N - 1, _N, 3 * _N + 4, 0], 1),
+], ids=["chunk", "cross_narrow"])
+def test_differential_mode_of_the_ragged_kernel_is_two_softmaxes(
+        q_lens, base, w_max):
     """Paired queries over a flat pool of head-64 kv heads (two pairs):
     the ragged kernel, all 128 output lanes kept, gives each query its
-    own kv head's softmax against the pair's two value heads."""
+    own kv head's softmax against the pair's two value heads — beside a
+    chunk, and in the cross layers' one-row-a-slot call."""
     rng = np.random.RandomState(2)
-    slots, mb, bs, h, hkv, d = 3, 4, 16, 8, 4, 64
-    q_lens, base = np.array([1, 20, 1]), np.array([30, 7, 0])
-    r = 32
+    q_lens, base = np.array(q_lens), np.array(base)
+    slots, bs, h, hkv, d = len(q_lens), 16, 8, 4, 64
+    mb = -(-int((base + q_lens).max()) // bs)
+    r = -(-int(q_lens.sum()) // 8) * 8 + 8
     row_slot, row_pos, row_starts, _ = pc.ragged_row_meta(q_lens, base, r,
                                                           10_000)
     kp, vp = (jnp.asarray(rng.randn(slots * mb + 1, bs, hkv * d),
@@ -400,7 +412,7 @@ def test_differential_mode_of_the_ragged_kernel_is_two_softmaxes():
     lens = jnp.asarray(base + 1, jnp.int32)
     got = pa.pallas_ragged_paged_attention(
         p4.diff_pairs(q), kp, vp, tables, lens, jnp.asarray(q_lens),
-        jnp.asarray(row_starts), w_max=20, sm_scale=d ** -0.5,
+        jnp.asarray(row_starts), w_max=w_max, sm_scale=d ** -0.5,
         interpret=True)
     # plain: per packed row of its slot, every query head over its kv
     # head's keys, both value heads of its pair side by side

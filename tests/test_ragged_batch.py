@@ -310,28 +310,69 @@ _GROUP_MIX = ([1, 3, 0, 20, 1, 0], [5, 130, 0, 24, 127, 77],
               [0, 1, 0, 0, 0, 0])
 
 
-@pytest.mark.parametrize("pool", ["bf16_4d", "flat_d64", "int8", "tree"])
-@pytest.mark.parametrize("tiles,hg", [(1, 1), (2, 2), (4, 4), (8, 4)],
-                         ids=["hg1", "hg2", "hg4", "hg4_two_groups"])
+def _narrow_mix(n, bs):
+    """``(q_lens, base, table blocks)`` of the narrow-walk cases: one-
+    token tiles whose token sees 1, 15, 16, 17, N - 1, N, N + 1 and
+    3N + 5 positions (N the narrow walk's kv tile), one that sees the
+    whole table (its last entry), beside a 20-row chunk and an empty
+    slot; ``base + 1`` positions are seen by a slot's first row."""
+    seen = [1, 15, 16, 17, n - 1, n, n + 1, 3 * n + 5]
+    mb = -(-(3 * n + 5) // bs)
+    seen.append(mb * bs)
+    q_lens = [1] * len(seen) + [20, 0]
+    base = [c - 1 for c in seen] + [40, 0]
+    return q_lens, base, mb
+
+
+_HEAD_GROUP_CASES = [
+    pytest.param(pool, tiles, hg, None, id=f"{tid}-{pool}")
+    for tiles, hg, tid in [(1, 1, "hg1"), (2, 2, "hg2"), (4, 4, "hg4"),
+                           (8, 4, "hg4_two_groups")]
+    for pool in ["bf16_4d", "flat_d64", "int8", "tree"]] + [
+    # the narrow walk at the cells' geometries: Qwen2 (4 x 128, 7 query
+    # heads a kv head), Solar (8 x 128: two groups), LFM2 (8 x 64 in 4
+    # pairs), the Phi-4 cross call (10 lane tiles of paired heads, one
+    # row a slot: every tile walks narrow, the whole pool row one head
+    # group), an int8 pool
+    pytest.param("bf16_4d", 4, 4, 7, id="narrow-qwen2"),
+    pytest.param("bf16_4d", 8, 4, 8, id="narrow-solar"),
+    pytest.param("flat_d64", 4, 4, 4, id="narrow-lfm2"),
+    pytest.param("flat_d128", 10, 2, 4, id="narrow-phi4_cross"),
+    pytest.param("int8", 4, 4, 7, id="narrow-int8")]
+
+
+@pytest.mark.parametrize("pool,tiles,hg,rep", _HEAD_GROUP_CASES)
 def test_ragged_kernel_head_groups_match_fallback_interpret(pool, tiles,
-                                                            hg):
+                                                            hg, rep):
     """A grid step takes ``hg`` kv heads (128-lane tiles of the pool
     row) — one (the TP shard's kernel), all of a narrow row, or a
     capped group of a row wider than ``_GROUP_LANES`` — and every live
     row agrees with the gather fallback whatever the pool: 4-D bf16,
     flat with head size 64 in pairs, int8 with its scale pools, f32
-    under the tree mask."""
+    under the tree mask. The ``narrow`` cases (``rep`` query heads a kv
+    head) put one-token tiles at the narrow walk's edges
+    (``_narrow_mix``) and fill every pool block past a one-token
+    slot's reach with NaN in the kernel's pools: a walk that copied
+    one, or kept a stale buffer row past the reach, would read NaN."""
     import jax.numpy as jnp
     from paddle_tpu.ops import paged_cache as pc
     from paddle_tpu.ops.pallas import paged_attention as pa
     assert pa._head_group(tiles, 128) == hg
-    q_lens, base, tree = (np.asarray(a, np.int64) for a in _GROUP_MIX)
-    flat, quant = pool == "flat_d64", pool == "int8"
+    flat, quant = pool.startswith("flat"), pool == "int8"
     dt = jnp.float32 if pool in ("int8", "tree") else jnp.bfloat16
-    D = 64 if flat else 128
+    D = 64 if pool == "flat_d64" else 128
     Hkv = tiles * 128 // D
-    S, H, BS, W, wn = len(q_lens), 2 * Hkv, 32 if quant else 16, 24, 3
-    MB = 192 // BS
+    BS = 32 if quant else 16
+    if rep is None:
+        q_lens, base, tree = (np.asarray(a, np.int64) for a in _GROUP_MIX)
+        H, W, wn, MB = 2 * Hkv, 24, 3, 192 // BS
+    else:
+        q_lens, base, MB = _narrow_mix(pa._NARROW_POSITIONS, BS)
+        q_lens, base = np.asarray(q_lens), np.asarray(base)
+        if pool == "flat_d128":           # one row a slot
+            q_lens = np.minimum(q_lens, 1)
+        H, W, wn, tree = rep * Hkv, int(q_lens.max()), 1, None
+    S = len(q_lens)
     R, NB = S * wn + W, 1 + S * MB
     rng = np.random.RandomState(3)
 
@@ -340,7 +381,10 @@ def test_ragged_kernel_head_groups_match_fallback_interpret(pool, tiles,
         return pc.QuantKV(*pc.kv_quantize(x)) if quant else x.astype(dt)
 
     kp, vp = make(), make()
-    tables = _block_tables(q_lens, base, MB, NB, BS)
+    if rep is None:
+        tables = _block_tables(q_lens, base, MB, NB, BS)
+    else:
+        tables = np.arange(1, NB, dtype=np.int32).reshape(S, MB)
     row_slot, _, row_starts, _ = pc.ragged_row_meta(q_lens, base, R,
                                                     MB * BS)
     q = jnp.asarray(rng.randn(R, H, D), dt)
@@ -351,6 +395,17 @@ def test_ragged_kernel_head_groups_match_fallback_interpret(pool, tiles,
         kw = dict(tree_anc=(0, 0), tree_slots=jnp.asarray(tree))
     ref = np.asarray(pa._xla_ragged_paged(
         q, kp, vp, *rest, jnp.asarray(row_slot), wn, W, **kw), np.float32)
+    if rep is not None:
+        # NaN in every block past a one-token slot's reach
+        past = np.asarray([tables[s, j] for s in range(S) if q_lens[s] == 1
+                           for j in range(-(-int(base[s] + 1) // BS), MB)])
+
+        def poison(x):
+            if quant:
+                return pc.QuantKV(x.data, x.scale.at[past].set(np.nan))
+            return x.at[past].set(np.nan)
+
+        kp, vp = poison(kp), poison(vp)
     if flat:
         kp, vp = (x.reshape(NB, BS, Hkv * D) for x in (kp, vp))
     out = np.asarray(pa.pallas_ragged_paged_attention(
@@ -371,6 +426,20 @@ def _direct_grid_count(q_lens, ctx, tq, span, heads, n_tiles):
     return (live + (n_tiles - tiles)) * heads, live * heads
 
 
+def _direct_blocks(q_lens, ctx, tq, span, bs, mb):
+    """Pool blocks one head group's walk copies a stream, tile by tile:
+    a tile of one window token the blocks its reach covers (the narrow
+    walk), any other its kv tiles whole."""
+    blocks = 0
+    for n, c in zip(q_lens, ctx):
+        for row0 in range(0, int(n), tq):
+            last = min(row0 + tq, int(n)) - 1
+            seen = int(c) + last
+            blocks += (min(-(-seen // bs), mb) if last == row0
+                       else -(-seen // span) * (span // bs))
+    return blocks
+
+
 @pytest.mark.parametrize("hkv,rep,lanes,bs,streams,groups", [
     (4, 7, 128, 16, 2, 1),  # the Qwen cells: a 4-D bf16 pool
     (4, 8, 128, 16, 2, 1),  # the wide cell: 8 heads of 64 in 4 pairs
@@ -386,7 +455,9 @@ def test_grid_units_keep_their_rule_and_count_the_copies(
     chunk, dead slots): ``(units, live)`` stay the (query tile, kv
     head, kv tile) count the kernel had with one head a grid step — a
     slot-by-slot walk — and ``copies`` is a descriptor a pool block a
-    stream a HEAD GROUP of every live (query tile, kv tile)."""
+    stream a HEAD GROUP: every block of a live (query tile, kv tile),
+    and of a one-token tile's narrow walk the blocks its reach covers,
+    none past it."""
     from paddle_tpu.ops.pallas import paged_attention as pa
     import jax.numpy as jnp
     q_lens = [1, 1, 0, 40, 1, 0, 3, 1]
@@ -398,10 +469,12 @@ def test_grid_units_keep_their_rule_and_count_the_copies(
         q_lens, ctx, head_lanes=lanes, streams=streams, **geo)
     assert (units, live) == _direct_grid_count(q_lens, ctx, tq, span, hkv,
                                                n_tiles)
-    kv_tiles, kb = live // hkv, span // bs
-    assert copies == kv_tiles * kb * streams * groups
+    blocks = _direct_blocks(q_lens, ctx, tq, span, bs, 1024 // bs)
+    assert copies == blocks * streams * groups
     # a descriptor a kv head, the kernel before: ``hg`` times as many
-    assert copies * pa._head_group(hkv, lanes) == live * kb * streams
+    assert copies * pa._head_group(hkv, lanes) == blocks * streams * hkv
+    # the decode rows' walks stop at their reach: under whole kv tiles
+    assert blocks < live // hkv * (span // bs)
 
 
 def test_tick_span_counts_the_attention_grid(llama_tiny):
@@ -433,10 +506,16 @@ def test_tick_span_counts_the_attention_grid(llama_tiny):
             [1, chunk], [5 + k, 8 * k + 1], tq, span, heads, n_tiles))
     got = [(a["attn_units"], a["attn_live"]) for a in spans]
     assert got[:20] == want
-    # 2 kv heads of 16 lanes are ONE head group: a copy a block (16 a
-    # kv tile) a stream (K, V) of every live (query tile, kv tile)
+    # 2 kv heads of 16 lanes are ONE head group: a copy a block a
+    # stream (K, V) — 16 a kv tile of the chunk, and the decode row's
+    # narrow walk the 8-position blocks of its reach
     assert [a["attn_copies"] for a in spans[:20]] == [
-        live // heads * 16 * 2 for _, live in want]
+        _direct_blocks([4], [1], tq, span, 8, 24) * 2] + [
+        _direct_blocks([1, min(8, 150 - 8 * k)], [5 + k, 8 * k + 1], tq,
+                       span, 8, 24) * 2 for k in range(19)]
+    # one layer's call: the decode row's units, walked narrow
+    assert [a["attn_narrow"] for a in spans[1:20]] == [
+        heads * -(-(5 + k) // span) for k in range(19)]
     # the chunk's walk grows past one kv tile at position 128
     assert want[15] == (8, 4) and want[17] == (10, 6)
 

@@ -229,6 +229,28 @@ def test_ragged_wide_cell_shape_compiles(tree):
     assert _lowers_to_mosaic(fn, *args)
 
 
+# reason-long-sat's cross layers' call (Phi-4-mini-flash): 96 slots of
+# one row, 40 paired query heads over a flat pool of 10 lane tiles (20
+# kv heads of 64, 1,280 lanes), an 8192-token table: every tile walks
+# narrow
+_CROSS = dict(s=96, r=96, w=1, rep=4, d=128, reach=8192)
+# reason-wide-sat's gqa call (Solar-Open2): 96 slots, 95 decode rows and
+# a 256-row chunk, 64 query heads over 8 kv heads of 128 in two head
+# groups, a 6144-token table
+_SOLAR = dict(s=96, r=352, w=256, rep=8, d=128, reach=6144)
+
+
+@pytest.mark.parametrize("hkv,c,flat", [(10, _CROSS, True),
+                                        (8, _SOLAR, False)],
+                         ids=["phi4_cross", "solar_gqa"])
+def test_ragged_narrow_walk_compiles(hkv, c, flat):
+    """The two shapes the narrow walk was measured at compile to Mosaic
+    with it: the cross call (one rung, every tile narrow, 5 head groups)
+    and the Solar call (both rungs in one body, 2 head groups)."""
+    fn, args = _serving_ragged_call(hkv, False, False, c, flat=flat)
+    assert _lowers_to_mosaic(fn, *args)
+
+
 def _launched_grid(fn, args):
     def pallas_calls(jaxpr):
         for eqn in jaxpr.eqns:

@@ -319,6 +319,26 @@ def test_tick_phases_cover_every_ragged_tick(llama_tiny):
     eng.shutdown()
 
 
+def test_paged_tick_span_carries_attn_narrow(llama_tiny):
+    """Over a paged (not latent) pool every ``tick`` span carries
+    ``attn_narrow``: the live units of one-token tiles, which the
+    ragged kernel walks narrow — none of a lone prompt chunk's, all of a
+    decode-only tick's, and a trickling prompt's row beside a chunk."""
+    rng = np.random.RandomState(4)
+    eng = ServingEngine(llama_tiny, ServingConfig(
+        num_slots=2, block_size=8, max_model_len=64, prefill_chunk=16))
+    eng.serve([rng.randint(1, 128, (6,))], max_new_tokens=3)
+    _mixed_wave(eng, [rng.randint(1, 128, (n,)) for n in (6, 20)], 5)
+    ticks = [e["args"] for e in eng.tracer.events() if e["name"] == "tick"]
+    eng.shutdown()
+    assert ticks and all(0 <= a["attn_narrow"] <= a["attn_live"]
+                         for a in ticks)
+    assert ticks[0]["attn_narrow"] == 0          # the lone prompt's chunk
+    assert ticks[1]["attn_narrow"] == ticks[1]["attn_live"] > 0
+    # the wave's first tick: one prompt's chunk, the other's first row
+    assert 0 < ticks[3]["attn_narrow"] < ticks[3]["attn_live"]
+
+
 @pytest.mark.parametrize("tier_bytes, stored", [(64 << 20, True),
                                                 (64, False)])
 def test_spill_phase_per_evicted_block(llama_tiny, tier_bytes, stored):
