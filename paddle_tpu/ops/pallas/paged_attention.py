@@ -60,15 +60,15 @@ tick holds, not what it could hold:
   rows never reach a live packed row; rows no slot owns come back
   zero. The gathers stand OUTSIDE ``kernel_scope``: the scope (and so
   the kernel's roofline share) holds the ``pallas_call`` alone.
-- **the grid** is ``(query tile, head group)``, and the
-  scalar-prefetched tile list rides in SMEM. A step holds one tile for
-  EVERY kv head of its slot while the pool row's lanes fit
-  ``_GROUP_LANES`` (``_head_group``: 4 heads of 128 lanes, or 8 of 64
-  in pairs, are one group; a wider row walks ``H_kv / group`` steps a
-  tile; a TP shard's one local head is a group of one) — per head the
-  ``[tq * rp, D]`` layout of the verify kernel, row ``r`` being window
-  token ``row0 + (r >> row_shift)`` with the causal bound ``lens +
-  that token`` — against kv tiles of ``kb`` pool blocks = 128 cache
+- **the grid** is ``(query tile,)``, and the scalar-prefetched tile
+  list rides in SMEM. A step holds one tile for EVERY kv head of its
+  slot — per head the ``[tq * rp, D]`` layout of the verify kernel,
+  row ``r`` being window token ``row0 + (r >> row_shift)`` with the
+  causal bound ``lens + that token`` — and walks it a HEAD GROUP at a
+  time (``_rung_heads``: 4 heads of 128 lanes, or 8 of 64 in pairs,
+  are one group of ``_GROUP_LANES``; a wider row walks ``H_kv /
+  group`` groups, one after another; a TP shard's one local head is a
+  group of one) against kv tiles of ``kb`` pool blocks = 128 cache
   positions (8 blocks of 16, 4 of int8's 32).
 - **the walk ends where the data ends.** The K/V pools stay in HBM; a
   loop of exactly ``ceil((lens + last live row of the tile) / 128)``
@@ -88,9 +88,9 @@ tick holds, not what it could hold:
   ``rp`` rows against kv tiles of ``_NARROW_POSITIONS`` positions and
   copies no block past its reach; the tile's live-token count rides
   in SMEM beside the rest of the tile list and picks the walk
-  (``_tile_rungs``, the latent kernel's rule). A call of one row a
-  slot takes the whole pool row (up to ``_ONE_ROW_LANES``) as its
-  head group.
+  (``_tile_rungs``, the latent kernel's rule). Its head group
+  follows the rung: the whole pool row (up to ``_ONE_ROW_LANES``), in
+  every call, where a tile of more keeps ``_GROUP_LANES``.
   At the default serving shape (8 slots, 136 rows, 4 kv heads, a
   64-block table) that is 25 grid steps of at most 8 iterations (176
   at the wide cell's 128 slots and 384 rows), where the ``(slot,
@@ -410,22 +410,24 @@ _TILE_POSITIONS = 128
 # / 1.13 / 0.95 / 0.93
 _NARROW_POSITIONS = 1024
 
-# lanes of a pool row one ragged grid step copies and holds: the kv
-# heads of one head group (``_head_group``). 512 lanes (4 heads of 128,
-# or 8 of 64 in pairs) keep the step's K/V halves, q/out blocks and
-# softmax state near 2 MB of VMEM and its body at four unrolled heads
+# lanes of a pool row a ragged query tile of MORE than one window token
+# copies and walks at once: the kv heads of one head group
+# (``_head_group``). 512 lanes (4 heads of 128, or 8 of 64 in pairs)
+# keep its 128-row body at four unrolled heads; a wider row walks its
+# groups one after another inside the grid step
 _GROUP_LANES = 512
-# the same for a call of ONE row a slot, where every tile walks narrow
-# (``_NARROW_POSITIONS``): a pool row of up to 2,048 lanes is one group,
-# so a block leaves HBM as one contiguous copy a stream. At the cross
-# layers' call (10 paired lane tiles, 96 one-row slots at ~1.85k
-# positions) five groups of 256 lanes — 16 strided 512-byte rows a
-# block copy — take 3.51 ms a call, the whole 1,280-lane row 1.75
+# the same for a tile of ONE window token, in every call: it walks
+# narrow (``_NARROW_POSITIONS``), and a pool row of up to 2,048 lanes is
+# one group, so a block leaves HBM as one contiguous copy a stream. At
+# the cross layers' call (10 paired lane tiles, 96 one-row slots at
+# ~1.85k positions) five groups of 256 lanes — 16 strided 512-byte rows
+# a block copy — take 3.51 ms a call, the whole 1,280-lane row 1.75
 # (chip run, the probe of ``_NARROW_POSITIONS``)
 _ONE_ROW_LANES = 2048
 # VMEM the ragged kernel asks Mosaic for (its default grant is 16 MB of
-# the v5e core's 128 MB): the narrow walk's K/V halves of a one-row
-# call's whole pool row are 10 MB at 1,280 lanes of bf16
+# the v5e core's 128 MB): the narrow walk's K/V halves of a whole pool
+# row are 10.5 MB at 1,280 lanes of bf16, beside a step's q and out
+# blocks of every kv head (327 KB each at that row, 128 rows a head)
 _VMEM_LIMIT = 48 << 20
 
 
@@ -510,18 +512,28 @@ def _ragged_tiles(xp, ql, context_lens, tq, kv_span, n_tiles, n_kv):
     return slot, row0, rows, kv
 
 
-def _head_group(num_kv_heads, head_lanes, one_row=False) -> int:
-    """kv heads (lane tiles of ``head_lanes``) one ragged grid step
-    takes: every one of the pool row while the group stays within
-    ``_GROUP_LANES`` (``_ONE_ROW_LANES`` in a call of ``one_row`` a
-    slot) — a pool block then leaves HBM as ONE contiguous copy — else
-    the largest divisor of ``num_kv_heads`` that does, and the step's
-    query tile is walked once a group."""
-    lanes = _ONE_ROW_LANES if one_row else _GROUP_LANES
+def _head_group(num_kv_heads, head_lanes, tokens) -> int:
+    """kv heads (lane tiles of ``head_lanes``) a tile of the rung of
+    ``tokens`` window tokens walks at once: every one of the pool row
+    while the group stays within ``_ONE_ROW_LANES`` for a one-token
+    tile (the narrow walk) and ``_GROUP_LANES`` for a tile of more —
+    a pool block then leaves HBM as ONE contiguous copy — else the
+    largest divisor of ``num_kv_heads`` that does, and the tile's
+    rows are walked once a group."""
+    lanes = _ONE_ROW_LANES if tokens == 1 else _GROUP_LANES
     hg = max(1, min(num_kv_heads, lanes // head_lanes))
     while num_kv_heads % hg:
         hg -= 1
     return hg
+
+
+def _rung_heads(num_kv_heads, head_lanes, rungs):
+    """The head group of each rung of ``rungs`` (``_head_group``): what
+    the ragged kernel walks at once, and so the copies it starts a pool
+    block a stream — ``num_kv_heads // heads`` — for the wrapper and
+    the host count alike."""
+    return tuple(_head_group(num_kv_heads, head_lanes, tokens)
+                 for tokens in rungs)
 
 
 def ragged_grid_units(q_lens, context_lens, *, rows, w_max, num_heads,
@@ -540,10 +552,12 @@ def ragged_grid_units(q_lens, context_lens, *, rows, w_max, num_heads,
     and ``live / units`` the share of the walk that is work. ``copies``
     is the async-copy descriptors the call issues, one a pool block a
     stream (K and V; an int8 pool's two scale pools make ``streams`` 4)
-    a head GROUP (``_head_group`` of ``head_lanes``-wide kv heads), not
-    one a kv head: ``kb`` blocks a live (query tile, kv tile) of the
-    full walk, and for a tile the ragged kernel walks narrow the blocks
-    its token's reach covers, none past it. ``narrow=True`` appends a
+    a head GROUP of the tile's rung (``_rung_heads`` of
+    ``head_lanes``-wide kv heads), not one a kv head: ``kb`` blocks a
+    live (query tile, kv tile) of the full walk, and for a tile the
+    ragged kernel walks narrow — in one group of the whole pool row
+    where it fits ``_ONE_ROW_LANES`` — the blocks its token's reach
+    covers, none past it. ``narrow=True`` appends a
     fourth value: the live units of tiles that hold one window token,
     which either kernel walks at its narrow rung (``_tile_rungs``)."""
     ql = np.minimum(np.asarray(q_lens, np.int64), w_max)
@@ -554,11 +568,13 @@ def ragged_grid_units(q_lens, context_lens, *, rows, w_max, num_heads,
         LATENT_TILE if latent else (_TILE_ROWS, _TILE_POSITIONS))
     slot, row0, held, kv = _ragged_tiles(np, ql, ctx, tq, kb * block_size,
                                          n_tiles, n_kv)
-    one = held <= _tile_rungs(tq)[0]
+    rungs = _tile_rungs(tq)
+    one = held <= rungs[0]
     walked = int(kv.sum())
     live = walked * num_kv_heads
-    groups = num_kv_heads // _head_group(num_kv_heads, head_lanes,
-                                         w_max == 1)
+    heads = _rung_heads(num_kv_heads, head_lanes, rungs)
+    groups = np.where(one, num_kv_heads // heads[0],
+                      num_kv_heads // heads[-1])
     blocks = kv * kb
     if not latent:
         reach = ctx[slot] + row0 + held - 1
@@ -566,7 +582,7 @@ def ragged_grid_units(q_lens, context_lens, *, rows, w_max, num_heads,
                           np.minimum(-(-reach // block_size), max_blocks),
                           blocks)
     counts = (live + int((kv == 0).sum()) * num_kv_heads, live,
-              int(blocks.sum()) * streams * groups)
+              int((blocks * groups).sum()) * streams)
     if not narrow:
         return counts
     return counts + (int(kv[one].sum()) * num_kv_heads,)
@@ -576,20 +592,21 @@ def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tlive_ref, tables_ref,
                    lens_ref, *args, scale, block_size, kv_blocks,
                    narrow_blocks, max_blocks, head_dim, heads, row_shift,
                    rungs, quantized=False, tree_bits=None):
-    """Ragged mixed-batch body: grid ``(query tile, head group)``. A
-    step holds ``tq`` consecutive window rows of ONE slot for the
-    ``heads`` kv heads of its group (``[heads, tq * rp, D]``, row ``r``
-    = window token ``trow_ref[t] + (r >> row_shift)``, causal bound
-    ``lens + that token``) and walks the slot's cache in kv tiles of
-    pool blocks, chased through ``tables_ref[slot]`` by
-    double-buffered async copies out of the HBM pools, to the tile's
-    own live reach — none for a tile past the live count (which stores
-    zeros). A pool block is copied ONCE a stream a kv tile, all the
-    group's heads side by side (where the group is the pool's whole
-    row, one contiguous block); the heads then run as a static loop
-    over the lane tiles of the copied buffer — ``heads`` independent
-    online-softmax chains an iteration, each with its own f32 state,
-    one mask.
+    """Ragged mixed-batch body: grid ``(query tile,)``. A step holds
+    ``tq`` consecutive window rows of ONE slot for every kv head
+    (``[H_kv, tq * rp, D]``, row ``r`` = window token ``trow_ref[t] +
+    (r >> row_shift)``, causal bound ``lens + that token``) and walks
+    the slot's cache in kv tiles of pool blocks, chased through
+    ``tables_ref[slot]`` by double-buffered async copies out of the HBM
+    pools, to the tile's own live reach — none for a tile past the
+    live count (which stores zeros). The walk takes a head GROUP at a
+    time, ``heads[i]`` kv heads at rung ``i`` (``_rung_heads``): a pool
+    block is copied ONCE a stream a kv tile for all the group's heads
+    side by side (where the group is the pool's whole row, one
+    contiguous block); the heads then run as a static loop over the
+    lane tiles of the copied buffer — independent online-softmax
+    chains an iteration, each with its own f32 state, one mask — and
+    the groups one after another, each over its own chain.
 
     A step computes over the rows its tile HOLDS, as
     ``_latent_kernel`` does: ``tlive_ref[t]`` is the tile's live
@@ -597,13 +614,16 @@ def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tlive_ref, tables_ref,
     compiled at (``_tile_rungs``). A tile of the whole rung walks
     ``tq * rp`` rows against kv tiles of ``kv_blocks`` blocks, exactly
     ``tkv_ref[t]`` of them (blocks past the table's end re-read its
-    last entry). A tile of ONE token — the narrow rung — walks its
-    ``rp`` rows against kv tiles of ``narrow_blocks`` blocks
-    (``_NARROW_POSITIONS``), copies only the blocks its token's reach
-    covers, waits for exactly those, and stores zeros in the rows of
-    its output block above ``rp``: a live row's scores, softmax and
-    weighted sum do not depend on the other rows of its tile. Both
-    rungs are the one body at another static geometry.
+    last entry), once a ``_GROUP_LANES`` group. A tile of ONE token —
+    the narrow rung — walks its ``rp`` rows against kv tiles of
+    ``narrow_blocks`` blocks (``_NARROW_POSITIONS``) in one group of
+    the whole pool row (up to ``_ONE_ROW_LANES``), copies only the
+    blocks its token's reach covers, waits for exactly those, and
+    stores zeros in the rows of its output block above ``rp``: a live
+    row's scores, softmax and weighted sum do not depend on the other
+    rows of its tile. Both rungs are the one body at another static
+    geometry; the whole rung's group reads the first lanes of the same
+    buffers.
 
     ``tree_bits`` (static ancestor bitmasks) adds a SEVENTH
     scalar-prefetch operand ``tree_ref`` [S]: slots flagged ``> 0``
@@ -611,8 +631,9 @@ def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tlive_ref, tables_ref,
     instead of the linear bound — unflagged slots (prefill chunks and
     their narrow trickle rows) keep the linear mask untouched.
     ``quantized``: the scale pools ride the same copies at the same
-    span, either rung (a block's scales once for all its heads), and
-    each K/V tile dequantizes in VMEM as in ``_decode_kernel``."""
+    span, either rung (a block's scales once a group for all its
+    heads), and each K/V tile dequantizes in VMEM as in
+    ``_decode_kernel``."""
     if tree_bits is not None:
         tree_ref, *args = args
     if quantized:
@@ -625,41 +646,53 @@ def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tlive_ref, tables_ref,
          m_scr, l_scr, acc_scr) = args
         streams = ((k_hbm, k_buf, True), (v_hbm, v_buf, True))
     t = pl.program_id(0)
-    g0 = pl.program_id(1) * heads             # the group's first kv head
     slot = tslot_ref[t]
     row0 = trow_ref[t]
     n_kv = tkv_ref[t]
     lens = lens_ref[slot]
     bs, d = block_size, head_dim
-    whole_rows = k_hbm.shape[2] == heads * d
+    hkv = q_ref.shape[1]
 
-    def copy(i, blk, buf):
-        """Start the copies of pool block ``blk`` into block ``i`` of
-        buffer half ``buf``, a stream each: the ``[BS, heads * D]``
-        lanes of the group (and, quantized, its ``[BS, H_kv]`` scale
-        block)."""
-        dst = pl.ds(pl.multiple_of(i * bs, bs), bs)
-        for n, (hbm, vmem, per_head) in enumerate(streams):
-            src = (hbm.at[blk, :, pl.ds(g0 * d, heads * d)]
-                   if per_head and not whole_rows else hbm.at[blk])
-            pltpu.make_async_copy(src, vmem.at[buf, dst, :],
-                                  sems.at[n, buf]).start()
-
-    def wait(buf, blocks):
-        """Wait for ``blocks`` (static) block copies into buffer half
-        ``buf``: a wait takes the semaphore and the destination's size
-        alone, so one descriptor a stream the size of those blocks
-        stands for them, and no table entry is read again."""
-        for n, (_, vmem, _) in enumerate(streams):
-            part = vmem.at[buf, pl.ds(0, blocks * bs)]
-            pltpu.make_async_copy(part, part, sems.at[n, buf]).wait()
-
-    def walk_rows(tokens):
-        """The whole step at the rung of ``tokens`` window tokens."""
+    def walk_group(tokens, hg, g0):
+        """The tile at the rung of ``tokens`` window tokens for the
+        ``hg`` kv heads from ``g0`` on."""
         rows = tokens << row_shift
         narrow = tokens == 1
         kb = narrow_blocks if narrow else kv_blocks
         span = kb * bs
+        # lanes the group's heads fill: the pool row's from g0 on, the
+        # buffers' from 0 on
+        src_lanes = None if hg == hkv else pl.ds(g0 * d, hg * d)
+        dst_lanes = (None if hg * d == k_buf.shape[2]
+                     else pl.ds(0, hg * d))
+
+        def copy(i, blk, buf):
+            """Start the copies of pool block ``blk`` into block ``i``
+            of buffer half ``buf``, a stream each: the ``[BS, hg * D]``
+            lanes of the group (and, quantized, its ``[BS, H_kv]``
+            scale block)."""
+            dst = pl.ds(pl.multiple_of(i * bs, bs), bs)
+            for n, (hbm, vmem, per_head) in enumerate(streams):
+                src = hbm.at[blk]
+                to = vmem.at[buf, dst]
+                if per_head and src_lanes is not None:
+                    src = hbm.at[blk, :, src_lanes]
+                if per_head and dst_lanes is not None:
+                    to = vmem.at[buf, dst, dst_lanes]
+                pltpu.make_async_copy(src, to, sems.at[n, buf]).start()
+
+        def wait(buf, blocks):
+            """Wait for ``blocks`` (static) block copies into buffer
+            half ``buf``: a wait takes the semaphore and the
+            destination's size alone, so one descriptor a stream the
+            size of those blocks stands for them, and no table entry
+            is read again."""
+            for n, (_, vmem, per_head) in enumerate(streams):
+                part = vmem.at[buf, pl.ds(0, blocks * bs)]
+                if per_head and dst_lanes is not None:
+                    part = vmem.at[buf, pl.ds(0, blocks * bs), dst_lanes]
+                pltpu.make_async_copy(part, part, sems.at[n, buf]).wait()
+
         if narrow:
             # the token sees lens + row0 positions: the blocks that hold
             # them, and kv tiles to cover those
@@ -723,11 +756,11 @@ def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tlive_ref, tables_ref,
 
                 jax.lax.fori_loop(got, kb, zero, 0)
 
-        m_scr[:, :rows] = jnp.full((heads, rows, m_scr.shape[2]), NEG_INF,
-                                   m_scr.dtype)
-        l_scr[:, :rows] = jnp.zeros((heads, rows, l_scr.shape[2]),
-                                    l_scr.dtype)
-        acc_scr[:, :rows] = jnp.zeros((heads, rows, d), acc_scr.dtype)
+        m_scr[:hg, :rows] = jnp.full((hg, rows, m_scr.shape[2]), NEG_INF,
+                                     m_scr.dtype)
+        l_scr[:hg, :rows] = jnp.zeros((hg, rows, l_scr.shape[2]),
+                                      l_scr.dtype)
+        acc_scr[:hg, :rows] = jnp.zeros((hg, rows, d), acc_scr.dtype)
 
         @pl.when(n_it > 0)
         def _first():
@@ -769,9 +802,9 @@ def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tlive_ref, tables_ref,
                 keep = (is_tree & ok_tree) | (
                     jnp.logical_not(is_tree) & linear)
             kv_rows = pl.ds(0, span)
-            for h in range(heads):
+            for h in range(hg):
                 lanes = pl.ds(h * d, d)
-                q = q_ref[0, h, :rows]                # [rows, D]
+                q = q_ref[0, g0 + h, :rows]           # [rows, D]
                 if quantized:
                     q = q.astype(jnp.float32)     # match the f32 dequant
                     k = _dequant_rows(k_buf[buf, kv_rows, lanes],
@@ -804,23 +837,37 @@ def _ragged_kernel(tslot_ref, trow_ref, tkv_ref, tlive_ref, tables_ref,
             return carry
 
         jax.lax.fori_loop(0, n_it, walk, 0)
-        l = l_scr[:, :rows, :1]
-        safe_l = jnp.where(l == 0.0, np.float32(1.0), l)
-        o_ref[0, :, :rows] = (acc_scr[:, :rows] / safe_l).astype(
-            o_ref.dtype)
         dead = o_ref.shape[2] - rows
-        if dead:
-            o_ref[0, :, rows:] = jnp.zeros((heads, dead, d), o_ref.dtype)
+        for h in range(hg):
+            l = l_scr[h, :rows, :1]
+            safe_l = jnp.where(l == 0.0, np.float32(1.0), l)
+            o_ref[0, g0 + h, :rows] = (acc_scr[h, :rows] / safe_l).astype(
+                o_ref.dtype)
+            if dead:
+                o_ref[0, g0 + h, rows:] = jnp.zeros((dead, d), o_ref.dtype)
+
+    def walk_rows(tokens, hg):
+        """The whole step at the rung of ``tokens`` window tokens, a
+        group of ``hg`` kv heads at a time."""
+        if hg == hkv:
+            walk_group(tokens, hg, 0)
+            return
+
+        def group(g, carry):
+            walk_group(tokens, hg, g * hg)
+            return carry
+
+        jax.lax.fori_loop(0, hkv // hg, group, 0)
 
     if len(rungs) == 1:
-        walk_rows(rungs[0])
+        walk_rows(rungs[0], heads[0])
         return
     # the smallest rung that covers the tile's live tokens; a tile past
     # the live count (0 tokens, no kv tiles) takes the first
     live = tlive_ref[t]
     rung = sum((live > tokens).astype(jnp.int32) for tokens in rungs[:-1])
     for i, tokens in enumerate(rungs):
-        pl.when(rung == i)(functools.partial(walk_rows, tokens))
+        pl.when(rung == i)(functools.partial(walk_rows, tokens, heads[i]))
 
 
 def flat_pool_tile(head_dim) -> int:
@@ -1039,42 +1086,45 @@ def pallas_ragged_paged_attention(q, k_pool, v_pool, block_tables,
     # anyway — a minor dim under 128 lanes is stored padded to them)
     scales = [jnp.pad(sc, ((0, 0), (0, 0), (0, -hkv % 128)))
               for sc in scales]
-    hg = _head_group(hkv, d, w == 1)
+    # a call of one row a slot holds no tile of more than one token
+    rungs = _tile_rungs(tq) if w > 1 else (1,)
+    heads = _rung_heads(hkv, d, rungs)
     nkb = _narrow_blocks(bs, mb)
     kernel = functools.partial(
         _ragged_kernel, scale=scale, block_size=bs, kv_blocks=kb,
-        narrow_blocks=nkb, max_blocks=mb, head_dim=d, heads=hg,
-        row_shift=rp.bit_length() - 1,
-        # a call of one row a slot holds no tile of more than one token
-        rungs=_tile_rungs(tq) if w > 1 else (1,), quantized=quant,
+        narrow_blocks=nkb, max_blocks=mb, head_dim=d, heads=heads,
+        row_shift=rp.bit_length() - 1, rungs=rungs, quantized=quant,
         tree_bits=tree_bits)
     span = max(kb, nkb) * bs                  # positions a buffer half
+    # the widest group's lanes (the narrow rung's: the whole row where
+    # it fits), and softmax state for it at the tile's full height
+    hmax = max(heads)
 
-    def q_block(t, g, *prefetch):
-        return (t, g, 0, 0)
+    def q_block(t, *prefetch):
+        return (t, 0, 0, 0)
 
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     n_streams = 2 + len(scales)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6 + len(tree_args),
-        grid=(n_tiles, hkv // hg),
-        in_specs=[pl.BlockSpec((1, hg, rows, d), q_block)]
+        grid=(n_tiles,),
+        in_specs=[pl.BlockSpec((1, hkv, rows, d), q_block)]
         + [hbm] * n_streams,
-        out_specs=pl.BlockSpec((1, hg, rows, d), q_block),
-        scratch_shapes=[pltpu.VMEM((2, span, hg * d), kd.dtype)] * 2
+        out_specs=pl.BlockSpec((1, hkv, rows, d), q_block),
+        scratch_shapes=[pltpu.VMEM((2, span, hmax * d), kd.dtype)] * 2
         + [pltpu.VMEM((2, span, sc.shape[2]), jnp.float32)
            for sc in scales]
         + [pltpu.SemaphoreType.DMA((n_streams, 2))]
-        + _softmax_scratch(rows, d, (hg,)),
+        + _softmax_scratch(rows, d, (hmax,)),
     )
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_tiles, hkv, rows, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            # no state crosses a step: every (tile, head group) starts
-            # and waits its own copies and writes its own output block
-            dimension_semantics=("parallel", "parallel"),
+            # no state crosses a step: every query tile starts and
+            # waits its own copies and writes its own output block
+            dimension_semantics=("parallel",),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret() if interpret is None else interpret,
     )

@@ -290,15 +290,22 @@ def _narrow_walk_copies(table, seen, bs, nkb):
     (dict(rows=96, w_max=1, num_heads=40, num_kv_heads=10),
      [1] * 95 + [0], [1, 15, 16, 17, 511, 512, 513, 1541] * 11 + [900] * 7
      + [0]),
-], ids=["qwen2", "phi4_cross"])
+    # the full layer's call: the same pool and heads, one-row slots at
+    # the narrow walk's edges beside a 256-row chunk and an empty slot;
+    # a one-token tile walks the whole row in one group, a chunk tile in
+    # five groups of two lane tiles
+    (dict(rows=352, w_max=256, num_heads=40, num_kv_heads=10),
+     [256] + [1] * 94 + [0], [300] + [1, 15, 16, 17, 1023, 1024, 1025,
+                                     1000] * 11 + [900] * 6 + [0]),
+], ids=["qwen2", "phi4_cross", "phi4_full"])
 def test_ragged_narrow_count_is_the_kernels_own_choice(geo, q_lens, ctx):
     """The paged geometry's ``ragged_grid_units(..., narrow=True)``:
     ``attn_narrow`` is the live units of the tiles the wrapper's own
     ``_ragged_tiles`` call hands the kernel with one live token (the
     rung ``_tile_rungs`` chooses), and ``attn_copies`` the block copies
     the narrow walk starts — the blocks of the token's reach, none past
-    it — beside whole kv tiles for every other tile, a stream a head
-    group."""
+    it, once for the whole pool row — beside whole kv tiles for every
+    other tile once a ``_GROUP_LANES`` head group, a stream each."""
     geo = dict(geo, q_dtype=jnp.bfloat16, block_size=16, max_blocks=64)
     q_lens, ctx = np.asarray(q_lens), np.asarray(ctx)
     units, live, copies, narrow = pa.ragged_grid_units(
@@ -316,7 +323,7 @@ def test_ragged_narrow_count_is_the_kernels_own_choice(geo, q_lens, ctx):
     assert narrow == int(kv[one].sum()) * hkv
     table = list(range(mb))
     nkb = pa._narrow_blocks(bs, mb)
-    blocks = 0
+    narrow_blocks = whole_blocks = 0
     for s_, r0, h_, k_ in zip(slot, row0, held, kv):
         if not h_:
             continue
@@ -324,17 +331,39 @@ def test_ragged_narrow_count_is_the_kernels_own_choice(geo, q_lens, ctx):
             seen = int(ctx[s_] + r0)
             walked = _narrow_walk_copies(table, seen, bs, nkb)
             assert max(walked) == min(-(-seen // bs), mb) - 1  # its reach
-            blocks += len(walked)
+            narrow_blocks += len(walked)
         else:
-            blocks += int(k_) * kb
-    one_row = geo["w_max"] == 1
-    groups = hkv // pa._head_group(hkv, 128, one_row)
-    assert copies == blocks * 2 * groups
-    # a one-row call: every tile narrow, the whole pool row one group
-    # (5 groups of 2 lane tiles in a call that may hold a chunk)
-    assert groups == (1 if one_row else hkv // 4)
-    if one_row:
-        assert live == narrow and hkv // pa._head_group(hkv, 128) == 5
+            whole_blocks += int(k_) * kb
+    rungs = pa._tile_rungs(tq) if geo["w_max"] > 1 else (1,)
+    heads = pa._rung_heads(hkv, 128, rungs)
+    groups = (hkv // heads[0], hkv // heads[-1])
+    assert copies == (narrow_blocks * groups[0]
+                      + whole_blocks * groups[1]) * 2
+    # a one-token tile walks the whole pool row as one group; a tile of
+    # more takes 2 lane tiles a group of the 10-tile row (5 groups)
+    assert groups[0] == 1
+    if geo["w_max"] == 1:
+        assert live == narrow and not whole_blocks
+    else:
+        assert groups[1] == {4: 1, 10: 5}[hkv] and whole_blocks
+
+
+def test_full_layer_count_keeps_its_units_and_cuts_the_narrow_copies():
+    """The ``tick`` span's counts for the full layer's call (the
+    ``phi4_full`` mix above): ``attn_units``, ``attn_live`` and
+    ``attn_narrow`` — the inputs of ``ragged_grid_live_share`` — are
+    the values the call gave while its group was chosen once a call,
+    and ``attn_copies`` loses four fifths of the one-token tiles' part:
+    their 3,202 blocks a stream leave HBM once as the whole row where
+    five 256-lane groups took them five times (41,940 copies then)."""
+    geo = dict(rows=352, w_max=256, num_heads=40, num_kv_heads=10,
+               q_dtype=jnp.bfloat16, block_size=16, max_blocks=64)
+    q_lens = [256] + [1] * 94 + [0]
+    ctx = [300] + [1, 15, 16, 17, 1023, 1024, 1025, 1000] * 11 + [900] * 6
+    units, live, copies, narrow = pa.ragged_grid_units(
+        np.asarray(q_lens), np.asarray(ctx + [0]), narrow=True, **geo)
+    assert (units, live, narrow) == (5820, 5680, 4440)
+    assert copies == 41940 - 2 * (5 - 1) * 3202 == 16324
 
 
 def test_latent_grid_units_follow_the_kernels_tiles():

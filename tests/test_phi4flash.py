@@ -386,21 +386,26 @@ def test_ring_write_keeps_each_slot_s_last_w_positions():
 _N = pa._NARROW_POSITIONS
 
 
-@pytest.mark.parametrize("q_lens,base,w_max", [
-    ([1, 20, 1], [30, 7, 0], 20),
+@pytest.mark.parametrize("q_lens,base,w_max,hkv", [
+    ([1, 20, 1], [30, 7, 0], 20, 4),
     # the cross layers' call: one row a slot, every tile walked narrow,
     # the token seeing 1, 16, 17, N, N + 1 and 3N + 5 positions
-    ([1, 1, 1, 1, 1, 1, 0], [0, 15, 16, _N - 1, _N, 3 * _N + 4, 0], 1),
-], ids=["chunk", "cross_narrow"])
+    ([1, 1, 1, 1, 1, 1, 0], [0, 15, 16, _N - 1, _N, 3 * _N + 4, 0], 1, 4),
+    # the full layer's call at its heads (20 kv heads of 64: 10 pairs)
+    # and its w_max: one-token tiles, walked as the whole row, beside a
+    # chunk tile, walked in five groups of two pairs
+    ([1, 20, 1, 0, 1], [30, 7, 0, 0, _N], 256, 20),
+], ids=["chunk", "cross_narrow", "full_layer"])
 def test_differential_mode_of_the_ragged_kernel_is_two_softmaxes(
-        q_lens, base, w_max):
-    """Paired queries over a flat pool of head-64 kv heads (two pairs):
-    the ragged kernel, all 128 output lanes kept, gives each query its
-    own kv head's softmax against the pair's two value heads — beside a
-    chunk, and in the cross layers' one-row-a-slot call."""
+        q_lens, base, w_max, hkv):
+    """Paired queries over a flat pool of head-64 kv heads (two pairs,
+    or the full layer's ten): the ragged kernel, all 128 output lanes
+    kept, gives each query its own kv head's softmax against the pair's
+    two value heads — beside a chunk, in the cross layers'
+    one-row-a-slot call, and in the full layer's call."""
     rng = np.random.RandomState(2)
     q_lens, base = np.array(q_lens), np.array(base)
-    slots, bs, h, hkv, d = len(q_lens), 16, 8, 4, 64
+    slots, bs, h, d = len(q_lens), 16, 2 * hkv, 64
     mb = -(-int((base + q_lens).max()) // bs)
     r = -(-int(q_lens.sum()) // 8) * 8 + 8
     row_slot, row_pos, row_starts, _ = pc.ragged_row_meta(q_lens, base, r,

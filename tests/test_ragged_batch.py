@@ -325,39 +325,48 @@ def _narrow_mix(n, bs):
 
 
 _HEAD_GROUP_CASES = [
-    pytest.param(pool, tiles, hg, None, id=f"{tid}-{pool}")
+    pytest.param(pool, tiles, hg, None, False, id=f"{tid}-{pool}")
     for tiles, hg, tid in [(1, 1, "hg1"), (2, 2, "hg2"), (4, 4, "hg4"),
                            (8, 4, "hg4_two_groups")]
     for pool in ["bf16_4d", "flat_d64", "int8", "tree"]] + [
     # the narrow walk at the cells' geometries: Qwen2 (4 x 128, 7 query
-    # heads a kv head), Solar (8 x 128: two groups), LFM2 (8 x 64 in 4
-    # pairs), the Phi-4 cross call (10 lane tiles of paired heads, one
-    # row a slot: every tile walks narrow, the whole pool row one head
-    # group), an int8 pool
-    pytest.param("bf16_4d", 4, 4, 7, id="narrow-qwen2"),
-    pytest.param("bf16_4d", 8, 4, 8, id="narrow-solar"),
-    pytest.param("flat_d64", 4, 4, 4, id="narrow-lfm2"),
-    pytest.param("flat_d128", 10, 2, 4, id="narrow-phi4_cross"),
-    pytest.param("int8", 4, 4, 7, id="narrow-int8")]
+    # heads a kv head), Solar (8 x 128: a chunk tile in two groups, a
+    # one-token tile in one), LFM2 (8 x 64 in 4 pairs), the Phi-4 cross
+    # call (10 lane tiles of paired heads, one row a slot: every tile
+    # walks narrow, the whole pool row one head group), an int8 pool
+    pytest.param("bf16_4d", 4, 4, 7, False, id="narrow-qwen2"),
+    pytest.param("bf16_4d", 8, 4, 8, False, id="narrow-solar"),
+    pytest.param("flat_d64", 4, 4, 4, False, id="narrow-lfm2"),
+    pytest.param("flat_d128", 10, 2, 4, True, id="narrow-phi4_cross"),
+    pytest.param("int8", 4, 4, 7, False, id="narrow-int8"),
+    # one-token tiles beside a chunk tile in a call wider than
+    # ``_GROUP_LANES``: the Phi-4 full layer (10 paired lane tiles, the
+    # chunk in 5 groups of 2, a one-token tile in one of 10), Solar's
+    # 8 x 128 over a flat row, an int8 pool of 8 heads
+    pytest.param("flat_d128", 10, 2, 4, False, id="narrow-phi4_full"),
+    pytest.param("flat_d128", 8, 4, 8, False, id="narrow-solar_flat"),
+    pytest.param("int8", 8, 4, 8, False, id="narrow-int8_two_groups")]
 
 
-@pytest.mark.parametrize("pool,tiles,hg,rep", _HEAD_GROUP_CASES)
+@pytest.mark.parametrize("pool,tiles,hg,rep,one_row", _HEAD_GROUP_CASES)
 def test_ragged_kernel_head_groups_match_fallback_interpret(pool, tiles,
-                                                            hg, rep):
-    """A grid step takes ``hg`` kv heads (128-lane tiles of the pool
-    row) — one (the TP shard's kernel), all of a narrow row, or a
-    capped group of a row wider than ``_GROUP_LANES`` — and every live
-    row agrees with the gather fallback whatever the pool: 4-D bf16,
-    flat with head size 64 in pairs, int8 with its scale pools, f32
-    under the tree mask. The ``narrow`` cases (``rep`` query heads a kv
-    head) put one-token tiles at the narrow walk's edges
+                                                            hg, rep,
+                                                            one_row):
+    """A tile of more than one window token walks ``hg`` kv heads
+    (128-lane tiles of the pool row) at a time — one (the TP shard's
+    kernel), all of a narrow row, or a capped group of a row wider
+    than ``_GROUP_LANES`` — and a one-token tile the whole row at once;
+    every live row agrees with the gather fallback whatever the pool:
+    4-D bf16, flat with head size 64 in pairs, int8 with its scale
+    pools, f32 under the tree mask. The ``narrow`` cases (``rep`` query
+    heads a kv head) put one-token tiles at the narrow walk's edges
     (``_narrow_mix``) and fill every pool block past a one-token
     slot's reach with NaN in the kernel's pools: a walk that copied
     one, or kept a stale buffer row past the reach, would read NaN."""
     import jax.numpy as jnp
     from paddle_tpu.ops import paged_cache as pc
     from paddle_tpu.ops.pallas import paged_attention as pa
-    assert pa._head_group(tiles, 128) == hg
+    assert pa._rung_heads(tiles, 128, (1, 8)) == (tiles, hg)
     flat, quant = pool.startswith("flat"), pool == "int8"
     dt = jnp.float32 if pool in ("int8", "tree") else jnp.bfloat16
     D = 64 if pool == "flat_d64" else 128
@@ -369,7 +378,7 @@ def test_ragged_kernel_head_groups_match_fallback_interpret(pool, tiles,
     else:
         q_lens, base, MB = _narrow_mix(pa._NARROW_POSITIONS, BS)
         q_lens, base = np.asarray(q_lens), np.asarray(base)
-        if pool == "flat_d128":           # one row a slot
+        if one_row:                       # one row a slot
             q_lens = np.minimum(q_lens, 1)
         H, W, wn, tree = rep * Hkv, int(q_lens.max()), 1, None
     S = len(q_lens)
@@ -426,17 +435,18 @@ def _direct_grid_count(q_lens, ctx, tq, span, heads, n_tiles):
     return (live + (n_tiles - tiles)) * heads, live * heads
 
 
-def _direct_blocks(q_lens, ctx, tq, span, bs, mb):
-    """Pool blocks one head group's walk copies a stream, tile by tile:
-    a tile of one window token the blocks its reach covers (the narrow
-    walk), any other its kv tiles whole."""
+def _direct_blocks(q_lens, ctx, tq, span, bs, mb, groups=(1, 1)):
+    """Pool blocks the walk copies a stream, tile by tile: a tile of one
+    window token the blocks its reach covers (the narrow walk) once for
+    each of ``groups[0]`` head groups, any other its kv tiles whole for
+    each of ``groups[1]``."""
     blocks = 0
     for n, c in zip(q_lens, ctx):
         for row0 in range(0, int(n), tq):
             last = min(row0 + tq, int(n)) - 1
             seen = int(c) + last
-            blocks += (min(-(-seen // bs), mb) if last == row0
-                       else -(-seen // span) * (span // bs))
+            blocks += (min(-(-seen // bs), mb) * groups[0] if last == row0
+                       else -(-seen // span) * (span // bs) * groups[1])
     return blocks
 
 
@@ -455,9 +465,10 @@ def test_grid_units_keep_their_rule_and_count_the_copies(
     chunk, dead slots): ``(units, live)`` stay the (query tile, kv
     head, kv tile) count the kernel had with one head a grid step — a
     slot-by-slot walk — and ``copies`` is a descriptor a pool block a
-    stream a HEAD GROUP: every block of a live (query tile, kv tile),
-    and of a one-token tile's narrow walk the blocks its reach covers,
-    none past it."""
+    stream a HEAD GROUP of the tile's rung: every block of a live
+    (query tile, kv tile) once a ``groups`` group, and of a one-token
+    tile's narrow walk the blocks its reach covers, none past it, in
+    one group of the whole row."""
     from paddle_tpu.ops.pallas import paged_attention as pa
     import jax.numpy as jnp
     q_lens = [1, 1, 0, 40, 1, 0, 3, 1]
@@ -469,12 +480,15 @@ def test_grid_units_keep_their_rule_and_count_the_copies(
         q_lens, ctx, head_lanes=lanes, streams=streams, **geo)
     assert (units, live) == _direct_grid_count(q_lens, ctx, tq, span, hkv,
                                                n_tiles)
-    blocks = _direct_blocks(q_lens, ctx, tq, span, bs, 1024 // bs)
-    assert copies == blocks * streams * groups
-    # a descriptor a kv head, the kernel before: ``hg`` times as many
-    assert copies * pa._head_group(hkv, lanes) == blocks * streams * hkv
+    narrow = _direct_blocks(q_lens, ctx, tq, span, bs, 1024 // bs, (1, 0))
+    whole = _direct_blocks(q_lens, ctx, tq, span, bs, 1024 // bs, (0, 1))
+    hn, hw = pa._rung_heads(hkv, lanes, pa._tile_rungs(tq))
+    assert (hkv // hn, hkv // hw) == (1, groups)
+    assert copies == (narrow + whole * groups) * streams
+    # a descriptor a kv head, the kernel before: a group's heads as many
+    assert narrow * hn + whole * groups * hw == (narrow + whole) * hkv
     # the decode rows' walks stop at their reach: under whole kv tiles
-    assert blocks < live // hkv * (span // bs)
+    assert narrow + whole < live // hkv * (span // bs)
 
 
 def test_tick_span_counts_the_attention_grid(llama_tiny):

@@ -238,16 +238,25 @@ _CROSS = dict(s=96, r=96, w=1, rep=4, d=128, reach=8192)
 # a 256-row chunk, 64 query heads over 8 kv heads of 128 in two head
 # groups, a 6144-token table
 _SOLAR = dict(s=96, r=352, w=256, rep=8, d=128, reach=6144)
+# reason-long-sat's full layer's call (layer 17): the cross call's pool
+# and heads, 95 one-row slots beside a 256-row chunk
+_FULL = dict(s=96, r=352, w=256, rep=4, d=128, reach=8192)
 
 
-@pytest.mark.parametrize("hkv,c,flat", [(10, _CROSS, True),
-                                        (8, _SOLAR, False)],
-                         ids=["phi4_cross", "solar_gqa"])
-def test_ragged_narrow_walk_compiles(hkv, c, flat):
-    """The two shapes the narrow walk was measured at compile to Mosaic
-    with it: the cross call (one rung, every tile narrow, 5 head groups)
-    and the Solar call (both rungs in one body, 2 head groups)."""
-    fn, args = _serving_ragged_call(hkv, False, False, c, flat=flat)
+@pytest.mark.parametrize("hkv,c,flat,quant", [
+    (10, _CROSS, True, False), (8, _SOLAR, False, False),
+    (10, _FULL, True, False), (8, _SOLAR, False, True)],
+    ids=["phi4_cross", "solar_gqa", "phi4_full", "solar_gqa_int8"])
+def test_ragged_narrow_walk_compiles(hkv, c, flat, quant):
+    """The shapes the narrow walk was measured at compile to Mosaic
+    with it, inside ``_VMEM_LIMIT``: the cross call (one rung, every
+    tile narrow, the whole pool row one head group), and the Solar and
+    full-layer calls (both rungs in one body: a one-token tile walks
+    the whole row, a chunk tile 2 and 5 groups of ``_GROUP_LANES``),
+    Solar's over an int8 pool too."""
+    hn, hw = pa._rung_heads(hkv, c["d"], (1, 2))
+    assert hn == hkv and hkv // hw == {8: 2, 10: 5}[hkv]
+    fn, args = _serving_ragged_call(hkv, quant, False, c, flat=flat)
     assert _lowers_to_mosaic(fn, *args)
 
 
@@ -265,34 +274,36 @@ def _launched_grid(fn, args):
 
 @pytest.mark.parametrize("hkv,groups", [(4, 1), (1, 1), (8, 2), (32, 8)])
 def test_ragged_launch_stays_far_below_the_old_grid(hkv, groups):
-    """One call at the default config launches ``(query tile, head
-    group)`` grid steps — a step takes every kv head of its slot's
-    tile while they fit ``_GROUP_LANES``, so ``hkv`` heads are
-    ``groups`` steps a tile — of at most ``n_kv`` loop iterations
-    each: under 1/64 of the ``slot x window_row x kv_head x block``
-    walk (262,144 steps at 4 kv heads) this kernel replaced, so that
-    walk cannot grow back unnoticed."""
+    """One call at the default config launches a grid step a query
+    tile, which takes every kv head of its slot's tile — a tile of more
+    than one token in ``groups`` walks while the row is wider than
+    ``_GROUP_LANES``, a one-token tile in one while it fits
+    ``_ONE_ROW_LANES`` — of at most ``n_kv`` loop iterations each:
+    under 1/64 of the ``slot x window_row x kv_head x block`` walk
+    (262,144 steps at 4 kv heads) this kernel replaced, so that walk
+    cannot grow back unnoticed."""
     c = _SERVING
     bs = 16
     grid = _launched_grid(*_serving_ragged_call(hkv, False, False))
     mb = c["reach"] // bs
     _, tq, kb, n_tiles, n_kv = pa._ragged_geometry(
         c["r"], c["s"], c["rep"], jnp.bfloat16, bs, mb)
-    assert pa._head_group(hkv, c["d"]) == hkv // groups
-    assert grid == (n_tiles, groups) == (c["s"] + -(-c["r"] // tq),
-                                         groups)
+    narrow, whole = pa._rung_heads(hkv, c["d"], pa._tile_rungs(tq))
+    assert (hkv // narrow, hkv // whole) == (max(1, hkv // 16), groups)
+    assert grid == (n_tiles,) == (c["s"] + -(-c["r"] // tq),)
     assert (tq, kb * bs, n_kv) == (8, 128, 8)
-    assert int(np.prod(grid)) * n_kv < c["s"] * c["w"] * hkv * mb // 64
+    assert n_tiles * groups * n_kv < c["s"] * c["w"] * hkv * mb // 64
 
 
 def test_ragged_wide_cell_launches_one_step_a_query_tile():
     """chat-wide-sat's call is 176 grid steps (128 slots + 384 / 8
-    query tiles, one head group of the 4 paired lane tiles), where a
-    step a kv head made 704."""
+    query tiles, one head group of the 4 paired lane tiles at either
+    rung), where a step a kv head made 704."""
     c = _WIDE
     grid = _launched_grid(*_serving_ragged_call(8, False, False, c,
                                                 flat=True))
-    assert grid == (c["s"] + c["r"] // 8, 1) == (176, 1)
+    assert grid == (c["s"] + c["r"] // 8,) == (176,)
+    assert pa._rung_heads(4, 128, (1, 8)) == (4, 4)
 
 
 def test_paged_eligibility_wants_whole_sublane_tiles():
